@@ -11,14 +11,20 @@ no C++ toolchain exists.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import shutil
 import subprocess
 import threading
 
+logger = logging.getLogger("paddle_tpu.native")
+
 _lock = threading.Lock()
 _lib = None
 _tried = False
+# how the current process got (or did not get) the library; see
+# native_status()
+_status = "not loaded yet"
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "csrc")
 _SO = os.path.join(_CSRC, "build", "libpaddle_tpu_native.so")
@@ -36,16 +42,22 @@ def _stale() -> bool:
         for f in os.listdir(_CSRC))
 
 
-def _build() -> bool:
-    if not os.path.isdir(_CSRC) or shutil.which("make") is None:
-        return False
+def _build():
+    """Run the csrc/ Makefile; returns None on success, else why not."""
+    if not os.path.isdir(_CSRC):
+        return f"no source directory {_CSRC}"
+    if shutil.which("make") is None:
+        return "no `make` on PATH"
     try:
         subprocess.run(
             ["make", "-C", _CSRC, f"-j{os.cpu_count() or 2}"],
             check=True, capture_output=True, timeout=300)
-        return os.path.exists(_SO)
-    except (subprocess.SubprocessError, OSError):
-        return False
+    except subprocess.CalledProcessError as e:
+        tail = e.stderr.decode(errors="replace").strip().splitlines()[-1:]
+        return f"make failed (rc {e.returncode}): {' '.join(tail)}"
+    except (subprocess.SubprocessError, OSError) as e:
+        return f"make failed: {e}"
+    return None if os.path.exists(_SO) else f"make produced no {_SO}"
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -117,7 +129,7 @@ def get_native():
     """Return the loaded CDLL, building it if needed; None if unavailable.
 
     Disable with PADDLE_TPU_DISABLE_NATIVE=1 (forces Python fallbacks)."""
-    global _lib, _tried
+    global _lib, _tried, _status
     if _lib is not None:
         return _lib
     with _lock:
@@ -125,19 +137,39 @@ def get_native():
             return _lib
         _tried = True
         if os.environ.get("PADDLE_TPU_DISABLE_NATIVE", "0") == "1":
+            _status = "python fallback: PADDLE_TPU_DISABLE_NATIVE=1"
             return None
-        if _stale() and not _build() and not os.path.exists(_SO):
-            return None
+        how = "loaded csrc/build (newer than every source)"
+        if _stale():
+            err = _build()
+            if err is None:
+                how = "built from csrc/ by this process"
+            elif os.path.exists(_SO):
+                how = f"loaded STALE csrc/build (rebuild failed: {err})"
+            else:
+                _status = f"python fallback: {err}"
+                logger.warning("native runtime unavailable, %s", _status)
+                return None
         try:
             lib = ctypes.CDLL(_SO)
             _declare(lib)
             _lib = lib
-        except (OSError, AttributeError):
+            _status = how
+        except (OSError, AttributeError) as e:
             # AttributeError: stale .so missing newer symbols and the
             # rebuild failed — use the pure-Python fallbacks instead
-            _lib = None
+            _status = f"python fallback: cannot load {_SO}: {e}"
+            logger.warning("native runtime unavailable, %s", _status)
     return _lib
 
 
 def native_available() -> bool:
     return get_native() is not None
+
+
+def native_status() -> str:
+    """Which runtime substrate this process runs on, and why: the
+    loaded csrc/build library, one built just now from csrc/, or the
+    pure-Python fallbacks with the reason the library is missing."""
+    get_native()
+    return _status

@@ -160,25 +160,6 @@ def _axis(group: Optional[Group]):
     return None
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: the top-level alias (and its
-    ``check_vma`` kwarg) only exist on newer jax; older releases ship
-    ``jax.experimental.shard_map.shard_map`` with the ``check_rep``
-    spelling. The seed pinned the new alias, which broke every spmd
-    test on the baked-in toolchain's jax."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:  # newer alias, older kwarg set
-            pass
-    from jax.experimental.shard_map import shard_map as _esm
-
-    return _esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma)
-
-
 def spmd(fn: Callable, mesh, in_specs=None, out_specs=None, check_vma=False):
     """Run ``fn`` as a per-rank program over ``mesh`` (the TPU-native
     equivalent of launching one process per rank). ``fn`` receives Tensors
@@ -227,8 +208,8 @@ def spmd(fn: Callable, mesh, in_specs=None, out_specs=None, check_vma=False):
             finally:
                 stack.pop()
 
-        sm = shard_map_compat(inner, mesh=jmesh, in_specs=spec_in,
-                              out_specs=spec_out, check_vma=check_vma)
+        sm = jax.shard_map(inner, mesh=jmesh, in_specs=spec_in,
+                           out_specs=spec_out, check_vma=check_vma)
         from ..ops.dispatch import apply_op
 
         outs = apply_op(f"spmd:{getattr(fn, '__name__', 'program')}", sm, *tensor_args)

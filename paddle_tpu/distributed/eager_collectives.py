@@ -144,8 +144,6 @@ def _compiled(kind: str, shape, dtype, extra):
         # process_group.h send:129/recv:139 / pp_utils
         # p2p_communication.py:576 _p2p_helper).
         shift, block = extra if isinstance(extra, tuple) else (extra, None)
-        from jax.experimental.shard_map import shard_map
-
         perm = [(i, i + shift) for i in range(W)
                 if 0 <= i + shift < W
                 and (block is None or i // block == (i + shift) // block)]
@@ -153,8 +151,8 @@ def _compiled(kind: str, shape, dtype, extra):
         def body(local):  # [1, *shape] — this process's row
             return jax.lax.ppermute(local, "world", perm)
 
-        f = shard_map(body, mesh=mesh, in_specs=P("world"),
-                      out_specs=P("world"))
+        f = jax.shard_map(body, mesh=mesh, in_specs=P("world"),
+                          out_specs=P("world"))
         return jax.jit(f, out_shardings=NamedSharding(mesh, P("world")))
     if kind == "scatter":
         src, axis = extra

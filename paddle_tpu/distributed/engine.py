@@ -266,11 +266,8 @@ class ShardedTrainStep:
     def eval_step(self, inputs, labels=None):
         raise NotImplementedError("use to_static on the model for eval; engine.step is the train path")
 
-    def _aot_compiled(self, inputs, labels):
-        """AOT-compile the step from avals (no device allocation) for the
-        XLA analyses below. Does not share jit's dispatch cache, so each
-        call costs one extra compile — callers wanting both analyses
-        should reuse the returned object."""
+    def _lowered(self, inputs, labels):
+        """Lower the step from avals (no device allocation)."""
         in_datas, lab_datas = self._stage_batch(inputs, labels)
 
         def aval(x):
@@ -281,7 +278,20 @@ class ShardedTrainStep:
         return self._step_fn.lower(
             jax.tree.map(aval, self.params), jax.tree.map(aval, self.opt_state),
             lr, jax.tree.map(aval, in_datas), jax.tree.map(aval, lab_datas),
-        ).compile()
+        )
+
+    def lowered_text(self, inputs, labels) -> str:
+        """StableHLO text of the train step as jit lowers it for this
+        backend — which kernels the step really contains (a Pallas
+        kernel shows as a ``tpu_custom_call``). Traces, never compiles."""
+        return self._lowered(inputs, labels).as_text()
+
+    def _aot_compiled(self, inputs, labels):
+        """AOT-compile the step for the XLA analyses below. Does not
+        share jit's dispatch cache, so each call costs one extra compile
+        — callers wanting both analyses should reuse the returned
+        object."""
+        return self._lowered(inputs, labels).compile()
 
     def memory_analysis(self, inputs, labels):
         """XLA's compiled-program HBM breakdown for the train step (device

@@ -89,7 +89,8 @@ class Partial(Placement):
 class ProcessMesh:
     """Parity: paddle.distributed.ProcessMesh(mesh, dim_names).
 
-    Backed by jax.sharding.Mesh over the PJRT devices with matching ids.
+    Backed by jax.sharding.Mesh over the PJRT devices the slots name
+    (by id, or by rank in id order).
     """
 
     def __init__(self, mesh, dim_names: Optional[Sequence[str]] = None, shape=None, process_ids=None):
@@ -99,14 +100,23 @@ class ProcessMesh:
         self._shape = tuple(arr.shape)
         self._dim_names = tuple(dim_names)
         self._process_ids = arr
-        devices = jax.devices()
-        dev_by_id = {d.id: d for d in devices}
-        try:
-            dev_arr = np.vectorize(lambda i: dev_by_id[int(i)])(arr)
-        except KeyError:
-            # Fewer physical devices than mesh slots (authoring on 1 chip):
-            # map ids modulo device count so shardings still construct.
-            dev_arr = np.vectorize(lambda i: devices[int(i) % len(devices)])(arr)
+        # slot values are device ids where every one of them is (one
+        # process, a TPU slice, create_hybrid_mesh), else ranks into the
+        # id-ordered device list (fleet topologies: multi-process CPU
+        # device ids run 0, 2048, ..)
+        devices = sorted(jax.devices(), key=lambda d: d.id)
+        by_id = {d.id: d for d in devices}
+        slots = {int(i) for i in arr.reshape(-1)}
+        if slots <= set(by_id):
+            pick = by_id.__getitem__
+        elif all(0 <= i < len(devices) for i in slots):
+            pick = devices.__getitem__
+        else:
+            raise ValueError(
+                f"ProcessMesh slots {sorted(slots)} are neither device ids "
+                f"({sorted(by_id)}) nor ranks below {len(devices)}: a mesh "
+                f"slot never aliases another slot's device")
+        dev_arr = np.vectorize(lambda i: pick(int(i)))(arr)
         self._jax_mesh = Mesh(dev_arr, axis_names=self._dim_names)
 
     @property

@@ -31,19 +31,7 @@ from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax.memory import Space
-except ImportError:  # older jax: no jax.memory module. The in-jit
-    # device_put targets below accept TransferToMemoryKind with the same
-    # semantics ("device" / "pinned_host" memory kinds); expose it under
-    # the Space.Device/Space.Host names the code uses. The seed pinned
-    # the new alias, which broke `import offload` (and test_offload
-    # collection) on the baked-in jax 0.4.37.
-    from jax._src.sharding_impls import TransferToMemoryKind
-
-    class Space:  # noqa: N801 - mirrors jax.memory.Space's attribute API
-        Device = TransferToMemoryKind("device")
-        Host = TransferToMemoryKind("pinned_host")
+from jax.memory import Space
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 __all__ = ["HostOffloadAdamW", "host_sharding", "host_memory_kind",

@@ -188,11 +188,9 @@ def pipeline_forward(block_params_stacked, x_microbatches, block_fn, mesh, n_mic
     jmesh = mesh.jax_mesh if isinstance(mesh, ProcessMesh) else mesh
     n_stages = dict(zip(jmesh.axis_names, jmesh.devices.shape))[pp_axis]
     per_rank = gpipe_spmd(block_fn, n_stages, n_micro, pp_axis)
-    from .collective import shard_map_compat
-
-    f = shard_map_compat(per_rank, mesh=jmesh,
-                         in_specs=(P(pp_axis), P()), out_specs=P(),
-                         check_vma=False)
+    f = jax.shard_map(per_rank, mesh=jmesh,
+                      in_specs=(P(pp_axis), P()), out_specs=P(),
+                      check_vma=False)
     return f(block_params_stacked, x_microbatches)
 
 
@@ -248,11 +246,9 @@ class PipelinedTrainStep:
         def loss_fn(params, ids_mb, labels_mb):
             embed_p, block_p, head_p = params
             x_mb = jax.vmap(lambda ids: embed_fn(embed_p, ids))(ids_mb)
-            from .collective import shard_map_compat
-
-            y_mb = shard_map_compat(per_rank, mesh=jmesh,
-                                    in_specs=(P(pp_axis), P()),
-                                    out_specs=P(), check_vma=False)(block_p, x_mb)
+            y_mb = jax.shard_map(per_rank, mesh=jmesh,
+                                 in_specs=(P(pp_axis), P()),
+                                 out_specs=P(), check_vma=False)(block_p, x_mb)
             losses = jax.vmap(lambda y, lab: head_loss_fn(head_p, y, lab))(y_mb, labels_mb)
             return losses.mean()
 

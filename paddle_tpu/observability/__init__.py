@@ -122,7 +122,7 @@ __all__ = [
 
 # Recompile monitoring is the subsystem's reason to exist; subscribe as
 # soon as the package is imported so no compile goes unattributed. Perf
-# capture rides the same funnel (backend_compile wrapper + entrypoint
+# capture rides the same funnel (compile_or_get_cached wrapper + entrypoint
 # call hook) — compile-time + host-side only, nothing on the dispatch
 # fast path.
 recompile.install()

@@ -11,14 +11,15 @@ first-class telemetry.
 
 How capture works (zero extra compiles, host-side only):
 
-- every XLA compilation funnels through
-  ``jax._src.compiler.backend_compile`` — the same choke point that
+- every executable a jit dispatch builds funnels through
+  ``jax._src.compiler.compile_or_get_cached`` — a fresh XLA compile
+  and a persistent-compilation-cache load alike, inside the scope that
   emits the ``backend_compile_duration`` monitoring event the
   recompile monitor listens to. ``install()`` wraps it once; the
   wrapper reads the recompile monitor's ``entrypoint()`` stack (the
   compile runs synchronously on the dispatching thread) and extracts
   ``cost_analysis()`` / ``get_compiled_memory_stats()`` from the
-  freshly built executable. Nothing is recompiled, nothing touches the
+  returned executable. Nothing is recompiled, nothing touches the
   dispatch fast path — capture costs one dict-read per *compile*.
 - an entry that compiles several programs (e.g. a tiny dtype-convert
   plus the real step) keeps the DOMINANT executable's analysis (max
@@ -27,11 +28,7 @@ How capture works (zero extra compiles, host-side only):
   ``recompile.add_call_hook`` (two clock reads per entry call — the
   engine's step loop already pays more than that for its histogram),
   so the ledger can join static FLOPs/bytes with measured time into
-  achieved FLOP/s, achieved GB/s, and MFU. Caveat: a persistent-
-  compilation-cache hit skips ``backend_compile`` — use
-  ``capture_compiled(entry, compiled)`` to seed the ledger explicitly
-  on such lanes (the AOT helpers below return the analyses either
-  way).
+  achieved FLOP/s, achieved GB/s, and MFU.
 
 Roofline classification compares each entry's arithmetic intensity
 (flops / bytes accessed) against the device's machine balance
@@ -192,8 +189,6 @@ def extract_cost_analysis(compiled) -> Optional[dict]:
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):  # older jax / raw PJRT wrap in a list
-        ca = ca[0] if ca else None
     if not ca:
         return None
     flops = ca.get("flops")
@@ -236,7 +231,7 @@ def extract_memory_analysis(compiled) -> Optional[dict]:
 
 
 # ---------------------------------------------------------------------------
-# capture (rides the backend_compile funnel + the entrypoint() stack)
+# capture (rides the compile_or_get_cached funnel + the entrypoint() stack)
 # ---------------------------------------------------------------------------
 
 
@@ -263,9 +258,8 @@ def _rec(entry: str) -> dict:
 def capture_compiled(entry: str, compiled) -> Optional[dict]:
     """Record ``compiled``'s cost/memory analysis under ``entry`` —
     keeping the dominant executable when the entry already holds one.
-    The backend_compile wrapper calls this for every compile; callers
-    on persistent-cache-hit lanes (where backend_compile is skipped)
-    can seed the ledger explicitly. Returns the stored analysis."""
+    The ``compile_or_get_cached`` wrapper calls this for every
+    executable jit builds or loads. Returns the stored analysis."""
     cost = extract_cost_analysis(compiled)
     mem = extract_memory_analysis(compiled)
     if cost is None and mem is None:
@@ -334,8 +328,10 @@ def note_entry_items(entry: str, n: int):
 
 
 def install() -> bool:
-    """Wrap ``jax._src.compiler.backend_compile`` (idempotent) so every
-    XLA compile contributes its analyses to the ledger, attributed via
+    """Wrap ``jax._src.compiler.compile_or_get_cached`` (idempotent),
+    the one funnel every jit dispatch builds its executable through —
+    a fresh XLA compile and a persistent-cache load alike — so each
+    executable contributes its analyses to the ledger, attributed via
     the recompile monitor's entrypoint stack. Also registers the
     entry-call timing hook and the flight-recorder state provider."""
     if _installed[0]:
@@ -343,15 +339,12 @@ def install() -> bool:
     with _install_lock:
         if _installed[0]:
             return True
-        try:
-            from jax._src import compiler as _jcompiler
-        except Exception:
-            return False
-        orig = _jcompiler.backend_compile
+        from jax._src import compiler as _jcompiler
 
-        def _backend_compile_captured(backend, module, options,
-                                      host_callbacks):
-            exe = orig(backend, module, options, host_callbacks)
+        orig = _jcompiler.compile_or_get_cached
+
+        def _compile_captured(*args, **kwargs):
+            exe = orig(*args, **kwargs)
             if perf_enabled():
                 try:
                     capture_compiled(_rc.current_entry(), exe)
@@ -359,7 +352,7 @@ def install() -> bool:
                     logger.debug("perf capture failed", exc_info=True)
             return exe
 
-        _jcompiler.backend_compile = _backend_compile_captured
+        _jcompiler.compile_or_get_cached = _compile_captured
         _rc.add_call_hook(_on_entry_call)
         from . import tracing as _tracing
 

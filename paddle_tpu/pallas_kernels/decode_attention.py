@@ -9,15 +9,17 @@ This kernel is the TPU-native fix (reference analogue: the decode branch
 of phi/kernels/gpu/flash_attn_kernel.cu and the flash-decoding split-K
 formulation):
 
-- split-K over the cache length: grid (B, kv_heads, num_kv_blocks);
-  every KV block computes an online-softmax PARTIAL (running max, sum,
-  unnormalized accumulator) and a small XLA combine merges them — short
-  batches still expose B * kv_heads * num_blocks parallel cells, and on
-  TPU the first two grid dims are declared "parallel" for megacore.
-- GQA-native: each grid cell loads its [block_k, d] K/V block ONCE and
-  serves the kv head's whole [group * q_len, d] query bundle through a
-  single MXU matmul — repeat_kv never materializes, so KV bytes drop by
-  the group factor (4x for Llama-70B-style heads/kv_heads ratios).
+- split-K over the cache length: grid (B, num_kv_blocks); every KV
+  block computes an online-softmax PARTIAL (running max, sum,
+  unnormalized accumulator) per kv head and a small XLA combine merges
+  them. A cell's K/V block is [block_k, kv_heads, d] — ALL kv heads of
+  the block in one DMA: the last two block dims must be whole TPU tiles
+  (or the whole axis), which a single kv head out of [.., kv_heads, d]
+  is not, and one DMA per cell moves kv_heads times the bytes.
+- GQA-native: each kv head's [block_k, d] slab is read ONCE and serves
+  the head's whole [group * q_len, d] query bundle through a single MXU
+  matmul — repeat_kv never materializes, so KV bytes drop by the group
+  factor (4x for Llama-70B-style heads/kv_heads ratios).
 - per-row length masking: the engine's per-slot [B] position vector is
   scalar-prefetched; each row's kv-block loop is bounded by its own
   length, blocks wholly beyond ``pos + q_len`` are skipped (the K/V
@@ -42,25 +44,20 @@ fused-conv instrumentation pattern.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; CPU tests run in interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_TPU_PALLAS = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_TPU_PALLAS = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..observability.metrics import _ENABLED as _obs_on
 from ..observability.metrics import counter as _obs_counter
 from ._blocks import pick_block
-from .flash_attention import NEG_INF, _dot_prec, _interpret
+from .flash_attention import (NEG_INF, VMEM_LIMIT_BYTES, _dot_prec,
+                              _interpret)
 
 __all__ = ["flash_decode_attention", "flash_decode_enabled",
            "decode_dispatch", "MAX_DECODE_Q_LEN",
@@ -136,8 +133,6 @@ def decode_dispatch(model: str, *, q_len: int, has_mask: bool,
     reason = None
     if not flash_decode_enabled():
         reason = "disabled"
-    elif not _HAS_TPU_PALLAS:  # pragma: no cover — jax without pallas.tpu
-        reason = "no_tpu_pallas"
     elif _tp_sharded():
         # pallas_call can't be partitioned by GSPMD; the XLA gather
         # fallback shards cleanly on the kv-heads axis instead
@@ -179,8 +174,6 @@ def paged_decode_dispatch(model: str, *, q_len: int, has_mask: bool,
     reason = None
     if not flash_decode_enabled():
         reason = "disabled"
-    elif not _HAS_TPU_PALLAS:  # pragma: no cover — jax without pallas.tpu
-        reason = "no_tpu_pallas"
     elif _tp_sharded():
         reason = "tp_sharded"
     elif has_mask:
@@ -234,8 +227,6 @@ def spec_verify_eligibility(spec_k: int, dtype, spec_tree=None):
     reason = None
     if not flash_decode_enabled():
         reason = "disabled"
-    elif not _HAS_TPU_PALLAS:  # pragma: no cover
-        reason = "no_tpu_pallas"
     elif width > MAX_PAGED_Q_LEN:
         reason = "q_len"
     elif str(dtype) not in ("float32", "bfloat16"):
@@ -247,47 +238,19 @@ def spec_verify_eligibility(spec_k: int, dtype, spec_tree=None):
     return False, reason
 
 
-_COMPILER_PARAMS = None
+def _visible(length, start, *, gq: int, block_k: int, q_len: int,
+             group: int, mask=None):
+    """[gq, block_k] bool: which cache columns of this kv block each
+    query row may attend. Identical for every kv head, so a cell builds
+    it once.
 
-
-def _compiler_kwargs():
-    """Megacore partitioning on chip: batch and kv-head grid dims are
-    embarrassingly parallel (every cell writes its own partial), only
-    the kv-block dim needs sequential order (the revisit-skip on the
-    K/V index map). Interpret mode takes no compiler params."""
-    if not _HAS_TPU_PALLAS or _interpret():
-        return {}
-    global _COMPILER_PARAMS
-    if _COMPILER_PARAMS is None:
-        params_cls = (getattr(pltpu, "CompilerParams", None)
-                      or getattr(pltpu, "TPUCompilerParams", None))
-        if params_cls is None:  # pragma: no cover
-            raise RuntimeError(
-                "paddle_tpu flash decode needs pallas TPU compiler params "
-                f"(neither CompilerParams nor TPUCompilerParams on "
-                f"jax=={jax.__version__})")
-        _COMPILER_PARAMS = params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return {"compiler_params": _COMPILER_PARAMS}
-
-
-def _cell_partial(q, k, v, length, start, o_ref, m_ref, l_ref, *,
-                  block_k: int, sm_scale: float, q_len: int, group: int,
-                  mask=None):
-    """The block's online-softmax partial for the whole query bundle —
-    shared by the plain and dequantizing kernel variants so the math can
-    never drift between them (quantized vs bf16 parity oracles depend on
-    identical masking/summation order).
-
-    ``mask`` (None or [q_len, q_len] f32, 1.0 = visible): the row's
+    ``mask`` (None or [gq, qp] f32, 1.0 = visible, rows already expanded
+    to r = i*group + g and columns zero-padded to qp): the row's
     in-bundle ancestor mask for tree-speculative verify. None keeps the
     causal bundle (kpos <= qpos) bitwise — a causal ancestor mask input
     reproduces it exactly, so the chain lane never pays the extra
     operand. Past-KV masking (everything before the bundle) is untouched
     either way: all of it is ancestry by construction."""
-    gq, d = q.shape
-    sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                 precision=_dot_prec(q.dtype)) * sm_scale
     kpos = start + jax.lax.broadcasted_iota(jnp.int32, (gq, block_k), 1)
     if mask is None:
         # query row r sits at absolute position pos + r // group; masking
@@ -295,218 +258,215 @@ def _cell_partial(q, k, v, length, start, o_ref, m_ref, l_ref, *,
         # and causality inside the q_len window
         qpos = (length - q_len) \
             + jax.lax.broadcasted_iota(jnp.int32, (gq, block_k), 0) // group
-        vis = kpos <= qpos
-    else:
-        # bundle node j lives at cache position (length - q_len) + j; a
-        # dynamic per-column gather of mask[:, j] is not expressible in
-        # the cell, so build the column one-hot [q_len, block_k] and
-        # read the tile through one small MXU matmul. Columns outside
-        # the bundle window match no one-hot row and fall to the past-KV
-        # term (kpos < length - q_len), which also bounds the right-pad:
-        # kpos >= length matches nothing and stays masked.
-        mask_g = jnp.broadcast_to(
-            mask[:, None, :], (q_len, group, q_len)).reshape(gq, q_len)
-        j_col = (start - (length - q_len)) \
-            + jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 1)
-        onehot = (jax.lax.broadcasted_iota(
-            jnp.int32, (q_len, block_k), 0) == j_col).astype(jnp.float32)
-        anc = jnp.dot(mask_g, onehot, preferred_element_type=jnp.float32)
-        vis = (kpos < length - q_len) | (anc > 0.5)
-    sc = jnp.where(vis, sc, NEG_INF)
-    m = sc.max(axis=-1)                # [gq] f32
-    p = jnp.exp(sc - m[:, None])
-    l = p.sum(axis=-1)
-    acc = jnp.dot(p.astype(v.dtype), v,
-                  preferred_element_type=jnp.float32,
-                  precision=_dot_prec(q.dtype))
-    o_ref[0, 0, 0] = acc
-    m_ref[0, 0, 0] = m[:, None]
-    l_ref[0, 0, 0] = l[:, None]
+        return kpos <= qpos
+    # bundle node j lives at cache position (length - q_len) + j; a
+    # dynamic per-column gather of mask[:, j] is not expressible in the
+    # cell, so build the column one-hot [qp, block_k] and read the tile
+    # through one small MXU matmul. Columns outside the bundle window
+    # match no one-hot row with a non-zero mask column (the pad columns
+    # are zero) and fall to the past-KV term (kpos < length - q_len),
+    # which also bounds the right-pad: kpos >= length stays masked.
+    qp = mask.shape[-1]
+    j_col = (start - (length - q_len)) \
+        + jax.lax.broadcasted_iota(jnp.int32, (qp, block_k), 1)
+    onehot = (jax.lax.broadcasted_iota(
+        jnp.int32, (qp, block_k), 0) == j_col).astype(jnp.float32)
+    anc = jnp.dot(mask, onehot, preferred_element_type=jnp.float32)
+    return (kpos < length - q_len) | (anc > 0.5)
 
 
-def _cell_skip(o_ref, m_ref, l_ref, gq: int, d: int):
-    # skipped blocks still own their partial slots; the finite
-    # NEG_INF sentinel makes them exact zeros in the combine
-    # (exp(NEG_INF - m_total) underflows to 0, l contributes 0)
-    o_ref[0, 0, 0] = jnp.zeros((gq, d), jnp.float32)
-    m_ref[0, 0, 0] = jnp.full((gq, 1), NEG_INF, jnp.float32)
-    l_ref[0, 0, 0] = jnp.zeros((gq, 1), jnp.float32)
+def _decode_kernel(*refs, n_prefetch: int, block_k: int, sm_scale: float,
+                   q_len: int, group: int, bound, tree: bool):
+    """One (batch row, kv block) cell: every kv head's online-softmax
+    partial for its whole query bundle. The K/V block carries ALL kv
+    heads — a block of 1 on the kv-heads axis is not a TPU tile, and one
+    DMA per cell moves kv_heads times more bytes than a per-head cell.
 
+    Refs (blocked), after the ``n_prefetch`` scalar-prefetch refs (the
+    first of which is the per-row valid kv length = pos + q_len):
+      q [1, KV, gq, d]            — rows r = i*group + g per kv head
+      k/v [1, block_k, KV, d]     — one cache block, all kv heads
+      ks/vs [1, block_k, KV] f32  — quantized caches only (``bound``
+                                    set): per-token-per-head absmax
+      mask [1, gq, qp] f32        — ``tree`` only: ancestor mask
+      o [1, 1, KV, gq, d] f32     — unnormalized accumulator partial
+      m/l [1, 1, KV, gq, 1] f32   — running max / sum partials
 
-def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
-                   block_k: int, sm_scale: float, q_len: int, group: int,
-                   mask_ref=None):
-    """One (batch row, kv head, kv block) cell: the block's online-
-    softmax partial for the whole query bundle.
-
-    Refs (blocked):
-      q [1, q_len, 1, group, d]   — the kv head's query bundle
-      k/v [1, block_k, 1, d]      — one cache block of this kv head
-      mask [1, q_len, q_len] f32  — optional in-bundle ancestor mask
-      o [1, 1, 1, gq, d] f32      — unnormalized accumulator partial
-      m/l [1, 1, 1, gq, 1] f32    — running max / sum partials
-    """
-    b = pl.program_id(0)
-    s = pl.program_id(2)
-    length = lens_ref[b]          # row's valid kv length = pos + q_len
-    start = s * block_k
-    gq = q_len * group
-    d = q_ref.shape[-1]
+    Quantized cells add a DEQUANT PROLOGUE: the int8/fp8 head slab and
+    its scale column are widened to the query dtype in VMEM before the
+    MXU matmuls, so the HBM stream is the narrow one. ``q * s / bound``
+    in that exact order matches ``quantization.intx.unpack_absmax``
+    bitwise, keeping the kernel and the XLA gather fallback
+    interchangeable."""
+    lens_ref = refs[0]
+    q_ref, k_ref, v_ref, *refs = refs[n_prefetch:]
+    if bound is not None:
+        ks_ref, vs_ref, *refs = refs
+    if tree:
+        mask_ref, *refs = refs
+    o_ref, m_ref, l_ref = refs
+    length = lens_ref[pl.program_id(0)]
+    start = pl.program_id(1) * block_k
+    _, kv, gq, d = q_ref.shape
 
     @pl.when(start < length)
     def _compute():
-        q = q_ref[0, :, 0].reshape(gq, d)  # rows r = i*group + g
-        k = k_ref[0, :, 0, :]              # [block_k, d]
-        v = v_ref[0, :, 0, :]
-        mask = None if mask_ref is None else mask_ref[0]
-        _cell_partial(q, k, v, length, start, o_ref, m_ref, l_ref,
-                      block_k=block_k, sm_scale=sm_scale, q_len=q_len,
-                      group=group, mask=mask)
+        vis = _visible(length, start, gq=gq, block_k=block_k, q_len=q_len,
+                       group=group, mask=mask_ref[0] if tree else None)
+        for h in range(kv):
+            q = q_ref[0, h]                    # [gq, d]
+            k = k_ref[0, :, h, :]              # [block_k, d]
+            v = v_ref[0, :, h, :]
+            if bound is not None:
+                k = (k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+                     / bound).astype(q.dtype)
+                v = (v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+                     / bound).astype(q.dtype)
+            sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                         precision=_dot_prec(q.dtype)) * sm_scale
+            sc = jnp.where(vis, sc, NEG_INF)
+            m = sc.max(axis=-1, keepdims=True)     # [gq, 1] f32
+            p = jnp.exp(sc - m)
+            o_ref[0, 0, h] = jnp.dot(p.astype(v.dtype), v,
+                                     preferred_element_type=jnp.float32,
+                                     precision=_dot_prec(q.dtype))
+            m_ref[0, 0, h] = m
+            l_ref[0, 0, h] = p.sum(axis=-1, keepdims=True)
 
     @pl.when(start >= length)
     def _skip():
-        _cell_skip(o_ref, m_ref, l_ref, gq, d)
+        # skipped blocks still own their partial slots; the finite
+        # NEG_INF sentinel makes them exact zeros in the combine
+        # (exp(NEG_INF - m_total) underflows to 0, l contributes 0)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
 
 
-def _decode_kernel_quant(lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                         o_ref, m_ref, l_ref, *, block_k: int,
-                         sm_scale: float, q_len: int, group: int,
-                         bound: float, mask_ref=None):
-    """The quantized-cache cell: identical to ``_decode_kernel`` plus a
-    DEQUANT PROLOGUE — the int8/fp8 K/V block and its per-token absmax
-    scale column ([1, block_k, 1] f32) are widened to the query dtype in
-    VMEM before the MXU matmuls, so the HBM stream is the narrow one.
-    ``q * s / bound`` in that exact order matches
-    ``quantization.intx.unpack_absmax`` bitwise, keeping the kernel and
-    the XLA gather fallback interchangeable."""
-    b = pl.program_id(0)
-    s = pl.program_id(2)
-    length = lens_ref[b]
-    start = s * block_k
-    gq = q_len * group
-    d = q_ref.shape[-1]
+def _split_k_attention(q5, kc, vc, lens, *, block_k: int, nb: int,
+                       kv_index, prefetch=(), sm_scale: float,
+                       k_scale=None, v_scale=None, ancestor_mask=None):
+    """The shared split-K call behind both cache layouts.
 
-    @pl.when(start < length)
-    def _compute():
-        q = q_ref[0, :, 0].reshape(gq, d)
-        # dequant prologue: [block_k, d] narrow values * [block_k, 1]
-        # absmax scales, widened in VMEM — nothing else in the cell
-        # changes
-        ks = ks_ref[0, :, 0]
-        vs = vs_ref[0, :, 0]
-        k = (k_ref[0, :, 0, :].astype(jnp.float32)
-             * ks[:, None] / bound).astype(q.dtype)
-        v = (v_ref[0, :, 0, :].astype(jnp.float32)
-             * vs[:, None] / bound).astype(q.dtype)
-        mask = None if mask_ref is None else mask_ref[0]
-        _cell_partial(q, k, v, length, start, o_ref, m_ref, l_ref,
-                      block_k=block_k, sm_scale=sm_scale, q_len=q_len,
-                      group=group, mask=mask)
+    q5 [B, q_len, KV, group, d]; kc/vc [*, *, KV, d] cut into
+    [1, block_k, KV, d] blocks; lens [B] int32 -> [B, KV, gq, d] f32,
+    combined and normalized, rows r = i*group + g.
 
-    @pl.when(start >= length)
-    def _skip():
-        _cell_skip(o_ref, m_ref, l_ref, gq, d)
+    ``kv_index(b, s, lens_ref, *prefetch_refs)`` maps grid cell (row b,
+    logical kv block s) to the (axis-0, axis-1) block index of its K/V
+    block — the ONLY thing that differs between the contiguous cache
+    and the paged pool. ``prefetch``: extra scalar-prefetch operands it
+    reads (the block table). Scale pools ride the same index.
 
-
-def _flash_decode(q5, kc, vc, lens, *, sm_scale: float, block_k: int,
-                  k_scale=None, v_scale=None):
-    """q5 [B, q_len, KV, group, d], caches [B, max_len, KV, d],
-    lens [B] int32 -> [B, KV, gq, d] f32 (unnormalized layout rows
-    r = i*group + g, already combined and normalized).
-
-    ``k_scale``/``v_scale`` ([B, max_len, KV] f32, both or neither):
-    the caches hold int8/fp8 and each grid cell dequantizes its block in
-    the kernel prologue (same grid, same index maps — the scale column
-    rides the K/V re-point-and-skip logic)."""
+    Grid (B, nb): rows are independent ("parallel"); the kv-block axis
+    must keep its order for the revisit-skip on the K/V index map."""
     from ..quantization.intx import format_bound
 
     B, q_len, KV, group, d = q5.shape
-    max_len = kc.shape[1]
-    bk = pick_block(max_len, block_k)
-    nb = max_len // bk
     gq = q_len * group
     quant = k_scale is not None
+    tree = ancestor_mask is not None
+    # per-kv-head query bundles as whole [gq, d] tiles: merging q_len
+    # into the group axis inside the cell is a sublane relayout Mosaic
+    # only takes for group % 8 == 0, so it happens here in XLA (tiny)
+    qk = jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(B, KV, gq, d)
 
-    def _idx_q(b, h, s, lens):
-        return (b, 0, h, 0, 0)
+    def _idx_kv(b, s, *pf):
+        return kv_index(b, s, *pf) + (0, 0)
 
-    def _idx_kv(b, h, s, lens):
-        # blocks beyond the row's last needed block re-point AT the last
-        # needed one: Pallas sees a repeated index and skips the fetch,
-        # so right-pad past pos (and dead slots pinned to pos 0) cost no
-        # HBM traffic beyond one block
-        last = jnp.maximum(pl.cdiv(lens[b], bk) - 1, 0)
-        return (b, jnp.minimum(s, last), h, 0)
+    # scale pools as [rows * blocks_per_row, block_k, KV] (a paged pool
+    # already is): each cell's [block_k, KV] block is then the array's
+    # own last two dims, a legal TPU block for any block_k
+    blocks_per_row = kc.shape[1] // block_k
 
-    def _idx_scale(b, h, s, lens):
-        last = jnp.maximum(pl.cdiv(lens[b], bk) - 1, 0)
-        return (b, jnp.minimum(s, last), h)
-
-    def _idx_out(b, h, s, lens):
-        return (b, h, s, 0, 0)
-
-    def _idx_stat(b, h, s, lens):
-        return (b, h, s, 0, 0)
+    def _idx_scale(b, s, *pf):
+        i0, i1 = kv_index(b, s, *pf)
+        return (i0 * blocks_per_row + i1, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, q_len, 1, group, d), _idx_q),
-        pl.BlockSpec((1, bk, 1, d), _idx_kv),
-        pl.BlockSpec((1, bk, 1, d), _idx_kv),
+        pl.BlockSpec((1, KV, gq, d), lambda b, s, *pf: (b, 0, 0, 0)),
+        pl.BlockSpec((1, block_k, KV, d), _idx_kv),
+        pl.BlockSpec((1, block_k, KV, d), _idx_kv),
     ]
+    operands = (lens.astype(jnp.int32),) + tuple(prefetch) + (qk, kc, vc)
     if quant:
-        in_specs += [pl.BlockSpec((1, bk, 1), _idx_scale),
-                     pl.BlockSpec((1, bk, 1), _idx_scale)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, KV, nb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, gq, d), _idx_out),
-            pl.BlockSpec((1, 1, 1, gq, 1), _idx_stat),
-            pl.BlockSpec((1, 1, 1, gq, 1), _idx_stat),
-        ],
-    )
+        in_specs += [pl.BlockSpec((1, block_k, KV), _idx_scale)] * 2
+        operands += tuple(sc.astype(jnp.float32).reshape(-1, block_k, KV)
+                          for sc in (k_scale, v_scale))
+    if tree:
+        # rows expanded to the kernel's r = i*group + g order and the
+        # contraction axis zero-padded to a lane multiple, so the cell's
+        # one-hot matmul is MXU-aligned for any bundle width (29, ...)
+        qp = -(-q_len // 128) * 128
+        am = jnp.repeat(ancestor_mask.astype(jnp.float32), group, axis=1)
+        am = jnp.pad(am, ((0, 0), (0, 0), (0, qp - q_len)))
+        in_specs.append(
+            pl.BlockSpec((1, gq, qp), lambda b, s, *pf: (b, 0, 0)))
+        operands += (am,)
 
-    if quant:
-        bound = format_bound(
-            "int8" if kc.dtype == jnp.int8 else "fp8")
+    def _idx_out(b, s, *pf):
+        return (b, s, 0, 0, 0)
 
-        def kern(lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                 m_ref, l_ref):
-            _decode_kernel_quant(lens_ref, q_ref, k_ref, v_ref, ks_ref,
-                                 vs_ref, o_ref, m_ref, l_ref, block_k=bk,
-                                 sm_scale=sm_scale, q_len=q_len,
-                                 group=group, bound=bound)
-
-        operands = (lens.astype(jnp.int32), q5, kc, vc,
-                    k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
-    else:
-        def kern(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref):
-            _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-                           l_ref, block_k=bk, sm_scale=sm_scale,
-                           q_len=q_len, group=group)
-
-        operands = (lens.astype(jnp.int32), q5, kc, vc)
-
+    kern = functools.partial(
+        _decode_kernel, n_prefetch=1 + len(prefetch), block_k=block_k,
+        sm_scale=sm_scale, q_len=q_len, group=group, tree=tree,
+        bound=format_bound("int8" if kc.dtype == jnp.int8 else "fp8")
+        if quant else None)
+    interpret = _interpret()
     o_p, m_p, l_p = pl.pallas_call(
         kern,
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((B, KV, nb, gq, d), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, nb, gq, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, nb, gq, 1), jnp.float32)),
-        interpret=_interpret(),
-        **_compiler_kwargs(),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1 + len(prefetch),
+            grid=(B, nb),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, KV, gq, d), _idx_out),
+                pl.BlockSpec((1, 1, KV, gq, 1), _idx_out),
+                pl.BlockSpec((1, 1, KV, gq, 1), _idx_out),
+            ],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((B, nb, KV, gq, d), jnp.float32),
+                   jax.ShapeDtypeStruct((B, nb, KV, gq, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, nb, KV, gq, 1), jnp.float32)),
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # whole-heads partial blocks grow with heads * q_len (a
+            # 256-token bundle at 32 heads needs ~24 MB with the
+            # lane-padded stat columns): past Mosaic's 16 MB default
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(*operands)
 
     # split-K combine (tiny: nb * gq * d floats per row/head): classic
     # log-sum-exp merge of the blocks' partials. Skipped blocks carry
     # (m=NEG_INF, l=0, acc=0) and contribute exact zeros; a fully-masked
     # row (dead slot) ends with l_total=0 and returns zeros.
-    m_tot = m_p.max(axis=2)                        # [B, KV, gq, 1]
-    alpha = jnp.exp(m_p - m_tot[:, :, None])       # [B, KV, nb, gq, 1]
-    l_tot = (l_p * alpha).sum(axis=2)
-    acc = (o_p * alpha).sum(axis=2)
+    m_tot = m_p.max(axis=1)                        # [B, KV, gq, 1]
+    alpha = jnp.exp(m_p - m_tot[:, None])          # [B, nb, KV, gq, 1]
+    l_tot = (l_p * alpha).sum(axis=1)
+    acc = (o_p * alpha).sum(axis=1)
     return acc / jnp.maximum(l_tot, 1e-30)
+
+
+def _flash_decode(q5, kc, vc, lens, *, sm_scale: float, block_k: int,
+                  k_scale=None, v_scale=None):
+    """Contiguous caches [B, max_len, KV, d] (scales [B, max_len, KV]
+    f32, both or neither): cell (b, s) reads cache block s of row b."""
+    max_len = kc.shape[1]
+    bk = pick_block(max_len, block_k)
+
+    def _kv_index(b, s, lens):
+        # blocks beyond the row's last needed block re-point AT the last
+        # needed one: Pallas sees a repeated index and skips the fetch,
+        # so right-pad past pos (and dead slots pinned to pos 0) cost no
+        # HBM traffic beyond one block
+        last = jnp.maximum(pl.cdiv(lens[b], bk) - 1, 0)
+        return (b, jnp.minimum(s, last))
+
+    return _split_k_attention(q5, kc, vc, lens, block_k=bk,
+                              nb=max_len // bk, kv_index=_kv_index,
+                              sm_scale=sm_scale, k_scale=k_scale,
+                              v_scale=v_scale)
 
 
 def _unwrap(x):
@@ -573,130 +533,29 @@ def flash_decode_attention(q, k_cache, v_cache, positions, sm_scale=None,
 
 def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
                         k_scale=None, v_scale=None, ancestor_mask=None):
-    """q5 [B, q_len, KV, group, d], pools [num_blocks, bs, KV, d],
-    bt [B, nb] int32, lens [B] int32 -> [B, KV, gq, d] f32 (combined and
-    normalized). Identical math to ``_flash_decode`` — the only change
-    is the K/V index map, which resolves the grid's logical kv-block
-    through the scalar-prefetched block table into a physical pool
-    block. Out-of-range blocks re-point at the row's LAST needed logical
-    block (the same Pallas revisit-skip as the contiguous kernel), so a
-    short row costs its own length, not the table width.
+    """Paged pools [num_blocks, bs, KV, d] (scales [num_blocks, bs, KV]
+    f32), bt [B, nb] int32: identical math to ``_flash_decode`` — the
+    only change is the K/V index map, which resolves the grid's logical
+    kv-block through the scalar-prefetched block table into a physical
+    pool block. Out-of-range blocks re-point at the row's LAST needed
+    logical block (the same Pallas revisit-skip as the contiguous
+    kernel), so a short row costs its own length, not the table width.
 
-    ``k_scale``/``v_scale`` ([num_blocks, bs, KV] f32): quantized pools
-    — the scale column rides the same table-indirected index map and the
-    cell dequantizes its block in the prologue.
-
-    ``ancestor_mask`` ([B, q_len, q_len] f32, 1.0 = visible): per-row
+    ``ancestor_mask`` ([B, q_len, q_len], 1.0 = visible): per-row
     in-bundle visibility for tree-speculative verify; every cell of row
-    b reads the same [q_len, q_len] block (index map pins (b, 0, 0)).
-    None compiles the causal bundle exactly as before."""
-    from ..quantization.intx import format_bound
-
-    B, q_len, KV, group, d = q5.shape
+    b reads the same block. None compiles the causal bundle exactly as
+    before."""
     bs = kp.shape[1]
-    nb = bt.shape[1]
-    gq = q_len * group
-    quant = k_scale is not None
-    tree = ancestor_mask is not None
 
-    def _idx_q(b, h, s, lens, bt):
-        return (b, 0, h, 0, 0)
-
-    def _idx_kv(b, h, s, lens, bt):
+    def _kv_index(b, s, lens, bt):
         last = jnp.maximum(pl.cdiv(lens[b], bs) - 1, 0)
-        return (bt[b, jnp.minimum(s, last)], 0, h, 0)
+        return (bt[b, jnp.minimum(s, last)], 0)
 
-    def _idx_scale(b, h, s, lens, bt):
-        last = jnp.maximum(pl.cdiv(lens[b], bs) - 1, 0)
-        return (bt[b, jnp.minimum(s, last)], 0, h)
-
-    def _idx_mask(b, h, s, lens, bt):
-        return (b, 0, 0)
-
-    def _idx_out(b, h, s, lens, bt):
-        return (b, h, s, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, q_len, 1, group, d), _idx_q),
-        pl.BlockSpec((1, bs, 1, d), _idx_kv),
-        pl.BlockSpec((1, bs, 1, d), _idx_kv),
-    ]
-    if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), _idx_scale),
-                     pl.BlockSpec((1, bs, 1), _idx_scale)]
-    if tree:
-        in_specs += [pl.BlockSpec((1, q_len, q_len), _idx_mask)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, nb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, gq, d), _idx_out),
-            pl.BlockSpec((1, 1, 1, gq, 1), _idx_out),
-            pl.BlockSpec((1, 1, 1, gq, 1), _idx_out),
-        ],
-    )
-
-    operands = (lens.astype(jnp.int32), bt.astype(jnp.int32), q5, kp, vp)
-    if quant:
-        bound = format_bound("int8" if kp.dtype == jnp.int8 else "fp8")
-        operands += (k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32))
-        if tree:
-            def _kern(lens_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref,
-                      vs_ref, am_ref, o_ref, m_ref, l_ref):
-                del bt_ref
-                _decode_kernel_quant(lens_ref, q_ref, k_ref, v_ref, ks_ref,
-                                     vs_ref, o_ref, m_ref, l_ref,
-                                     block_k=bs, sm_scale=sm_scale,
-                                     q_len=q_len, group=group, bound=bound,
-                                     mask_ref=am_ref)
-        else:
-            def _kern(lens_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref,
-                      vs_ref, o_ref, m_ref, l_ref):
-                del bt_ref
-                _decode_kernel_quant(lens_ref, q_ref, k_ref, v_ref, ks_ref,
-                                     vs_ref, o_ref, m_ref, l_ref,
-                                     block_k=bs, sm_scale=sm_scale,
-                                     q_len=q_len, group=group, bound=bound)
-    else:
-        if tree:
-            def _kern(lens_ref, bt_ref, q_ref, k_ref, v_ref, am_ref,
-                      o_ref, m_ref, l_ref):
-                del bt_ref
-                _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
-                               m_ref, l_ref, block_k=bs,
-                               sm_scale=sm_scale, q_len=q_len,
-                               group=group, mask_ref=am_ref)
-        else:
-            def _kern(lens_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-                      l_ref):
-                # bt_ref is consumed by the index maps; the cell body
-                # itself is the contiguous kernel verbatim (same
-                # lens-bounded masking)
-                del bt_ref
-                _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
-                               m_ref, l_ref, block_k=bs,
-                               sm_scale=sm_scale, q_len=q_len,
-                               group=group)
-    if tree:
-        operands += (ancestor_mask.astype(jnp.float32),)
-
-    o_p, m_p, l_p = pl.pallas_call(
-        _kern,
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((B, KV, nb, gq, d), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, nb, gq, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, nb, gq, 1), jnp.float32)),
-        interpret=_interpret(),
-        **_compiler_kwargs(),
-    )(*operands)
-
-    m_tot = m_p.max(axis=2)
-    alpha = jnp.exp(m_p - m_tot[:, :, None])
-    l_tot = (l_p * alpha).sum(axis=2)
-    acc = (o_p * alpha).sum(axis=2)
-    return acc / jnp.maximum(l_tot, 1e-30)
+    return _split_k_attention(q5, kp, vp, lens, block_k=bs,
+                              nb=bt.shape[1], kv_index=_kv_index,
+                              prefetch=(bt.astype(jnp.int32),),
+                              sm_scale=sm_scale, k_scale=k_scale,
+                              v_scale=v_scale, ancestor_mask=ancestor_mask)
 
 
 def paged_flash_decode_attention(q, k_pool, v_pool, block_table, positions,

@@ -31,46 +31,20 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-only module; CPU tests run in interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_TPU_PALLAS = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_TPU_PALLAS = False
-
-_VMEM_PARAMS = None
-
-
-def _vmem_params():
-    # Raise Mosaic's 16 MB default scoped-VMEM cap: the backward kernels
-    # hold full-sequence q/do (dK/dV pass) and k/v (dQ pass) refs, which
-    # at seq >= 8192 exceed 16 MB while the chip has 128 MB VMEM. Looked
-    # up lazily at first kernel launch so that a renamed class on a future
-    # jax only breaks the TPU compile path, not `import paddle_tpu`
-    # (interpret/CPU mode never needs the cap). A constructor failure must
-    # still SURFACE here: silently dropping the cap would break the
-    # documented seq-8192 support. Older jax spells it TPUCompilerParams.
-    global _VMEM_PARAMS
-    if _VMEM_PARAMS is None:
-        params_cls = (getattr(pltpu, "CompilerParams", None)
-                      or getattr(pltpu, "TPUCompilerParams", None))
-        if params_cls is None:
-            raise RuntimeError(
-                "paddle_tpu flash attention needs pallas TPU compiler params "
-                "(jax.experimental.pallas.tpu.CompilerParams or "
-                "TPUCompilerParams) to raise the scoped-VMEM cap for "
-                "seq>=8192 support; this jax version exposes neither. "
-                f"jax=={jax.__version__}")
-        _VMEM_PARAMS = params_cls(vmem_limit_bytes=100 * 1024 * 1024)
-    return _VMEM_PARAMS
+# Mosaic's default scoped-VMEM cap is 16 MB: the backward kernels hold
+# full-sequence q/do (dK/dV pass) and k/v (dQ pass) refs, which at
+# seq >= 8192 exceed it while the chip has 128 MB of VMEM.
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 
 def _compiler_kwargs():
-    if not _HAS_TPU_PALLAS or _interpret():
+    if _interpret():
         return {}
-    return {"compiler_params": _vmem_params()}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)}
+
 
 NEG_INF = -1e30
 

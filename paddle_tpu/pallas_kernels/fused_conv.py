@@ -51,11 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; CPU tests run in interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _compiler_kwargs, _dot_prec, _interpret
 
@@ -174,8 +170,8 @@ def _stats_kernel(x_ref, w_ref, o_ref, s1_ref, s2_ref, acc_ref, *,
     # pass never re-reads the conv output from HBM (and is MORE accurate
     # than reducing the rounded bf16 output — cf. the single-pass-stats
     # note at _bn_train_fwd in nn/functional.py)
-    s1_ref[:] = jnp.sum(acc, axis=0, keepdims=True)
-    s2_ref[:] = jnp.sum(acc * acc, axis=0, keepdims=True)
+    s1_ref[0] = jnp.sum(acc, axis=0, keepdims=True)
+    s2_ref[0] = jnp.sum(acc * acc, axis=0, keepdims=True)
 
 
 def _prep(x, w):
@@ -255,18 +251,21 @@ def _pallas_stats(x, w, pre=None):
     out, s1, s2 = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((m, k), x.dtype),
-                   jax.ShapeDtypeStruct((g, k), jnp.float32),
-                   jax.ShapeDtypeStruct((g, k), jnp.float32)),
+                   jax.ShapeDtypeStruct((g, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((g, 1, k), jnp.float32)),
         grid=(g,),
         in_specs=in_specs,
+        # partials as [g, 1, K] so each block's last two dims are the
+        # array's own (a [1, K] row out of [g, K] is not a TPU tile)
         out_specs=(pl.BlockSpec((bm, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))),
+                   pl.BlockSpec((1, 1, k), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((1, 1, k), lambda i: (i, 0, 0))),
         scratch_shapes=scratch,
         interpret=_interpret(),
         **_compiler_kwargs(),
     )(*args)
-    return out.reshape(n, h, w_sp, k), jnp.sum(s1, 0), jnp.sum(s2, 0)
+    return (out.reshape(n, h, w_sp, k), jnp.sum(s1, (0, 1)),
+            jnp.sum(s2, (0, 1)))
 
 
 def _xla_conv(x, w):

@@ -36,14 +36,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; CPU tests run in interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_TPU_PALLAS = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_TPU_PALLAS = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..observability.metrics import _ENABLED as _obs_on
 from ..observability.metrics import counter as _obs_counter
@@ -81,8 +74,6 @@ def quant_matmul_dispatch(*, dtype, fmt: str) -> bool:
     reason = None
     if not quant_matmul_enabled():
         reason = "disabled"
-    elif not _HAS_TPU_PALLAS:  # pragma: no cover — jax without pallas.tpu
-        reason = "no_tpu_pallas"
     elif str(dtype) not in ("float32", "bfloat16"):
         reason = "dtype"
     else:
@@ -99,28 +90,6 @@ def quant_matmul_dispatch(*, dtype, fmt: str) -> bool:
     if _obs_on[0]:
         _qm_fallbacks.labels(reason).inc()
     return False
-
-
-_COMPILER_PARAMS = None
-
-
-def _compiler_kwargs():
-    """m/n grid dims are embarrassingly parallel; the k dim accumulates
-    into the revisited output block and must stay sequential."""
-    if not _HAS_TPU_PALLAS or _interpret():
-        return {}
-    global _COMPILER_PARAMS
-    if _COMPILER_PARAMS is None:
-        params_cls = (getattr(pltpu, "CompilerParams", None)
-                      or getattr(pltpu, "TPUCompilerParams", None))
-        if params_cls is None:  # pragma: no cover
-            raise RuntimeError(
-                "paddle_tpu quant matmul needs pallas TPU compiler params "
-                f"(neither CompilerParams nor TPUCompilerParams on "
-                f"jax=={jax.__version__})")
-        _COMPILER_PARAMS = params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return {"compiler_params": _COMPILER_PARAMS}
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *, nk: int):
@@ -140,7 +109,9 @@ def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *, nk: int):
     # dequant prologue: widen the narrow weight block to the activation
     # dtype in VMEM; the per-channel scale moves to the accumulator
     # epilogue below (identical math, n multiplies instead of n*k)
-    w = w_ref[...].astype(x.dtype)
+    # (through f32: Mosaic has no direct fp8 -> bf16 cast, and the hop
+    # is exact for both narrow formats)
+    w = w_ref[...].astype(jnp.float32).astype(x.dtype)
     o_ref[...] += jnp.dot(x, w.T, preferred_element_type=jnp.float32,
                           precision=_dot_prec(x.dtype))
 
@@ -201,7 +172,11 @@ def quant_matmul(x, qweight, scale, block_m: int = 128,
             out_specs=pl.BlockSpec((bm, bn), _idx_o),
             out_shape=jax.ShapeDtypeStruct((m, N), jnp.float32),
             interpret=_interpret(),
-            **_compiler_kwargs(),
+            # m/n grid dims are embarrassingly parallel; the k dim
+            # accumulates into the revisited output block and must stay
+            # sequential
+            compiler_params=None if _interpret() else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
         )(xm, qa, s2)
         return out.reshape(lead + (N,)).astype(xa.dtype)
 
