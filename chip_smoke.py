@@ -28,9 +28,12 @@ imports jax (a process that has touched jax holds the chip). The
 children share the persistent compile cache
 (``paddle_tpu/core/compile_cache.py``).
 
-The last stdout line is one JSON object, ``{"ok": true, "device":
-{"platform": "tpu", "kind": ..., "count": n}, ...}``; any failed check,
-or no TPU, is a non-zero exit with no result line. Progress goes to
+Stdout is two lines, each one JSON object. The first is the report:
+versions, compile cache, and per phase its wall time, compiles, kernel
+counters, requests or losses, and peak bytes. The last is the result,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}``
+with exactly those keys and the device as jax reports it. Any failed
+check, or no TPU, is a non-zero exit with neither line. Progress goes to
 stderr. ``--rehearse-on-cpu`` walks the same code at toy sizes with the
 kernels interpreted, for debugging this script where there is no chip;
 it reports ``"platform": "cpu"`` and is never what a bare run does.
@@ -555,19 +558,19 @@ def main(argv=None):
         log(f"FAILED phases: {failed}")
         return 1
 
-    devices = {json.dumps(r.pop("device"), sort_keys=True)
-               for r in results.values()}
+    devices = [r.pop("device") for r in results.values()]
     caches = {r.pop("compile_cache_dir") for r in results.values()}
-    check(len(devices) == 1 and len(caches) == 1,
+    check(all(d == devices[0] for d in devices) and len(caches) == 1,
           "every phase saw the same devices and the same compile cache")
     print(json.dumps({
-        "ok": True, "device": json.loads(devices.pop()),
         "rehearsal": args.rehearse_on_cpu,
         "versions": {p: metadata.version(p)
                      for p in ("jax", "jaxlib", "libtpu")},
         "compile_cache_dir": caches.pop(),
         "wall_s": round(time.monotonic() - t_start, 1), **results,
     }))
+    # the result line: these keys and no others
+    print(json.dumps({"ok": True, "device": devices[0]}), flush=True)
     return 0
 
 
