@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.autograd import no_grad
 from ..core.tensor import Parameter, Tensor
+from ..observability import tracing as _trace
 from ..optimizer import functional as fopt
 from ..optimizer.lr import LRScheduler
 from ..utils.functional import functional_call
@@ -253,11 +254,18 @@ class ShardedTrainStep:
         return in_datas, lab_datas
 
     def step(self, inputs, labels) -> Tensor:
-        """One optimizer step. inputs/labels: Tensor or tuple of Tensors."""
-        in_datas, lab_datas = self._stage_batch(inputs, labels)
-        lr = jnp.asarray(self._eager_opt.get_lr(), jnp.float32)
-        loss, self.params, self.opt_state = self._step_fn(self.params, self.opt_state, lr,
-                                                          in_datas, lab_datas)
+        """One optimizer step. inputs/labels: Tensor or tuple of Tensors.
+
+        ``train.dispatch`` (entry to the return of the jitted call:
+        staging the batch and the enqueue) is the trainer's one host
+        phase inside the program; the step itself runs on the device
+        after this returns."""
+        with _trace.profiled_span("train.dispatch", "train", "train",
+                                  {"step": self._eager_opt._step_count}):
+            in_datas, lab_datas = self._stage_batch(inputs, labels)
+            lr = jnp.asarray(self._eager_opt.get_lr(), jnp.float32)
+            loss, self.params, self.opt_state = self._step_fn(
+                self.params, self.opt_state, lr, in_datas, lab_datas)
         self._eager_opt._step_count += 1
         if isinstance(self._eager_opt._learning_rate, LRScheduler):
             pass  # user drives scheduler.step() as in eager flow

@@ -24,11 +24,16 @@ How capture works (zero extra compiles, host-side only):
 - an entry that compiles several programs (e.g. a tiny dtype-convert
   plus the real step) keeps the DOMINANT executable's analysis (max
   flops, then max bytes) and counts the rest.
-- per-entry wall timings ride the existing ``entrypoint()`` scopes via
-  ``recompile.add_call_hook`` (two clock reads per entry call — the
-  engine's step loop already pays more than that for its histogram),
-  so the ledger can join static FLOPs/bytes with measured time into
-  achieved FLOP/s, achieved GB/s, and MFU.
+- an entry's time is what its OWNER hands it with ``note_entry_time``
+  from an interval that ends in a host sync (the serving engine:
+  dispatch of ``serving.step`` to its tokens on the host). A jitted
+  call returns before the device has run it, so the wall time of the
+  ``entrypoint()`` scope around it is the enqueue, and static FLOPs
+  over that is no rate: this module no longer times those scopes.
+  Where no synced interval exists (prefill chunks, the asynchronous
+  train step) the ledger's achieved FLOP/s, GB/s, MFU and bandwidth
+  fields are null. The device's own times are in a profiler trace
+  (``perfbench/trace_reduce.py``).
 
 Roofline classification compares each entry's arithmetic intensity
 (flops / bytes accessed) against the device's machine balance
@@ -85,7 +90,8 @@ __all__ = [
     "extract_cost_analysis", "extract_memory_analysis",
     "capture_compiled", "MEMORY_STATS_UNSUPPORTED",
     "peak_specs", "PEAK_FLOPS_ENV", "PEAK_HBM_ENV",
-    "ledger", "ledger_entry", "note_entry_items", "reset",
+    "ledger", "ledger_entry", "note_entry_items", "note_entry_time",
+    "reset",
     "register_memory_component", "unregister_memory_component",
     "hbm_ledger",
     "is_oom_error", "oom_report", "dump_oom",
@@ -127,7 +133,7 @@ _install_lock = threading.Lock()
 
 _lock = threading.Lock()
 # entry -> ledger record (see _new_rec); writer paths take _lock only
-# on compile capture (rare); the per-call hook appends to a deque.
+# on compile capture (rare); note_entry_time appends to a deque.
 _entries: Dict[str, dict] = {}
 
 # timing window per entry: achieved numbers use the recent mean so a
@@ -135,7 +141,7 @@ _entries: Dict[str, dict] = {}
 _TIMING_WINDOW = 64
 
 # thread-local set of entries that compiled during the CURRENT call:
-# the call hook drops that call's wall time (it includes the XLA
+# note_entry_time drops that call's time (it includes the XLA
 # compile — folding it in would understate steady-state MFU wildly)
 _tls = threading.local()
 
@@ -286,12 +292,14 @@ def capture_compiled(entry: str, compiled) -> Optional[dict]:
     return {**(cost or {}), **(mem or {})}
 
 
-def _on_entry_call(entry: str, dt_s: float):
-    """recompile.entrypoint exit hook: the measured-wall-time half of
-    the ledger join (StepTelemetry/step histograms already time the
-    same scopes; this keeps the per-ENTRY association). A call whose
-    scope compiled something is warmup — its wall time (which includes
-    the XLA compile) is excluded from the achieved-rate window."""
+def note_entry_time(entry: str, dt_s: float):
+    """One call of ``entry`` took ``dt_s``: the measured half of the
+    ledger join, handed over by the entry's owner from an interval that
+    ends in a host sync (see the module docstring; an interval that
+    ends when the jitted call returns measures the enqueue). A call
+    that compiled something on this thread is warmup — its time
+    includes the XLA compile and stays out of the achieved-rate
+    window."""
     if not perf_enabled():
         return
     compiled_now = getattr(_tls, "compiled", None)
@@ -333,7 +341,7 @@ def install() -> bool:
     a fresh XLA compile and a persistent-cache load alike — so each
     executable contributes its analyses to the ledger, attributed via
     the recompile monitor's entrypoint stack. Also registers the
-    entry-call timing hook and the flight-recorder state provider."""
+    flight-recorder state provider."""
     if _installed[0]:
         return True
     with _install_lock:
@@ -353,7 +361,6 @@ def install() -> bool:
             return exe
 
         _jcompiler.compile_or_get_cached = _compile_captured
-        _rc.add_call_hook(_on_entry_call)
         from . import tracing as _tracing
 
         _tracing.register_state_provider("perf", _state_provider)
@@ -484,7 +491,7 @@ def ledger_entry(entry: str, peaks: Optional[dict] = None,
     row["roofline"] = roofline_class(row["arithmetic_intensity"], peaks)
     row["bytes_per_item"] = (
         nbytes * row["calls"] / row["items"]
-        if nbytes and row["items"] else None)
+        if nbytes and row["items"] and row["calls"] else None)
     row["items_per_s"] = (
         row["items"] / row["total_time_s"]
         if row["items"] and row["total_time_s"] else None)

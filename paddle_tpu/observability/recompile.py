@@ -32,7 +32,7 @@ from . import metrics as _m
 __all__ = ["install", "installed", "entrypoint", "current_entry",
            "compile_events", "total_compiles", "entry_stats", "reset_entries",
            "reset_warmup", "warmup_scope", "register_entry_location",
-           "entry_location", "add_call_hook", "remove_call_hook"]
+           "entry_location"]
 
 logger = logging.getLogger("paddle_tpu.observability")
 
@@ -51,25 +51,6 @@ _entries_lock = threading.Lock()
 # entry name -> "file:line" of the jitted definition, so the retrace
 # warning points at the source the static analyzer also reports on
 _entry_locations: Dict[str, str] = {}
-# completed-call hooks: fn(entry_name, wall_seconds) fired on every
-# successful entrypoint exit — how the perf ledger joins each entry's
-# static FLOPs/bytes with measured time. Empty list = zero clock reads.
-_call_hooks: List = []
-
-
-def add_call_hook(fn) -> None:
-    """Register ``fn(entry, dt_s)`` to run when an entrypoint scope
-    completes (idempotent). With no hooks registered the entrypoint
-    takes no timestamps at all."""
-    if fn not in _call_hooks:
-        _call_hooks.append(fn)
-
-
-def remove_call_hook(fn) -> None:
-    try:
-        _call_hooks.remove(fn)
-    except ValueError:
-        pass
 
 
 def register_entry_location(name: str, fn=None,
@@ -121,19 +102,16 @@ class entrypoint:
     innermost entry. Completing the ``with`` block counts one call —
     the retrace detector's notion of "this entry is past warmup"."""
 
-    __slots__ = ("name", "t0")
+    __slots__ = ("name",)
 
     def __init__(self, name: str):
         self.name = name
-        self.t0 = None
 
     def __enter__(self):
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
         stack.append(self.name)
-        if _call_hooks:
-            self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
@@ -141,13 +119,6 @@ class entrypoint:
         if exc[0] is None:
             st = _entry_state(self.name)
             st["calls"] += 1
-            if self.t0 is not None:
-                dt = time.perf_counter() - self.t0
-                for hook in _call_hooks:
-                    try:
-                        hook(self.name, dt)
-                    except Exception:  # a perf hook must never break a call
-                        logger.debug("entry call hook failed", exc_info=True)
         return False
 
 

@@ -19,11 +19,30 @@ timestamps) plus a ``deque.append`` into a per-thread buffer.
 Per-thread buffers self-compact into the global bounded ring every
 ``_COMPACT_AT`` events (one amortized lock), and readers (exporters,
 the flight recorder) drain them under the same lock. Tracing is
-DEFAULT-ON: the measured overhead on ``bench_serving.py`` is the <2%
-acceptance number, and everything here is host-side only — no traced
-value ever sees an event, so the one-step-compile invariant holds with
-tracing enabled. ``PADDLE_TPU_TRACING=0`` (or ``disable_tracing()``)
-reduces every site to a single list-index check.
+DEFAULT-ON and host-side only — no traced value ever sees an event, so
+the one-step-compile invariant holds with tracing enabled. What it
+costs is measured on the chip by the benchmark's cells, spans on in
+every run (PERF.md, section 6, PR 25); no CPU timing stands for it.
+``PADDLE_TPU_TRACING=0`` (or ``disable_tracing()``) reduces every site
+to a single list-index check (``Phases``: one an iteration, in
+``open``), the profiler annotations of ``Phases`` and ``profiled_span``
+included.
+
+Clocks. The ring's ``ts_ns`` is ``time.perf_counter_ns`` (Linux:
+``CLOCK_MONOTONIC``), the clock the ``Request`` timestamps and the
+benchmark's host clock use. A ``jax.profiler`` session keeps its own
+clock: an ``.xplane.pb`` stores every host and device event relative
+to the start of its session, and exports no offset to
+``perf_counter``. So the spans that have to be read against device
+operations (``Phases``, ``profiled_span``: the serving engine's
+``engine.*`` phases and the trainer's ``train.dispatch``) are written
+twice at the same boundaries: into the ring from the timestamps the
+caller took, and, through ``jax.profiler.TraceAnnotation``, onto the
+host plane of whatever profiler session is active, under the same
+name. A tool that needs both clocks in one file reads the annotation
+(``perfbench/gap_phases.py``); one that has the session's start on the
+host clock adds it to the trace's times. With no session active an
+annotation is a flag check in the runtime (0.3 us each, measured here).
 
 Event schema (what ``events()`` returns and the JSONL export writes,
 one JSON object per line):
@@ -66,11 +85,15 @@ import weakref
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+# the span on the host plane of an active profiler session (Clocks, above)
+from jax.profiler import TraceAnnotation as _Annotation
+
 from . import metrics as _m
 
 __all__ = [
     "tracing_enabled", "enable_tracing", "disable_tracing",
     "span", "begin_span", "end_span", "instant", "complete",
+    "profiled_span", "Phases",
     "trace_context", "current_trace",
     "events", "clear", "chrome_trace", "export_chrome_trace",
     "export_jsonl", "span_counts", "summary",
@@ -90,7 +113,10 @@ _TRACING = [os.environ.get("PADDLE_TPU_TRACING", "1") != "0"]
 _COMPACT_AT = 512
 
 # The bounded flight-recorder ring: most recent events, process-wide.
-_RING_CAPACITY = int(os.environ.get("PADDLE_TPU_TRACE_RING", "16384"))
+# Sized for the serving engine's iteration phases: about nine events an
+# iteration, 400 a second on a chip, so 65,536 reach back over two
+# minutes (the benchmark reads a 51 s window after its drain).
+_RING_CAPACITY = int(os.environ.get("PADDLE_TPU_TRACE_RING", "65536"))
 
 _lock = threading.Lock()
 _ring: deque = deque(maxlen=_RING_CAPACITY)
@@ -153,6 +179,7 @@ def _flush_locked():
     per-name totals); prune buffers whose threads are gone."""
     with _lock:
         dead = []
+        by_cat: Dict[str, int] = {}
         for i, (tref, b) in enumerate(_buffers):
             while True:
                 try:
@@ -162,11 +189,13 @@ def _flush_locked():
                 _ring.append(ev)
                 key = ev[1]
                 _counts[key] = _counts.get(key, 0) + 1
-                _events_total.labels(ev[2]).inc()
+                by_cat[ev[2]] = by_cat.get(ev[2], 0) + 1
             if tref() is None:
                 dead.append(i)
         for i in reversed(dead):
             del _buffers[i]
+        for cat, n in by_cat.items():
+            _events_total.labels(cat).inc(n)
 
 
 # ---------------------------------------------------------------------------
@@ -298,6 +327,124 @@ def complete(name: str, cat: str, trace, ts_ns: int, dur_ns: int, args=None):
         return
     _record("X", name, cat, trace, threading.get_ident(), ts_ns,
             max(dur_ns, 0), args)
+
+
+class profiled_span:
+    """``span`` that is also written to an active profiler session
+    under the same name::
+
+        with tracing.profiled_span("train.dispatch", "train", "train",
+                                   {"step": n}):
+            ...
+
+    With tracing disabled it is the one flag check and nothing else:
+    no annotation, no clock read."""
+
+    __slots__ = ("name", "cat", "trace", "args", "_t0", "_ann")
+
+    def __init__(self, name: str, cat: str = "", trace=None, args=None):
+        self.name, self.cat, self.trace, self.args = name, cat, trace, args
+        self._ann = None
+
+    def __enter__(self):
+        if _TRACING[0]:
+            self._ann = _Annotation(self.name)
+            self._ann.__enter__()
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            t1 = time.perf_counter_ns()
+            self._ann.__exit__(*exc)
+            self._ann = None
+            complete(self.name, self.cat, self.trace, self._t0,
+                     t1 - self._t0, self.args)
+        return False
+
+
+class Phases:
+    """The consecutive phases of one loop iteration on one thread, under
+    a parent span: ``open`` starts the parent and its first phase,
+    every ``mark`` is ONE clock read that ends the open phase and
+    starts the next, ``close`` ends both. No clock is read that is not
+    a span's edge. The phases are kept until ``close`` says whether the
+    iteration did any work: an iteration that did none records nothing
+    in the ring. All of them go to an active profiler session, where a
+    spin that found nothing to do is worth seeing. Each recorded event
+    carries ``iter``, the number of the iteration, which ties a span on
+    another lane to the iteration that ran it.
+
+    ``open`` reads the tracing flag once for the iteration (``on``).
+    With tracing disabled ``open`` is its one clock read (its caller
+    uses the time), ``mark`` returns 0 and ``close`` only counts: no
+    annotation, no list append, no further clock read. A caller builds
+    a phase's args only where ``on`` is true."""
+
+    __slots__ = ("parent", "cat", "trace", "seq", "on", "t_open", "t_mark",
+                 "_name", "_done", "_ann", "_ann_parent")
+
+    def __init__(self, parent: str, cat: str, trace):
+        self.parent, self.cat, self.trace = parent, cat, trace
+        self.seq = 0          # number of the open (or next) iteration
+        self.on = False       # tracing was enabled when this one opened
+        self.t_open = self.t_mark = 0
+        self._name = None
+        self._done: list = []
+        self._ann = self._ann_parent = None
+
+    def open(self, first: str) -> int:
+        """Start an iteration in phase ``first``; returns the time."""
+        if self._name is not None:   # an exception skipped close()
+            self.close(False)
+        self.on = _TRACING[0]
+        if self.on:
+            self._done.clear()
+            self._ann_parent = _Annotation(self.parent)
+            self._ann_parent.__enter__()
+            self._ann = _Annotation(first)
+            self._ann.__enter__()
+            self._name = first
+        self.t_open = self.t_mark = time.perf_counter_ns()
+        return self.t_open
+
+    def mark(self, name: str, args: Optional[dict] = None) -> int:
+        """End the open phase, with its ``args``, and start ``name``;
+        returns the time of the boundary (0 with tracing disabled)."""
+        if not self.on:
+            return 0
+        now = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
+        self._done.append((self._name, self.t_mark, now, args))
+        self._ann = _Annotation(name)
+        self._ann.__enter__()
+        self._name, self.t_mark = name, now
+        return now
+
+    def close(self, worked: bool, args: Optional[dict] = None,
+              parent_args: Optional[dict] = None) -> int:
+        """End the open phase (``args``) and the parent
+        (``parent_args``); record them if the iteration ``worked``.
+        Returns the time, as ``mark`` does."""
+        now = 0
+        if self.on:
+            now = time.perf_counter_ns()
+            self._ann.__exit__(None, None, None)
+            self._ann_parent.__exit__(None, None, None)
+            self._done.append((self._name, self.t_mark, now, args))
+            self._name = None
+            if worked:
+                tid = threading.get_ident()
+                seq = {"iter": self.seq}
+                for name, t0, t1, a in self._done:
+                    _record("X", name, self.cat, self.trace, tid, t0,
+                            t1 - t0, {**a, **seq} if a else seq)
+                _record("X", self.parent, self.cat, self.trace, tid,
+                        self.t_open, now - self.t_open,
+                        {**parent_args, **seq} if parent_args else seq)
+        if worked:
+            self.seq += 1
+        return now
 
 
 # ---------------------------------------------------------------------------

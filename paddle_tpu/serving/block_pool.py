@@ -57,7 +57,12 @@ class BlockPoolError(RuntimeError):
 class BlockPool:
     """Ref-counted free-list allocator over ``num_blocks`` KV blocks.
 
-    Thread-safe (one lock; every operation is O(1) or O(n_requested)).
+    Thread-safe (one lock; every operation is O(1) or O(n_requested),
+    the count of shared blocks included: it is kept as references
+    cross 1 <-> 2, never reduced over the pool). The ``kv_blocks_*``
+    gauges are not written by alloc/incref/decref: their owner calls
+    ``set_gauges()`` at its own grain (the serving engine once an
+    iteration), which is finer than any scrape.
     Allocation is all-or-nothing: ``alloc(n)`` either returns ``n``
     block ids or raises ``PoolExhaustedError`` leaving the pool
     untouched. The free list is LIFO so tests and replays are
@@ -70,6 +75,7 @@ class BlockPool:
     GUARDED_BY = {
         "_free": "_lock",
         "_ref": "_lock",
+        "_shared": "_lock",
         "alloc_total": "_lock",
         "free_total": "_lock",
         "cow_forks": "_lock",
@@ -90,12 +96,12 @@ class BlockPool:
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
         self._ref = np.zeros(num_blocks, np.int64)
         self._ref[DUMP_BLOCK] = 1  # pinned forever
+        self._shared = 0     # live blocks with more than one reference
         self.alloc_total = 0
         self.free_total = 0
         self.cow_forks = 0   # engine reports forks via note_cow_fork()
         self.high_watermark = 0
-        with self._lock:
-            self._set_gauges()
+        self.set_gauges()
 
     # -- core ops ------------------------------------------------------------
     def alloc(self, n: int = 1) -> List[int]:
@@ -114,7 +120,6 @@ class BlockPool:
             self.alloc_total += n
             self.high_watermark = max(self.high_watermark,
                                       self._used_unlocked())
-            self._set_gauges()
             return ids
 
     def incref(self, block_id: int) -> None:
@@ -122,19 +127,21 @@ class BlockPool:
         with self._lock:
             self._check_live(block_id)
             self._ref[block_id] += 1
-            self._set_gauges()
+            if self._ref[block_id] == 2:
+                self._shared += 1
 
     def decref(self, block_id: int) -> bool:
         """Drop one reference; returns True when the block was freed."""
         with self._lock:
             self._check_live(block_id)
             self._ref[block_id] -= 1
-            if self._ref[block_id] == 0:
+            left = self._ref[block_id]
+            if left == 0:
                 self._free.append(block_id)
                 self.free_total += 1
-                self._set_gauges()
                 return True
-            self._set_gauges()
+            if left == 1:
+                self._shared -= 1
             return False
 
     def ref(self, block_id: int) -> int:
@@ -170,7 +177,7 @@ class BlockPool:
         return self.usable_blocks - len(self._free)
 
     def _shared_unlocked(self) -> int:  # holds-lock: _lock
-        return int((self._ref[1:] > 1).sum())
+        return self._shared
 
     @property
     def usable_blocks(self) -> int:
@@ -211,10 +218,13 @@ class BlockPool:
                 "cow_forks": self.cow_forks,
             }
 
-    def _set_gauges(self):  # holds-lock: _lock
-        _sm.kv_blocks_total.set(self.usable_blocks)
-        _sm.kv_blocks_in_use.set(self._used_unlocked())
-        _sm.kv_blocks_shared.set(self._shared_unlocked())
+    def set_gauges(self) -> None:
+        """Publish ``paddle_tpu_kv_blocks_{total,in_use,shared}``: three
+        stores under one lock hold, no reduction."""
+        with self._lock:
+            _sm.kv_blocks_total.set(self.usable_blocks)
+            _sm.kv_blocks_in_use.set(self._used_unlocked())
+            _sm.kv_blocks_shared.set(self._shared_unlocked())
 
 
 class PrefixCache:

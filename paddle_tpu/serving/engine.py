@@ -64,8 +64,16 @@ read path gathers the exact same K/V values the contiguous cache holds
 (garbage beyond a row's length is an exact no-op under the additive
 causal mask, just like the contiguous cache's zeros).
 
-Observability: the ``paddle_tpu_serving_*`` instruments plus the paged
-``paddle_tpu_kv_blocks_{total,in_use,shared}`` gauges and
+Observability: every iteration that does work records ``engine.iter``
+and its phases (``engine.admit`` / ``.prefill`` / ``.reserve`` /
+``.dispatch`` / ``.wait`` / ``.emit``; ``engine.idle`` in the serving
+loop) on the engine lane of ``observability.tracing`` and, while a
+``jax.profiler`` session is active, on its host plane; the counts the
+benchmark reads are plain integers on the engine (``counters()``, O(1)
+and lock-free; ``stats()`` adds the sections that cost). Then the
+``paddle_tpu_serving_*`` instruments plus the paged
+``paddle_tpu_kv_blocks_{total,in_use,shared}`` gauges (set once an
+iteration) and
 ``paddle_tpu_prefix_cache_{hits,misses}_total`` counters; compiles are
 attributed to ``serving.step`` / ``serving.prefill_chunk`` /
 ``serving.cow`` (paged) or ``serving.prefill[bucket]`` (contiguous) —
@@ -555,6 +563,18 @@ class ServingEngine:
         self._occupancy_integral = 0
         self._outcomes = {}
         self._preempt_count = 0
+        # event counters: plain ints bumped where the event happens
+        # (under _step_lock), read lock-free by counters() and written
+        # as deltas into the engine.* span args of the iteration; with
+        # _preempt_count, _steps and _occupancy_integral above they are
+        # all a metric reads (blocks, COW forks and chunks are counted
+        # once already: pool.alloc_total, pool.cow_forks,
+        # prefill_chunks_total)
+        self._n_prompt_tokens = 0      # tokens admitted prefills cover
+        self._n_prefix_hit_tokens = 0  # of those, adopted from the cache
+        # the iteration's phase spans (engine.iter and its children);
+        # .seq numbers the iterations that did work
+        self._phases = _trace.Phases("engine.iter", "engine", "engine")
         self._last_progress_ts = time.perf_counter()  # stall detector
         self._step_lock = threading.RLock()
         self._wake = threading.Condition()
@@ -2062,6 +2082,8 @@ class ServingEngine:
                 self.pool.decref(b)
             raise
         req._resume = None  # consumed only once admission is certain
+        self._n_prompt_tokens += total
+        self._n_prefix_hit_tokens += matched_tok
         if self.prefix_cache is not None:
             self.prefix_cache.note(len(mblocks), n_blocks - len(mblocks))
             _sm.prefix_cache_hits.inc(len(mblocks))
@@ -2152,7 +2174,7 @@ class ServingEngine:
         tc1 = time.perf_counter_ns()
         _trace.complete("prefill_chunk", "request", req.trace, tc0, tc1 - tc0,
                         {"slot": slot, "start": start, "end": end,
-                         "last": is_last})
+                         "last": is_last, "iter": self._phases.seq})
         _sm.prefill_chunk_seconds.observe((tc1 - tc0) / 1e9)
         job.done = end
         _sm.prefill_chunks_total.inc()
@@ -2182,7 +2204,7 @@ class ServingEngine:
         req._tr_event("first_token")
         _sm.ttft_seconds.observe(req.ttft_s)
         _sm.ttft_summary.observe(req.ttft_s)
-        _sm.tokens_total.labels("generated").inc()
+        _sm.tokens_generated.inc()
         self._finish_or_keep(slot, req, tok0, now)
         self._update_occupancy_gauges()
 
@@ -2195,6 +2217,7 @@ class ServingEngine:
         ids[0, :L] = req.prompt
         t0 = time.perf_counter()
         req.slot = slot
+        self._n_prompt_tokens += L
         self._note_admission(req, t0)
         with _trace.trace_context(req.trace), \
                 _entrypoint(f"serving.prefill[{Lb}]"):
@@ -2219,7 +2242,7 @@ class ServingEngine:
         now = time.perf_counter()
         _sm.prefill_seconds.observe(now - t0)
         _sm.tokens_total.labels("prompt").inc(L)
-        _sm.tokens_total.labels("generated").inc()
+        _sm.tokens_generated.inc()
 
         self._slot_req[slot] = req
         self._slot_sampling[slot] = bool(p.do_sample)
@@ -2312,116 +2335,150 @@ class ServingEngine:
             raise
 
     def _step_impl(self) -> bool:
+        """The iteration, under ``_step_lock``: admit, advance prefills,
+        reserve blocks for the decode rows, dispatch the step, wait for
+        its tokens, emit them, each a phase of ``engine.iter``. Every
+        ``ph.mark`` is the one clock read of a phase boundary; the step
+        histogram and the ``serving.step`` span reuse those readings
+        (with tracing disabled a mark reads no clock, and the step's
+        two edges are read here)."""
         with self._step_lock:
-            self._last_progress_ts = time.perf_counter()
-            self._admit()
+            ph = self._phases
+            n_hit, n_prompt, n_pre = (self._n_prefix_hit_tokens,
+                                      self._n_prompt_tokens,
+                                      self._preempt_count)
+            self._last_progress_ts = ph.open("engine.admit") / 1e9
             worked = False
-            if self.paged:
-                for slot in range(self.config.max_slots):
-                    if self._jobs[slot] is None:
-                        continue
-                    worked = True
-                    try:
-                        self._advance_prefill(slot)
-                    except PoolExhaustedError:
-                        self._preempt(slot)  # retried from the queue front
-                    except Exception as e:  # noqa: BLE001
-                        self._free_slot(slot, RequestStatus.FAILED, "failed",
-                                        error=repr(e))
-
-            active = [i for i, r in enumerate(self._slot_req)
-                      if r is not None and self._decoding[i]]
-            # cancellation between steps: drop flagged slots without
-            # paying another decode step for them
-            for i in list(active):
-                if self._slot_req[i].cancel_requested:
-                    self._free_slot(i, RequestStatus.CANCELLED, "cancelled")
-                    active.remove(i)
-            if not active:
-                self._update_occupancy_gauges()
-                return worked
-
-            if self.paged:
-                # every active row writes this step's K/V at its current
-                # length — or, speculatively, at its whole verify-bundle
-                # window [len, len + spec_len): cross a block boundary
-                # -> allocate; write into a shared (prefix-cached) block
-                # -> COW fork. Allocation pressure preempts the
-                # latest-admitted request, which can shrink `active`.
-                bs = self.config.block_size
+            try:
+                self._admit()
+                ph.mark("engine.prefill", ph.on and {
+                    "prefix_hit_tokens": self._n_prefix_hit_tokens - n_hit,
+                    "prompt_tokens": self._n_prompt_tokens - n_prompt})
+                if self.paged:
+                    for slot in range(self.config.max_slots):
+                        if self._jobs[slot] is None:
+                            continue
+                        worked = True
+                        try:
+                            self._advance_prefill(slot)
+                        except PoolExhaustedError:
+                            self._preempt(slot)  # retried from the queue front
+                        except Exception as e:  # noqa: BLE001
+                            self._free_slot(slot, RequestStatus.FAILED,
+                                            "failed", error=repr(e))
+                ph.mark("engine.reserve")
+                active = [i for i, r in enumerate(self._slot_req)
+                          if r is not None and self._decoding[i]]
+                # cancellation between steps: drop flagged slots without
+                # paying another decode step for them
                 for i in list(active):
-                    if self._slot_req[i] is None or not self._decoding[i]:
-                        continue  # preempted by an earlier row's reclaim
-                    # _row_spec_len is a pure function of host state that
-                    # does not change between here and the dispatch, so
-                    # the bundle can never write past this coverage
-                    m = self._row_spec_len(i) if self.spec else 1
-                    first_bi = self._slot_len[i] // bs
-                    last_bi = (self._slot_len[i] + m - 1) // bs
-                    try:
-                        for bi in range(first_bi, last_bi + 1):
-                            if bi >= len(self._slot_blocks[i]):
-                                nid = self._reclaim_alloc(1, i)[0]
-                                self._slot_blocks[i].append(nid)
-                                self._bt[i, bi] = nid
-                            else:
-                                self._ensure_writable(i, bi)
-                    except PoolExhaustedError:
-                        self._preempt(i)
-                active = [i for i in active
-                          if self._slot_req[i] is not None
-                          and self._decoding[i]]
+                    if self._slot_req[i].cancel_requested:
+                        self._free_slot(i, RequestStatus.CANCELLED,
+                                        "cancelled")
+                        active.remove(i)
                 if not active:
-                    self._update_occupancy_gauges()
+                    return worked
+
+                if self.paged:
+                    # every active row writes this step's K/V at its current
+                    # length — or, speculatively, at its whole verify-bundle
+                    # window [len, len + spec_len): cross a block boundary
+                    # -> allocate; write into a shared (prefix-cached) block
+                    # -> COW fork. Allocation pressure preempts the
+                    # latest-admitted request, which can shrink `active`.
+                    bs = self.config.block_size
+                    for i in list(active):
+                        if self._slot_req[i] is None or not self._decoding[i]:
+                            continue  # preempted by an earlier row's reclaim
+                        # _row_spec_len is a pure function of host state that
+                        # does not change between here and the dispatch, so
+                        # the bundle can never write past this coverage
+                        m = self._row_spec_len(i) if self.spec else 1
+                        first_bi = self._slot_len[i] // bs
+                        last_bi = (self._slot_len[i] + m - 1) // bs
+                        try:
+                            for bi in range(first_bi, last_bi + 1):
+                                if bi >= len(self._slot_blocks[i]):
+                                    nid = self._reclaim_alloc(1, i)[0]
+                                    self._slot_blocks[i].append(nid)
+                                    self._bt[i, bi] = nid
+                                else:
+                                    self._ensure_writable(i, bi)
+                        except PoolExhaustedError:
+                            self._preempt(i)
+                    active = [i for i in active
+                              if self._slot_req[i] is not None
+                              and self._decoding[i]]
+                    if not active:
+                        worked = True
+                        return True
+
+                worked = True
+                t0_ns = ph.mark("engine.dispatch") \
+                    or time.perf_counter_ns()
+                any_sampling = any(self._slot_sampling[i] for i in active)
+                active_mask = np.zeros(self.config.max_slots, bool)
+                active_mask[active] = True
+                if self.spec:
+                    self._spec_step(active, active_mask, any_sampling,
+                                    t0_ns, ph)
                     return True
+                with _entrypoint("serving.step"):
+                    if self.paged:
+                        bt_step = self._bt.copy()
+                        bt_step[~active_mask] = 0  # inactive -> dump block
+                        toks, self._pools, self._state = self._step_fn(
+                            self._pb, self._pools, self._state,
+                            jnp.asarray(bt_step), jnp.asarray(any_sampling),
+                            jnp.asarray(active_mask))
+                    else:
+                        toks, self._caches, self._state = self._step_fn(
+                            self._pb, self._caches, self._state,
+                            jnp.asarray(any_sampling),
+                            jnp.asarray(active_mask))
+                ph.mark("engine.wait")
+                toks_np = np.asarray(toks)  # the step's ONE device->host sync
+                now_ns = ph.mark("engine.emit") or time.perf_counter_ns()
+                now = now_ns / 1e9
+                step_s = (now_ns - t0_ns) / 1e9
+                _sm.steps_total.inc()
+                _sm.step_seconds.observe(step_s)
+                # the engine-lane step span (dispatch plus wait) reuses the
+                # boundaries' timestamps: no extra clock read on the hot path
+                _trace.complete("serving.step", "engine", "engine", t0_ns,
+                                now_ns - t0_ns,
+                                {"active": len(active), "step": self._steps})
+                self._steps += 1
+                self._occupancy_integral += len(active)
+                from ..observability import perf as _perf
+                _perf.note_entry_items("serving.step", len(active))
+                # dispatch to tokens on the host: the one synced interval of
+                # the step, which is what the perf ledger may divide by
+                _perf.note_entry_time("serving.step", step_s)
 
-            t0 = time.perf_counter()
-            any_sampling = any(self._slot_sampling[i] for i in active)
-            active_mask = np.zeros(self.config.max_slots, bool)
-            active_mask[active] = True
-            if self.spec:
-                return self._spec_step(active, active_mask, any_sampling, t0)
-            with _entrypoint("serving.step"):
+                for i in active:
+                    req = self._slot_req[i]
+                    if self.paged:
+                        self._slot_len[i] = min(self._slot_len[i] + 1,
+                                                self.config.max_len - 1)
+                    t = int(toks_np[i])
+                    prev = req.last_token_ts
+                    req.push_token(t, now)
+                    _sm.tokens_generated.inc()
+                    if prev is not None:
+                        _sm.tpot_seconds.observe(now - prev)
+                        _sm.tpot_summary.observe(now - prev)
+                    self._finish_or_keep(i, req, t, now)
+                return True
+            finally:
+                self._update_occupancy_gauges()
                 if self.paged:
-                    bt_step = self._bt.copy()
-                    bt_step[~active_mask] = 0  # inactive rows -> dump block
-                    toks, self._pools, self._state = self._step_fn(
-                        self._pb, self._pools, self._state,
-                        jnp.asarray(bt_step), jnp.asarray(any_sampling),
-                        jnp.asarray(active_mask))
-                else:
-                    toks, self._caches, self._state = self._step_fn(
-                        self._pb, self._caches, self._state,
-                        jnp.asarray(any_sampling), jnp.asarray(active_mask))
-            toks_np = np.asarray(toks)  # the step's ONE device->host sync
-            now = time.perf_counter()
-            _sm.steps_total.inc()
-            _sm.step_seconds.observe(now - t0)
-            # the engine-lane step span reuses the timestamps already
-            # taken for the histogram: zero extra clock reads on the
-            # decode hot path
-            _trace.complete("serving.step", "engine", "engine",
-                            int(t0 * 1e9), int((now - t0) * 1e9),
-                            {"active": len(active), "step": self._steps})
-            self._steps += 1
-            self._occupancy_integral += len(active)
-            from ..observability import perf as _perf
-            _perf.note_entry_items("serving.step", len(active))
-
-            for i in active:
-                req = self._slot_req[i]
-                if self.paged:
-                    self._slot_len[i] = min(self._slot_len[i] + 1,
-                                            self.config.max_len - 1)
-                t = int(toks_np[i])
-                prev = req.last_token_ts
-                req.push_token(t, now)
-                _sm.tokens_total.labels("generated").inc()
-                if prev is not None:
-                    _sm.tpot_seconds.observe(now - prev)
-                    _sm.tpot_summary.observe(now - prev)
-                self._finish_or_keep(i, req, t, now)
-            return True
+                    self.pool.set_gauges()
+                # an iteration that only admitted (and lost the request
+                # again) is recorded too: its engine.admit has tokens
+                ph.close(worked or self._n_prompt_tokens > n_prompt, None,
+                         ph.on and {"preempted":
+                                    self._preempt_count - n_pre})
 
     # -- the speculative iteration -------------------------------------------
     def _row_spec_len(self, slot: int) -> int:
@@ -2448,14 +2505,17 @@ class ServingEngine:
             return max(1, min(width, room))
         return max(1, min(k_req + 1, remaining, room))
 
-    def _spec_step(self, active, active_mask, any_sampling, t0: float) -> bool:
+    def _spec_step(self, active, active_mask, any_sampling, t0_ns: int,
+                   ph) -> None:
         """One speculative iteration for the whole pool: ONE jitted
         draft program (k draft-model forwards), ONE jitted verify
         (target scores the k+1-wide bundle through the paged kernel,
         accepts the longest matching prefix, bumps each row's position
         by its own accept length through the block tables). The draft
         program is skipped — host-side, no recompile — when no live row
-        wants more than a plain step this round."""
+        wants more than a plain step this round. Entered in
+        ``engine.dispatch`` (since ``t0_ns``); leaves ``engine.emit``
+        open."""
         B = self.config.max_slots
         k = self._spec_k
         spec_valid = np.zeros(B, np.int32)
@@ -2494,11 +2554,13 @@ class ServingEngine:
                 cand, n_emit, self._pools, self._state = self._verify_fn(
                     self._pb, self._pools, self._state, bt_j, drafts,
                     sv_j, as_j, jnp.asarray(active_mask))
+        ph.mark("engine.wait")
         cand_np = np.asarray(cand)   # the round's device->host sync
         n_np = np.asarray(n_emit)
-        now = time.perf_counter()
+        now_ns = ph.mark("engine.emit") or time.perf_counter_ns()
+        now = now_ns / 1e9
         _sm.steps_total.inc()
-        _sm.step_seconds.observe(now - t0)
+        _sm.step_seconds.observe((now_ns - t0_ns) / 1e9)
         _trace.complete("serving.spec_verify", "engine", "engine",
                         int(tv0 * 1e9), int((now - tv0) * 1e9),
                         {"active": len(active), "step": self._steps,
@@ -2513,6 +2575,8 @@ class ServingEngine:
                                    int((spec_valid - 1).clip(0).sum()))
         _perf.note_entry_items("serving.spec_verify",
                                int(n_np[active].sum()))
+        # verify dispatch to its tokens on the host: a synced interval
+        _perf.note_entry_time("serving.spec_verify", now - tv0)
 
         for i in active:
             req = self._slot_req[i]
@@ -2543,10 +2607,11 @@ class ServingEngine:
                                     self.config.max_len - 1)
             prev = req.last_token_ts
             interval = (now - prev) if prev is not None else None
+            pushed = 0
             for j in range(n):
                 t = int(cand_np[i, j])
                 req.push_token(t, now)
-                _sm.tokens_total.labels("generated").inc()
+                pushed += 1
                 if interval is not None:
                     # the round's wall time amortized over its tokens —
                     # the honest per-token cadence of a multi-token step
@@ -2554,7 +2619,7 @@ class ServingEngine:
                     _sm.tpot_summary.observe(interval / n)
                 if self._finish_or_keep(i, req, t, now):
                     break
-        return True
+            _sm.tokens_generated.inc(pushed)
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> int:
         """Drive ``step()`` until queue and slots are empty (the
@@ -2598,7 +2663,9 @@ class ServingEngine:
                     with self._wake:
                         if self._running and not self.scheduler.depth \
                                 and not self.busy_slots():
-                            self._wake.wait(0.05)
+                            with _trace.profiled_span("engine.idle", "engine",
+                                                      "engine"):
+                                self._wake.wait(0.05)
         except BaseException as e:  # noqa: BLE001 — loop-level crash
             self._on_loop_crash(e)
 
@@ -2656,6 +2723,8 @@ class ServingEngine:
             req.finish(RequestStatus.FAILED, error=error)
             _sm.requests_total.labels("failed").inc()
             self._outcomes["failed"] = self._outcomes.get("failed", 0) + 1
+        if self.paged:
+            self.pool.set_gauges()  # slots freed outside an iteration
 
     def _export_inflight(self) -> tuple:
         """Detach every running and queued request WITHOUT finishing
@@ -2961,15 +3030,44 @@ class ServingEngine:
         payload["status"] = "ok"
         return 200, payload
 
+    def counters(self) -> dict:
+        """The engine's running counts, O(1): plain integers read
+        without ``_step_lock``, the pool lock, a digest or the perf
+        ledger, so a caller may poll this while the engine serves (the
+        queue depth takes the scheduler's own short lock). Each is
+        bumped where its event happens; the ``engine.admit`` and
+        ``engine.iter`` spans carry the token and preemption counts per
+        iteration. ``slot_steps`` is the occupancy integral: decode rows
+        summed over ``steps``. Blocks, COW forks and chunks are counted
+        by the pool (``stats()["kv_blocks"]``) and the metrics
+        registry."""
+        return {
+            "steps": self._steps,
+            "slots": self.config.max_slots,
+            "slot_steps": self._occupancy_integral,
+            "queue_depth": self.scheduler.depth,
+            "prompt_tokens": self._n_prompt_tokens,
+            "prefix_hit_tokens": self._n_prefix_hit_tokens,
+            "preemptions": self._preempt_count,
+        }
+
     def stats(self) -> dict:
+        """``counters()`` plus the sections that cost: latency digests
+        (sorts of up to 4,096 samples each), the perf ledger, pool and
+        prefix-cache statistics (their own locks). None of it takes
+        ``_step_lock``; it is pure-Python work beside the engine's
+        thread, so call it at a scrape's grain, not a step's."""
+        c = self.counters()
         out = {
             "kv_mode": self.config.kv_mode,
-            "slots": self.config.max_slots,
+            "slots": c["slots"],
             "slots_busy": self.busy_slots(),
-            "queue_depth": self.scheduler.depth,
+            "queue_depth": c["queue_depth"],
             "max_len": self.config.max_len,
-            "steps": self._steps,
-            "mean_occupancy": self.mean_occupancy,
+            "steps": c["steps"],
+            "mean_occupancy": ((c["slot_steps"] / (c["steps"] * c["slots"]))
+                               if c["steps"] else None),
+            "counters": c,
             "outcomes": dict(self._outcomes),
             "running": self._running,
             "healthy": self.healthy,
@@ -2980,7 +3078,7 @@ class ServingEngine:
             "max_queue_depth": self.scheduler.max_queue_depth,
             "latency_digests": _sm.latency_digests(),
             "goodput_tokens_per_s": _sm.goodput_tokens_per_second.value(),
-            "preemptions": self._preempt_count,
+            "preemptions": c["preemptions"],
             "tp": self._tp,
         }
         # the performance ledger for this engine's executables: per-entry

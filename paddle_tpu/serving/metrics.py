@@ -18,7 +18,7 @@ from ..observability.perf import hbm_bw_util_gauge, mfu_gauge
 
 __all__ = [
     "mfu_gauge", "hbm_bw_util_gauge",
-    "requests_total", "tokens_total", "queue_depth", "slots_busy",
+    "requests_total", "tokens_total", "tokens_generated", "queue_depth", "slots_busy",
     "slot_occupancy", "steps_total", "step_seconds", "prefill_seconds",
     "ttft_seconds", "tpot_seconds", "engine_crashes_total",
     "kv_blocks_total", "kv_blocks_in_use", "kv_blocks_shared",
@@ -56,6 +56,9 @@ tokens_total = _m.counter(
     "paddle_tpu_serving_tokens_total",
     "tokens through the serving engine (prompt = prefilled, "
     "generated = decoded)", ("kind",))
+# the per-token child, bound once: the decode loop bumps it for every
+# row of every step and need not look its label up each time
+tokens_generated = tokens_total.labels("generated")
 queue_depth = _m.gauge(
     "paddle_tpu_serving_queue_depth",
     "requests waiting for a decode slot")

@@ -1,0 +1,439 @@
+"""The engine iteration's phase spans, the counters bumped where the
+work happens, and the instrumentation taken off the per-block path.
+
+Oracles:
+- PHASES: every iteration that worked has ONE ``engine.iter`` whose
+  children (admit, prefill, reserve, dispatch, wait, emit) are disjoint,
+  lie inside it and cover it; each carries ``iter``; a request's
+  ``prefill_chunk`` carries the ``iter`` of the iteration that ran it.
+- COUNTERS: ``counters()`` is lock-free and agrees with ``stats()`` and
+  with the sums of the span args; a span carries no arg, and
+  ``counters()`` no key, that nothing reads.
+- GAUGES: the ``kv_blocks_*`` gauges are set once an iteration and equal
+  ``BlockPool.stats()``; no pool operation reduces over the pool.
+- ONE CLOCK: the phases also reach an active profiler session.
+- OFF MEANS OFF: with tracing disabled nothing of this is recorded, no
+  annotation is made, no clock is read beyond the step's own, and the
+  tokens are the same.
+"""
+
+import glob
+import threading
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import serving
+from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.observability import tracing
+from paddle_tpu.serving import metrics as sm
+from paddle_tpu.serving.block_pool import BlockPool
+
+CHILDREN = ["engine.admit", "engine.prefill", "engine.reserve",
+            "engine.dispatch", "engine.wait", "engine.emit"]
+BLOCK = 16
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    paddle.seed(0)
+    cfg = LlamaConfig.tiny(max_position_embeddings=256)
+    return LlamaForCausalLM(cfg), cfg
+
+
+def _prompt(rng, cfg, n):
+    return rng.randint(1, cfg.vocab_size, n).astype("int32")
+
+
+def _engine(model, **kw):
+    kw = {"max_slots": 3, "max_len": 128, "prefill_chunk": 16,
+          "block_size": BLOCK, **kw}
+    return serving.ServingEngine(model, **kw)
+
+
+def _engine_lane(since=0):
+    """What this thread's (synchronously driven) engine recorded; an
+    engine another test left serving idles on a thread of its own."""
+    return [e for e in tracing.events(trace="engine")
+            if e["ts_ns"] >= since and e["tid"] == threading.get_ident()]
+
+
+def _by_iter(events):
+    out = {}
+    for e in events:
+        if e["name"].startswith("engine.") and e["name"] != "engine.idle":
+            out.setdefault(e["args"]["iter"], []).append(e)
+    return out
+
+
+@pytest.fixture(scope="module")
+def served(tiny_model):
+    """One engine driven through shared prefixes, preemption-free
+    decode and completion; returns (engine, requests, its events)."""
+    model, cfg = tiny_model
+    tracing.clear()
+    eng = _engine(model)
+    rng = np.random.RandomState(11)
+    base = _prompt(rng, cfg, 2 * BLOCK)
+    first = eng.submit(base, max_new_tokens=6)
+    eng.run_until_idle()
+    # two follow-ups that extend the first prompt by less than a block:
+    # each adopts its two whole blocks from the prefix cache
+    more = [eng.submit(np.concatenate([base, _prompt(rng, cfg, 5 + i)]),
+                       max_new_tokens=4 + i) for i in range(2)]
+    lone = eng.submit(_prompt(rng, cfg, 21), max_new_tokens=3)
+    eng.run_until_idle()
+    reqs = [first, *more, lone]
+    assert all(r.status == serving.RequestStatus.COMPLETED for r in reqs)
+    return eng, reqs, tracing.events()
+
+
+class TestPhases:
+    def test_one_iter_per_worked_iteration_with_children_that_cover_it(
+            self, served):
+        eng, _, events = served
+        groups = _by_iter([e for e in events if e["trace"] == "engine"])
+        assert sorted(groups) == list(range(eng._phases.seq))
+        for it, evs in groups.items():
+            (parent,) = [e for e in evs if e["name"] == "engine.iter"]
+            kids = sorted((e for e in evs if e["name"] in CHILDREN),
+                          key=lambda e: e["ts_ns"])
+            names = [k["name"] for k in kids]
+            assert names == CHILDREN[:len(names)] and len(names) >= 3, it
+            lo, hi = parent["ts_ns"], parent["ts_ns"] + parent["dur_ns"]
+            for a, b in zip(kids, kids[1:]):
+                assert a["ts_ns"] + a["dur_ns"] <= b["ts_ns"]   # disjoint
+            assert all(lo <= k["ts_ns"] and k["ts_ns"] + k["dur_ns"] <= hi
+                       for k in kids)
+            assert sum(k["dur_ns"] for k in kids) >= 0.95 * parent["dur_ns"]
+
+    def test_every_engine_span_is_on_the_engine_lane(self, served):
+        _, _, events = served
+        mine = [e for e in events if e["name"].startswith("engine.")]
+        assert mine and all(e["cat"] == "engine" and e["trace"] == "engine"
+                            and e["ph"] == "X" for e in mine)
+
+    def test_a_span_carries_only_the_args_a_metric_reads(self, served):
+        # iter ties the lanes together; preempted is preemptions.*;
+        # the two token counts are prefix_hit_share.chat
+        _, _, events = served
+        want = {"engine.iter": {"iter", "preempted"},
+                "engine.admit": {"iter", "prefix_hit_tokens",
+                                 "prompt_tokens"}}
+        # (another test's engine may idle on a thread of its own meanwhile)
+        mine = [e for e in events if e["name"].startswith("engine.")
+                and e["name"] != "engine.idle"]
+        assert {e["name"] for e in mine} == {"engine.iter", *CHILDREN}
+        for e in mine:
+            assert set(e["args"]) == want.get(e["name"], {"iter"}), e
+
+    def test_prefill_chunk_carries_the_iter_that_ran_it(self, served):
+        _, reqs, events = served
+        prefill = {e["args"]["iter"]: e for e in events
+                   if e["name"] == "engine.prefill"}
+        chunks = [e for e in events if e["name"] == "prefill_chunk"]
+        assert len(chunks) >= len(reqs)
+        for ch in chunks:
+            ph = prefill[ch["args"]["iter"]]   # its iteration recorded one
+            assert ph["ts_ns"] <= ch["ts_ns"]
+            assert ch["ts_ns"] + ch["dur_ns"] <= ph["ts_ns"] + ph["dur_ns"]
+            assert ch["trace"] != "engine"     # stays on the request's lane
+
+    def test_prefix_hit_tokens_sum_to_the_cache_hits_times_block_size(
+            self, served):
+        eng, _, events = served
+        admits = [e for e in events if e["name"] == "engine.admit"]
+        hit = sum(e["args"]["prefix_hit_tokens"] for e in admits)
+        assert hit == eng.prefix_cache.stats()["hits"] * BLOCK == 4 * BLOCK
+        c = eng.counters()
+        assert hit == c["prefix_hit_tokens"]
+        assert sum(e["args"]["prompt_tokens"] for e in admits) \
+            == c["prompt_tokens"] == 32 + 37 + 38 + 21
+
+    def test_every_decode_step_has_one_emit_and_one_wait(self, served):
+        eng, reqs, events = served
+        emits = [e for e in events if e["name"] == "engine.emit"]
+        waits = [e for e in events if e["name"] == "engine.wait"]
+        assert len(emits) == len(waits) == eng.counters()["steps"] > 0
+        # every request's first token comes out of its last prefill
+        # chunk; the rest one a row a step, which slot_steps integrates
+        assert eng.counters()["slot_steps"] \
+            == sum(len(r.output_tokens) - 1 for r in reqs)
+
+    def test_serving_step_keeps_its_extent_dispatch_plus_wait(self, served):
+        _, _, events = served
+        steps = [e for e in events if e["name"] == "serving.step"]
+        disp = {e["args"]["iter"]: e for e in events
+                if e["name"] == "engine.dispatch"}
+        wait = {e["args"]["iter"]: e for e in events
+                if e["name"] == "engine.wait"}
+        assert len(steps) == len(disp) == len(wait) > 0
+        by_start = {e["ts_ns"]: e for e in steps}
+        for it, d in disp.items():
+            st = by_start[d["ts_ns"]]
+            assert st["dur_ns"] == d["dur_ns"] + wait[it]["dur_ns"]
+
+    def test_an_iteration_that_did_nothing_records_nothing(self, tiny_model):
+        model, _ = tiny_model
+        eng = _engine(model)
+        t0 = tracing.events()[-1]["ts_ns"] + 1 if tracing.events() else 0
+        assert eng.step() is False and eng.step() is False
+        assert _engine_lane(t0) == [] and eng._phases.seq == 0
+
+    def test_preemptions_in_the_spans_sum_to_the_counter(self, tiny_model):
+        model, cfg = tiny_model
+        eng = _engine(model, num_blocks=13)   # 12 usable blocks, 3 slots
+        rng = np.random.RandomState(4242)
+        t0 = tracing.events()[-1]["ts_ns"] + 1
+        for n in (40, 55, 33):
+            eng.submit(_prompt(rng, cfg, n), max_new_tokens=30)
+        eng.run_until_idle(max_steps=5000)
+        lane = _engine_lane(t0)
+        c = eng.counters()
+        assert c["preemptions"] >= 1
+        assert sum(e["args"]["preempted"] for e in lane
+                   if e["name"] == "engine.iter") == c["preemptions"]
+        assert eng.stats()["preemptions"] == c["preemptions"]
+
+    def test_the_serving_loop_records_its_idle_wait(self, tiny_model):
+        model, cfg = tiny_model
+        eng = _engine(model)
+        eng.start()
+        tid = eng._thread.ident   # other engines' loops may idle beside it
+
+        def mine(name):
+            return [e for e in tracing.events(trace="engine", name=name)
+                    if e["tid"] == tid]
+
+        try:
+            req = eng.submit(_prompt(np.random.RandomState(5), cfg, 8),
+                             max_new_tokens=3)
+            req.result(timeout=120)
+            for _ in range(200):
+                if mine("engine.idle"):
+                    break
+                threading.Event().wait(0.02)
+        finally:
+            eng.stop()
+        idle, iters = mine("engine.idle"), mine("engine.iter")
+        assert idle and iters and all(e["dur_ns"] > 0 for e in idle)
+        for e in idle:   # idle lies between iterations, never inside one
+            assert not any(i["ts_ns"] < e["ts_ns"] < i["ts_ns"] + i["dur_ns"]
+                           for i in iters)
+
+    def test_disabled_tracing_records_nothing_and_serves_the_same_tokens(
+            self, tiny_model):
+        model, cfg = tiny_model
+        prompts = [_prompt(np.random.RandomState(6), cfg, n)
+                   for n in (9, 30)]
+
+        def run():
+            eng = _engine(model)
+            reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+            eng.run_until_idle()
+            return eng, [list(r.output_tokens) for r in reqs]
+
+        _, want = run()
+        before = tracing.span_counts()
+        tracing.disable_tracing()
+        try:
+            eng, got = run()
+        finally:
+            tracing.enable_tracing()
+        assert got == want
+        after = tracing.span_counts()
+        assert {k: v for k, v in after.items() if k.startswith("engine.")} \
+            == {k: v for k, v in before.items() if k.startswith("engine.")}
+        assert eng._phases.seq > 0 and eng.counters()["steps"] > 0
+        # ... and the loop paid no annotation, no list append and no
+        # clock read beyond open's for them
+        assert not eng._phases.on and eng._phases._done == []
+
+
+class TestPhasesObject:
+    def test_marks_are_shared_edges_and_close_records_in_order(self):
+        ph = tracing.Phases("t_ph.iter", "test", "t_ph")
+        t0 = ph.open("t_ph.a")
+        t1 = ph.mark("t_ph.b", {"n": 1})
+        t2 = ph.close(True, {"m": 2}, {"p": 3})
+        evs = tracing.events(trace="t_ph")
+        assert [(e["name"], e["ts_ns"], e["dur_ns"]) for e in evs] == [
+            ("t_ph.a", t0, t1 - t0), ("t_ph.iter", t0, t2 - t0),
+            ("t_ph.b", t1, t2 - t1)]
+        args = {e["name"]: e["args"] for e in evs}
+        assert args == {"t_ph.a": {"n": 1, "iter": 0},
+                        "t_ph.b": {"m": 2, "iter": 0},
+                        "t_ph.iter": {"p": 3, "iter": 0}}
+        assert ph.seq == 1
+
+    def test_an_iteration_that_did_not_work_leaves_no_event_and_no_number(
+            self):
+        ph = tracing.Phases("t_ph2.iter", "test", "t_ph2")
+        ph.open("t_ph2.a")
+        ph.mark("t_ph2.b")
+        ph.close(False)
+        assert tracing.events(trace="t_ph2") == [] and ph.seq == 0
+        ph.open("t_ph2.a")      # an exception skipped close(): reopened
+        ph.open("t_ph2.a")
+        ph.close(True)
+        assert [e["args"] for e in tracing.events(trace="t_ph2")] \
+            == [{"iter": 0}, {"iter": 0}]
+
+    def test_disabled_phases_read_one_clock_and_annotate_nothing(
+            self, monkeypatch):
+        made, reads = [], []
+        real = tracing.time.perf_counter_ns
+        monkeypatch.setattr(tracing, "_Annotation",
+                            lambda name: made.append(name))
+        ph = tracing.Phases("t_ph3.iter", "test", "t_ph3")
+        tracing.disable_tracing()
+        try:
+            monkeypatch.setattr(tracing.time, "perf_counter_ns",
+                                lambda: reads.append(1) or real())
+            assert ph.open("t_ph3.a") > 0 and not ph.on
+            assert ph.mark("t_ph3.b", {"n": 1}) == 0
+            assert ph.close(True) == 0
+            with tracing.profiled_span("t_ph3.x", "test", "t_ph3"):
+                pass
+        finally:
+            monkeypatch.undo()
+            tracing.enable_tracing()
+        assert made == [] and len(reads) == 1 and ph.seq == 1
+        assert tracing.events(trace="t_ph3") == []
+
+    def test_profiled_span_records_like_a_span(self):
+        with tracing.profiled_span("t_ps.x", "test", "t_ps", {"k": 1}):
+            pass
+        (e,) = tracing.events(trace="t_ps")
+        assert (e["name"], e["ph"], e["args"]) == ("t_ps.x", "X", {"k": 1})
+
+
+class TestCounters:
+    def test_counters_never_takes_the_step_lock(self, served):
+        eng, _, _ = served
+        got = []
+        with eng._step_lock:   # a step is held
+            t = threading.Thread(target=lambda: got.append(eng.counters()))
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive() and got
+
+    def test_counters_agree_with_stats(self, served):
+        eng, reqs, _ = served
+        c, st = eng.counters(), eng.stats()
+        assert st["counters"] == c
+        assert (st["steps"], st["slots"], st["queue_depth"],
+                st["preemptions"]) == (c["steps"], c["slots"],
+                                       c["queue_depth"], c["preemptions"])
+        assert st["mean_occupancy"] == pytest.approx(
+            c["slot_steps"] / (c["steps"] * c["slots"]))
+        # nothing here that the pool or the registry counts already
+        assert set(c) == {"steps", "slots", "slot_steps", "queue_depth",
+                          "prompt_tokens", "prefix_hit_tokens",
+                          "preemptions"}
+
+
+class TestPoolGauges:
+    def test_gauges_after_an_iteration_equal_the_pool_statistics(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = _engine(model)
+        rng = np.random.RandomState(8)
+        base = _prompt(rng, cfg, 2 * BLOCK)
+        eng.submit(base, max_new_tokens=2)
+        eng.run_until_idle()
+        eng.submit(np.concatenate([base, _prompt(rng, cfg, 3)]),
+                   max_new_tokens=8)
+        for _ in range(3):
+            assert eng.step()
+            st = eng.pool.stats()
+            assert (sm.kv_blocks_total.value(), sm.kv_blocks_in_use.value(),
+                    sm.kv_blocks_shared.value()) \
+                == (st["usable"], st["in_use"], st["shared"])
+            assert st["shared"] >= 2
+
+    def test_freeing_200_blocks_reduces_over_nothing(self, monkeypatch):
+        pool = BlockPool(256, BLOCK)
+        calls = []
+        real = BlockPool._shared_unlocked
+        monkeypatch.setattr(
+            BlockPool, "_shared_unlocked",
+            lambda self: calls.append(1) or real(self))
+        ids = pool.alloc(200)
+        for b in ids[:50]:
+            pool.incref(b)
+        for b in ids:
+            pool.decref(b)
+        assert calls == [] and pool.used_blocks == 50
+        pool.set_gauges()
+        assert len(calls) == 1 and sm.kv_blocks_shared.value() == 0
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_the_running_shared_count_is_the_reduction(self, seed):
+        rng = np.random.RandomState(seed)
+        pool = BlockPool(64, BLOCK)
+        live = []
+        for _ in range(600):
+            op = rng.randint(3)
+            if op == 0 and pool.free_blocks:
+                live.extend(pool.alloc(1))
+            elif op == 1 and live:
+                b = live[rng.randint(len(live))]
+                pool.incref(b)
+                live.append(b)
+            elif live:
+                pool.decref(live.pop(rng.randint(len(live))))
+            assert pool.shared_blocks == int((pool._ref[1:] > 1).sum())
+        assert pool.stats()["shared"] == pool.shared_blocks
+
+
+class TestOneClock:
+    def test_phases_reach_an_active_profiler_session(self, tiny_model,
+                                                     tmp_path):
+        import jax
+
+        from perfbench import trace_reduce
+
+        model, cfg = tiny_model
+        eng = _engine(model)
+        eng.submit(_prompt(np.random.RandomState(9), cfg, 20),
+                   max_new_tokens=3)
+        eng.run_until_idle()    # compile outside the session
+        eng.submit(_prompt(np.random.RandomState(10), cfg, 20),
+                   max_new_tokens=3)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.run_until_idle()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        host = [ev for plane, lines in trace_reduce.load(path).items()
+                if plane.startswith("/host:") for ev in lines["host"]]
+        names = [n for n, _, _ in host if n.startswith("engine.")]
+        assert {"engine.iter", *CHILDREN} <= set(names)
+        # as many as the ring holds of the same run, no-work spins aside
+        assert names.count("engine.wait") == 2
+
+    def test_train_dispatch_is_recorded_for_each_step(self):
+        import paddle_tpu.distributed as dist
+        from paddle_tpu import nn
+        from paddle_tpu.distributed.engine import ShardedTrainStep
+
+        paddle.seed(0)
+        m = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 4))
+        lossfn = nn.CrossEntropyLoss()
+        opt = paddle.optimizer.SGD(0.1, parameters=m.parameters())
+        mesh = dist.ProcessMesh(np.arange(2).reshape(2), ["dp"])
+        step = ShardedTrainStep(m, lambda o, lab: lossfn(o, lab), opt, mesh)
+        rng = np.random.RandomState(1)
+        x = paddle.to_tensor(rng.randn(16, 8).astype(np.float32))
+        y = paddle.to_tensor(rng.randint(0, 4, 16).astype(np.int64))
+        before = len(tracing.events(trace="train"))
+        for _ in range(3):
+            float(step.step(x, y))
+        evs = tracing.events(trace="train")[before:]
+        assert [e["name"] for e in evs] == ["train.dispatch"] * 3
+        assert [e["args"]["step"] for e in evs] == [0, 1, 2]
+        assert all(e["cat"] == "train" and e["dur_ns"] > 0 for e in evs)

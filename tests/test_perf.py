@@ -18,6 +18,7 @@ Oracles:
 
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -90,21 +91,42 @@ class TestCapture:
         assert row["compiles_captured"] >= 2
 
     def test_warmup_call_excluded_from_timing_window(self):
-        """The call that paid the compile is warmup: its wall time
-        (compile included) must not enter the achieved-rate window."""
+        """The call that paid the compile is warmup: the time its owner
+        hands over (compile included) must not enter the achieved-rate
+        window. The owner times a synced interval; the entrypoint scope
+        itself is not timed (it closes before the device has run)."""
         jf = jax.jit(lambda x: x * 2.0)
         x = jnp.ones((32,), jnp.float32)
         with recompile.entrypoint("t_perf.warmup"):
             jf(x).block_until_ready()  # compiles -> excluded
+        perf.note_entry_time("t_perf.warmup", 1.0)
         assert perf.ledger()["t_perf.warmup"]["calls"] == 0
         for _ in range(3):
             with recompile.entrypoint("t_perf.warmup"):
                 jf(x).block_until_ready()
+            perf.note_entry_time("t_perf.warmup", 0.002)
         row = perf.ledger()["t_perf.warmup"]
         assert row["calls"] == 3
-        assert row["mean_time_s"] is not None and row["mean_time_s"] > 0
+        assert row["mean_time_s"] == pytest.approx(0.002)
         assert row["achieved_flops_per_s"] is None or \
             row["achieved_flops_per_s"] > 0
+
+    def test_an_entry_nobody_timed_has_null_rates(self):
+        """An ``entrypoint`` scope alone (an asynchronous dispatch)
+        gives the ledger static cost and no time: achieved rate, MFU
+        and bandwidth are null instead of wrong."""
+        jf = jax.jit(lambda x: x @ x)
+        x = jnp.ones((64, 64), jnp.float32)
+        for _ in range(3):
+            with recompile.entrypoint("t_perf.untimed"):
+                jf(x)
+        row = perf.ledger()["t_perf.untimed"]
+        # the HBM ledger's tests count on the few executables they add
+        perf._entries.pop("t_perf.untimed")
+        assert row["flops"] > 0 and row["calls"] == 0
+        for k in ("mean_time_s", "achieved_flops_per_s", "achieved_gbps",
+                  "mfu", "hbm_bw_util", "items_per_s", "bytes_per_item"):
+            assert row[k] is None, k
 
     def test_disable_stops_capture_and_timing(self):
         jf = jax.jit(lambda x: x - 1)
@@ -119,8 +141,7 @@ class TestCapture:
 
     def test_items_accounting(self):
         perf.note_entry_items("t_perf.items", 128)
-        with recompile.entrypoint("t_perf.items"):
-            pass  # one timed (non-compiling) call
+        perf.note_entry_time("t_perf.items", 0.5)  # one timed call
         row = perf.ledger()["t_perf.items"]
         assert row["items"] == 128
         assert row["items_per_s"] is not None
@@ -155,8 +176,10 @@ class TestPeaks:
         jf = jax.jit(lambda x: x @ x)
         x = jnp.ones((64, 64), jnp.float32)
         for _ in range(2):
+            t0 = time.perf_counter()
             with recompile.entrypoint("t_perf.env"):
                 jf(x).block_until_ready()
+            perf.note_entry_time("t_perf.env", time.perf_counter() - t0)
         peaks = perf.peak_specs()
         assert peaks["source"] == "env"
         assert peaks["machine_balance_flops_per_byte"] == pytest.approx(10.0)
@@ -455,7 +478,13 @@ class TestServingLedgerAcceptance:
         roofline class) in snapshot() and engine /stats."""
         cfg, plain, spec = engines
         self._waves(plain, cfg)
-        self._waves(spec, cfg, sampled=True)
+        # a second engine in the process shares entry names with the
+        # first: its first compiles are warm-up, not retraces, and must
+        # not be left behind as such for whatever file this worker runs
+        # next (test_paged_kv and test_spec_decode hold the process-wide
+        # count of serving.prefill_chunk retraces at zero)
+        with recompile.warmup_scope():
+            self._waves(spec, cfg, sampled=True)
         led = obs.snapshot()["perf"]["ledger"]
         for entry in ("serving.step", "serving.prefill_chunk",
                       "serving.cow", "serving.spec_draft",
