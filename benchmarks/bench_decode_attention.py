@@ -6,7 +6,7 @@ cache occupancies (25/50/100% — per-row positions, the continuous-
 batching steady state) and two GQA ratios (1x and 4x), timed three ways:
 
 - ``kernel``:   pallas_kernels.decode_attention.flash_decode_attention
-                (split-K grid, GQA-native, per-row length masking);
+                (one pass a row, GQA-native, bounded by each row's length);
 - ``fallback``: the post-PR XLA path — grouped-einsum SDPA over the
                 masked cache (nn.functional.grouped_query_sdpa form),
                 no repeat_kv materialization;

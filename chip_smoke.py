@@ -86,8 +86,8 @@ REHEARSAL = dict(
 # RTOL * |ref|. The reference runs the XLA path on f32 copies of the
 # same bf16 values at "highest" matmul precision, so the difference is
 # the kernel's own rounding, and bf16 rounding is relative (2^-9, 0.2%):
-# RTOL covers the output's cast to bf16 with room for the split-K
-# combine; ATOL covers the softmax weights' cast to bf16 before the PV
+# RTOL covers the output's cast to bf16 with room for the order of the
+# float32 sums; ATOL covers the softmax weights' cast to bf16 before the PV
 # matmul, an error that scales with max |v| (about 4 for these normal
 # pools), not with the output. Both sides dequantize int8 identically.
 KERNEL_ATOL = 1e-2
@@ -202,8 +202,10 @@ def _compile_summary():
 def _kernel_parity(sizes, heads, head_dim, num_blocks, block_size, chunk):
     """paged_flash_decode_attention against the XLA path
     (generation.gather_paged_kv + SDPA) on seeded pools at the engine's
-    own shapes: the decode step, one prefill chunk, an int8 pool, and an
-    ancestor-masked tree bundle."""
+    own shapes: the decode step, one prefill chunk, an int8 pool, an
+    ancestor-masked tree bundle, and the decode step and chunk again at
+    GQA 32/8. The seeded lengths are ragged across the kernel's cell
+    boundary."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -211,41 +213,54 @@ def _kernel_parity(sizes, heads, head_dim, num_blocks, block_size, chunk):
     import paddle_tpu.nn.functional as F
     from paddle_tpu import generation
     from paddle_tpu.pallas_kernels.decode_attention import (
-        paged_flash_decode_attention, spec_tree_width)
+        _blocks_per_cell, paged_flash_decode_attention, spec_tree_width)
     from paddle_tpu.quantization import intx
 
     slots, max_len = sizes["slots"], sizes["max_len"]
     nb = max_len // block_size
     rng = np.random.RandomState(SEED)
-    kp, vp = (jnp.asarray(rng.randn(num_blocks, block_size, heads, head_dim),
-                          jnp.bfloat16) for _ in range(2))
+    gqa_heads, gqa_kv = 32, 8
+    kp, vp, kp_gqa, vp_gqa = (
+        jnp.asarray(rng.randn(num_blocks, block_size, kv, head_dim),
+                    jnp.bfloat16) for kv in (heads, heads, gqa_kv, gqa_kv))
     # every slot owns nb distinct physical blocks, in shuffled order;
     # block 0 is the engine's dump block
     bt = jnp.asarray(1 + rng.permutation(slots * nb).reshape(slots, nb),
                      jnp.int32)
 
     def reference(q, k_full, v_full, visible):
+        group = q.shape[2] // k_full.shape[2]
         with jax.default_matmul_precision("highest"):
             out = F.scaled_dot_product_attention(
-                q.astype(jnp.float32), k_full.astype(jnp.float32),
-                v_full.astype(jnp.float32), attn_mask=visible[:, None])
+                q.astype(jnp.float32),
+                jnp.repeat(k_full.astype(jnp.float32), group, axis=2),
+                jnp.repeat(v_full.astype(jnp.float32), group, axis=2),
+                attn_mask=visible[:, None])
         return np.asarray(out._data)
 
     worst = {}
     tree_w = spec_tree_width(sizes["tree"])
     cases = {"decode": (slots, 1), "prefill_chunk": (1, chunk),
-             "int8_decode": (slots, 1), "tree_bundle": (slots, tree_w)}
+             "int8_decode": (slots, 1), "tree_bundle": (slots, tree_w),
+             "gqa_decode": (slots, 1), "gqa_prefill_chunk": (1, chunk)}
     for name, (b, q_len) in cases.items():
-        q = jnp.asarray(rng.randn(b, q_len, heads, head_dim), jnp.bfloat16)
-        # the edges (empty cache, full cache, block boundaries) and
-        # random interiors
-        edges = [0, max_len - q_len, block_size - 1, block_size]
-        pos = np.array((edges + list(rng.randint(0, max_len - q_len, slots))
-                        )[:b], np.int32)
+        gqa = name.startswith("gqa_")
+        q_heads = gqa_heads if gqa else heads
+        q = jnp.asarray(rng.randn(b, q_len, q_heads, head_dim), jnp.bfloat16)
+        # a bundle that straddles the kernel's cell boundary, lengths
+        # one short of a cell and one cell exactly, the edges (empty
+        # cache, full cache, block boundaries) and random interiors
+        cell = block_size * _blocks_per_cell(
+            block_size, nb, gqa_kv if gqa else heads, head_dim, jnp.bfloat16,
+            q_len * (gqa_heads // gqa_kv if gqa else 1))
+        edges = [cell - q_len + 1, 0, max_len - q_len, block_size - 1,
+                 block_size, cell - q_len - 1, cell - q_len]
+        pos = np.clip((edges + list(rng.randint(0, max_len - q_len, slots))
+                       )[:b], 0, max_len - q_len).astype(np.int32)
         t = np.arange(max_len)[None, None, :]
         qpos = pos[:, None, None] + np.arange(q_len)[None, :, None]
         visible = t <= qpos
-        pools, kwargs = (kp, vp), {}
+        pools, kwargs = ((kp_gqa, vp_gqa) if gqa else (kp, vp)), {}
         k_full, v_full = (generation.gather_paged_kv(p, bt[:b])._data
                           for p in pools)
         if name == "int8_decode":

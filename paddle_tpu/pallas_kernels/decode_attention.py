@@ -1,35 +1,40 @@
-"""Flash-decode: GQA-native split-K Pallas attention for the decode hot path.
+"""Flash-decode: GQA-native one-pass Pallas attention for the decode hot path.
 
 The serving/generation decode step runs single-query attention (q_len
-small, typically 1) against the static [B, max_len, kv_heads, d] KV
-caches. The plain XLA path scores the ENTIRE padded cache and — for GQA
-models — first materializes the repeat_kv-expanded [B, max_len, heads, d]
-K/V in HBM, multiplying the dominant HBM stream by heads/kv_heads.
-This kernel is the TPU-native fix (reference analogue: the decode branch
-of phi/kernels/gpu/flash_attn_kernel.cu and the flash-decoding split-K
-formulation):
+small, typically 1) against the KV cache: the paged pools
+[num_blocks, block_size, kv_heads, d] behind a block table, or the
+static [B, max_len, kv_heads, d] caches. The plain XLA path scores the
+ENTIRE padded cache and — for GQA models — first materializes the
+repeat_kv-expanded K/V in HBM, multiplying the dominant HBM stream by
+heads/kv_heads. This kernel is the TPU-native fix (reference analogue:
+the decode branch of phi/kernels/gpu/flash_attn_kernel.cu and the
+paged-attention kernels that walk a block table):
 
-- split-K over the cache length: grid (B, num_kv_blocks); every KV
-  block computes an online-softmax PARTIAL (running max, sum,
-  unnormalized accumulator) per kv head and a small XLA combine merges
-  them. A cell's K/V block is [block_k, kv_heads, d] — ALL kv heads of
-  the block in one DMA: the last two block dims must be whole TPU tiles
-  (or the whole axis), which a single kv head out of [.., kv_heads, d]
-  is not, and one DMA per cell moves kv_heads times the bytes.
-- GQA-native: each kv head's [block_k, d] slab is read ONCE and serves
-  the head's whole [group * q_len, d] query bundle through a single MXU
-  matmul — repeat_kv never materializes, so KV bytes drop by the group
-  factor (4x for Llama-70B-style heads/kv_heads ratios).
-- per-row length masking: the engine's per-slot [B] position vector is
-  scalar-prefetched; each row's kv-block loop is bounded by its own
-  length, blocks wholly beyond ``pos + q_len`` are skipped (the K/V
-  BlockSpec index map re-points them at the row's last needed block,
-  which Pallas recognizes as a revisit and does not re-fetch), and the
-  boundary block masks ``kpos <= qpos`` element-wise. A mostly-empty
-  cache therefore costs proportional to occupancy, not max_len; dead
-  slots (the serving engine pins freed slots to pos 0) touch one block.
+- one pass, one output: grid (B,), a row a grid step. The row's cache is
+  walked in cells of several pool blocks (``_blocks_per_cell``: 128 to
+  512 positions, from the shapes against a VMEM budget), the online
+  softmax (running max, sum, unnormalised accumulator) lives in VMEM
+  scratch across the cells, and the row is normalised and written once,
+  [KV, gq, d] in the query's dtype. Nothing per cell reaches HBM and
+  nothing is left for XLA to merge.
+- bounded by each row's own length: the cell loop runs ``cdiv(len,
+  cell)`` times and a cell fetches only the pool blocks inside the
+  length, so a short row, or a dead slot the serving engine pins to
+  pos 0, costs its own blocks and not the table's width.
+- manual, double-buffered DMA: the pools stay in HBM (``pl.ANY``); a
+  cell's blocks are scattered, so each comes by its own
+  ``make_async_copy`` through the scalar-prefetched block table, the
+  next cell's (or the next row's first) in flight while this one is
+  scored. A block is [block_size, kv_heads, d]: ALL kv heads in one
+  contiguous transfer.
+- GQA-native, every head in one matmul: the cell is turned heads-first
+  in VMEM and ONE batched MXU matmul serves each kv head's whole
+  [group * q_len, d] query bundle — repeat_kv never materializes, so KV
+  bytes drop by the group factor (4x for Llama-70B-style ratios).
 - bf16 (or fp32) streams with fp32 statistics and accumulation
   (preferred_element_type on both matmuls, stats never leave fp32).
+- the contiguous cache is the same call: a pool whose table is the
+  identity (``_flash_decode``).
 
 Layout contract matches generation.make_kv_caches: q [B, q_len, heads,
 d], caches [B, max_len, kv_heads, d], query head j reads kv head
@@ -164,8 +169,8 @@ def decode_dispatch(model: str, *, q_len: int, has_mask: bool,
 def paged_decode_dispatch(model: str, *, q_len: int, has_mask: bool,
                           dtype, quantized: bool = False) -> bool:
     """Dispatch decision for the PAGED decode/chunk-prefill path: True
-    -> ``paged_flash_decode_attention`` (block-table gather inside the
-    kernel's index map); False -> the XLA gather fallback
+    -> ``paged_flash_decode_attention`` (the kernel walks the block
+    table itself); False -> the XLA gather fallback
     (``gather_paged_kv`` + grouped SDPA — ``gather_paged_kv_dequant``
     for quantized pools), with the reason counted under a ``paged_``
     prefix (``paged_quant_`` when the pool is quantized). Same gates as
@@ -275,22 +280,59 @@ def _visible(length, start, *, gq: int, block_k: int, q_len: int,
     return (kpos < length - q_len) | (anc > 0.5)
 
 
-def _decode_kernel(*refs, n_prefetch: int, block_k: int, sm_scale: float,
-                   q_len: int, group: int, bound, tree: bool):
-    """One (batch row, kv block) cell: every kv head's online-softmax
-    partial for its whole query bundle. The K/V block carries ALL kv
-    heads — a block of 1 on the kv-heads axis is not a TPU tile, and one
-    DMA per cell moves kv_heads times more bytes than a per-head cell.
+# VMEM the streamed part of a cell may take: both slots of the K and V
+# buffers, their heads-first copies and the score-sized temporaries. What is
+# left of the 100 MB limit holds the query bundle, the accumulators and
+# the output block, which do not grow with the cell.
+_CELL_VMEM_BYTES = 8 * 1024 * 1024
 
-    Refs (blocked), after the ``n_prefetch`` scalar-prefetch refs (the
-    first of which is the per-row valid kv length = pos + q_len):
-      q [1, KV, gq, d]            — rows r = i*group + g per kv head
-      k/v [1, block_k, KV, d]     — one cache block, all kv heads
-      ks/vs [1, block_k, KV] f32  — quantized caches only (``bound``
-                                    set): per-token-per-head absmax
-      mask [1, gq, qp] f32        — ``tree`` only: ancestor mask
-      o [1, 1, KV, gq, d] f32     — unnormalized accumulator partial
-      m/l [1, 1, KV, gq, 1] f32   — running max / sum partials
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _blocks_per_cell(block_size: int, nb: int, kv_heads: int, d: int,
+                     kv_dtype, gq: int) -> int:
+    """Pool blocks one kernel cell streams and scores at once: 128 to
+    512 positions (a multiple of the block size, at most the table's
+    width), as many as ``_CELL_VMEM_BYTES`` holds. A pure function of
+    the shapes the call can see — nothing to configure."""
+    item = jnp.dtype(kv_dtype).itemsize
+    # VMEM tiles are 8 x 32-bit sublanes by 128 lanes: a narrow dtype
+    # packs more rows a tile, and the kv-heads axis pads up to it
+    per_pos = 2 * 2 * _round_up(kv_heads, 32 // item) * d * item
+    per_pos += 2 * kv_heads * d * 2                # K and V, heads first
+    per_pos += 3 * kv_heads * _round_up(gq, 8) * 4  # scores, weights, mask
+    positions = min(max(_CELL_VMEM_BYTES // per_pos // 128 * 128, 128), 512)
+    return max(1, min(positions // block_size, nb))
+
+
+def _decode_kernel(lens_ref, bt_ref, q_ref, *refs, bpc: int, sm_scale: float,
+                   q_len: int, group: int, bound, tree: bool):
+    """One batch row: the row's whole attention in one pass.
+
+    The row's cache is walked in cells of ``bpc`` pool blocks, and only
+    as far as the row's own length. A cell's blocks are scattered over
+    the pool, so they come by manual DMA through the scalar-prefetched
+    block table into one of two VMEM slots, the next cell's in flight
+    while this one is scored (the last cell of a row starts the first
+    of the next row). Each block is [block_size, KV, d]: all kv heads in
+    one contiguous transfer. Running max, sum and the unnormalised
+    accumulator stay in VMEM scratch in float32; the row is normalised
+    and written once.
+
+    Refs, after the scalar-prefetched per-row valid kv length
+    (= pos + q_len) and block table:
+      q [1, KV, gq, d]               rows r = i*group + g per kv head
+      k/v pools [N, bs, KV, d]       in HBM (``pl.ANY``)
+      ks/vs [1, cells * cell, KV]    quantized pools only (``bound``
+                                     set): the row's per-token-per-head
+                                     absmax scales, f32
+      mask [1, gq, qp] f32           ``tree`` only: ancestor mask
+      o [1, KV, gq, d]               normalised, in the query's dtype
+    then scratch: the K and V buffers [2, cell, KV, d], DMA semaphores
+    [2, 2], m/l [KV, gq, 1] and acc [KV, gq, d] f32, and the slot
+    holding the row's first cell.
 
     Quantized cells add a DEQUANT PROLOGUE: the int8/fp8 head slab and
     its scale column are widened to the query dtype in VMEM before the
@@ -298,175 +340,207 @@ def _decode_kernel(*refs, n_prefetch: int, block_k: int, sm_scale: float,
     in that exact order matches ``quantization.intx.unpack_absmax``
     bitwise, keeping the kernel and the XLA gather fallback
     interchangeable."""
-    lens_ref = refs[0]
-    q_ref, k_ref, v_ref, *refs = refs[n_prefetch:]
+    pools, refs = refs[:2], refs[2:]
+    scales = (None, None)
     if bound is not None:
-        ks_ref, vs_ref, *refs = refs
+        scales, refs = refs[:2], refs[2:]
     if tree:
         mask_ref, *refs = refs
-    o_ref, m_ref, l_ref = refs
-    length = lens_ref[pl.program_id(0)]
-    start = pl.program_id(1) * block_k
+    o_ref, *bufs, sems, m_scr, l_scr, acc_scr, slot_ref = refs
+    bs = pools[0].shape[1]
+    cell = bpc * bs
     _, kv, gq, d = q_ref.shape
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    length = lens_ref[b]
+    n_cells = pl.cdiv(length, cell)
 
-    @pl.when(start < length)
-    def _compute():
-        vis = _visible(length, start, gq=gq, block_k=block_k, q_len=q_len,
+    def fetch(row, j, slot, start: bool):
+        """Start (or wait for) the blocks of the row's cell ``j`` that
+        lie inside its length: none for a cell beyond it."""
+        live = jnp.clip(pl.cdiv(lens_ref[row] - j * cell, bs), 0, bpc)
+
+        def _block(i, _):
+            page = bt_ref[row, j * bpc + i]
+            for s, (pool, buf) in enumerate(zip(pools, bufs)):
+                copy = pltpu.make_async_copy(
+                    pool.at[page], buf.at[slot, pl.ds(i * bs, bs)],
+                    sems.at[slot, s])
+                copy.start() if start else copy.wait()
+        jax.lax.fori_loop(0, live, _block, None)
+
+    def fetch_next(j, slot):
+        """Start what is scored after the row's cell ``j``: its next
+        cell, or the first cell of the next row."""
+        more = j + 1 < n_cells
+
+        @pl.when(more | (b + 1 < rows))
+        def _start():
+            fetch(jnp.where(more, b, jnp.minimum(b + 1, rows - 1)),
+                  jnp.where(more, j + 1, 0), slot, True)
+
+    def heads_first(buf, scale_ref, slot, j):
+        """A slot's [cell, KV, d] as [KV, cell, d] in the query's dtype:
+        one batched matmul then serves every kv head (head by head the
+        MXU took three times as long, my chip run, PR 26)."""
+        if bound is None:
+            return jnp.swapaxes(buf[slot], 0, 1)
+        at = pl.ds(pl.multiple_of(j * cell, cell), cell)
+        return jnp.stack([
+            (buf[slot, :, h, :].astype(jnp.float32)
+             * scale_ref[0, at, h:h + 1] / bound).astype(q_ref.dtype)
+            for h in range(kv)])
+
+    @pl.when(b == 0)
+    def _first():
+        # a partly filled cell is scored whole and masked: what the
+        # buffers hold beyond the fetched blocks must be finite
+        for buf in bufs:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        slot_ref[0] = 0
+        fetch(0, 0, 0, True)
+
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    first = slot_ref[0]
+
+    def _score(j, _):
+        slot = (first + j) % 2
+        fetch_next(j, 1 - slot)
+        fetch(b, j, slot, False)
+        vis = _visible(length, j * cell, gq=gq, block_k=cell, q_len=q_len,
                        group=group, mask=mask_ref[0] if tree else None)
-        for h in range(kv):
-            q = q_ref[0, h]                    # [gq, d]
-            k = k_ref[0, :, h, :]              # [block_k, d]
-            v = v_ref[0, :, h, :]
-            if bound is not None:
-                k = (k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
-                     / bound).astype(q.dtype)
-                v = (v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
-                     / bound).astype(q.dtype)
-            sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                         precision=_dot_prec(q.dtype)) * sm_scale
-            sc = jnp.where(vis, sc, NEG_INF)
-            m = sc.max(axis=-1, keepdims=True)     # [gq, 1] f32
-            p = jnp.exp(sc - m)
-            o_ref[0, 0, h] = jnp.dot(p.astype(v.dtype), v,
-                                     preferred_element_type=jnp.float32,
-                                     precision=_dot_prec(q.dtype))
-            m_ref[0, 0, h] = m
-            l_ref[0, 0, h] = p.sum(axis=-1, keepdims=True)
+        q = q_ref[0]                              # [KV, gq, d]
+        k, v = (heads_first(buf, sc, slot, j) for buf, sc in zip(bufs, scales))
+        sc = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),   # [KV, gq, cell]
+            preferred_element_type=jnp.float32,
+            precision=_dot_prec(q.dtype)) * sm_scale
+        sc = jnp.where(vis[None], sc, NEG_INF)
+        m_prev = m_scr[...]                       # [KV, gq, 1] f32
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32, precision=_dot_prec(q.dtype))
+        m_scr[...] = m_new
 
-    @pl.when(start >= length)
-    def _skip():
-        # skipped blocks still own their partial slots; the finite
-        # NEG_INF sentinel makes them exact zeros in the combine
-        # (exp(NEG_INF - m_total) underflows to 0, l contributes 0)
-        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    @pl.when(n_cells == 0)
+    def _hand_on():
+        # a row of length 0 scores nothing, and hands the chain on
+        fetch_next(-1, 1 - first)
+
+    jax.lax.fori_loop(0, n_cells, _score, None)
+    slot_ref[0] = (first + jnp.maximum(n_cells, 1)) % 2
+    # a row that attended nothing keeps l = 0 and returns zeros
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                ).astype(o_ref.dtype)
 
 
-def _split_k_attention(q5, kc, vc, lens, *, block_k: int, nb: int,
-                       kv_index, prefetch=(), sm_scale: float,
-                       k_scale=None, v_scale=None, ancestor_mask=None):
-    """The shared split-K call behind both cache layouts.
+def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
+                        k_scale=None, v_scale=None, ancestor_mask=None):
+    """The one attention call behind both cache layouts.
 
-    q5 [B, q_len, KV, group, d]; kc/vc [*, *, KV, d] cut into
-    [1, block_k, KV, d] blocks; lens [B] int32 -> [B, KV, gq, d] f32,
-    combined and normalized, rows r = i*group + g.
+    q5 [B, q_len, KV, group, d]; pools [N, bs, KV, d] (scales
+    [N, bs, KV] f32, both or neither); bt [B, nb] int32: row b's logical
+    block j is pool block ``bt[b, j]``; lens [B] int32 -> [B, KV, gq, d]
+    in q5's dtype, rows r = i*group + g.
 
-    ``kv_index(b, s, lens_ref, *prefetch_refs)`` maps grid cell (row b,
-    logical kv block s) to the (axis-0, axis-1) block index of its K/V
-    block — the ONLY thing that differs between the contiguous cache
-    and the paged pool. ``prefetch``: extra scalar-prefetch operands it
-    reads (the block table). Scale pools ride the same index.
+    ``ancestor_mask`` ([B, q_len, q_len], 1.0 = visible): per-row
+    in-bundle visibility for tree-speculative verify. None compiles the
+    causal bundle.
 
-    Grid (B, nb): rows are independent ("parallel"); the kv-block axis
-    must keep its order for the revisit-skip on the K/V index map."""
+    Grid (B,): a row is one grid step, and the rows run in order (the
+    fetch of a row's first cell is started by the row before it)."""
     from ..quantization.intx import format_bound
 
     B, q_len, KV, group, d = q5.shape
+    bs, nb = kp.shape[1], bt.shape[1]
     gq = q_len * group
     quant = k_scale is not None
     tree = ancestor_mask is not None
+    bpc = _blocks_per_cell(bs, nb, KV, d, kp.dtype, gq)
     # per-kv-head query bundles as whole [gq, d] tiles: merging q_len
     # into the group axis inside the cell is a sublane relayout Mosaic
     # only takes for group % 8 == 0, so it happens here in XLA (tiny)
     qk = jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(B, KV, gq, d)
 
-    def _idx_kv(b, s, *pf):
-        return kv_index(b, s, *pf) + (0, 0)
+    def row(b, *_):
+        return (b, 0, 0, 0)
 
-    # scale pools as [rows * blocks_per_row, block_k, KV] (a paged pool
-    # already is): each cell's [block_k, KV] block is then the array's
-    # own last two dims, a legal TPU block for any block_k
-    blocks_per_row = kc.shape[1] // block_k
-
-    def _idx_scale(b, s, *pf):
-        i0, i1 = kv_index(b, s, *pf)
-        return (i0 * blocks_per_row + i1, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, KV, gq, d), lambda b, s, *pf: (b, 0, 0, 0)),
-        pl.BlockSpec((1, block_k, KV, d), _idx_kv),
-        pl.BlockSpec((1, block_k, KV, d), _idx_kv),
-    ]
-    operands = (lens.astype(jnp.int32),) + tuple(prefetch) + (qk, kc, vc)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, KV, gq, d), row), hbm, hbm]
+    operands = [lens.astype(jnp.int32), bt.astype(jnp.int32), qk, kp, vp]
     if quant:
-        in_specs += [pl.BlockSpec((1, block_k, KV), _idx_scale)] * 2
-        operands += tuple(sc.astype(jnp.float32).reshape(-1, block_k, KV)
-                          for sc in (k_scale, v_scale))
+        # Mosaic cannot slice an HBM ref whose minor dim is under a lane
+        # tile (KV < 128), so the scales do not come by the cell's DMA:
+        # XLA gathers each row's through the table (1/d of the pool's
+        # bytes) and the row's whole column set rides a BlockSpec
+        cells = -(-nb // bpc)
+        for sc in (k_scale, v_scale):
+            sc = sc.astype(jnp.float32)[bt].reshape(B, nb * bs, KV)
+            operands.append(jnp.pad(
+                sc, ((0, 0), (0, (cells * bpc - nb) * bs), (0, 0))))
+        in_specs += [pl.BlockSpec((1, cells * bpc * bs, KV),
+                                  lambda b, *_: (b, 0, 0))] * 2
     if tree:
         # rows expanded to the kernel's r = i*group + g order and the
         # contraction axis zero-padded to a lane multiple, so the cell's
         # one-hot matmul is MXU-aligned for any bundle width (29, ...)
-        qp = -(-q_len // 128) * 128
+        qp = _round_up(q_len, 128)
         am = jnp.repeat(ancestor_mask.astype(jnp.float32), group, axis=1)
         am = jnp.pad(am, ((0, 0), (0, 0), (0, qp - q_len)))
-        in_specs.append(
-            pl.BlockSpec((1, gq, qp), lambda b, s, *pf: (b, 0, 0)))
-        operands += (am,)
-
-    def _idx_out(b, s, *pf):
-        return (b, s, 0, 0, 0)
+        in_specs.append(pl.BlockSpec((1, gq, qp), lambda b, *_: (b, 0, 0)))
+        operands.append(am)
+    scratch = [pltpu.VMEM((2, bpc * bs, KV, d), kp.dtype)] * 2 + [
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((KV, gq, 1), jnp.float32),
+        pltpu.VMEM((KV, gq, 1), jnp.float32),
+        pltpu.VMEM((KV, gq, d), jnp.float32),
+        pltpu.SMEM((1,), jnp.int32)]
 
     kern = functools.partial(
-        _decode_kernel, n_prefetch=1 + len(prefetch), block_k=block_k,
-        sm_scale=sm_scale, q_len=q_len, group=group, tree=tree,
-        bound=format_bound("int8" if kc.dtype == jnp.int8 else "fp8")
+        _decode_kernel, bpc=bpc, sm_scale=sm_scale, q_len=q_len, group=group,
+        tree=tree,
+        bound=format_bound("int8" if kp.dtype == jnp.int8 else "fp8")
         if quant else None)
     interpret = _interpret()
-    o_p, m_p, l_p = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1 + len(prefetch),
-            grid=(B, nb),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, KV, gq, d), _idx_out),
-                pl.BlockSpec((1, 1, KV, gq, 1), _idx_out),
-                pl.BlockSpec((1, 1, KV, gq, 1), _idx_out),
-            ],
-        ),
-        out_shape=(jax.ShapeDtypeStruct((B, nb, KV, gq, d), jnp.float32),
-                   jax.ShapeDtypeStruct((B, nb, KV, gq, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((B, nb, KV, gq, 1), jnp.float32)),
+            num_scalar_prefetch=2, grid=(B,), in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, KV, gq, d), row),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((B, KV, gq, d), q5.dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            # whole-heads partial blocks grow with heads * q_len (a
-            # 256-token bundle at 32 heads needs ~24 MB with the
-            # lane-padded stat columns): past Mosaic's 16 MB default
+            dimension_semantics=("arbitrary",),
+            # the accumulators and the query and output blocks grow with
+            # heads * q_len (a 256-token bundle at 32 heads needs ~24 MB
+            # with the lane-padded stat columns): past Mosaic's 16 MB
+            # default
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(*operands)
-
-    # split-K combine (tiny: nb * gq * d floats per row/head): classic
-    # log-sum-exp merge of the blocks' partials. Skipped blocks carry
-    # (m=NEG_INF, l=0, acc=0) and contribute exact zeros; a fully-masked
-    # row (dead slot) ends with l_total=0 and returns zeros.
-    m_tot = m_p.max(axis=1)                        # [B, KV, gq, 1]
-    alpha = jnp.exp(m_p - m_tot[:, None])          # [B, nb, KV, gq, 1]
-    l_tot = (l_p * alpha).sum(axis=1)
-    acc = (o_p * alpha).sum(axis=1)
-    return acc / jnp.maximum(l_tot, 1e-30)
 
 
 def _flash_decode(q5, kc, vc, lens, *, sm_scale: float, block_k: int,
                   k_scale=None, v_scale=None):
     """Contiguous caches [B, max_len, KV, d] (scales [B, max_len, KV]
-    f32, both or neither): cell (b, s) reads cache block s of row b."""
-    max_len = kc.shape[1]
+    f32, both or neither) are a pool whose table is the identity: row
+    b's block j is block ``b * nb + j`` of the cache cut into
+    ``block_k``-position blocks (a free reshape)."""
+    B, max_len, KV, d = kc.shape
     bk = pick_block(max_len, block_k)
-
-    def _kv_index(b, s, lens):
-        # blocks beyond the row's last needed block re-point AT the last
-        # needed one: Pallas sees a repeated index and skips the fetch,
-        # so right-pad past pos (and dead slots pinned to pos 0) cost no
-        # HBM traffic beyond one block
-        last = jnp.maximum(pl.cdiv(lens[b], bk) - 1, 0)
-        return (b, jnp.minimum(s, last))
-
-    return _split_k_attention(q5, kc, vc, lens, block_k=bk,
-                              nb=max_len // bk, kv_index=_kv_index,
-                              sm_scale=sm_scale, k_scale=k_scale,
-                              v_scale=v_scale)
+    nb = max_len // bk
+    bt = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    if k_scale is not None:
+        k_scale, v_scale = (s.reshape(B * nb, bk, KV)
+                            for s in (k_scale, v_scale))
+    return _paged_flash_decode(
+        q5, kc.reshape(B * nb, bk, KV, d), vc.reshape(B * nb, bk, KV, d),
+        bt, lens, sm_scale=sm_scale, k_scale=k_scale, v_scale=v_scale)
 
 
 def _unwrap(x):
@@ -495,8 +569,8 @@ def flash_decode_attention(q, k_cache, v_cache, positions, sm_scale=None,
     QUANTIZED caches: pass the per-token-per-head absmax scales
     ``k_scale``/``v_scale`` ([B, max_len, kv_heads] f32, the
     ``make_kv_caches(kv_format=...)`` companions) and int8/fp8 caches —
-    each grid cell dequantizes its block in the kernel prologue, so the
-    HBM stream is the narrow one and nothing else changes.
+    each cell is dequantized in the kernel prologue, so the HBM stream
+    is the narrow one and nothing else changes.
     """
     from ..core.tensor import Tensor
     from ..ops.dispatch import apply_op
@@ -531,33 +605,6 @@ def flash_decode_attention(q, k_cache, v_cache, positions, sm_scale=None,
     return _f(jnp.asarray(q), jnp.asarray(k_cache), jnp.asarray(v_cache))
 
 
-def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
-                        k_scale=None, v_scale=None, ancestor_mask=None):
-    """Paged pools [num_blocks, bs, KV, d] (scales [num_blocks, bs, KV]
-    f32), bt [B, nb] int32: identical math to ``_flash_decode`` — the
-    only change is the K/V index map, which resolves the grid's logical
-    kv-block through the scalar-prefetched block table into a physical
-    pool block. Out-of-range blocks re-point at the row's LAST needed
-    logical block (the same Pallas revisit-skip as the contiguous
-    kernel), so a short row costs its own length, not the table width.
-
-    ``ancestor_mask`` ([B, q_len, q_len], 1.0 = visible): per-row
-    in-bundle visibility for tree-speculative verify; every cell of row
-    b reads the same block. None compiles the causal bundle exactly as
-    before."""
-    bs = kp.shape[1]
-
-    def _kv_index(b, s, lens, bt):
-        last = jnp.maximum(pl.cdiv(lens[b], bs) - 1, 0)
-        return (bt[b, jnp.minimum(s, last)], 0)
-
-    return _split_k_attention(q5, kp, vp, lens, block_k=bs,
-                              nb=bt.shape[1], kv_index=_kv_index,
-                              prefetch=(bt.astype(jnp.int32),),
-                              sm_scale=sm_scale, k_scale=k_scale,
-                              v_scale=v_scale, ancestor_mask=ancestor_mask)
-
-
 def paged_flash_decode_attention(q, k_pool, v_pool, block_table, positions,
                                  sm_scale=None, k_scale=None, v_scale=None,
                                  ancestor_mask=None):
@@ -576,8 +623,7 @@ def paged_flash_decode_attention(q, k_pool, v_pool, block_table, positions,
     QUANTIZED pools: pass the [num_blocks, block_size, kv_heads] f32
     absmax scale pools as ``k_scale``/``v_scale``
     (``make_paged_kv_pools(kv_format=...)``'s ``ks``/``vs``) — dequant
-    happens in the kernel prologue, per block, behind the same
-    table-indirected index map.
+    happens in the kernel prologue, cell by cell.
 
     TREE-SPECULATIVE bundles: ``ancestor_mask`` [B, q_len, q_len] bool
     (True = bundle node i may attend bundle node j) replaces ONLY the

@@ -2379,6 +2379,7 @@ class ServingEngine:
                 if not active:
                     return worked
 
+                dispatch_args = None
                 if self.paged:
                     # every active row writes this step's K/V at its current
                     # length — or, speculatively, at its whole verify-bundle
@@ -2412,6 +2413,12 @@ class ServingEngine:
                     if not active:
                         worked = True
                         return True
+                    # the pool blocks the step's attention reads: each
+                    # active row's, up to the end of what it writes
+                    dispatch_args = ph.on and {"kv_blocks": sum(
+                        -(-(self._slot_len[i] + (self._row_spec_len(i)
+                                                 if self.spec else 1)) // bs)
+                        for i in active)}
 
                 worked = True
                 t0_ns = ph.mark("engine.dispatch") \
@@ -2421,7 +2428,7 @@ class ServingEngine:
                 active_mask[active] = True
                 if self.spec:
                     self._spec_step(active, active_mask, any_sampling,
-                                    t0_ns, ph)
+                                    t0_ns, ph, dispatch_args)
                     return True
                 with _entrypoint("serving.step"):
                     if self.paged:
@@ -2436,7 +2443,7 @@ class ServingEngine:
                             self._pb, self._caches, self._state,
                             jnp.asarray(any_sampling),
                             jnp.asarray(active_mask))
-                ph.mark("engine.wait")
+                ph.mark("engine.wait", dispatch_args)
                 toks_np = np.asarray(toks)  # the step's ONE device->host sync
                 now_ns = ph.mark("engine.emit") or time.perf_counter_ns()
                 now = now_ns / 1e9
@@ -2506,7 +2513,7 @@ class ServingEngine:
         return max(1, min(k_req + 1, remaining, room))
 
     def _spec_step(self, active, active_mask, any_sampling, t0_ns: int,
-                   ph) -> None:
+                   ph, dispatch_args) -> None:
         """One speculative iteration for the whole pool: ONE jitted
         draft program (k draft-model forwards), ONE jitted verify
         (target scores the k+1-wide bundle through the paged kernel,
@@ -2554,7 +2561,7 @@ class ServingEngine:
                 cand, n_emit, self._pools, self._state = self._verify_fn(
                     self._pb, self._pools, self._state, bt_j, drafts,
                     sv_j, as_j, jnp.asarray(active_mask))
-        ph.mark("engine.wait")
+        ph.mark("engine.wait", dispatch_args)
         cand_np = np.asarray(cand)   # the round's device->host sync
         n_np = np.asarray(n_emit)
         now_ns = ph.mark("engine.emit") or time.perf_counter_ns()

@@ -37,6 +37,7 @@ SLOTS, NB, BS = 16, 128, 16
 POOL_DTYPES = {"bf16": BF16, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 # paged bundles: (batch rows, q_len, ancestor mask)
 BUNDLES = {"decode": (SLOTS, 1, False), "chunk64": (1, 64, False),
+           "chunk32": (1, 32, False),   # the chunk the served cells run
            "spec5": (SLOTS, 5, False), "tree29": (SLOTS, 29, True)}
 RESNET50 = [(56, 64), (28, 128), (14, 256), (7, 512)]  # (H=W, channels)
 

@@ -1,7 +1,7 @@
 """Flash-decode attention (pallas_kernels/decode_attention.py).
 
 Oracles:
-- KERNEL PARITY: the split-K GQA kernel must match a float64 dense SDPA
+- KERNEL PARITY: the one-pass GQA kernel must match a float64 dense SDPA
   over each row's valid cache prefix — across q_len {1, 4}, GQA ratios
   {1, 2, 4}, ragged per-row positions including the pos=0 and
   pos=max_len-q_len edge rows, fp32 at exact-class tolerance and bf16 at
@@ -64,9 +64,9 @@ class TestKernelParity:
     @pytest.mark.parametrize("group", [1, 2, 4])
     @pytest.mark.parametrize("q_len", [1, 4])
     def test_fp32_parity_ragged_positions(self, group, q_len):
-        """block_k=16 over max_len=48 forces a 3-block split-K grid with
-        per-row block skipping; rows pin the pos=0 and pos=max_len-q_len
-        edges plus a mid-cache position."""
+        """block_k=16 over max_len=48 is a three-block cell fetched only
+        as far as each row's length; rows pin the pos=0 and
+        pos=max_len-q_len edges plus a mid-cache position."""
         rng = np.random.RandomState(group * 10 + q_len)
         B, KV, d, max_len = 3, 2, 16, 48
         q, kc, vc = _rand_qkv(rng, B, q_len, KV, group, d, max_len)
@@ -125,6 +125,41 @@ class TestKernelParity:
         ref = _oracle(q, kc, vc, pos)
         assert np.isfinite(out).all()
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+class TestOnePassCells:
+    """The contiguous entry is the paged kernel over an identity table:
+    a cache of several cells, row lengths on every side of the cell
+    boundaries, against the f64 oracle."""
+
+    @pytest.mark.parametrize("group", [1, 4])
+    @pytest.mark.parametrize("q_len", [1, 4])
+    def test_lengths_across_cell_boundaries(self, group, q_len):
+        import jax.numpy as jnp
+
+        rng = np.random.RandomState(group * 100 + q_len)
+        KV, d, block_k, max_len = 2, 16, 64, 1280
+        cell = block_k * fd._blocks_per_cell(
+            block_k, max_len // block_k, KV, d, jnp.float32, q_len * group)
+        assert 2 * cell + 3 < max_len
+        lens = np.array([q_len, cell - 1, cell, cell + 1, 2 * cell + 3,
+                         max_len], np.int32)
+        q, kc, vc = _rand_qkv(rng, len(lens), q_len, KV, group, d, max_len)
+        pos = lens - q_len
+        out = np.asarray(flash_decode_attention(q, kc, vc, pos,
+                                                block_k=block_k))
+        np.testing.assert_allclose(out, _oracle(q, kc, vc, pos),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_a_row_of_length_zero_returns_zeros(self):
+        rng = np.random.RandomState(23)
+        q, kc, vc = _rand_qkv(rng, 3, 1, 2, 2, 8, 64)
+        pos = np.array([9, -1, 40], np.int32)   # lens = pos + 1
+        out = np.asarray(flash_decode_attention(q, kc, vc, pos, block_k=16))
+        assert not out[1].any()
+        ref = _oracle(q, kc, vc, np.array([9, 0, 40]))
+        np.testing.assert_allclose(out[[0, 2]], ref[[0, 2]], atol=2e-5,
+                                   rtol=2e-5)
 
 
 class TestGroupedFallback:

@@ -116,11 +116,13 @@ class TestPhases:
 
     def test_a_span_carries_only_the_args_a_metric_reads(self, served):
         # iter ties the lanes together; preempted is preemptions.*;
-        # the two token counts are prefix_hit_share.chat
+        # the two token counts are prefix_hit_share.chat; kv_blocks is
+        # decode_live_blocks_per_step.*
         _, _, events = served
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
-                                 "prompt_tokens"}}
+                                 "prompt_tokens"},
+                "engine.dispatch": {"iter", "kv_blocks"}}
         # (another test's engine may idle on a thread of its own meanwhile)
         mine = [e for e in events if e["name"].startswith("engine.")
                 and e["name"] != "engine.idle"]

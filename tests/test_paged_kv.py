@@ -563,3 +563,185 @@ class TestPagedKernel:
             got = np.asarray(req.result(timeout=1.0))
             np.testing.assert_array_equal(
                 got, _ref(model, p, max_new_tokens=4))
+
+
+# ---------------------------------------------------------------------------
+# the one-pass kernel against the XLA gather path
+# ---------------------------------------------------------------------------
+
+# (heads, kv_heads): the serving cells' MHA and Llama-style GQA
+LAYOUTS = {"mha16": (16, 16), "gqa32_8": (32, 8)}
+# bundle -> q_len; tree29 is the [4, 2, 2] draft tree with its mask
+BUNDLES = {"decode": 1, "spec5": 5, "chunk32": 32, "tree29": 29}
+ONE_PASS_CASES = (
+    [(h, b, "f32") for h in LAYOUTS for b in BUNDLES]
+    + [(h, b, f) for h in LAYOUTS for b in ("decode", "spec5")
+       for f in ("bf16", "int8", "fp8")])
+
+
+def _one_pass_problem(layout, bundle, fmt, seed=0):
+    """Pools, a table and positions that put every edge of the cell
+    loop into one batch: lengths 1 (or the bundle), cell - 1, cell,
+    cell + 1, two cells + 3 and the table's full width; a table that is
+    not monotone; two rows that share their prefix blocks."""
+    import jax.numpy as jnp
+    from paddle_tpu.pallas_kernels import decode_attention as fd
+    from paddle_tpu.quantization import intx
+
+    H, KV = LAYOUTS[layout]
+    q_len = BUNDLES[bundle]
+    d, bs, nb = 8, 16, 72
+    store = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+             "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}[fmt]
+    cell = bs * fd._blocks_per_cell(bs, nb, KV, d, store,
+                                    q_len * (H // KV))
+    assert 2 * cell + 3 < nb * bs, "the table must span over two cells"
+    lens = np.array([q_len, cell - 1, cell, cell + 1, 2 * cell + 3,
+                     nb * bs], np.int32)
+    B = len(lens)
+    rng = np.random.RandomState(seed)
+    bt = 1 + rng.permutation(B * nb).reshape(B, nb).astype(np.int32)
+    bt[3, :cell // bs] = bt[2, :cell // bs]     # a shared prefix
+    N = B * nb + 1
+    qdt = jnp.bfloat16 if fmt == "bf16" else jnp.float32
+    q = jnp.asarray(rng.randn(B, q_len, H, d), qdt)
+    kp, vp = (jnp.asarray(rng.randn(N, bs, KV, d), qdt) for _ in range(2))
+    kwargs = {}
+    if fmt in ("int8", "fp8"):
+        scales = [intx.absmax_along(p, axis=-1) for p in (kp, vp)]
+        kp, vp = (intx.pack_absmax(p, s[..., None], fmt)
+                  for p, s in zip((kp, vp), scales))
+        full = [generation.gather_paged_kv_dequant(p, s, bt)._data
+                for p, s in zip((kp, vp), scales)]
+        kwargs = dict(k_scale=scales[0], v_scale=scales[1])
+    else:
+        full = [generation.gather_paged_kv(p, bt)._data for p in (kp, vp)]
+    pos = lens - q_len
+    t = np.arange(nb * bs)[None, None, :]
+    visible = t <= pos[:, None, None] + np.arange(q_len)[None, :, None]
+    if bundle == "tree29":
+        anc = np.asarray(generation.spec_tree_plan([4, 2, 2])["anc"], bool)
+        assert anc.shape == (q_len, q_len)
+        kwargs["ancestor_mask"] = np.broadcast_to(anc, (B, q_len, q_len))
+        idx = np.clip(t - pos[:, None, None], 0, q_len - 1)
+        in_bundle = (t >= pos[:, None, None]) \
+            & (t < pos[:, None, None] + q_len)
+        visible = (t < pos[:, None, None]) | (in_bundle & np.take_along_axis(
+            np.broadcast_to(anc, (B, q_len, q_len)),
+            np.broadcast_to(idx, (B, q_len, nb * bs)), axis=2))
+    return (q, kp, vp, bt, pos, kwargs), (full, visible), cell
+
+
+def _xla_gather_attention(q, k_full, v_full, visible):
+    """The path the kernel replaces: the gathered cache, kv heads
+    repeated, a masked softmax in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    g = q.shape[2] // k_full.shape[2]
+    k, v = (jnp.repeat(a.astype(jnp.float32), g, axis=2)
+            for a in (k_full, v_full))
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bqhd,bthd->bhqt", q.astype(jnp.float32), k) \
+            / np.sqrt(q.shape[-1])
+        s = jnp.where(jnp.asarray(visible)[:, None], s, -1e30)
+        return np.asarray(jnp.einsum("bhqt,bthd->bqhd",
+                                     jax.nn.softmax(s, axis=-1), v))
+
+
+class TestOnePassKernel:
+    @pytest.mark.parametrize("layout,bundle,fmt", ONE_PASS_CASES)
+    def test_matches_the_xla_gather_path(self, layout, bundle, fmt):
+        from paddle_tpu.pallas_kernels.decode_attention import \
+            paged_flash_decode_attention
+
+        (q, kp, vp, bt, pos, kwargs), (full, visible), _ = \
+            _one_pass_problem(layout, bundle, fmt)
+        out = np.asarray(paged_flash_decode_attention(
+            q, kp, vp, bt, pos, **kwargs), np.float32)
+        ref = _xla_gather_attention(q, *full, visible)
+        # float32 everywhere but the bf16 lane, whose probabilities go
+        # into the second matmul in bf16 (the documented tolerance)
+        tol = 2e-2 if fmt == "bf16" else 2e-5
+        np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
+
+    def test_a_dead_slot_returns_zeros_and_leaves_its_neighbours(self):
+        """A row with nothing to attend (length 0) between two live
+        rows: zeros out, and the chain of fetches goes on past it."""
+        from paddle_tpu.pallas_kernels.decode_attention import \
+            paged_flash_decode_attention
+
+        (q, kp, vp, bt, pos, _), (full, visible), _ = _one_pass_problem(
+            "mha16", "decode", "f32", seed=3)
+        pos = pos.copy()
+        pos[[0, 3]] = -1                     # lens = pos + q_len = 0
+        out = np.asarray(paged_flash_decode_attention(q, kp, vp, bt, pos))
+        assert not out[[0, 3]].any()
+        ref = _xla_gather_attention(q, *full, visible)
+        live = [1, 2, 4, 5]
+        np.testing.assert_allclose(out[live], ref[live], atol=2e-5,
+                                   rtol=2e-5)
+
+    def test_one_pallas_call_no_partials_no_merge(self):
+        """The call's jaxpr holds exactly one ``pallas_call``, none of
+        its outputs has an axis as wide as the table, and no reduction
+        follows it: the whole softmax is inside the kernel."""
+        import jax
+        from paddle_tpu.pallas_kernels.decode_attention import \
+            paged_flash_decode_attention
+
+        (q, kp, vp, bt, pos, _), _, _ = _one_pass_problem(
+            "gqa32_8", "spec5", "f32")
+        nb = bt.shape[1]
+
+        def flat(jaxpr):
+            for eqn in jaxpr.eqns:
+                subs = [v for v in eqn.params.values()
+                        if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+                if eqn.primitive.name != "pallas_call" and subs:
+                    for sub in subs:
+                        yield from flat(getattr(sub, "jaxpr", sub))
+                else:
+                    yield eqn
+
+        eqns = list(flat(jax.make_jaxpr(
+            lambda *a: paged_flash_decode_attention(*a))(
+                q, kp, vp, bt, pos).jaxpr))
+        calls = [i for i, e in enumerate(eqns)
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        call = eqns[calls[0]]
+        assert [v.aval.shape for v in call.outvars] \
+            == [(q.shape[0], 8, 5 * 4, q.shape[-1])]
+        assert all(nb not in v.aval.shape for v in call.outvars)
+        after = {e.primitive.name for e in eqns[calls[0] + 1:]}
+        assert not {n for n in after if n.startswith("reduce")
+                    or n in ("exp", "argmax", "cumsum", "dot_general")}, after
+
+    def test_cell_size_is_a_pure_function_of_the_shapes(self, monkeypatch):
+        import inspect
+        import jax.numpy as jnp
+        from paddle_tpu.pallas_kernels import decode_attention as fd
+
+        assert list(inspect.signature(fd._blocks_per_cell).parameters) == [
+            "block_size", "nb", "kv_heads", "d", "kv_dtype", "gq"]
+        shapes = {"gpt_step": (16, 128, 16, 128, jnp.bfloat16, 1),
+                  "gpt_chunk": (16, 128, 16, 128, jnp.bfloat16, 32),
+                  "gqa_int8": (16, 128, 8, 128, jnp.int8, 4),
+                  "window256": (16, 128, 8, 128, jnp.bfloat16, 1024),
+                  "tiny_table": (8, 4, 2, 8, jnp.float32, 1)}
+        first = {k: fd._blocks_per_cell(*s) for k, s in shapes.items()}
+        # neither the environment nor a ServingConfig reaches it
+        for key in ("PADDLE_TPU_FLASH_DECODE", "PADDLE_TPU_DECODE_CELL",
+                    "PADDLE_TPU_DECODE_BLOCK_K"):
+            monkeypatch.setenv(key, "64")
+        serving.ServingConfig(max_slots=2, max_len=64, block_size=16)
+        assert {k: fd._blocks_per_cell(*s)
+                for k, s in shapes.items()} == first
+        code = fd._blocks_per_cell.__code__
+        assert not {"os", "environ", "getenv"} & set(code.co_names)
+        for k, (bs, nb, *_) in shapes.items():
+            positions = first[k] * bs
+            assert positions == nb * bs or 128 <= positions <= 512, k
+        # a wide bundle's score tiles leave less room for the stream
+        assert first["window256"] <= first["gpt_step"]
