@@ -69,6 +69,8 @@ CHIP = dict(
     prompts=(5, 20, 200, 333, 900), prefix=96, new_tokens=(32, 48, 64),
     llama=dict(num_hidden_layers=TRAIN_LAYERS),
     seq=4096, tree=(4, 2, 2),
+    # the EVA cell's decode step: rows, heads, head_dim, window, block
+    eva=(20, 32, 128, 2048, 16),
 )
 REHEARSAL = dict(
     gpt=dict(hidden_size=512, num_hidden_layers=2, num_attention_heads=4,
@@ -80,6 +82,7 @@ REHEARSAL = dict(
                num_attention_heads=4, num_key_value_heads=4, vocab_size=512,
                max_position_embeddings=256),
     seq=256, tree=(2, 2),
+    eva=(3, 4, 32, 64, 4),
 )
 
 # Kernel-vs-XLA tolerance on attention outputs, |out - ref| <= ATOL +
@@ -298,6 +301,55 @@ def _kernel_parity(sizes, heads, head_dim, num_blocks, block_size, chunk):
     return worst
 
 
+def _eva_kernel_parity(sizes):
+    """The same kernel on the rows of an EVA model (chunked linearized
+    attention, models/evabyte.py): 32 heads of 128, 20 rows two windows
+    behind, so a row's table is [the summary blocks of two windows | the
+    window's blocks] and its length the summaries plus its place in the
+    window (``generation.eva_virtual_position``). Against the XLA path
+    over the same table."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu import generation
+    from paddle_tpu.pallas_kernels.decode_attention import \
+        paged_flash_decode_attention
+
+    rows, heads, d, window, bs = sizes["eva"]
+    chunk = 16
+    nb = (3 * window // chunk + window) // bs      # three windows' summaries
+    rng = np.random.RandomState(SEED + 1)
+    kp, vp = (jnp.asarray(rng.randn(1 + rows * nb, bs, heads, d), jnp.bfloat16)
+              for _ in range(2))
+    bt = jnp.asarray(1 + rng.permutation(rows * nb).reshape(rows, nb),
+                     jnp.int32)
+    q = jnp.asarray(rng.randn(rows, 1, heads, d), jnp.bfloat16)
+    # the window's first and last positions, then anywhere in it
+    pos = 2 * window + np.asarray(
+        ([0, window - 1] + list(rng.randint(0, window, rows)))[:rows])
+    vpos = generation.eva_virtual_position(pos, window, chunk)
+    check(int(vpos.min()) == 2 * window // chunk
+          and int(vpos.max()) < nb * bs - window // chunk,
+          "EVA rows read two windows of summaries and their own window")
+    out = np.asarray(paged_flash_decode_attention(
+        q, kp, vp, bt, jnp.asarray(vpos, jnp.int32)), np.float32)
+    visible = jnp.asarray(np.arange(nb * bs)[None, None, :]
+                          <= vpos[:, None, None])
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(F.scaled_dot_product_attention(
+            q.astype(jnp.float32),
+            *(generation.gather_paged_kv(p, bt)._data.astype(jnp.float32)
+              for p in (kp, vp)), attn_mask=visible[:, None])._data)
+    diff = np.abs(out - ref)
+    ratio = float((diff / (KERNEL_ATOL + KERNEL_RTOL * np.abs(ref))).max())
+    check(np.isfinite(out).all() and ratio <= 1,
+          f"kernel eva_decode agrees with the XLA path (max |diff| "
+          f"{diff.max():.5f}, {ratio:.2f} of the tolerance)")
+    return round(float(diff.max()), 5)
+
+
 def phase_serve(rehearse):
     t_phase = time.perf_counter()
     common, sizes = _start(rehearse)
@@ -315,6 +367,7 @@ def phase_serve(rehearse):
     parity = _kernel_parity(sizes, cfg.num_attention_heads, head_dim,
                             scfg.default_num_blocks(), scfg.block_size,
                             scfg.prefill_chunk)
+    parity["eva_decode"] = _eva_kernel_parity(sizes)
 
     paddle.seed(SEED)
     model = GPTForCausalLM(cfg)

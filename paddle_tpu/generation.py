@@ -34,7 +34,8 @@ __all__ = ["GenerationConfig", "generate", "generate_uncached",
            "paged_kv_cache_write", "gather_paged_kv",
            "kv_cache_write_quant", "paged_kv_cache_write_quant",
            "gather_paged_kv_dequant", "dequantize_kv_buffer",
-           "kv_format_of", "kv_cache_bytes_per_token"]
+           "kv_format_of", "kv_cache_bytes_per_token",
+           "eva_virtual_position", "eva_pool_chunks", "eva_summary_write"]
 
 
 def _is_per_row(position_offset) -> bool:
@@ -418,6 +419,95 @@ def _update_paged_kv_cache(kv_cache: dict, k, v, position_offset,
         return (gather_paged_kv(ck, bt), gather_paged_kv(cv, bt),
                 new_cache, mask)
     return ck, cv, new_cache, mask
+
+
+def eva_virtual_position(pos, window: int, chunk: int):
+    """Where absolute position ``pos`` sits in an EVA slot's combined
+    table (chunked linearized attention: exact keys inside a window of
+    ``window`` positions, one summary entry per ``chunk`` positions of
+    every window behind it). The table is laid out, in entries,
+
+        [summaries of windows 0..g-1 | window g's exact keys | window g's
+         summaries, being filled]
+
+    with ``window // chunk`` summaries a window, so position ``pos`` of
+    window ``g = pos // window`` is entry ``g * window // chunk + pos %
+    window``: every entry before it is one its query attends (the
+    summaries behind and the window's earlier keys), which is exactly
+    the paged cache's causal rule on these virtual positions. Works on
+    python ints, traced scalars and per-row [B] vectors alike."""
+    return (pos // window) * (window // chunk) + pos % window
+
+
+def eva_pool_chunks(kc, vc, phi, mu, sm_scale: float):
+    """EVA's chunk summaries: keys and values ``[..., chunk, h, d]``
+    pooled over the chunk axis with the learned ``phi``, ``mu`` [h, d],
+
+        a_j = softmax_j(sm_scale * k_j . phi);  kbar = sum_j a_j k_j + mu;
+        vbar = sum_j a_j v_j
+
+    in float32 -> (kbar, vbar) ``[..., h, d]``. Raw arrays."""
+    kf, vf = kc.astype(jnp.float32), vc.astype(jnp.float32)
+    a = jax.nn.softmax(sm_scale * jnp.einsum(
+        "...chd,hd->...ch", kf, phi.astype(jnp.float32)), axis=-2)
+    kbar = jnp.einsum("...ch,...chd->...hd", a, kf) + mu.astype(jnp.float32)
+    return kbar, jnp.einsum("...ch,...chd->...hd", a, vf)
+
+
+def eva_summary_write(kv_cache: dict, phi, mu, position_offset, s: int,
+                      window: int, chunk: int, sm_scale: float):
+    """The second write of an EVA cache: for every ``chunk``-token chunk
+    that the ``s`` tokens just written at ``position_offset`` COMPLETE,
+    pool the chunk's keys and values (``eva_pool_chunks``) and scatter
+    ``(kbar, vbar)`` into the slot's next summary entry, which lies
+    behind the window's keys in the table (``eva_virtual_position``'s
+    layout) until the engine rolls the window. The chunk's keys are read
+    back from the pool through the table, so a chunk that a prefill
+    began and decode steps finish is pooled like any other. Chunks not
+    completed by this write (and pad tokens beyond ``valid``) route to
+    the dump block 0, as padded K/V writes do: the executable is the
+    same whatever completes.
+
+    ``kv_cache`` is the paged dict AFTER this step's K/V write (pools
+    [N, bs, h, d], ``bt`` [b, nb], optional ``valid``); ``phi``/``mu``
+    [h, d]; a bundle is one token or whole aligned chunks. Cache
+    plumbing under no_grad, like the scatter it follows: it returns the
+    cache with both pools updated and is no op of its own on the
+    dispatch surface."""
+    if s != 1 and s % chunk:
+        raise ValueError(
+            f"an EVA cache takes a decode step or a bundle of whole "
+            f"{chunk}-token chunks, got {s} tokens")
+
+    def raw(t):
+        return t._data if isinstance(t, Tensor) else jnp.asarray(t)
+
+    bt, kp, vp = raw(kv_cache["bt"]), raw(kv_cache["k"]), raw(kv_cache["v"])
+    n_blocks, bs = kp.shape[0], kp.shape[1]
+    b, n_c = bt.shape[0], max(1, s // chunk)
+    pos = jnp.broadcast_to(raw(position_offset).astype(jnp.int32), (b,))
+    valid = jnp.broadcast_to(
+        raw(kv_cache.get("valid", s)).astype(jnp.int32), (b,))
+    first = pos // chunk * chunk               # the first chunk touched
+    flat = [p.reshape((n_blocks * bs,) + p.shape[2:]) for p in (kp, vp)]
+    idx = _paged_flat_indices(bt, eva_virtual_position(first, window, chunk),
+                              None, n_blocks, bs, b, n_c * chunk)
+    pooled = eva_pool_chunks(
+        *(f[idx].reshape((b, n_c, chunk) + kp.shape[2:]) for f in flat),
+        raw(phi), raw(mu), sm_scale)
+    # the summary entries of these chunks follow the window's keys, in
+    # order; those this write did not complete go to the dump block
+    ends = first[:, None] + chunk * (1 + jnp.arange(n_c))[None, :]
+    done = (ends > pos[:, None]) & (ends <= (pos + valid)[:, None])
+    at = _paged_flat_indices(
+        bt, pos // window * (window // chunk) + window
+        + first % window // chunk, None, n_blocks, bs, b, n_c)
+    at = jnp.where(done, at, 0).reshape(-1)
+    out = dict(kv_cache)
+    for name, f, new in zip(("k", "v"), flat, pooled):
+        out[name] = Tensor(f.at[at].set(new.astype(f.dtype).reshape(
+            (b * n_c,) + f.shape[1:])).reshape(kp.shape))
+    return out
 
 
 def update_static_kv_cache(kv_cache: dict, k, v, position_offset,

@@ -1729,6 +1729,9 @@ _RANDOM = ("stochastic op (fresh PRNG key per call); distributional "
            "behavior tested in ")
 
 NO_SCHEMA_WHITE_LIST = {
+    # EVA chunked linearized attention (models/evabyte.py)
+    "eva_attention": "dense window-plus-summaries attention; parity with "
+                     "the plain reference in test_evabyte.py",
     # eager collectives / distributed-internal ops
     "all_reduce": _COLLECTIVE,
     "all_gather": _COLLECTIVE,

@@ -37,7 +37,7 @@ import numpy as np
 from . import metrics as _sm
 
 __all__ = ["BlockPool", "PrefixCache", "PoolExhaustedError",
-           "BlockPoolError", "DUMP_BLOCK"]
+           "BlockPoolError", "DUMP_BLOCK", "WindowedLayout"]
 
 # physical block 0: the write sink for inactive/padded rows. Never
 # allocated, never freed, never cached.
@@ -52,6 +52,76 @@ class PoolExhaustedError(RuntimeError):
 class BlockPoolError(RuntimeError):
     """Allocator invariant violation (double free, bad block id) — a
     bug in the caller, never load-dependent."""
+
+
+class WindowedLayout:
+    """Where the blocks of a slot sit in its table when the model keeps
+    exact keys only inside a window and one summary entry per chunk of
+    every window behind it (EVA; ``generation.eva_virtual_position`` is
+    the device's side of the same layout). In table entries of
+    ``block_size`` positions, a slot in window ``g`` holds
+
+        [ g * per_window summary blocks | up to window_blocks of exact
+          keys | up to per_window blocks of window g's own summaries ]
+
+    and when its position crosses into window ``g + 1`` the engine
+    rolls the row: the exact keys' blocks are released, window ``g``'s
+    summary blocks move down beside the older ones, and the window
+    starts again. Pure arithmetic on the host; the pool and the table
+    stay the engine's."""
+
+    def __init__(self, block_size: int, window: int, chunk: int,
+                 max_len: int):
+        if window % chunk or window % block_size \
+                or (window // chunk) % block_size:
+            raise ValueError(
+                f"a window of {window} positions must be whole chunks of "
+                f"{chunk}, whole blocks of {block_size}, and its "
+                f"{window // chunk} summaries must fill whole blocks: pick a "
+                f"block_size that divides {window // chunk}")
+        self.block_size, self.window, self.chunk = block_size, window, chunk
+        self.window_blocks = window // block_size
+        self.per_window = window // chunk // block_size
+        self.width = self.per_window * ((max_len - 1) // window + 1) \
+            + self.window_blocks
+
+    def entries(self, start: int, end: int) -> List[int]:
+        """Table indices that writing positions ``[start, end)`` (inside
+        one window) touches: the exact keys' blocks and the summary
+        blocks of every chunk the write completes."""
+        bs, g = self.block_size, start // self.window
+        base = g * self.per_window
+        lo, hi = start % self.window, (end - 1) % self.window
+        out = list(range(base + lo // bs, base + hi // bs + 1))
+        # chunk m is completed by this write when start < (m+1)*chunk <= end
+        first_c = start // self.chunk % (self.window // self.chunk)
+        n_done = end // self.chunk - start // self.chunk
+        if n_done > 0:
+            sb = base + self.window_blocks
+            out += range(sb + first_c // bs,
+                         sb + (first_c + n_done - 1) // bs + 1)
+        return out
+
+    def held(self, n: int) -> int:
+        """Blocks a slot holds once positions ``[0, n)`` are written
+        (before the roll that the write of position ``n`` would bring)."""
+        if n <= 0:
+            return 0
+        g, inw = (n - 1) // self.window, (n - 1) % self.window + 1
+        bs = self.block_size
+        return g * self.per_window + -(-inw // bs) \
+            + -(-(inw // self.chunk) // bs)
+
+    def peak(self, n: int) -> int:
+        """The most blocks the slot holds on its way to ``n`` positions:
+        at ``n``, or at the end of the last whole window before it."""
+        return max(self.held(n), self.held(n // self.window * self.window))
+
+    def read_blocks(self, n: int):
+        """(window blocks, summary blocks) that attention reads for a
+        query at position ``n - 1``."""
+        g, inw = (n - 1) // self.window, (n - 1) % self.window + 1
+        return -(-inw // self.block_size), g * self.per_window
 
 
 class BlockPool:

@@ -104,7 +104,7 @@ from ..observability import tracing as _trace
 from ..observability.recompile import entrypoint as _entrypoint
 from . import metrics as _sm
 from .block_pool import (DUMP_BLOCK, BlockPool, PoolExhaustedError,
-                         PrefixCache)
+                         PrefixCache, WindowedLayout)
 from .kv_tier import DiskPrefixStore, KVTier, TierCostModel
 from .request import Request, RequestStatus, SamplingParams
 from .scheduler import Scheduler
@@ -455,6 +455,12 @@ class ServingEngine:
                 f"max_len ({config.max_len}) exceeds the model's "
                 f"max_position_embeddings ({mcfg.max_position_embeddings})")
         self.paged = config.kv_mode == "paged"
+        # a model with chunked linearized attention (EVA) keeps exact
+        # keys inside a window and summaries behind it: its slots give
+        # blocks back in mid-sequence (WindowedLayout)
+        self._layout: Optional[WindowedLayout] = None
+        if getattr(mcfg, "attention_class", None) == "eva":
+            self._layout = self._eva_layout(mcfg, config, draft_model)
         self.draft_model = draft_model
         self.spec = draft_model is not None
         if self.spec:
@@ -572,6 +578,10 @@ class ServingEngine:
         # prefill_chunks_total)
         self._n_prompt_tokens = 0      # tokens admitted prefills cover
         self._n_prefix_hit_tokens = 0  # of those, adopted from the cache
+        # windowed layout (EVA) only
+        self._n_window_rolls = 0       # slots that crossed into a window
+        self._n_window_blocks_released = 0   # exact-key blocks given back
+        self._n_summary_entries = 0    # chunks pooled into a summary
         # the iteration's phase spans (engine.iter and its children);
         # .seq numbers the iterations that did work
         self._phases = _trace.Phases("engine.iter", "engine", "engine")
@@ -613,6 +623,48 @@ class ServingEngine:
         else:
             self._init_contiguous(B, run)
         self._register_memory_components()
+
+    @staticmethod
+    def _eva_layout(mcfg, config: ServingConfig, draft_model):
+        """The table layout of an EVA model's slots, after refusing the
+        options whose bookkeeping assumes that a slot keeps the exact
+        keys of every position it has passed."""
+        refused = [
+            (config.kv_mode != "paged",
+             "kv_mode='contiguous': summaries live in pool blocks behind "
+             "the block table; use kv_mode='paged'"),
+            (config.prefix_caching,
+             "prefix_caching=True: a slot forgets its exact keys behind "
+             "the window, so a later prompt cannot adopt them; pass "
+             "prefix_caching=False"),
+            (draft_model is not None,
+             "a draft_model: a verify bundle of a few tokens is no whole "
+             "chunk, and rollback by position cannot take back a summary "
+             "already pooled; drop the draft model"),
+            (config.kv_tier,
+             "kv_tier=True: tier entries are keyed by the prefix cache, "
+             "which this model cannot use; drop kv_tier"),
+            (int(config.tp) > 1,
+             f"tp={config.tp}: the paged kernel that reads summaries and "
+             f"window as one row declines under tp; serve it with tp=1"),
+            (config.kv_format != "bf16",
+             f"kv_format={config.kv_format!r}: summaries are pooled in the "
+             f"model's dtype; use kv_format='bf16'"),
+        ]
+        for bad, why in refused:
+            if bad:
+                raise ValueError(
+                    "an EVA model (chunked linearized attention) cannot be "
+                    "served with " + why)
+        W, c = int(mcfg.window_size), int(mcfg.chunk_size)
+        C = int(config.prefill_chunk)
+        if C % c or W % C:
+            raise ValueError(
+                f"prefill_chunk ({C}) must be a multiple of the model's "
+                f"chunk_size ({c}) that divides its window_size ({W}), so "
+                f"that a prefill chunk pools whole chunks and never "
+                f"straddles a window")
+        return WindowedLayout(config.block_size, W, c, config.max_len)
 
     def _register_memory_components(self):
         """HBM-ledger attribution (``observability.perf.hbm_ledger``):
@@ -685,7 +737,8 @@ class ServingEngine:
         config = self.config
         mcfg = self._mcfg
         bs = config.block_size
-        nb = config.blocks_per_slot()
+        nb = config.blocks_per_slot() if self._layout is None \
+            else self._layout.width
         self._nblocks = int(config.num_blocks or config.default_num_blocks())
         self.pool = BlockPool(self._nblocks, bs)
         self.prefix_cache = PrefixCache(self.pool) if config.prefix_caching \
@@ -710,6 +763,7 @@ class ServingEngine:
         self._bt = np.zeros((B, nb), np.int32)           # host block tables
         self._slot_blocks: List[List[int]] = [[] for _ in range(B)]
         self._slot_len = [0] * B                         # host mirror of pos
+        self._slot_win = [0] * B     # windowed layout: the row's window
         self._jobs: List[Optional[_PrefillJob]] = [None] * B
         # this engine's closures are NEW executables — their first
         # compiles are warmup, not retraces of a previous engine's
@@ -1760,7 +1814,9 @@ class ServingEngine:
                 f"{self.config.max_len}")
         if self.paged:
             bs = self.config.block_size
-            worst = -(-(L + params.max_new_tokens - 1) // bs)
+            worst = -(-(L + params.max_new_tokens - 1) // bs) \
+                if self._layout is None \
+                else self._layout.peak(L + params.max_new_tokens - 1)
             if worst > self.pool.usable_blocks:
                 raise ValueError(
                     f"prompt ({L}) + max_new_tokens "
@@ -1806,6 +1862,7 @@ class ServingEngine:
             self._slot_blocks[slot] = []
             self._bt[slot, :] = 0
             self._slot_len[slot] = 0
+            self._slot_win[slot] = 0
 
     def _note_admission(self, req: Request, now: float,
                         resumed: bool = False):
@@ -1968,6 +2025,58 @@ class ServingEngine:
         _sm.preemptions_total.inc()
         self._update_occupancy_gauges()
 
+    def _reserve_write(self, slot: int, start: int, end: int):
+        """Make the slot's table ready for a write of positions
+        ``[start, end)``: allocate what the write crosses into, COW-fork
+        what it would dirty of a shared block. Under a windowed layout
+        the row is rolled first when ``start`` opens a new window, and
+        the summary blocks of the chunks the write completes are
+        allocated with the exact keys' (nothing is ever shared there).
+        Pool pressure preempts the latest-admitted other request;
+        ``PoolExhaustedError`` when that does not help."""
+        lay = self._layout
+        if lay is None:
+            bs = self.config.block_size
+            for bi in range(start // bs, (end - 1) // bs + 1):
+                if bi >= len(self._slot_blocks[slot]):
+                    nid = self._reclaim_alloc(1, slot)[0]
+                    self._slot_blocks[slot].append(nid)
+                    self._bt[slot, bi] = nid
+                else:
+                    self._ensure_writable(slot, bi)
+            return
+        if start // lay.window > self._slot_win[slot]:
+            self._roll_window(slot)
+        for e in lay.entries(start, end):
+            if not self._bt[slot, e]:
+                nid = self._reclaim_alloc(1, slot)[0]
+                self._slot_blocks[slot].append(nid)
+                self._bt[slot, e] = nid
+        self._n_summary_entries += end // lay.chunk - start // lay.chunk
+
+    def _roll_window(self, slot: int):
+        """The slot's position crossed a multiple of the window: give
+        the window's exact-key blocks back to the pool, move the
+        window's own summary blocks down beside the older summaries,
+        and start the window's part of the row again. Host work only:
+        the device derives the same layout from the position."""
+        lay = self._layout
+        row = self._bt[slot]
+        lo = self._slot_win[slot] * lay.per_window
+        mid, hi = lo + lay.window_blocks, lo + lay.window_blocks \
+            + lay.per_window
+        released = {int(b) for b in row[lo:mid] if b}
+        summaries = row[mid:hi].copy()
+        row[lo:hi] = 0
+        row[lo:lo + lay.per_window] = summaries
+        for b in released:
+            self.pool.decref(b)
+        self._slot_blocks[slot] = [b for b in self._slot_blocks[slot]
+                                   if b not in released]
+        self._slot_win[slot] += 1
+        self._n_window_rolls += 1
+        self._n_window_blocks_released += len(released)
+
     def _ensure_writable(self, slot: int, block_idx: int):
         """COW: the first write into a SHARED block forks it — allocate
         a fresh block, copy the shared content (one jitted dispatch),
@@ -2011,6 +2120,15 @@ class ServingEngine:
         total = int(tokens.shape[0])
         bs = self.config.block_size
         n_blocks = -(-total // bs)
+        if self._layout is not None:
+            # blocks come chunk by chunk, as windows give theirs back on
+            # the way; admission asks that the pool could hold the
+            # prefill's peak now
+            if self.pool.free_blocks < self._layout.peak(total):
+                raise PoolExhaustedError(
+                    f"KV block pool: {self._layout.peak(total)} blocks for "
+                    f"a prefill of {total}, {self.pool.free_blocks} free")
+            n_blocks = 0
         matched_tok, mblocks = 0, []
         if self.prefix_cache is not None:
             matched_tok, mblocks = self.prefix_cache.match(tokens, total - 1)
@@ -2142,8 +2260,7 @@ class ServingEngine:
         start = job.done
         end = min(start + C, job.total)
         is_last = end == job.total
-        for bi in range(start // bs, (end - 1) // bs + 1):
-            self._ensure_writable(slot, bi)
+        self._reserve_write(slot, start, end)
         ids = np.full((1, C), self.config.pad_token_id, np.int32)
         ids[0, :end - start] = job.tokens[start:end]
         p = req.params
@@ -2153,16 +2270,24 @@ class ServingEngine:
         # would-be-retrace bug) lands in this request's timeline
         with _trace.trace_context(req.trace), \
                 _entrypoint("serving.prefill_chunk"):
+            # host arrays of the warmup's shapes and dtypes, handed to
+            # the executable as they are: the call moves them with its
+            # other arguments, where a jnp.asarray each is a dispatch
+            # and a transfer of its own, twelve a chunk, and lets the
+            # host set the pace of a long prompt (PERF.md, PR 27)
+            i32 = np.int32
             chunk_args = (
-                jnp.asarray(self._bt[slot:slot + 1]),
-                jnp.asarray(ids), jnp.asarray(start, jnp.int32),
-                jnp.asarray(end - start, jnp.int32),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(is_last),
-                jnp.asarray(job.total - 1 - start, jnp.int32), job.key,
-                jnp.asarray([p.do_sample]),
-                jnp.asarray([p.temperature], jnp.float32),
-                jnp.asarray([p.top_k], jnp.int32),
-                jnp.asarray([p.top_p], jnp.float32))
+                # a copy: the backend may alias a numpy view, and a
+                # windowed row is rewritten (rolled) while earlier
+                # chunks are still in flight
+                self._bt[slot:slot + 1].copy(),
+                ids, np.asarray(start, i32), np.asarray(end - start, i32),
+                np.asarray(slot, i32), np.asarray(is_last, bool),
+                np.asarray(job.total - 1 - start, i32), job.key,
+                np.asarray([p.do_sample], bool),
+                np.asarray([p.temperature], np.float32),
+                np.asarray([p.top_k], i32),
+                np.asarray([p.top_p], np.float32))
             if self.spec:
                 token, self._pools, self._dpools, self._state = \
                     self._chunk_spec_fn(self._pb, self._dpb, self._pools,
@@ -2395,16 +2520,9 @@ class ServingEngine:
                         # does not change between here and the dispatch, so
                         # the bundle can never write past this coverage
                         m = self._row_spec_len(i) if self.spec else 1
-                        first_bi = self._slot_len[i] // bs
-                        last_bi = (self._slot_len[i] + m - 1) // bs
                         try:
-                            for bi in range(first_bi, last_bi + 1):
-                                if bi >= len(self._slot_blocks[i]):
-                                    nid = self._reclaim_alloc(1, i)[0]
-                                    self._slot_blocks[i].append(nid)
-                                    self._bt[i, bi] = nid
-                                else:
-                                    self._ensure_writable(i, bi)
+                            self._reserve_write(i, self._slot_len[i],
+                                                self._slot_len[i] + m)
                         except PoolExhaustedError:
                             self._preempt(i)
                     active = [i for i in active
@@ -2415,10 +2533,18 @@ class ServingEngine:
                         return True
                     # the pool blocks the step's attention reads: each
                     # active row's, up to the end of what it writes
-                    dispatch_args = ph.on and {"kv_blocks": sum(
-                        -(-(self._slot_len[i] + (self._row_spec_len(i)
-                                                 if self.spec else 1)) // bs)
-                        for i in active)}
+                    if self._layout is None:
+                        dispatch_args = ph.on and {"kv_blocks": sum(
+                            -(-(self._slot_len[i] + (self._row_spec_len(i)
+                                                     if self.spec else 1))
+                              // bs) for i in active)}
+                    else:
+                        # exact keys of the window, and summaries behind it
+                        read = ph.on and [self._layout.read_blocks(
+                            self._slot_len[i] + 1) for i in active]
+                        dispatch_args = ph.on and {
+                            "kv_blocks": sum(r[0] for r in read),
+                            "summary_blocks": sum(r[1] for r in read)}
 
                 worked = True
                 t0_ns = ph.mark("engine.dispatch") \
@@ -2435,9 +2561,8 @@ class ServingEngine:
                         bt_step = self._bt.copy()
                         bt_step[~active_mask] = 0  # inactive -> dump block
                         toks, self._pools, self._state = self._step_fn(
-                            self._pb, self._pools, self._state,
-                            jnp.asarray(bt_step), jnp.asarray(any_sampling),
-                            jnp.asarray(active_mask))
+                            self._pb, self._pools, self._state, bt_step,
+                            np.asarray(any_sampling, bool), active_mask)
                     else:
                         toks, self._caches, self._state = self._step_fn(
                             self._pb, self._caches, self._state,
@@ -2943,6 +3068,11 @@ class ServingEngine:
                 continue
             used = self._jobs[slot].done if self._jobs[slot] is not None \
                 else self._slot_len[slot]
+            if self._layout is not None:
+                # entries in use: the window's exact keys and a summary
+                # for every whole chunk so far
+                used = (used - 1) % self._layout.window + 1 \
+                    + used // self._layout.chunk if used else 0
             frag += len(self._slot_blocks[slot]) * bs - used
         stats["internal_fragmentation_tokens"] = frag
         stats["kv_format"] = self.config.kv_format
@@ -3048,7 +3178,7 @@ class ServingEngine:
         summed over ``steps``. Blocks, COW forks and chunks are counted
         by the pool (``stats()["kv_blocks"]``) and the metrics
         registry."""
-        return {
+        out = {
             "steps": self._steps,
             "slots": self.config.max_slots,
             "slot_steps": self._occupancy_integral,
@@ -3057,6 +3187,14 @@ class ServingEngine:
             "prefix_hit_tokens": self._n_prefix_hit_tokens,
             "preemptions": self._preempt_count,
         }
+        if self._layout is not None:
+            # slots that crossed into a new window, the exact-key blocks
+            # those rolls gave back, chunks pooled into a summary
+            out.update(
+                window_rolls=self._n_window_rolls,
+                window_blocks_released=self._n_window_blocks_released,
+                summary_entries_written=self._n_summary_entries)
+        return out
 
     def stats(self) -> dict:
         """``counters()`` plus the sections that cost: latency digests

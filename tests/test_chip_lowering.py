@@ -29,7 +29,7 @@ decode_attention, flash_attention, fused_conv, quant_matmul = (
 
 BF16 = jnp.bfloat16
 # (heads, kv_heads) at head_dim 128
-HEADS = {"mha16": (16, 16), "gqa32_8": (32, 8)}
+HEADS = {"mha16": (16, 16), "gqa32_8": (32, 8), "mha32": (32, 32)}
 D = 128
 # the serving engine's pool geometry: 16 slots x 2048 tokens in
 # 16-token blocks, plus the dump block
@@ -39,6 +39,11 @@ POOL_DTYPES = {"bf16": BF16, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 BUNDLES = {"decode": (SLOTS, 1, False), "chunk64": (1, 64, False),
            "chunk32": (1, 32, False),   # the chunk the served cells run
            "spec5": (SLOTS, 5, False), "tree29": (SLOTS, 29, True)}
+# the EVA cell (evabyte-6p5b-cut): 20 rows whose table is [72 summary
+# blocks | 128 window blocks | 8 more], a pool of 2,816 blocks, the
+# step and the 256-token prefill chunk
+EVA_BUNDLES = {"eva-step": (20, 1), "eva-chunk256": (1, 256)}
+EVA_NB, EVA_POOL = 208, 2816
 RESNET50 = [(56, 64), (28, 128), (14, 256), (7, 512)]  # (H=W, channels)
 
 
@@ -48,10 +53,12 @@ def _s(shape, dtype):
 
 def _paged(heads, fmt, bundle):
     h, kv = HEADS[heads]
-    b, q_len, tree = BUNDLES[bundle]
-    n = SLOTS * NB + 1
+    if bundle in EVA_BUNDLES:
+        (b, q_len), tree, n, nb = EVA_BUNDLES[bundle], False, EVA_POOL, EVA_NB
+    else:
+        (b, q_len, tree), n, nb = BUNDLES[bundle], SLOTS * NB + 1, NB
     pool = _s((n, BS, kv, D), POOL_DTYPES[fmt])
-    args = [_s((b, q_len, h, D), BF16), pool, pool, _s((b, NB), jnp.int32),
+    args = [_s((b, q_len, h, D), BF16), pool, pool, _s((b, nb), jnp.int32),
             _s((b,), jnp.int32)]
     quant = fmt != "bf16"
     if quant:
@@ -124,8 +131,9 @@ def _conv(hw, c, train):
             [x, w, vec, vec])
 
 
-CASES = {}
-for _h in HEADS:
+CASES = {f"paged-mha32-bf16-{_b}": (_paged, ("mha32", "bf16", _b))
+         for _b in EVA_BUNDLES}
+for _h in ("mha16", "gqa32_8"):
     for _b in BUNDLES:
         CASES[f"paged-{_h}-bf16-{_b}"] = (_paged, (_h, "bf16", _b))
     for _f in ("int8", "fp8"):

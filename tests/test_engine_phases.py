@@ -11,6 +11,9 @@ Oracles:
   ``counters()`` no key, that nothing reads.
 - GAUGES: the ``kv_blocks_*`` gauges are set once an iteration and equal
   ``BlockPool.stats()``; no pool operation reduces over the pool.
+- HOST ARGUMENTS: a prefill chunk and a step hand their host arrays to
+  the executable as they are; nothing is made with ``jnp.asarray`` a
+  chunk or a step.
 - ONE CLOCK: the phases also reach an active profiler session.
 - OFF MEANS OFF: with tracing disabled nothing of this is recorded, no
   annotation is made, no clock is read beyond the step's own, and the
@@ -129,6 +132,42 @@ class TestPhases:
         assert {e["name"] for e in mine} == {"engine.iter", *CHILDREN}
         for e in mine:
             assert set(e["args"]) == want.get(e["name"], {"iter"}), e
+
+    def test_a_windowed_engine_adds_its_summary_blocks_and_no_other_arg(
+            self):
+        """An EVA model's slot reads the exact keys of its window and
+        the summaries behind it: ``engine.dispatch`` splits the count
+        (``decode_live_blocks_per_step.eva``,
+        ``eva_summary_blocks_per_step.eva``), and no other span gains an
+        arg."""
+        from paddle_tpu.models import EvaByteConfig, EvaByteForCausalLM
+
+        paddle.seed(0)
+        cfg = EvaByteConfig.tiny()     # window 16 in chunks of 4
+        eng = serving.ServingEngine(
+            EvaByteForCausalLM(cfg), max_slots=2, max_len=128, block_size=4,
+            prefill_chunk=8, prefix_caching=False)
+        t0 = tracing.events()[-1]["ts_ns"] + 1 if tracing.events() else 0
+        eng.submit(_prompt(np.random.RandomState(3), cfg, 37),
+                   max_new_tokens=7)
+        eng.run_until_idle()
+        lane = [e for e in _engine_lane(t0)
+                if e["name"].startswith("engine.")
+                and e["name"] != "engine.idle"]
+        want = {"engine.iter": {"iter", "preempted"},
+                "engine.admit": {"iter", "prefix_hit_tokens",
+                                 "prompt_tokens"},
+                "engine.dispatch": {"iter", "kv_blocks", "summary_blocks"}}
+        for e in lane:
+            assert set(e["args"]) == want.get(e["name"], {"iter"}), e
+        # six steps, the queries at positions 37..42: two windows behind
+        # (a block of four summaries each), the window's 6..11 keys
+        reads = [(e["args"]["kv_blocks"], e["args"]["summary_blocks"])
+                 for e in lane if e["name"] == "engine.dispatch"]
+        assert reads == [(-(-(p % 16 + 1) // 4), 2) for p in range(37, 43)]
+        c = eng.counters()
+        assert (c["window_rolls"], c["window_blocks_released"],
+                c["summary_entries_written"]) == (2, 8, 43 // 4)
 
     def test_prefill_chunk_carries_the_iter_that_ran_it(self, served):
         _, reqs, events = served
@@ -334,6 +373,42 @@ class TestCounters:
         assert set(c) == {"steps", "slots", "slot_steps", "queue_depth",
                           "prompt_tokens", "prefix_hit_tokens",
                           "preemptions"}
+
+
+class TestHostArguments:
+    def test_chunks_and_steps_construct_no_device_array(self, tiny_model,
+                                                        monkeypatch):
+        """The arguments of a prefill chunk and of a step are host
+        arrays handed to the executable as they are: what the engine
+        builds with ``jnp.asarray`` it builds once a request, however
+        many chunks and steps the request takes."""
+        import sys
+
+        import jax.numpy as jnp
+
+        model, cfg = tiny_model
+        eng = _engine(model)
+        eng.warmup()
+        real, calls = jnp.asarray, []
+
+        def counting(*a, **kw):
+            if sys._getframe(1).f_code.co_filename.endswith(
+                    "serving/engine.py"):
+                calls.append(sys._getframe(1).f_code.co_name)
+            return real(*a, **kw)
+
+        monkeypatch.setattr(jnp, "asarray", counting)
+        rng = np.random.RandomState(5)
+        made = []
+        for n_prompt, n_new in ((BLOCK + 3, 2), (6 * BLOCK + 3, 20)):
+            del calls[:]
+            req = eng.submit(_prompt(rng, cfg, n_prompt),
+                             max_new_tokens=n_new)
+            eng.run_until_idle()
+            assert len(req.output_tokens) == n_new
+            made.append(sorted(calls))
+        assert made[0] == made[1]
+        assert not {"_advance_prefill", "_step_impl"} & set(made[1])
 
 
 class TestPoolGauges:
