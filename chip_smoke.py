@@ -10,7 +10,9 @@ seeded random bf16 weights, and checks what comes out:
   -> ``serving.ServingEngine`` in its default paged mode, every kernel
   at its default dispatch -> ``warmup()`` -> ``start()`` -> 8 requests
   (short, multi-chunk, shared-prefix, greedy and sampled) read through
-  ``stream()``/``result()`` -> ``stop()``.
+  ``stream()``/``result()`` -> ``stop()``. Before it, on one chip, the
+  case ``batched_prefill_chunk``: the ``[P, C]`` prefill program against
+  its rows run one at a time, and the program alone timed at each width.
 - train: ``LlamaForCausalLM(LlamaConfig.llama2_7b(...))`` at 7B widths
   with flash attention, depth cut to TRAIN_LAYERS -> ``ShardedTrainStep``
   with ``llama_pretrain_loss`` and AdamW, TRAIN_STEPS steps at seq 4096
@@ -71,6 +73,8 @@ CHIP = dict(
     seq=4096, tree=(4, 2, 2),
     # the EVA cell's decode step: rows, heads, head_dim, window, block
     eva=(20, 32, 128, 2048, 16),
+    # batched_prefill_chunk: program widths timed, a row's context, runs
+    chunk_rows=(1, 4, 8, 16), chunk_ctx=1024, chunk_reps=20,
 )
 REHEARSAL = dict(
     gpt=dict(hidden_size=512, num_hidden_layers=2, num_attention_heads=4,
@@ -83,6 +87,7 @@ REHEARSAL = dict(
                max_position_embeddings=256),
     seq=256, tree=(2, 2),
     eva=(3, 4, 32, 64, 4),
+    chunk_rows=(1, 2, 4), chunk_ctx=128, chunk_reps=2,
 )
 
 # Kernel-vs-XLA tolerance on attention outputs, |out - ref| <= ATOL +
@@ -350,6 +355,119 @@ def _eva_kernel_parity(sizes):
     return round(float(diff.max()), 5)
 
 
+# The rows of the case ``batched_prefill_chunk``, iteration by
+# iteration: (pos0, valid) for a live row, None for a row that carries
+# nothing. Mid-prompt chunks at different offsets, padded last chunks
+# (valid 7, 20 and 1), a row that sits an iteration out, rows that start
+# late and a row that never carries anything; the first P columns are
+# used.
+CHUNK_SCHEDULE = (
+    ((0, 32), (0, 32), (0, 32), (0, 32), (0, 32), (0, 32), None, None),
+    ((32, 32), (32, 32), (32, 32), (32, 32), (32, 7), None, (0, 32), None),
+    ((64, 32), (64, 1), None, (64, 20), None, (32, 32), (32, 32), None),
+)
+
+
+def _batched_prefill_chunk(model, cfg, scfg, sizes):
+    """The ``[P, C]`` prefill program against the same rows run one at
+    a time through the ``[1, C]`` program, on the model the engine will
+    serve: the largest gap between the logits either way selects from,
+    the pools they leave behind compared block for block, and the
+    program alone timed at each ``chunk_rows`` with one row live and
+    with every row live. The program is the engine's ``_chunk`` less its
+    select: ``run`` over ``bt``, ``valid`` and ``head_idx``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu import generation
+    from paddle_tpu.serving.engine import prefill_batch_rows
+
+    C, bs = scfg.prefill_chunk, scfg.block_size
+    dtype = next(iter(model.parameters()))._data.dtype
+    P = prefill_batch_rows(C, dtype, sizes["slots"])
+    widths = sizes["chunk_rows"]
+    nb = sizes["chunk_ctx"] // bs               # table width of a row
+    n_blocks = 1 + max(widths) * nb
+    run = generation.make_cached_runner(model)
+    pb = {**{k: v._data for k, v in model.named_parameters()},
+          **{k: v._data for k, v in model.named_buffers()}}
+    rng = np.random.RandomState(SEED + 28)
+
+    def fwd(pb, pools, bt, ids, pos0, valid, last_idx):
+        caches = [dict(c, bt=bt, valid=valid) for c in pools]
+        caches[0]["head_idx"] = last_idx
+        logits, newc = run(pb, ids, caches, pos0)
+        return logits[:, 0].astype(jnp.float32), [
+            {"k": c["k"], "v": c["v"]} for c in newc]
+
+    fwd = jax.jit(fwd, donate_argnums=(1,))
+
+    def pools():
+        return generation.make_paged_kv_pools(cfg, n_blocks, bs, dtype)
+
+    def args(rows, width):
+        """Host arguments of one ``[width, C]`` program: ``rows`` maps a
+        row of the program to (table row, tokens, pos0, valid)."""
+        bt = np.zeros((width, nb), np.int32)
+        ids = np.zeros((width, C), np.int32)
+        pos0, valid, last = (np.zeros(width, np.int32) for _ in range(3))
+        for r, (owner, toks, p0, n) in rows.items():
+            bt[r] = 1 + owner * nb + np.arange(nb)
+            ids[r, :n] = toks[:n]
+            pos0[r], valid[r], last[r] = p0, n, n - 1
+        return bt, ids, pos0, valid, last
+
+    # the same rows, batched and one at a time
+    batched, single, gap = pools(), pools(), 0.0
+    for it in CHUNK_SCHEDULE:
+        live = {r: (r, rng.randint(1, cfg.vocab_size, C), *cell)
+                for r, cell in enumerate(it[:P]) if cell is not None}
+        lg, batched = fwd(pb, batched, *args(live, P))
+        lg = np.asarray(lg)
+        for r, row in live.items():
+            one, single = fwd(pb, single, *args({0: row}, 1))
+            gap = max(gap, float(np.abs(np.asarray(one)[0] - lg[r]).max()))
+    # block 0 is the dump block: every padded tail and dead row wrote it
+    diffs = [jnp.abs(a[n][1:].astype(jnp.float32)
+                     - b[n][1:].astype(jnp.float32)).max()
+             for a, b in zip(batched, single) for n in ("k", "v")]
+    pool_gap = float(jnp.max(jnp.stack(diffs)))
+    written = float(jnp.abs(batched[0]["k"][1:].astype(jnp.float32)).max())
+    del batched, single
+
+    # the program alone at each width
+    timings = {}
+    reps = sizes["chunk_reps"]
+    toks = rng.randint(1, cfg.vocab_size, C)
+    for width in widths:
+        cur = pools()
+        span = (nb * bs - C) // C // max(width - 1, 1) * C
+        cases = {"one_live": {0: (0, toks, nb * bs - C, C)},
+                 "all_live": {r: (r, toks, r * span, C)
+                              for r in range(width)}}
+        for name, rows in cases.items():
+            a = args(rows, width)
+            for _ in range(3):
+                lg, cur = fwd(pb, cur, *a)
+            jax.block_until_ready(lg)
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                lg, cur = fwd(pb, cur, *a)
+            jax.block_until_ready(lg)
+            timings[f"{width}x{C}.{name}_ms"] = round(
+                (time.perf_counter() - t0) / reps * 1e3, 3)
+        del cur
+    log(f"batched_prefill_chunk: P={P}, logit gap {gap:.5f}, pool gap "
+        f"{pool_gap:.5f} (largest entry {written:.2f}); program alone, ms: "
+        f"{timings}")
+    check(written > 0 and gap <= 0.1 and pool_gap <= 0.1,
+          f"[{P}, {C}] prefill program agrees with its rows run one at a "
+          f"time (logits {gap:.5f}, pools {pool_gap:.5f})")
+    return {"rows": P, "chunk": C, "logit_max_abs_diff": gap,
+            "pool_max_abs_diff": pool_gap, "program_alone": timings}
+
+
 def phase_serve(rehearse):
     t_phase = time.perf_counter()
     common, sizes = _start(rehearse)
@@ -372,6 +490,9 @@ def phase_serve(rehearse):
     paddle.seed(SEED)
     model = GPTForCausalLM(cfg)
     model.to(dtype="bfloat16")
+    # before the engine takes its pool: the case brings two of its own
+    batched_chunk = _batched_prefill_chunk(model, cfg, scfg, sizes) \
+        if n_dev == 1 else None
     engine = serving.ServingEngine(model, scfg)
     t0 = time.perf_counter()
     warm = engine.warmup()
@@ -452,6 +573,7 @@ def phase_serve(rehearse):
         "wall_s": round(time.perf_counter() - t_phase, 1),
         "warmup_s": round(warmup_s, 1), "traffic_s": round(traffic_s, 2),
         "compile": after_traffic, "kernel_max_abs_diff": parity,
+        "batched_prefill_chunk": batched_chunk,
         "flash_decode_hits": hits, "flash_decode_fallbacks": fallbacks,
         "requests": len(reqs), "tokens": sum(map(len, outputs)),
         "prefix_cache": stats["prefix_cache"],

@@ -510,6 +510,24 @@ def eva_summary_write(kv_cache: dict, phi, mu, position_offset, s: int,
     return out
 
 
+def head_rows(h, kv_caches):
+    """The hidden states [b, s, hidden] a causal LM's head has to see.
+    A cached forward whose caller reads one position a row (a batch of
+    prefill chunks wants each row's last prompt token alone) puts
+    ``head_idx`` [b] int32 into its FIRST cache dict; the head then sees
+    ``h[b, head_idx[b]]`` as [b, 1, hidden], and the [b, s, vocab]
+    product is never made. Without the key, all of ``h``. Cache
+    plumbing under no_grad, like the scatters above: no op of its own
+    on the dispatch surface."""
+    idx = kv_caches[0].get("head_idx") \
+        if isinstance(kv_caches[0], dict) else None
+    if idx is None:
+        return h
+    idx = idx._data if isinstance(idx, Tensor) else jnp.asarray(idx)
+    return Tensor(jnp.take_along_axis(
+        h._data, idx.astype(jnp.int32)[:, None, None], axis=1))
+
+
 def update_static_kv_cache(kv_cache: dict, k, v, position_offset,
                            build_mask: bool = True, gather: bool = True):
     """The static-cache protocol shared by the decoder models (llama/

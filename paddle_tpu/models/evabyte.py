@@ -276,5 +276,8 @@ class EvaByteForCausalLM(nn.Layer):
             b, s, _ = h.shape
             return _fp32_logits(h, w).reshape(
                 [b, s, cfg.num_pred_heads, cfg.vocab_size])
+        from ..generation import head_rows
+
         h, new_caches = self.evabyte(input_ids, kv_caches, position_offset)
-        return _fp32_logits(h, w[:, :cfg.vocab_size]), new_caches
+        return _fp32_logits(head_rows(h, kv_caches),
+                            w[:, :cfg.vocab_size]), new_caches

@@ -184,8 +184,10 @@ class GPTForCausalLM(nn.Layer):
 
     def forward(self, input_ids, attn_mask=None, kv_caches=None, position_offset=0):
         if kv_caches is not None:
+            from ..generation import head_rows
+
             h, new_caches = self.gpt(input_ids, attn_mask, kv_caches, position_offset)
-            return self.lm_head(h), new_caches
+            return self.lm_head(head_rows(h, kv_caches)), new_caches
         return self.lm_head(self.gpt(input_ids, attn_mask))
 
     def generate(self, input_ids, max_new_tokens: int = 32, **kwargs):

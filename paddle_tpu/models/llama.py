@@ -110,10 +110,26 @@ def apply_rotary_pos_emb(q: Tensor, k: Tensor, cos_tab, sin_tab, position_offset
             c = cos[position_offset]   # [b, s, d/2]
             si = sin[position_offset]
         elif getattr(position_offset, "ndim", 0) == 1:
-            # per-row offsets [b]: gather [b, s] position rows
-            idx = position_offset[:, None] + jnp.arange(s)
-            c = cos[idx]   # [b, s, d/2]
-            si = sin[idx]
+            # per-row offsets [b]: [b, s] position rows, a row past the
+            # table's end reading its last row
+            if s == 1:   # the decode step: one table row a sequence
+                c, si = (tab[position_offset[:, None]] for tab in (cos, sin))
+            else:
+                # a batch of prefill chunks or verify bundles: ONE slice
+                # of s rows a sequence (its start clamped into the
+                # table, the rows then shifted back to their places). As
+                # a gather of b * s single rows XLA first converts the
+                # whole table to the activation dtype: 0.1 ms a program
+                # at EvaByte's 32768 x 64 (PERF.md section 6, PR 28)
+                def rows(tab):
+                    def one(p):
+                        start = jnp.clip(p, 0, tab.shape[0] - s)
+                        sl = jax.lax.dynamic_slice_in_dim(tab, start, s, 0)
+                        return sl[jnp.minimum(
+                            jnp.arange(s) + (p - start), s - 1)]
+                    return jax.vmap(one)(position_offset)
+
+                c, si = rows(cos), rows(sin)
         else:  # traced offset (jitted decode step)
             c = jax.lax.dynamic_slice_in_dim(cos, position_offset, s, 0)
             si = jax.lax.dynamic_slice_in_dim(sin, position_offset, s, 0)
@@ -425,7 +441,10 @@ class LlamaForCausalLM(nn.Layer):
 
     def forward(self, input_ids, attn_mask=None, kv_caches=None, position_offset=0):
         if kv_caches is not None:
+            from ..generation import head_rows
+
             h, new_caches = self.llama(input_ids, attn_mask, kv_caches, position_offset)
+            h = head_rows(h, kv_caches)
         else:
             h = self.llama(input_ids, attn_mask)
         if self.lm_head is None:

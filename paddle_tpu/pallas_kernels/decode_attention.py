@@ -454,66 +454,84 @@ def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
 
     Grid (B,): a row is one grid step, and the rows run in order (the
     fetch of a row's first cell is started by the row before it)."""
-    from ..quantization.intx import format_bound
-
     B, q_len, KV, group, d = q5.shape
     bs, nb = kp.shape[1], bt.shape[1]
     gq = q_len * group
-    quant = k_scale is not None
+    # the quantized pools' format, or None
+    fmt = None if k_scale is None else \
+        "int8" if kp.dtype == jnp.int8 else "fp8"
     tree = ancestor_mask is not None
     bpc = _blocks_per_cell(bs, nb, KV, d, kp.dtype, gq)
     # per-kv-head query bundles as whole [gq, d] tiles: merging q_len
     # into the group axis inside the cell is a sublane relayout Mosaic
     # only takes for group % 8 == 0, so it happens here in XLA (tiny)
     qk = jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(B, KV, gq, d)
+    operands = [lens.astype(jnp.int32), bt.astype(jnp.int32), qk, kp, vp]
+    cells = -(-nb // bpc)
+    if fmt is not None:
+        # Mosaic cannot slice an HBM ref whose minor dim is under a lane
+        # tile (KV < 128), so the scales do not come by the cell's DMA:
+        # XLA gathers each row's through the table (1/d of the pool's
+        # bytes) and the row's whole column set rides a BlockSpec
+        for sc in (k_scale, v_scale):
+            sc = sc.astype(jnp.float32)[bt].reshape(B, nb * bs, KV)
+            operands.append(jnp.pad(
+                sc, ((0, 0), (0, (cells * bpc - nb) * bs), (0, 0))))
+    qp = _round_up(q_len, 128)
+    if tree:
+        # rows expanded to the kernel's r = i*group + g order and the
+        # contraction axis zero-padded to a lane multiple, so the cell's
+        # one-hot matmul is MXU-aligned for any bundle width (29, ...)
+        am = jnp.repeat(ancestor_mask.astype(jnp.float32), group, axis=1)
+        operands.append(jnp.pad(am, ((0, 0), (0, 0), (0, qp - q_len))))
+    return _decode_call(B, q_len, KV, group, d, bs, cells, bpc,
+                        jnp.dtype(kp.dtype), jnp.dtype(q5.dtype),
+                        float(sm_scale), fmt, tree, _interpret())(*operands)
+
+
+@functools.lru_cache(maxsize=64)
+def _decode_call(B, q_len, KV, group, d, bs, cells, bpc, kv_dtype, q_dtype,
+                 sm_scale, fmt, tree, interpret):
+    """The ``pallas_call`` behind ``_paged_flash_decode``, one object
+    for each set of shapes. The object is a jitted (inlined) callable
+    that traces ``_decode_kernel`` when it first meets its operands'
+    shapes, so the layers of a program, which all call with the same,
+    share ONE trace of the kernel where a fresh ``pallas_call`` a layer
+    traced it 24 times a program (0.17 s each on the chip's host, a
+    third of what ``warmup()`` costs an executable out of the compile
+    cache: PERF.md section 6, PR 28). ``fmt`` is the quantized pools'
+    format, or None."""
+    from ..quantization.intx import format_bound
+
+    gq = q_len * group
 
     def row(b, *_):
         return (b, 0, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, KV, gq, d), row), hbm, hbm]
-    operands = [lens.astype(jnp.int32), bt.astype(jnp.int32), qk, kp, vp]
-    if quant:
-        # Mosaic cannot slice an HBM ref whose minor dim is under a lane
-        # tile (KV < 128), so the scales do not come by the cell's DMA:
-        # XLA gathers each row's through the table (1/d of the pool's
-        # bytes) and the row's whole column set rides a BlockSpec
-        cells = -(-nb // bpc)
-        for sc in (k_scale, v_scale):
-            sc = sc.astype(jnp.float32)[bt].reshape(B, nb * bs, KV)
-            operands.append(jnp.pad(
-                sc, ((0, 0), (0, (cells * bpc - nb) * bs), (0, 0))))
+    if fmt is not None:
         in_specs += [pl.BlockSpec((1, cells * bpc * bs, KV),
                                   lambda b, *_: (b, 0, 0))] * 2
     if tree:
-        # rows expanded to the kernel's r = i*group + g order and the
-        # contraction axis zero-padded to a lane multiple, so the cell's
-        # one-hot matmul is MXU-aligned for any bundle width (29, ...)
-        qp = _round_up(q_len, 128)
-        am = jnp.repeat(ancestor_mask.astype(jnp.float32), group, axis=1)
-        am = jnp.pad(am, ((0, 0), (0, 0), (0, qp - q_len)))
-        in_specs.append(pl.BlockSpec((1, gq, qp), lambda b, *_: (b, 0, 0)))
-        operands.append(am)
-    scratch = [pltpu.VMEM((2, bpc * bs, KV, d), kp.dtype)] * 2 + [
+        in_specs.append(pl.BlockSpec((1, gq, _round_up(q_len, 128)),
+                                     lambda b, *_: (b, 0, 0)))
+    scratch = [pltpu.VMEM((2, bpc * bs, KV, d), kv_dtype)] * 2 + [
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.VMEM((KV, gq, 1), jnp.float32),
         pltpu.VMEM((KV, gq, 1), jnp.float32),
         pltpu.VMEM((KV, gq, d), jnp.float32),
         pltpu.SMEM((1,), jnp.int32)]
-
     kern = functools.partial(
         _decode_kernel, bpc=bpc, sm_scale=sm_scale, q_len=q_len, group=group,
-        tree=tree,
-        bound=format_bound("int8" if kp.dtype == jnp.int8 else "fp8")
-        if quant else None)
-    interpret = _interpret()
+        tree=tree, bound=None if fmt is None else format_bound(fmt))
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B,), in_specs=in_specs,
             out_specs=pl.BlockSpec((1, KV, gq, d), row),
             scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((B, KV, gq, d), q5.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, gq, d), q_dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -522,7 +540,7 @@ def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
             # with the lane-padded stat columns): past Mosaic's 16 MB
             # default
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-    )(*operands)
+    )
 
 
 def _flash_decode(q5, kc, vc, lens, *, sm_scale: float, block_k: int,

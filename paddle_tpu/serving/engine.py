@@ -21,6 +21,12 @@ reuse, built on this repo's static-shape decode substrate:
     (ONE ``serving.prefill_chunk`` executable replaces every per-bucket
     prefill program) interleaved with decode steps, so a long prompt
     never head-of-line-blocks running requests for its whole length.
+    The chunks of the slots that prefill in one iteration are the rows
+    of ONE ``[P, C]`` program: every prefilling slot still advances one
+    chunk an iteration, and the weights are read once for all of them
+    (an iteration's lone chunk rides the ``[1, C]`` form of the same
+    body: two executables, ``serving.prefill_chunk`` and
+    ``serving.prefill_chunk[P]``).
   * **preemption by recompute**: under pool pressure the latest-admitted
     request is preempted — its blocks freed, the request requeued at the
     queue front with its generated tokens folded into the prefill and
@@ -127,6 +133,56 @@ class EngineDrainingError(EngineStoppedError):
     replacement replica is up."""
 
 
+# Rows up to which a bf16 prefill program shares its pass over the
+# weights to any profit, on the chip this engine is written for. A v5e's
+# peaks put the ridge at 197 TFLOP/s / 819 GB/s = 240 rows; the program
+# alone at GPT-3 1.3B's widths (PERF.md section 6, PR 28) costs 3.7 ms
+# at [1, 32], 4.8 at [4, 32], 6.6 at [8, 32] and 12.4 at [16, 32]: a
+# chunk costs 3.7, 1.2, 0.82 and 0.77 ms, so past 256 rows a program's
+# time doubles with its rows and a chunk gets no cheaper. A wider dtype
+# streams more bytes a weight and so buys as many more rows.
+_WEIGHT_PASS_ROWS_BF16 = 256
+
+
+def prefill_batch_rows(chunk: int, dtype, max_slots: int) -> int:
+    """P of the ``[P, chunk]`` prefill program: the largest power of two
+    with ``P * chunk`` no more than the rows that share one pass over
+    the weights (``_WEIGHT_PASS_ROWS_BF16``, scaled by the dtype's
+    width), at least 1, at most ``max_slots``. A pure function of what
+    the engine can see: nothing to configure."""
+    rows = _WEIGHT_PASS_ROWS_BF16 * np.dtype(dtype).itemsize // 2
+    p = 1
+    while 2 * p <= min(rows // int(chunk), int(max_slots)):
+        p *= 2
+    return p
+
+
+# What a row of a prefill program carries besides its table row and its
+# token ids. The rows of one program reach it as ONE int32 host array
+# [P, nb + C + len(_ROW_COLUMNS)] (a key's uint32 words and the float32
+# values as their bits): every host argument of an executable is a
+# transfer of its own, and twelve small ones a program were most of
+# what the host did between a sync and the next enqueue.
+_ROW_COLUMNS = ("pos0", "valid", "slot", "is_last", "last_idx", "ds", "tk",
+                "key0", "key1", "temp", "tp")
+_ONE_BITS = int(np.float32(1.0).view(np.int32))
+
+
+def _unpack_rows(rows, nb: int, chunk: int):
+    """A packed prefill batch on the device: ``(bt [P, nb], ids [P,
+    chunk], fields)``, ``fields`` the per-row columns by name in their
+    own dtypes, with ``key`` [P, 2] uint32 for the two key words."""
+    col = dict(zip(_ROW_COLUMNS, jnp.moveaxis(rows[:, nb + chunk:], 1, 0)))
+    f = {k: col[k] for k in ("pos0", "valid", "slot", "last_idx", "tk")}
+    f.update(
+        is_last=col["is_last"] != 0, ds=col["ds"] != 0,
+        key=jax.lax.bitcast_convert_type(
+            jnp.stack([col["key0"], col["key1"]], axis=1), jnp.uint32),
+        temp=jax.lax.bitcast_convert_type(col["temp"], jnp.float32),
+        tp=jax.lax.bitcast_convert_type(col["tp"], jnp.float32))
+    return rows[:, :nb], rows[:, nb:nb + chunk], f
+
+
 def _default_buckets(max_len: int) -> tuple:
     """Powers of two from 16 up to (and always including) max_len."""
     out = []
@@ -155,9 +211,16 @@ class ServingConfig:
       Default ``max_slots * (max_len / block_size) + 1`` (worst case —
       paging can never run out); size it below that to oversubscribe
       slots against a fixed HBM budget (preemption keeps it safe).
-    - ``prefill_chunk``: tokens per prefill chunk (paged): one fixed
-      [1, prefill_chunk] executable replaces every prefill bucket, and
-      long prompts are admitted chunk-by-chunk between decode steps.
+    - ``prefill_chunk``: tokens per prefill chunk (paged): a prompt
+      advances this many tokens an iteration, between decode steps, so
+      a long one never blocks the running requests for its length. One
+      fixed ``[P, prefill_chunk]`` executable replaces every prefill
+      bucket: the next chunks of up to P prefilling slots are the rows
+      of one program, so their one pass over the weights is shared;
+      an iteration's lone chunk rides the ``[1, prefill_chunk]`` form
+      of the same body. P is not an option: ``prefill_batch_rows``
+      reads it from this width, the weights' dtype and ``max_slots``
+      (8 at the default 32 in bf16; 1 at 256).
     - ``prefix_caching``: reuse previously prefilled prompt prefixes
       (ref-counted, COW-protected). Disable for strictly independent
       workloads.
@@ -426,9 +489,13 @@ class _PrefillJob:
     tokens: np.ndarray           # prompt (+ replayed generation on resume)
     total: int
     done: int                    # tokens already in the cache (prefix hits
-    key: "jax.Array"             # + completed chunks)
+    key: np.ndarray              # + completed chunks); uint32 [2]
     skip: int                    # 1 on resume: final select re-derives an
     t0: float = field(default_factory=time.perf_counter)  # already-sent token
+
+    def span(self, chunk: int) -> tuple:
+        """[start, end) of the job's next chunk of ``chunk`` tokens."""
+        return self.done, min(self.done + chunk, self.total)
 
 
 class ServingEngine:
@@ -578,6 +645,8 @@ class ServingEngine:
         # prefill_chunks_total)
         self._n_prompt_tokens = 0      # tokens admitted prefills cover
         self._n_prefix_hit_tokens = 0  # of those, adopted from the cache
+        self._n_prefill_rows = 0       # chunks that rode a prefill program
+        self._n_prefill_programs = 0   # prefill programs enqueued
         # windowed layout (EVA) only
         self._n_window_rolls = 0       # slots that crossed into a window
         self._n_window_blocks_released = 0   # exact-key blocks given back
@@ -767,7 +836,11 @@ class ServingEngine:
         self._jobs: List[Optional[_PrefillJob]] = [None] * B
         # this engine's closures are NEW executables — their first
         # compiles are warmup, not retraces of a previous engine's
-        warm = ["serving.step", "serving.prefill_chunk", "serving.cow"]
+        C = int(config.prefill_chunk)
+        P = prefill_batch_rows(C, self._dtype, B)
+        # the one body's two widths: [1, C] for a lone chunk, and [P, C]
+        self._chunk_entries = [self._chunk_entry(w) for w in sorted({1, P})]
+        warm = ["serving.step", "serving.cow", *self._chunk_entries]
         if self.spec:
             warm += ["serving.spec_draft", "serving.spec_verify"]
         if config.kv_tier:
@@ -785,8 +858,6 @@ class ServingEngine:
                 self._dpools, self._tp_dpool_sh = _partition.shard_kv_pools(
                     self._dpools, tpm)
             self._drun = make_cached_runner(self.draft_model)
-
-        C = int(config.prefill_chunk)
 
         # executable wrapper: plain jit at tp=1; at tp>1 jit with
         # EXPLICIT in/out shardings — round-tripped trees (pools, state)
@@ -815,47 +886,53 @@ class ServingEngine:
         self._tp_state_sh = state_sh
         self._tp_wrap = _wrap
 
-        def _chunk(pb, pools, state, bt_row, ids, pos0, valid, slot, is_last,
-                   last_idx, key, ds, temp, tk, tp):
-            """ONE fixed-shape prefill chunk: forward ``ids`` [1, C] at
-            offset ``pos0`` through the paged caches (writes scatter
-            through the slot's block table; pad tokens beyond ``valid``
-            land in the dump block), then the final-token select with
-            generate's exact key chain. State rows for ``slot`` are set
-            only when ``is_last`` (traced — chunk count never retraces);
-            the select itself is computed every chunk and simply unused
-            until then."""
-            caches = [dict(c, bt=bt_row, valid=valid[None])
-                      for c in pools]
-            logits, newc = run(pb, ids, caches, pos0)
-            last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
-                                                axis=1)[:, 0]
-            key2, sub = jax.random.split(key)
+        def _first_tokens(state, last, f):
+            """The final-token select of a batch of chunks (``f``: the
+            rows' fields, ``_unpack_rows``), each row with its own key
+            chain (generate's, as ``_step`` walks it for the pool), and
+            the state rows of the slots whose chunk ``is_last``. Every
+            other row's write goes out of range and is dropped, so a row
+            that carries nothing can name any slot."""
+            key2, sub = split_keys(f["key"])
             token = jax.lax.cond(
-                ds[0],
-                lambda: select_tokens(last, sub[None], ds, temp, tk, tp),
+                jnp.any(f["ds"]),
+                lambda: select_tokens(last, sub, f["ds"], f["temp"],
+                                      f["tk"], f["tp"]),
                 lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
+            at = jnp.where(f["is_last"], f["slot"], B)
             state = dict(state)
+            for name, new in (("tokens", token),
+                              ("pos", f["pos0"] + f["valid"]),
+                              ("keys", key2), ("ds", f["ds"]),
+                              ("temp", f["temp"]), ("tk", f["tk"]),
+                              ("tp", f["tp"])):
+                state[name] = state[name].at[at].set(
+                    new.astype(state[name].dtype), mode="drop")
+            return token, state
 
-            def _sel(new, old):
-                return jnp.where(is_last, new, old)
-
-            state["tokens"] = state["tokens"].at[slot].set(
-                _sel(token[0], state["tokens"][slot]))
-            state["pos"] = state["pos"].at[slot].set(
-                _sel(pos0 + valid, state["pos"][slot]))
-            state["keys"] = state["keys"].at[slot].set(
-                _sel(key2, state["keys"][slot]))
-            state["ds"] = state["ds"].at[slot].set(_sel(ds[0], state["ds"][slot]))
-            state["temp"] = state["temp"].at[slot].set(
-                _sel(temp[0], state["temp"][slot]))
-            state["tk"] = state["tk"].at[slot].set(_sel(tk[0], state["tk"][slot]))
-            state["tp"] = state["tp"].at[slot].set(_sel(tp[0], state["tp"][slot]))
+        def _chunk(pb, pools, state, rows):
+            """The fixed-shape prefill program: the next chunk of up to
+            P prefilling slots as the rows of ``rows`` (packed:
+            ``_unpack_rows``), row r's ``ids`` [C] at offset ``pos0[r]``
+            through table row ``bt[r]`` (writes scatter through it; pad
+            tokens beyond ``valid[r]`` land in the dump block, and so
+            does all of a row that carries no chunk: ``valid`` 0 and a
+            zeroed table row). The head sees each row's ``last_idx``
+            position alone. State rows are set only for rows whose
+            ``is_last`` is set (traced: neither the chunk count nor the
+            number of live rows retraces); the select itself is computed
+            every time and simply unused until then. The one body is run
+            at two widths: [P, C], and [1, C] for an iteration's lone
+            chunk (``_enqueue_claimed``)."""
+            bt, ids, f = _unpack_rows(rows, nb, C)
+            caches = [dict(c, bt=bt, valid=f["valid"]) for c in pools]
+            caches[0]["head_idx"] = f["last_idx"]
+            logits, newc = run(pb, ids, caches, f["pos0"])
+            token, state = _first_tokens(state, logits[:, 0], f)
             pools_out = [{kk: c[kk] for kk in pool_keys} for c in newc]
             return token, pools_out, state
 
-        _chunk = _wrap(_chunk, (1, 2),
-                       (pb_sh, pool_sh, state_sh) + (rep,) * 12,
+        _chunk = _wrap(_chunk, (1, 2), (pb_sh, pool_sh, state_sh, rep),
                        (rep, pool_sh, state_sh))
 
         def _step(pb, pools, state, bt, any_sampling, active):
@@ -907,14 +984,16 @@ class ServingEngine:
         self._step_fn = _step
         self._cow_fn = _cow
         self._chunk_size = C
+        self._chunk_rows = P
         # retrace warnings for the engine entries cite these defs
         _recompile.register_entry_location("serving.step", _step)
-        _recompile.register_entry_location("serving.prefill_chunk", _chunk)
+        for entry in self._chunk_entries:
+            _recompile.register_entry_location(entry, _chunk)
         _recompile.register_entry_location("serving.cow", _cow)
         if config.kv_tier:
             self._init_kv_tier(pool_keys, _wrap, rep, pool_sh)
         if self.spec:
-            self._init_spec(B, run)
+            self._init_spec(B, run, _first_tokens)
         if self._tp > 1:
             # per-shard perf-ledger rows: the sharded executables'
             # cost_analysis is captured from the PARTITIONED module, so
@@ -1129,7 +1208,7 @@ class ServingEngine:
                           f"(persistence skipped): {e!r}")
 
     # -- executables: speculative lane (paged only) --------------------------
-    def _init_spec(self, B: int, run):
+    def _init_spec(self, B: int, run, first_tokens):
         """Draft + verify executables over the shared block tables.
 
         Two programs replace the plain decode step: ``spec_draft`` runs
@@ -1156,6 +1235,7 @@ class ServingEngine:
         k = self._spec_k
         drun = self._drun
         pool_keys = self._pool_keys
+        nb, C = self._bt.shape[1], self._chunk_size
         _wrap = self._tp_wrap
         rep = self._tp_rep
         pb_sh, dpb_sh = self._tp_pb_sh, self._tp_dpb_sh
@@ -1264,50 +1344,28 @@ class ServingEngine:
             # BOTH models' caches).
             _draft, _verify = self._build_tree_spec(B, run)
 
-        def _chunk_spec(pb, dpb, pools, dpools, state, bt_row, ids, pos0,
-                        valid, slot, is_last, last_idx, key, ds, temp, tk,
-                        tp):
-            """The prefill chunk with the draft model riding along: both
-            models' paged caches take the chunk's writes through the one
-            block table, so prefix-cached blocks carry BOTH models' KV
-            and preemption-resume re-prefills both. Select/state logic
-            is the plain chunk's, verbatim."""
-            caches = [dict(c, bt=bt_row, valid=valid[None])
-                      for c in pools]
-            dcaches = [dict(c, bt=bt_row, valid=valid[None])
-                       for c in dpools]
-            logits, newc = run(pb, ids, caches, pos0)
-            _, newdc = drun(dpb, ids, dcaches, pos0)
-            last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
-                                                axis=1)[:, 0]
-            key2, sub = jax.random.split(key)
-            token = jax.lax.cond(
-                ds[0],
-                lambda: select_tokens(last, sub[None], ds, temp, tk, tp),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            state = dict(state)
-
-            def _sel(new, old):
-                return jnp.where(is_last, new, old)
-
-            state["tokens"] = state["tokens"].at[slot].set(
-                _sel(token[0], state["tokens"][slot]))
-            state["pos"] = state["pos"].at[slot].set(
-                _sel(pos0 + valid, state["pos"][slot]))
-            state["keys"] = state["keys"].at[slot].set(
-                _sel(key2, state["keys"][slot]))
-            state["ds"] = state["ds"].at[slot].set(_sel(ds[0], state["ds"][slot]))
-            state["temp"] = state["temp"].at[slot].set(
-                _sel(temp[0], state["temp"][slot]))
-            state["tk"] = state["tk"].at[slot].set(_sel(tk[0], state["tk"][slot]))
-            state["tp"] = state["tp"].at[slot].set(_sel(tp[0], state["tp"][slot]))
+        def _chunk_spec(pb, dpb, pools, dpools, state, rows):
+            """The prefill program with the draft model riding along:
+            both models' paged caches take the chunks' writes through
+            the one batch of table rows, so prefix-cached blocks carry
+            BOTH models' KV and preemption-resume re-prefills both.
+            Select and state are the plain program's."""
+            bt, ids, f = _unpack_rows(rows, nb, C)
+            caches = [dict(c, bt=bt, valid=f["valid"]) for c in pools]
+            caches[0]["head_idx"] = f["last_idx"]
+            dcaches = [dict(c, bt=bt, valid=f["valid"]) for c in dpools]
+            # the draft's logits are read by nobody: one position a row
+            dcaches[0]["head_idx"] = f["last_idx"]
+            logits, newc = run(pb, ids, caches, f["pos0"])
+            _, newdc = drun(dpb, ids, dcaches, f["pos0"])
+            token, state = first_tokens(state, logits[:, 0], f)
             pools_out = [{kk: c[kk] for kk in pool_keys} for c in newc]
             dpools_out = [{kk: c[kk] for kk in pool_keys} for c in newdc]
             return token, pools_out, dpools_out, state
 
         _chunk_spec = _wrap(
             _chunk_spec, (2, 3, 4),
-            (pb_sh, dpb_sh, pool_sh, dpool_sh, state_sh) + (rep,) * 12,
+            (pb_sh, dpb_sh, pool_sh, dpool_sh, state_sh, rep),
             (rep, pool_sh, dpool_sh, state_sh))
 
         def _cow_spec(pools, dpools, src, dst):
@@ -1334,8 +1392,8 @@ class ServingEngine:
         self._zero_drafts = jnp.zeros((B, wd), jnp.int32)
         _recompile.register_entry_location("serving.spec_draft", _draft)
         _recompile.register_entry_location("serving.spec_verify", _verify)
-        _recompile.register_entry_location("serving.prefill_chunk",
-                                           _chunk_spec)
+        for entry in self._chunk_entries:
+            _recompile.register_entry_location(entry, _chunk_spec)
         _recompile.register_entry_location("serving.cow", _cow_spec)
 
     def _build_tree_spec(self, B: int, run):
@@ -1648,7 +1706,8 @@ class ServingEngine:
     def warmup(self) -> dict:
         """Compile every executable this engine will dispatch — the
         pool-wide decode step (or the spec draft+verify pair), the
-        ``[1, C]`` prefill chunk, and the COW fork (contiguous mode:
+        prefill program at ``[1, C]`` and ``[P, C]``, and the COW fork
+        (contiguous mode:
         every prefill bucket + splice + step) — by running each once
         with inert inputs: zeroed block tables route every write to the
         reserved dump block, ``valid``/``active`` masks are all-off, and
@@ -1684,26 +1743,14 @@ class ServingEngine:
     def _warmup_paged(self) -> list:
         B = self.config.max_slots
         nb = self._bt.shape[1]
-        bt1 = jnp.zeros((1, nb), jnp.int32)
         btB = jnp.zeros((B, nb), jnp.int32)
         off = jnp.zeros(B, bool)
         zero_i = jnp.asarray(0, jnp.int32)
-        chunk_args = (
-            bt1, jnp.zeros((1, self._chunk_size), jnp.int32),
-            zero_i, zero_i, zero_i, jnp.asarray(False), zero_i,
-            jax.random.PRNGKey(0), jnp.asarray([False]),
-            jnp.asarray([1.0], jnp.float32), jnp.asarray([0], jnp.int32),
-            jnp.asarray([1.0], jnp.float32))
-        entries = ["serving.prefill_chunk", "serving.cow"]
-        with _entrypoint("serving.prefill_chunk"):
-            if self.spec:
-                _, self._pools, self._dpools, self._state = \
-                    self._chunk_spec_fn(self._pb, self._dpb, self._pools,
-                                        self._dpools, self._state,
-                                        *chunk_args)
-            else:
-                _, self._pools, self._state = self._chunk_fn(
-                    self._pb, self._pools, self._state, *chunk_args)
+        entries = ["serving.cow"]
+        for width in sorted({1, self._chunk_rows}):
+            # no row carries a chunk
+            entries.append(self._enqueue_chunks(
+                self._chunk_args((), width))[1])
         if self.spec:
             # a spec engine never traces the plain step — its decode
             # round is the draft+verify pair
@@ -2232,21 +2279,22 @@ class ServingEngine:
         req.status = RequestStatus.RUNNING
         self._note_admission(req, time.perf_counter(),
                              resumed=resume is not None)
+        # the key on the host, once a request: it rides its chunks as a
+        # row of the program's [P, 2] host array
         self._jobs[slot] = _PrefillJob(req=req, tokens=tokens, total=total,
-                                       done=covered, key=key, skip=skip)
+                                       done=covered, key=np.asarray(key),
+                                       skip=skip)
         self._update_occupancy_gauges()
 
-    def _advance_prefill(self, slot: int):
-        """Run ONE fixed-size prefill chunk for the slot. The final
-        chunk also selects the first token (generate's key chain) and
-        flips the slot into the decode batch; its already-prefilled
-        prompt blocks are registered with the prefix cache BEFORE any
-        decode write can dirty them (COW keeps them pristine)."""
-        job = self._jobs[slot]
+    def _claim_chunk(self, slot: int, job: _PrefillJob) -> bool:
+        """Make the slot's next chunk ready to ride this iteration's
+        prefill program: the cancel and deadline checks (False when one
+        of them freed the slot), then the blocks its write needs.
+        ``PoolExhaustedError`` where the pool cannot give them."""
         req = job.req
         if req.cancel_requested:
             self._free_slot(slot, RequestStatus.CANCELLED, "cancelled")
-            return
+            return False
         if req.deadline_ts is not None \
                 and time.perf_counter() > req.deadline_ts:
             # the deadline can expire BETWEEN admission and the first
@@ -2254,66 +2302,150 @@ class ServingEngine:
             # burning chunk dispatches on a request nobody will read
             self._free_slot(slot, RequestStatus.EXPIRED, "expired",
                             error="deadline passed during prefill")
-            return
-        C = self._chunk_size
-        bs = self.config.block_size
-        start = job.done
-        end = min(start + C, job.total)
-        is_last = end == job.total
-        self._reserve_write(slot, start, end)
-        ids = np.full((1, C), self.config.pad_token_id, np.int32)
-        ids[0, :end - start] = job.tokens[start:end]
-        p = req.params
-        tc0 = time.perf_counter_ns()
-        # the request is the active trace during its chunk, so an XLA
-        # compile fired here (the one serving.prefill_chunk warmup, or a
-        # would-be-retrace bug) lands in this request's timeline
-        with _trace.trace_context(req.trace), \
-                _entrypoint("serving.prefill_chunk"):
-            # host arrays of the warmup's shapes and dtypes, handed to
-            # the executable as they are: the call moves them with its
-            # other arguments, where a jnp.asarray each is a dispatch
-            # and a transfer of its own, twelve a chunk, and lets the
-            # host set the pace of a long prompt (PERF.md, PR 27)
-            i32 = np.int32
-            chunk_args = (
-                # a copy: the backend may alias a numpy view, and a
-                # windowed row is rewritten (rolled) while earlier
-                # chunks are still in flight
-                self._bt[slot:slot + 1].copy(),
-                ids, np.asarray(start, i32), np.asarray(end - start, i32),
-                np.asarray(slot, i32), np.asarray(is_last, bool),
-                np.asarray(job.total - 1 - start, i32), job.key,
-                np.asarray([p.do_sample], bool),
-                np.asarray([p.temperature], np.float32),
-                np.asarray([p.top_k], i32),
-                np.asarray([p.top_p], np.float32))
+            return False
+        self._reserve_write(slot, *job.span(self._chunk_size))
+        return True
+
+    @staticmethod
+    def _chunk_entry(width: int) -> str:
+        """The recompile monitor's name for the prefill program at
+        ``width`` rows, one name an executable: the ``[1, C]`` form
+        keeps the name it always had."""
+        return "serving.prefill_chunk" if width == 1 \
+            else f"serving.prefill_chunk[{width}]"
+
+    def _chunk_args(self, rows, width: int) -> np.ndarray:
+        """The host argument of one ``[width, C]`` prefill program for
+        ``rows``, at most ``width`` pairs of a slot and its job: one
+        int32 array, a row of it a program row's table row, token ids
+        and ``_ROW_COLUMNS``. A row past ``rows`` carries nothing:
+        ``valid`` 0 and a zeroed table row, so it writes the dump block
+        alone. A fresh array every call, handed over as it is: the call
+        moves it with its other arguments, where a ``jnp.asarray`` is a
+        dispatch and a transfer of its own (PERF.md, PR 27), and the
+        backend may alias a host array, so one still in flight must not
+        be written again (the table rows are copies for the same
+        reason: a windowed row is rolled while earlier chunks are in
+        flight)."""
+        C, nb = self._chunk_size, self._bt.shape[1]
+        packed = np.zeros((width, nb + C + len(_ROW_COLUMNS)), np.int32)
+        packed[:, nb:nb + C] = self.config.pad_token_id
+        packed[:, -2:] = _ONE_BITS          # temp, tp
+        for r, (slot, job) in enumerate(rows):
+            start, end = job.span(C)
+            p = job.req.params
+            row = packed[r]
+            row[:nb] = self._bt[slot]
+            row[nb:nb + end - start] = job.tokens[start:end]
+            # pos0, valid, slot, is_last, last_idx (a padded tail's
+            # position, clipped, is read by nobody), ds, tk, key
+            row[nb + C:-2] = (start, end - start, slot, end == job.total,
+                              min(job.total - 1 - start, C - 1),
+                              p.do_sample, p.top_k, *job.key.view(np.int32))
+            row[-2:] = np.asarray((p.temperature, p.top_p),
+                                  np.float32).view(np.int32)
+        return packed
+
+    def _enqueue_chunks(self, packed) -> tuple:
+        """One prefill program over ``_chunk_args``' host array, at its
+        width; returns its first tokens, one a row and still on the
+        device, and the entry it ran under."""
+        entry = self._chunk_entry(packed.shape[0])
+        with _entrypoint(entry):
             if self.spec:
                 token, self._pools, self._dpools, self._state = \
                     self._chunk_spec_fn(self._pb, self._dpb, self._pools,
-                                        self._dpools, self._state,
-                                        *chunk_args)
+                                        self._dpools, self._state, packed)
             else:
                 token, self._pools, self._state = self._chunk_fn(
-                    self._pb, self._pools, self._state, *chunk_args)
-        tc1 = time.perf_counter_ns()
-        _trace.complete("prefill_chunk", "request", req.trace, tc0, tc1 - tc0,
-                        {"slot": slot, "start": start, "end": end,
-                         "last": is_last, "iter": self._phases.seq})
-        _sm.prefill_chunk_seconds.observe((tc1 - tc0) / 1e9)
-        job.done = end
-        _sm.prefill_chunks_total.inc()
-        _sm.tokens_total.labels("prompt").inc(end - start)
+                    self._pb, self._pools, self._state, packed)
+        return token, entry
+
+    def _enqueue_claimed(self, claimed):
+        """One ``serving.prefill_chunk`` program for ``claimed``, at
+        most P pairs of a slot and its job; None where nothing was
+        enqueued. It goes out as soon as its rows are claimed, so the
+        device works while the host claims the next program's (at P = 1
+        every chunk is enqueued before the next slot's blocks are
+        reserved); the rows' bookkeeping waits for ``_book_chunks``.
+
+        A lone chunk rides the ``[1, C]`` form of the same body: on the
+        chip ``[8, 32]`` takes 2.1 ms longer than ``[1, 32]`` whatever
+        rows are live (its 256 rows are computed, PERF.md section 6, PR
+        28), which every iteration with one prefilling slot would pay;
+        from two chunks on the wide program is the cheaper."""
+        # a row whose slot a later row's reservation preempted carries
+        # nothing: its blocks may be that row's by now
+        rows = [(slot, job) for slot, job in claimed
+                if self._jobs[slot] is job]
+        if not rows:
+            return None
+        tc0 = time.perf_counter_ns()
+        try:
+            # the first row's request is the active trace, so an XLA
+            # compile fired here (the one serving.prefill_chunk warmup,
+            # or a would-be-retrace bug) lands in a timeline
+            with _trace.trace_context(rows[0][1].req.trace):
+                token, entry = self._enqueue_chunks(self._chunk_args(
+                    rows, self._chunk_rows if len(rows) > 1 else 1))
+        except Exception as e:  # noqa: BLE001 — engine must survive
+            for slot, _ in rows:
+                self._free_slot(slot, RequestStatus.FAILED, "failed",
+                                error=repr(e))
+            return None
+        self._n_prefill_rows += len(rows)
+        self._n_prefill_programs += 1
+        return rows, token, entry, tc0, time.perf_counter_ns()
+
+    def _book_chunks(self, ran):
+        """The bookkeeping of the rows of this iteration's prefill
+        programs (``_enqueue_claimed``'s records), once all of them are
+        enqueued: a chunk that ends its prompt also selects the first
+        token (generate's key chain) and flips the slot into the decode
+        batch, its prompt blocks registered with the prefix cache BEFORE
+        any decode write can dirty them (COW keeps them pristine). One
+        device-to-host read a program, and only where a row of it is
+        last."""
         from ..observability import perf as _perf
-        _perf.note_entry_items("serving.prefill_chunk", end - start)
-        if not is_last:
-            return
+        C = self._chunk_size
+        for rows, token, entry, tc0, tc1 in ran:
+            _sm.prefill_chunk_seconds.observe((tc1 - tc0) / 1e9)
+            toks = None
+            for r, (slot, job) in enumerate(rows):
+                if self._jobs[slot] is not job:
+                    # preempted, after its program went out, by the
+                    # reservation of a later program's row: recomputed
+                    continue
+                start, end = job.span(C)
+                job.done = end
+                # the rows of one program share its two clock reads
+                _trace.complete(
+                    "prefill_chunk", "request", job.req.trace, tc0,
+                    tc1 - tc0, {"slot": slot, "start": start, "end": end,
+                                "last": end == job.total,
+                                "iter": self._phases.seq})
+                _sm.prefill_chunks_total.inc()
+                _sm.tokens_total.labels("prompt").inc(end - start)
+                _perf.note_entry_items(entry, end - start)
+                if end < job.total:
+                    continue
+                try:
+                    if toks is None:
+                        toks = np.asarray(token)
+                    self._finish_prefill(slot, job, int(toks[r]))
+                except Exception as e:  # noqa: BLE001 — that slot alone
+                    self._free_slot(slot, RequestStatus.FAILED, "failed",
+                                    error=repr(e))
+
+    def _finish_prefill(self, slot: int, job: _PrefillJob, tok0: int):
+        """The slot's last chunk ran and selected ``tok0``."""
+        req, p = job.req, job.req.params
         if self.prefix_cache is not None:
+            bs = self.config.block_size
             n_reg = min(int(req.prompt.shape[0]), job.total)
             self.prefix_cache.insert(
                 job.tokens, n_reg,
                 self._slot_blocks[slot][:-(-n_reg // bs)])
-        tok0 = int(np.asarray(token)[0])
         now = time.perf_counter()
         _sm.prefill_seconds.observe(now - job.t0)
         self._jobs[slot] = None
@@ -2472,6 +2604,8 @@ class ServingEngine:
             n_hit, n_prompt, n_pre = (self._n_prefix_hit_tokens,
                                       self._n_prompt_tokens,
                                       self._preempt_count)
+            n_rows, n_programs = (self._n_prefill_rows,
+                                  self._n_prefill_programs)
             self._last_progress_ts = ph.open("engine.admit") / 1e9
             worked = False
             try:
@@ -2480,18 +2614,35 @@ class ServingEngine:
                     "prefix_hit_tokens": self._n_prefix_hit_tokens - n_hit,
                     "prompt_tokens": self._n_prompt_tokens - n_prompt})
                 if self.paged:
+                    # every prefilling slot advances one chunk, in slot
+                    # order; the chunks ride one program, P rows each,
+                    # which goes out as soon as its rows are claimed
+                    claimed, ran = [], []
                     for slot in range(self.config.max_slots):
-                        if self._jobs[slot] is None:
+                        job = self._jobs[slot]
+                        if job is None:
                             continue
                         worked = True
                         try:
-                            self._advance_prefill(slot)
+                            if self._claim_chunk(slot, job):
+                                claimed.append((slot, job))
                         except PoolExhaustedError:
                             self._preempt(slot)  # retried from the queue front
                         except Exception as e:  # noqa: BLE001
                             self._free_slot(slot, RequestStatus.FAILED,
                                             "failed", error=repr(e))
-                ph.mark("engine.reserve")
+                        if len(claimed) == self._chunk_rows:
+                            ran.append(self._enqueue_claimed(claimed))
+                            claimed = []
+                    if claimed:
+                        ran.append(self._enqueue_claimed(claimed))
+                    self._book_chunks([r for r in ran if r is not None])
+                # rows and programs, on the iterations that enqueued any
+                ph.mark("engine.reserve", ph.on
+                        and self._n_prefill_programs > n_programs and {
+                            "rows": self._n_prefill_rows - n_rows,
+                            "programs": self._n_prefill_programs
+                            - n_programs} or None)
                 active = [i for i, r in enumerate(self._slot_req)
                           if r is not None and self._decoding[i]]
                 # cancellation between steps: drop flagged slots without
@@ -3186,6 +3337,9 @@ class ServingEngine:
             "prompt_tokens": self._n_prompt_tokens,
             "prefix_hit_tokens": self._n_prefix_hit_tokens,
             "preemptions": self._preempt_count,
+            # chunks that rode a prefill program, and the programs
+            "prefill_rows": self._n_prefill_rows,
+            "prefill_programs": self._n_prefill_programs,
         }
         if self._layout is not None:
             # slots that crossed into a new window, the exact-key blocks

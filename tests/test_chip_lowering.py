@@ -37,7 +37,10 @@ SLOTS, NB, BS = 16, 128, 16
 POOL_DTYPES = {"bf16": BF16, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 # paged bundles: (batch rows, q_len, ancestor mask)
 BUNDLES = {"decode": (SLOTS, 1, False), "chunk64": (1, 64, False),
-           "chunk32": (1, 32, False),   # the chunk the served cells run
+           "chunk32": (1, 32, False),   # one prefilling slot's chunk
+           # the prefill program the served cells run: the chunks of up
+           # to eight slots as its rows, each at its own length
+           "chunk8x32": (8, 32, False),
            "spec5": (SLOTS, 5, False), "tree29": (SLOTS, 29, True)}
 # the EVA cell (evabyte-6p5b-cut): 20 rows whose table is [72 summary
 # blocks | 128 window blocks | 8 more], a pool of 2,816 blocks, the

@@ -11,9 +11,9 @@ Oracles:
   ``counters()`` no key, that nothing reads.
 - GAUGES: the ``kv_blocks_*`` gauges are set once an iteration and equal
   ``BlockPool.stats()``; no pool operation reduces over the pool.
-- HOST ARGUMENTS: a prefill chunk and a step hand their host arrays to
-  the executable as they are; nothing is made with ``jnp.asarray`` a
-  chunk or a step.
+- HOST ARGUMENTS: a prefill program (one row or several) and a step hand
+  their host arrays to the executable as they are; nothing is made with
+  ``jnp.asarray`` a chunk or a step.
 - ONE CLOCK: the phases also reach an active profiler session.
 - OFF MEANS OFF: with tracing disabled nothing of this is recorded, no
   annotation is made, no clock is read beyond the step's own, and the
@@ -120,7 +120,9 @@ class TestPhases:
     def test_a_span_carries_only_the_args_a_metric_reads(self, served):
         # iter ties the lanes together; preempted is preemptions.*;
         # the two token counts are prefix_hit_share.chat; kv_blocks is
-        # decode_live_blocks_per_step.*
+        # decode_live_blocks_per_step.*; rows and programs are
+        # prefill_rows_per_iter.* and prefill_programs_per_iter.*, on
+        # the iterations that enqueued a prefill program and no other
         _, _, events = served
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
@@ -130,8 +132,16 @@ class TestPhases:
         mine = [e for e in events if e["name"].startswith("engine.")
                 and e["name"] != "engine.idle"]
         assert {e["name"] for e in mine} == {"engine.iter", *CHILDREN}
+        chunk_iters = {e["args"]["iter"] for e in events
+                       if e["name"] == "prefill_chunk"}
         for e in mine:
+            if e["name"] == "engine.prefill":
+                ran = e["args"]["iter"] in chunk_iters
+                assert set(e["args"]) == (
+                    {"iter", "rows", "programs"} if ran else {"iter"}), e
+                continue
             assert set(e["args"]) == want.get(e["name"], {"iter"}), e
+        assert chunk_iters
 
     def test_a_windowed_engine_adds_its_summary_blocks_and_no_other_arg(
             self):
@@ -159,7 +169,9 @@ class TestPhases:
                                  "prompt_tokens"},
                 "engine.dispatch": {"iter", "kv_blocks", "summary_blocks"}}
         for e in lane:
-            assert set(e["args"]) == want.get(e["name"], {"iter"}), e
+            # (engine.prefill: rows and programs, as on any paged engine)
+            assert set(e["args"]) - {"rows", "programs"} \
+                == want.get(e["name"], {"iter"}), e
         # six steps, the queries at positions 37..42: two windows behind
         # (a block of four summaries each), the window's 6..11 keys
         reads = [(e["args"]["kv_blocks"], e["args"]["summary_blocks"])
@@ -180,6 +192,42 @@ class TestPhases:
             assert ph["ts_ns"] <= ch["ts_ns"]
             assert ch["ts_ns"] + ch["dur_ns"] <= ph["ts_ns"] + ph["dur_ns"]
             assert ch["trace"] != "engine"     # stays on the request's lane
+
+    def test_one_prefill_chunk_span_a_live_row_and_the_sums_are_the_counters(
+            self, served):
+        """``engine.prefill`` carries ``rows`` (live rows enqueued) and
+        ``programs`` on the iterations that enqueued any; each request
+        keeps one ``prefill_chunk`` span a chunk, with the args it
+        always had, and the rows of one program share its clock
+        reads."""
+        eng, reqs, events = served
+        prefill = {e["args"]["iter"]: e["args"] for e in events
+                   if e["name"] == "engine.prefill" and "rows" in e["args"]}
+        chunks = {}
+        for e in events:
+            if e["name"] == "prefill_chunk":
+                assert set(e["args"]) == {"slot", "start", "end", "last",
+                                          "iter"}
+                chunks.setdefault(e["args"]["iter"], []).append(e)
+        assert set(prefill) == set(chunks)
+        P = eng._chunk_rows
+        assert P == 2       # (16, float32, 3 slots): a power of two
+        for it, args in prefill.items():
+            assert args["rows"] == len(chunks[it])
+            assert args["programs"] == -(-args["rows"] // P)
+            assert len({e["args"]["slot"] for e in chunks[it]}) \
+                == args["rows"]
+        # the three requests admitted together rode two programs, and the
+        # two that shared one share its two clock reads
+        assert max(a["rows"] for a in prefill.values()) == 3
+        it = next(i for i, a in prefill.items() if a["rows"] == 3)
+        assert len({(e["ts_ns"], e["dur_ns"]) for e in chunks[it]}) == 2
+        c = eng.counters()
+        assert c["prefill_rows"] == sum(a["rows"] for a in prefill.values())
+        assert c["prefill_programs"] \
+            == sum(a["programs"] for a in prefill.values())
+        # every chunk of every request is a row: 32 | 5+i, 21 -> 16s
+        assert c["prefill_rows"] == 2 + 1 + 1 + 2
 
     def test_prefix_hit_tokens_sum_to_the_cache_hits_times_block_size(
             self, served):
@@ -372,16 +420,18 @@ class TestCounters:
         # nothing here that the pool or the registry counts already
         assert set(c) == {"steps", "slots", "slot_steps", "queue_depth",
                           "prompt_tokens", "prefix_hit_tokens",
-                          "preemptions"}
+                          "preemptions", "prefill_rows",
+                          "prefill_programs"}
 
 
 class TestHostArguments:
     def test_chunks_and_steps_construct_no_device_array(self, tiny_model,
                                                         monkeypatch):
-        """The arguments of a prefill chunk and of a step are host
-        arrays handed to the executable as they are: what the engine
-        builds with ``jnp.asarray`` it builds once a request, however
-        many chunks and steps the request takes."""
+        """The arguments of a prefill program (one row or several) and
+        of a step are host arrays handed to the executable as they are:
+        what the engine builds with ``jnp.asarray`` it builds once a
+        request, however many chunks and steps the request takes and
+        however many requests share a program."""
         import sys
 
         import jax.numpy as jnp
@@ -408,7 +458,24 @@ class TestHostArguments:
             assert len(req.output_tokens) == n_new
             made.append(sorted(calls))
         assert made[0] == made[1]
-        assert not {"_advance_prefill", "_step_impl"} & set(made[1])
+        hot = {"_claim_chunk", "_chunk_args", "_enqueue_claimed",
+               "_book_chunks", "_finish_prefill", "_step_impl"}
+        assert not hot & set(made[1])
+        # the batched call: three prompts whose chunks share programs
+        del calls[:]
+        before = eng.counters()
+        reqs = [eng.submit(_prompt(rng, cfg, n), max_new_tokens=3)
+                for n in (2 * BLOCK + 3, 3 * BLOCK, 5)]
+        eng.run_until_idle()
+        assert all(len(r.output_tokens) == 3 for r in reqs)
+        after = eng.counters()
+        assert after["prefill_rows"] - before["prefill_rows"] == 3 + 3 + 1
+        assert after["prefill_programs"] - before["prefill_programs"] \
+            == 2 + 1 + 1
+        assert not hot & set(calls)
+        # and the rows reach the program as one host array
+        assert all(type(eng._chunk_args([], w)) is np.ndarray
+                   for w in (1, eng._chunk_rows))
 
 
 class TestPoolGauges:

@@ -745,3 +745,395 @@ class TestOnePassKernel:
             assert positions == nb * bs or 128 <= positions <= 512, k
         # a wide bundle's score tiles leave less room for the stream
         assert first["window256"] <= first["gpt_step"]
+
+
+# ---------------------------------------------------------------------------
+# one prefill program an iteration: the chunks of several slots as rows
+# ---------------------------------------------------------------------------
+
+# (prefill_chunk, dtype, max_slots) -> P: 256 rows of a bf16 matmul ride
+# one pass over its weights, a float32 weight streams twice the bytes
+ROWS_RULE = {(32, "bfloat16", 16): 8, (256, "bfloat16", 20): 1,
+             (32, "bfloat16", 2): 2, (32, "float32", 64): 16,
+             (16, "bfloat16", 3): 2, (512, "bfloat16", 4): 1,
+             (16, "float32", 5): 4}
+
+
+def _one_row_engine(monkeypatch, model, **kw):
+    """The same engine with every chunk a program of its own: the
+    measured constant of the rule pushed under one chunk."""
+    from paddle_tpu.serving import engine as engine_mod
+
+    with monkeypatch.context() as m:
+        m.setattr(engine_mod, "_WEIGHT_PASS_ROWS_BF16", 1)
+        eng = serving.ServingEngine(model, **kw)
+    assert eng._chunk_rows == 1
+    return eng
+
+
+def _serve_all(eng, prompts, specs):
+    reqs = [eng.submit(p, **s) for p, s in zip(prompts, specs)]
+    eng.run_until_idle(max_steps=5000)
+    assert all(r.status == serving.RequestStatus.COMPLETED for r in reqs)
+    return [list(r.output_tokens) for r in reqs]
+
+
+class TestBatchedPrefill:
+    KW = dict(max_slots=5, max_len=128, block_size=16, prefill_chunk=16)
+    # one iteration's program holds a mid-prompt chunk, a last chunk
+    # that fills the program's width, a padded last chunk, ...
+    LENGTHS = (37, 16, 5, 48, 70)
+
+    @pytest.mark.parametrize("shape,want", ROWS_RULE.items(),
+                             ids=[f"{c}-{d}-{b}" for c, d, b in ROWS_RULE])
+    def test_rows_of_the_program_come_from_the_shapes(self, shape, want):
+        from paddle_tpu.serving.engine import prefill_batch_rows
+
+        assert prefill_batch_rows(*shape) == want
+
+    @pytest.mark.parametrize("s", [1, 8])
+    def test_per_row_rope_reads_each_row_at_its_own_offset(self, s):
+        """Offsets a row, one of them so near the table's end that its
+        slice has to start earlier than the row does (a verify bundle
+        or a chunk after a prefix hit at ``max_len``): every position
+        inside the table gets its own table row."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.models.llama import (_rope_tables,
+                                             apply_rotary_pos_emb)
+
+        n, d = 64, 8
+        cos, sin = _rope_tables(d, n, 10000.0)
+        rng = np.random.RandomState(SEED + 31)
+        pos = np.array([0, 13, n - s, n - 3], np.int32)
+        x = rng.randn(len(pos), s, 2, d).astype(np.float32)
+        q, _ = apply_rotary_pos_emb(paddle.to_tensor(x), paddle.to_tensor(x),
+                                    cos, sin, jnp.asarray(pos))
+        for b, p in enumerate(pos):
+            live = min(s, n - int(p))       # positions inside the table
+            one, _ = apply_rotary_pos_emb(
+                paddle.to_tensor(x[b:b + 1, :live]),
+                paddle.to_tensor(x[b:b + 1, :live]), cos, sin, int(p))
+            np.testing.assert_allclose(np.asarray(q._data)[b, :live],
+                                       np.asarray(one._data)[0], atol=1e-6)
+
+    @pytest.mark.parametrize("n", [2, 4, 5], ids=["two", "P", "P_plus_1"])
+    def test_simultaneous_prompts_match_generate_and_one_row_programs(
+            self, tiny_model, monkeypatch, n):
+        """Greedy and sampled rows in one program give the tokens that
+        ``generate`` gives each prompt alone, and that the engine gives
+        with every chunk a program of its own; rows past the live ones
+        carry nothing."""
+        model, cfg = tiny_model
+        rng = np.random.RandomState(SEED + 20)
+        prompts = [_prompt(rng, cfg, L) for L in self.LENGTHS[:n]]
+        specs = [dict(max_new_tokens=5 + i) if i % 2 == 0 else
+                 dict(max_new_tokens=5 + i, do_sample=True, top_k=8,
+                      temperature=0.8, seed=30 + i) for i in range(n)]
+        eng = serving.ServingEngine(model, **self.KW)
+        assert eng._chunk_rows == 4
+        got = _serve_all(eng, prompts, specs)
+        for g, p, s in zip(got, prompts, specs):
+            assert g == list(_ref(model, p, **s))
+        assert got == _serve_all(
+            _one_row_engine(monkeypatch, model, **self.KW), prompts, specs)
+        c = eng.counters()
+        chunks = sum(-(-L // 16) for L in self.LENGTHS[:n])
+        assert c["prefill_rows"] == chunks
+        # the rows of an iteration share programs of four
+        live = [sum(L > 16 * i for L in self.LENGTHS[:n]) for i in range(5)]
+        assert c["prefill_programs"] == sum(-(-k // 4) for k in live)
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+    def test_a_lone_chunk_rides_the_one_row_form_of_the_program(
+            self, tiny_model):
+        """Two prompts of five chunks and of one: the first iteration
+        is one [4, C] program, the four after it a [1, C] program
+        each."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        seen = []
+        real = eng._chunk_fn
+        eng._chunk_fn = lambda pb, pools, state, bt, *a: (
+            seen.append(bt.shape[0]) or real(pb, pools, state, bt, *a))
+        rng = np.random.RandomState(SEED + 29)
+        prompts = [_prompt(rng, cfg, L) for L in (70, 9)]
+        got = _serve_all(eng, prompts, [dict(max_new_tokens=3)] * 2)
+        assert seen == [4, 1, 1, 1, 1]
+        for g, p in zip(got, prompts):
+            assert g == list(_ref(model, p, max_new_tokens=3))
+        c = eng.counters()
+        assert (c["prefill_rows"], c["prefill_programs"]) == (6, 5)
+
+    def test_gpt_rows_match_generate(self):
+        paddle.seed(3)
+        cfg = GPTConfig.tiny(max_position_embeddings=128)
+        model = GPTForCausalLM(cfg)
+        rng = np.random.RandomState(SEED + 21)
+        prompts = [_prompt(rng, cfg, L) for L in (21, 8, 40)]
+        specs = [dict(max_new_tokens=4)] * 3
+        eng = serving.ServingEngine(model, max_slots=4, max_len=128,
+                                    block_size=8, prefill_chunk=8)
+        assert eng._chunk_rows == 4
+        for g, p in zip(_serve_all(eng, prompts, specs), prompts):
+            assert g == list(_ref(model, p, max_new_tokens=4))
+
+    def test_pool_exhaustion_on_one_row_preempts_that_slot_alone(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 22)
+        prompts = [_prompt(rng, cfg, L) for L in (40, 50, 45)]
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        eng.step()
+        real, fired = eng._reserve_write, []
+
+        def reserve(slot, start, end):
+            if slot == 1 and not fired:
+                fired.append(slot)
+                raise PoolExhaustedError("no block for this row")
+            return real(slot, start, end)
+
+        eng._reserve_write = reserve
+        before = eng.counters()
+        eng.step()
+        after = eng.counters()
+        assert fired and after["preemptions"] - before["preemptions"] == 1
+        assert reqs[1].slot is None and reqs[1].preempt_count == 1
+        # the other two rode the iteration's one program
+        assert after["prefill_rows"] - before["prefill_rows"] == 2
+        assert after["prefill_programs"] - before["prefill_programs"] == 1
+        assert [eng._jobs[r.slot].done for r in (reqs[0], reqs[2])] \
+            == [32, 32]
+        eng.run_until_idle()
+        for r, p in zip(reqs, prompts):
+            assert list(r.output_tokens) == list(
+                _ref(model, p, max_new_tokens=4))
+
+    def test_a_row_whose_slot_a_later_reservation_preempts_carries_nothing(
+            self, tiny_model):
+        """Row 1's reservation takes the blocks of row 0, claimed a
+        moment before: row 0 is taken out of the program (its blocks
+        may be row 1's by now) and recomputed from the queue."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 23)
+        prompts = [_prompt(rng, cfg, L) for L in (40, 50)]
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        eng.step()
+        real, fired = eng._reserve_write, []
+
+        def reserve(slot, start, end):
+            if slot == 1 and not fired:
+                fired.append(slot)
+                eng._preempt(0)     # what _reclaim_alloc does under pressure
+            return real(slot, start, end)
+
+        eng._reserve_write = reserve
+        before = eng.counters()
+        eng.step()
+        after = eng.counters()
+        assert fired and reqs[0].preempt_count == 1
+        assert after["prefill_rows"] - before["prefill_rows"] == 1
+        eng.run_until_idle()
+        for r, p in zip(reqs, prompts):
+            assert list(r.output_tokens) == list(
+                _ref(model, p, max_new_tokens=4))
+
+    @pytest.mark.parametrize("one_row", [True, False],
+                             ids=["P1", "P4"])
+    def test_a_program_goes_out_as_soon_as_its_rows_are_claimed(
+            self, tiny_model, monkeypatch, one_row):
+        """The device gets a program before the host reserves the next
+        program's blocks: at P = 1 every chunk before the next slot's
+        reservation, at P = 4 the first four slots' before the
+        fifth's."""
+        from paddle_tpu.serving import engine as engine_mod
+
+        model, cfg = tiny_model
+        eng = _one_row_engine(monkeypatch, model, **self.KW) if one_row \
+            else serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 32)
+        prompts = [_prompt(rng, cfg, 40) for _ in range(5)]
+        reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        events = []
+        reserve, enqueue = eng._reserve_write, eng._enqueue_chunks
+
+        def reserving(slot, start, end):
+            events.append(slot)
+            return reserve(slot, start, end)
+
+        def enqueuing(packed):
+            valid = packed[:, -len(engine_mod._ROW_COLUMNS):][:, 1]
+            events.append(("program", int((valid > 0).sum())))
+            return enqueue(packed)
+
+        eng._reserve_write, eng._enqueue_chunks = reserving, enqueuing
+        eng.step()
+        one, four = ("program", 1), ("program", 4)
+        assert events == ([0, one, 1, one, 2, one, 3, one, 4, one]
+                          if one_row else [0, 1, 2, 3, four, 4, one])
+        eng.run_until_idle()
+        for r, p in zip(reqs, prompts):
+            assert list(r.output_tokens) == list(
+                _ref(model, p, max_new_tokens=3))
+
+    def test_a_row_preempted_after_its_program_went_out_is_recomputed(
+            self, tiny_model):
+        """The fifth slot's reservation, made with the first program
+        already out, preempts a row of that program: the row's chunk is
+        not booked, and the request is served from the queue's front."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 33)
+        prompts = [_prompt(rng, cfg, 40) for _ in range(5)]
+        reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        real, fired = eng._reserve_write, []
+
+        def reserve(slot, start, end):
+            if slot == 4 and not fired:
+                fired.append(slot)
+                eng._preempt(1)     # what _reclaim_alloc does under pressure
+            return real(slot, start, end)
+
+        eng._reserve_write = reserve
+        eng.step()
+        assert fired and reqs[1].preempt_count == 1 and reqs[1].slot is None
+        assert eng.counters()["prefill_rows"] == 5
+        assert [eng._jobs[r.slot].done for r in reqs if r.slot is not None] \
+            == [16] * 4
+        eng.run_until_idle()
+        for r, p in zip(reqs, prompts):
+            assert list(r.output_tokens) == list(
+                _ref(model, p, max_new_tokens=3))
+
+    def test_cancel_and_deadline_between_chunks_free_their_rows_alone(
+            self, tiny_model):
+        import time
+
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 24)
+        prompts = [_prompt(rng, cfg, L) for L in (60, 60, 60)]
+        reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        eng.step()
+        eng.cancel(reqs[0])
+        reqs[2].deadline_ts = time.perf_counter() - 1.0
+        before = eng.counters()
+        eng.step()
+        assert reqs[0].status == serving.RequestStatus.CANCELLED
+        assert reqs[2].status == serving.RequestStatus.EXPIRED
+        assert "prefill" in reqs[2].error
+        assert eng.counters()["prefill_rows"] - before["prefill_rows"] == 1
+        eng.run_until_idle()
+        assert list(reqs[1].output_tokens) == list(
+            _ref(model, prompts[1], max_new_tokens=3))
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+    def test_a_prefix_hit_and_a_cow_fork_on_a_row_of_a_batch(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 25)
+        base = _prompt(rng, cfg, 40)        # two blocks and half a block
+        first = eng.submit(base, max_new_tokens=3)
+        eng.run_until_idle()
+        # beside two fresh prompts: one that adopts base's blocks and
+        # writes into the shared half block (a fork), one that adopts
+        # the two whole blocks
+        prompts = [_prompt(rng, cfg, 23),
+                   np.concatenate([base, _prompt(rng, cfg, 9)]),
+                   np.concatenate([base[:32], _prompt(rng, cfg, 20)]),
+                   _prompt(rng, cfg, 35)]
+        hits, forks = eng.prefix_cache.hits, eng.pool.stats()["cow_forks"]
+        before = eng.counters()
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        eng.step()
+        after = eng.counters()
+        assert after["prefill_rows"] - before["prefill_rows"] == 4
+        assert after["prefill_programs"] - before["prefill_programs"] == 1
+        assert eng.prefix_cache.hits - hits == 3 + 2
+        assert eng.pool.stats()["cow_forks"] - forks >= 1
+        assert after["prefix_hit_tokens"] - before["prefix_hit_tokens"] \
+            == 40 + 32
+        eng.run_until_idle()
+        for r, p in zip([first, *reqs], [base, *prompts]):
+            assert list(r.output_tokens) == list(
+                _ref(model, p, max_new_tokens=len(r.output_tokens)))
+
+    def test_a_windowed_engine_rolls_a_window_inside_a_batch(
+            self, monkeypatch):
+        """An EVA layout at a tiny chunk: rows of one program sit in
+        different windows, and one of them rolls its window in the
+        iteration that the others only write."""
+        from paddle_tpu.models import EvaByteConfig, EvaByteForCausalLM
+
+        paddle.seed(0)
+        cfg = EvaByteConfig.tiny()     # window 16 in chunks of 4
+        model = EvaByteForCausalLM(cfg)
+        kw = dict(max_slots=4, max_len=128, block_size=4, prefill_chunk=8,
+                  prefix_caching=False)
+        rng = np.random.RandomState(SEED + 26)
+        prompts = [_prompt(rng, cfg, L) for L in (37, 12, 53, 22)]
+        specs = [dict(max_new_tokens=6)] * 4
+        eng = serving.ServingEngine(model, **kw)
+        assert eng._chunk_rows == 4 and eng._layout is not None
+        reqs = [eng.submit(p, **s) for p, s in zip(prompts, specs)]
+        rolls, rows = [], []
+        while True:
+            before = eng.counters()
+            if not eng.step():
+                break
+            after = eng.counters()
+            rolls.append(after["window_rolls"] - before["window_rolls"])
+            rows.append(after["prefill_rows"] - before["prefill_rows"])
+        # the third iteration writes positions 16..23 of three prompts:
+        # each rolls, in a program that another row does not ride
+        assert rows[:3] == [4, 4, 3] and rolls[:3] == [0, 0, 3]
+        got = [list(r.output_tokens) for r in reqs]
+        assert all(len(g) == 6 for g in got)
+        assert got == _serve_all(_one_row_engine(monkeypatch, model, **kw),
+                                 prompts, specs)
+        for p, g in zip(prompts, got):
+            ids = np.concatenate([p, np.asarray(g, np.int32)])
+            lg = model(paddle.to_tensor(ids[None]))._data[0, :, 0]
+            assert np.asarray(lg.argmax(-1))[len(p) - 1:-1].tolist() == g
+        assert eng.pool.free_blocks == eng.pool.usable_blocks
+
+    def test_int8_pool_rows_match_one_row_programs(self, tiny_model,
+                                                   monkeypatch):
+        model, cfg = tiny_model
+        kw = dict(self.KW, kv_format="int8")
+        rng = np.random.RandomState(SEED + 27)
+        prompts = [_prompt(rng, cfg, L) for L in (37, 16, 5)]
+        specs = [dict(max_new_tokens=6), dict(max_new_tokens=6),
+                 dict(max_new_tokens=6, do_sample=True, top_k=8, seed=5)]
+        eng = serving.ServingEngine(model, **kw)
+        assert eng._chunk_rows == 4 and "ks" in eng._pools[0]
+        assert _serve_all(eng, prompts, specs) == _serve_all(
+            _one_row_engine(monkeypatch, model, **kw), prompts, specs)
+
+    def test_the_program_compiles_once_whatever_the_live_rows(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, max_queue_depth=32, **self.KW)
+        eng.warmup()
+        names = ("serving.prefill_chunk", "serving.prefill_chunk[4]")
+        stats0 = {n: dict(recompile.entry_stats()[n]) for n in names}
+        total0 = recompile.total_compiles()
+        rng = np.random.RandomState(SEED + 28)
+        for n in (1, 2, 3, 4, 5, 9):
+            reqs = [eng.submit(_prompt(rng, cfg, 5 + 13 * i),
+                               max_new_tokens=2, do_sample=bool(i % 2),
+                               seed=i, top_k=4) for i in range(n)]
+            eng.run_until_idle()
+            assert all(r.status == serving.RequestStatus.COMPLETED
+                       for r in reqs)
+        # the one body at its two widths, [1, C] for a lone chunk: both
+        # compiled by warmup(), neither again
+        for n in names:
+            stats1 = recompile.entry_stats()[n]
+            assert stats1["calls"] > stats0[n]["calls"]
+            assert stats1["compiles"] == stats0[n]["compiles"]
+            assert stats1["retraces"] == stats0[n]["retraces"]
+        assert recompile.total_compiles() == total0

@@ -649,6 +649,7 @@ class TestSpecEngineWarmup:
                                     max_slots=2, max_len=64)
         info = eng.warmup()
         assert set(info["entries"]) == {"serving.prefill_chunk",
+                                        "serving.prefill_chunk[2]",
                                         "serving.cow", "serving.spec_draft",
                                         "serving.spec_verify"}
         before = _serving_compiles()

@@ -470,10 +470,13 @@ class TestWarmup:
         assert not eng.warmed_up
         info = eng.warmup()
         assert eng.warmed_up
+        # the prefill program at its two widths: [1, C] for an
+        # iteration's lone chunk, and [2, C]
         assert set(info["entries"]) == {"serving.step",
                                         "serving.prefill_chunk",
+                                        "serving.prefill_chunk[2]",
                                         "serving.cow"}
-        assert info["compiles"] >= 3
+        assert info["compiles"] >= 4
         before = self._serving_compiles()
         rng = np.random.RandomState(61)
         p = _prompt(rng, cfg, 5)
@@ -747,3 +750,142 @@ class TestDeadlineCancelRacesEngine:
         assert rb.status == serving.RequestStatus.CANCELLED
         assert list(rb.output_tokens) == delivered  # nothing re-delivered
         assert ra.status == serving.RequestStatus.COMPLETED
+
+
+class TestBatchedPrefillServing:
+    """One prefill program an iteration, seen from the serving surface:
+    the chunks of every prefilling slot ride it as rows, and what goes
+    wrong with one row stays with that row's request."""
+
+    KW = dict(max_slots=4, max_len=64, block_size=16, prefill_chunk=8)
+
+    def _ref(self, model, p, **s):
+        return list(generation.generate(model, p[None],
+                                        **s).numpy()[0, len(p):])
+
+    @pytest.mark.parametrize("n", [2, 4, 7])
+    def test_simultaneous_arrivals_through_the_loop_match_generate(
+            self, tiny_model, n):
+        """Through the background loop, with more arrivals than slots
+        at the largest ``n``: every stream is what ``generate`` gives
+        its prompt alone, greedy or sampled."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, max_queue_depth=16, **self.KW)
+        assert eng._chunk_rows == 4
+        rng = np.random.RandomState(80 + n)
+        prompts = [_prompt(rng, cfg, 3 + (11 * i) % 29) for i in range(n)]
+        specs = [dict(max_new_tokens=4 + i % 3) if i % 2 else
+                 dict(max_new_tokens=4 + i % 3, do_sample=True, top_k=6,
+                      seed=i) for i in range(n)]
+        eng.start()
+        try:
+            reqs = [eng.submit(p, **s) for p, s in zip(prompts, specs)]
+            got = [r.result(timeout=60.0) for r in reqs]
+        finally:
+            eng.stop()
+        for g, p, s in zip(got, prompts, specs):
+            assert list(g) == self._ref(model, p, **s)
+        c = eng.counters()
+        assert c["prefill_rows"] == sum(-(-len(p) // 8) for p in prompts)
+        assert c["prefill_programs"] <= c["prefill_rows"]
+
+    def test_what_fails_after_a_rows_last_chunk_fails_that_request_alone(
+            self, tiny_model):
+        """Three last chunks in one program; the prefix-cache insert of
+        the second row's prompt raises: that request fails with the
+        error, its neighbours get their first tokens and go on."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(90)
+        prompts = [_prompt(rng, cfg, 5) for _ in range(3)]
+        real = eng.prefix_cache.insert
+
+        def insert(tokens, n, blocks):
+            if np.array_equal(tokens[:n], prompts[1]):
+                raise RuntimeError("cache refused the prompt")
+            return real(tokens, n, blocks)
+
+        eng.prefix_cache.insert = insert
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        eng.run_until_idle()
+        assert reqs[1].status == serving.RequestStatus.FAILED
+        assert "cache refused the prompt" in reqs[1].error
+        for i in (0, 2):
+            assert reqs[i].status == serving.RequestStatus.COMPLETED
+            assert list(reqs[i].output_tokens) == self._ref(
+                model, prompts[i], max_new_tokens=4)
+        assert eng.busy_slots() == 0
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+    def test_a_program_that_fails_to_enqueue_fails_its_rows_and_no_other(
+            self, tiny_model):
+        """Five prefilling slots are two programs at four rows each: the
+        first one's failure is its four requests', the fifth rides the
+        second program and is served."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **dict(self.KW, max_slots=5))
+        rng = np.random.RandomState(91)
+        prompts = [_prompt(rng, cfg, 6) for _ in range(5)]
+        real, calls = eng._chunk_fn, []
+
+        def chunk_fn(*a):
+            calls.append(1)
+            if len(calls) == 1:
+                raise RuntimeError("enqueue refused")
+            return real(*a)
+
+        eng._chunk_fn = chunk_fn
+        reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        eng.run_until_idle()
+        assert [r.status for r in reqs[:4]] \
+            == [serving.RequestStatus.FAILED] * 4
+        assert all("enqueue refused" in r.error for r in reqs[:4])
+        assert reqs[4].status == serving.RequestStatus.COMPLETED
+        assert list(reqs[4].output_tokens) == self._ref(
+            model, prompts[4], max_new_tokens=3)
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+    def test_warmup_compiles_the_program_at_its_rows_and_traffic_none(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        names = ("serving.prefill_chunk", "serving.prefill_chunk[4]",
+                 "serving.step")
+        stats0 = {n: dict(recompile.entry_stats().get(
+            n, {"compiles": 0, "retraces": 0})) for n in names}
+        eng.warmup()
+        # each executable is built once
+        for n in names:
+            st = recompile.entry_stats()[n]
+            assert st["compiles"] - stats0[n]["compiles"] == 1, n
+            assert st["retraces"] == stats0[n]["retraces"], n
+        before = recompile.total_compiles()
+        rng = np.random.RandomState(92)
+        for n in (1, 4, 3):
+            reqs = [eng.submit(_prompt(rng, cfg, 4 + 9 * i),
+                               max_new_tokens=2) for i in range(n)]
+            eng.run_until_idle()
+            assert all(r.status == serving.RequestStatus.COMPLETED
+                       for r in reqs)
+        assert recompile.total_compiles() == before
+
+    def test_a_deadline_on_one_row_leaves_the_program_to_the_others(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(93)
+        prompts = [_prompt(rng, cfg, 30) for _ in range(3)]  # 4 chunks
+        reqs = [eng.submit(p, max_new_tokens=3,
+                           deadline_s=0.05 if i == 0 else None)
+                for i, p in enumerate(prompts)]
+        eng.step()
+        assert all(r.status == serving.RequestStatus.RUNNING for r in reqs)
+        time.sleep(0.1)
+        eng.step()
+        assert reqs[0].status == serving.RequestStatus.EXPIRED
+        assert "prefill" in reqs[0].error
+        eng.run_until_idle()
+        for i in (1, 2):
+            assert list(reqs[i].output_tokens) == self._ref(
+                model, prompts[i], max_new_tokens=3)
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
