@@ -2,22 +2,23 @@
 
 Two workloads against the SAME KV HBM budget:
 
-1. **Long-tail capacity A/B** — the tentpole claim. A fixed budget of
-   KV token-slots is spent two ways:
-
-   - ``contiguous``: ``S_c`` slots * ``max_len`` tokens each (the
-     pre-paging engine — capacity bounded by WORST-CASE length);
-   - ``paged``: the same budget as a block pool
-     (``S_c * max_len / block_size`` blocks) fronted by 4x the slots —
-     capacity bounded by TOKENS IN FLIGHT, preemption-by-recompute
-     keeps oversubscription safe.
+1. **Long-tail capacity** — the tentpole claim. A budget of KV
+   token-slots that a layout reserving ``max_len`` a slot would spend on
+   ``S_c`` slots (capacity bounded by WORST-CASE length: at most ``S_c``
+   requests at once, whatever their lengths) is spent as a block pool
+   (``S_c * max_len / block_size`` blocks) fronted by 4x the slots —
+   capacity bounded by TOKENS IN FLIGHT, preemption-by-recompute keeps
+   oversubscription safe.
 
    A long-tail request mix (mostly short, a few near-max_len) drains
-   through both engines; the bench measures MEAN ACTIVE REQUESTS
+   through the engine; the bench measures MEAN ACTIVE REQUESTS
    (concurrency actually sustained), wall time, and tok/s, and asserts
    per-request bit-parity with ``generation.generate`` plus the
-   one-step-compile invariant while it runs. Acceptance:
-   ``capacity_ratio >= 1.5``.
+   one-step-compile invariant while it runs. (``capacity_ratio`` and
+   its verdict were measured against the per-slot-buffer engine this
+   lane once ran beside it; that engine is gone, a fresh artifact has
+   neither key, and the committed ``bench_paged_kv.json`` holds the
+   last CPU reading, which is what the CPU gate's pin reads.)
 
 2. **Shared-prefix prefill savings** — 12 requests sharing a 64-token
    system prompt. After the first request populates the prefix cache,
@@ -116,56 +117,44 @@ def check_parity(model, reqs, workload):
 def run_capacity_lane(model, cfg):
     workload = make_requests(cfg, LONG_TAIL, seed=7)
     gen_tokens = sum(params["max_new_tokens"] for _, params in workload)
-    lanes = {}
-    for mode, kwargs in (
-            ("contiguous", dict(kv_mode="contiguous",
-                                max_slots=CONTIG_SLOTS)),
-            ("paged", dict(kv_mode="paged", max_slots=PAGED_SLOTS,
-                           block_size=BLOCK_SIZE, num_blocks=NUM_BLOCKS,
-                           prefix_caching=False))):
-        eng = serving.ServingEngine(model, max_len=MAX_LEN,
-                                    max_queue_depth=len(workload), **kwargs)
-        drain(eng, workload)  # warmup: compile every executable
-        base_steps, base_occ = eng._steps, eng._occupancy_integral
-        step_before = recompile.entry_stats().get(
-            "serving.step", {"compiles": 0, "retraces": 0})
-        reqs, wall = drain(eng, workload)
-        step_after = recompile.entry_stats().get(
-            "serving.step", {"compiles": 0, "retraces": 0})
-        steps = eng._steps - base_steps
-        mean_active = (eng._occupancy_integral - base_occ) / max(1, steps)
-        lanes[mode] = {
-            "max_slots": eng.config.max_slots,
-            "kv_token_budget": (NUM_BLOCKS - 1) * BLOCK_SIZE
-            if mode == "paged" else CONTIG_SLOTS * MAX_LEN,
-            "completed": sum(r.status == "completed" for r in reqs),
-            "requests": len(workload),
-            "mean_active_requests": round(mean_active, 2),
-            "decode_steps": steps,
-            "wall_s": round(wall, 3),
-            "tok_s": round(gen_tokens / wall, 1),
-            "parity": check_parity(model, reqs, workload),
-            "step_compiles_measured":
-                step_after["compiles"] - step_before["compiles"],
-            "step_retraces_measured":
-                step_after["retraces"] - step_before["retraces"],
-        }
-        if mode == "paged":
-            lanes[mode]["num_blocks"] = NUM_BLOCKS - 1
-            lanes[mode]["preemptions"] = eng._preempt_count
-            lanes[mode]["kv_blocks_high_watermark"] = \
-                eng.pool.stats()["high_watermark"]
-    ratio = (lanes["paged"]["mean_active_requests"]
-             / max(1e-9, lanes["contiguous"]["mean_active_requests"]))
+    eng = serving.ServingEngine(
+        model, max_len=MAX_LEN, max_queue_depth=len(workload),
+        max_slots=PAGED_SLOTS, block_size=BLOCK_SIZE, num_blocks=NUM_BLOCKS,
+        prefix_caching=False)
+    drain(eng, workload)  # warmup: compile every executable
+    base_steps, base_occ = eng._steps, eng._occupancy_integral
+    step_before = recompile.entry_stats().get(
+        "serving.step", {"compiles": 0, "retraces": 0})
+    reqs, wall = drain(eng, workload)
+    step_after = recompile.entry_stats().get(
+        "serving.step", {"compiles": 0, "retraces": 0})
+    steps = eng._steps - base_steps
+    mean_active = (eng._occupancy_integral - base_occ) / max(1, steps)
+    paged = {
+        "max_slots": eng.config.max_slots,
+        "kv_token_budget": (NUM_BLOCKS - 1) * BLOCK_SIZE,
+        "completed": sum(r.status == "completed" for r in reqs),
+        "requests": len(workload),
+        "mean_active_requests": round(mean_active, 2),
+        "decode_steps": steps,
+        "wall_s": round(wall, 3),
+        "tok_s": round(gen_tokens / wall, 1),
+        "parity": check_parity(model, reqs, workload),
+        "step_compiles_measured":
+            step_after["compiles"] - step_before["compiles"],
+        "step_retraces_measured":
+            step_after["retraces"] - step_before["retraces"],
+        "num_blocks": NUM_BLOCKS - 1,
+        "preemptions": eng._preempt_count,
+        "kv_blocks_high_watermark": eng.pool.stats()["high_watermark"],
+    }
     return {
         "kv_token_budget": CONTIG_SLOTS * MAX_LEN,
         "block_size": BLOCK_SIZE,
         "generated_tokens": gen_tokens,
-        "contiguous": lanes["contiguous"],
-        "paged": lanes["paged"],
-        "capacity_ratio": round(ratio, 2),
-        "tok_s_ratio": round(lanes["paged"]["tok_s"]
-                             / max(1e-9, lanes["contiguous"]["tok_s"]), 2),
+        # the most a max_len-a-slot layout holds of this budget at once
+        "worst_case_length_slots": CONTIG_SLOTS,
+        "paged": paged,
     }
 
 
@@ -348,10 +337,8 @@ def main():
     formats = run_format_lane()
 
     verdicts = {
-        "capacity_ge_1_5x": capacity["capacity_ratio"] >= 1.5,
         "prefix_savings_proportional": shared["savings_vs_shareable"] >= 0.9,
-        "parity": (capacity["contiguous"]["parity"]
-                   and capacity["paged"]["parity"] and shared["parity"]),
+        "parity": capacity["paged"]["parity"] and shared["parity"],
         "one_step_compile": (
             capacity["paged"]["step_compiles_measured"] == 0
             and capacity["paged"]["step_retraces_measured"] == 0),

@@ -27,7 +27,8 @@ from .observability.recompile import entrypoint as _entrypoint
 from .utils.functional import functional_call
 
 __all__ = ["GenerationConfig", "generate", "generate_uncached",
-           "update_static_kv_cache", "make_kv_caches", "make_cached_runner",
+           "update_static_kv_cache", "cached_attention", "kv_cache_layout",
+           "make_kv_caches", "make_cached_runner",
            "select_tokens", "split_keys", "split_key_levels",
            "spec_accept_length", "spec_tree_plan", "truncated_draft",
            "make_paged_kv_pools",
@@ -123,6 +124,14 @@ def _cache_mask(kv_cache, position_offset, s: int, max_len: int):
     if tm is not None:
         return _tree_cache_mask(position_offset, s, max_len, tm)
     return _causal_cache_mask(position_offset, s, max_len)
+
+
+def kv_cache_layout(kv_cache: dict) -> tuple:
+    """``(paged, quantized)`` of a static-cache dict: whether it carries
+    a ``"bt"`` block table over [num_blocks, block_size, h, d] pools
+    (else contiguous [b, max_len, h, d] rows), and whether its store is
+    int8/fp8 with ``"ks"``/``"vs"`` absmax scale companions."""
+    return "bt" in kv_cache, "ks" in kv_cache
 
 
 def kv_format_of(arr) -> str:
@@ -547,10 +556,11 @@ def update_static_kv_cache(kv_cache: dict, k, v, position_offset,
     [b, nb*block_size, h, d] view for the XLA attention fallbacks, while
     ``gather=False`` (the paged-kernel path, which reads the pool
     directly) skips that copy and returns the raw pools as (k, v)."""
-    if isinstance(kv_cache, dict) and "bt" in kv_cache:
+    paged, quantized = kv_cache_layout(kv_cache)
+    if paged:
         return _update_paged_kv_cache(kv_cache, k, v, position_offset,
                                       build_mask, gather)
-    if "ks" in kv_cache:  # quantized contiguous cache
+    if quantized:  # int8/fp8 contiguous cache
         fmt = kv_format_of(kv_cache["k"])
         ck, cks = kv_cache_write_quant(kv_cache["k"], kv_cache["ks"], k,
                                        position_offset, fmt)
@@ -579,6 +589,87 @@ def update_static_kv_cache(kv_cache: dict, k, v, position_offset,
     new_cache = dict(kv_cache)
     new_cache.update({"k": ck, "v": cv})
     return ck, cv, new_cache, mask
+
+
+def cached_attention(q, k, v, kv_cache: dict, position_offset, *, family: str,
+                     attn_mask=None, flash_prefill: bool = False,
+                     after_write=None):
+    """Attention of one cached forward, the one place that knows which
+    kernel reads which cache: write this call's rotated ``k``/``v``
+    [b, s, kv_heads, d] into ``kv_cache`` at ``position_offset`` (python
+    int, traced scalar or per-row [b]; ``update_static_kv_cache``), then
+    attend ``q`` [b, s, heads, d] over what the cache holds up to each
+    query's own position. Returns ``(out [b, s, heads, d], new_cache)``.
+
+    ``decode_dispatch`` decides the reader and counts it under
+    ``family``: the Pallas flash-decode kernel over the raw buffers or
+    pools (GQA-native, int8/fp8 dequantized in its prologue, a spec-tree
+    bundle's ``"tree_mask"`` as the paged kernel's ancestor mask), or the
+    XLA fallback over the dense view under the additive mask (grouped
+    where ``k`` has fewer heads than ``q``). An external ``attn_mask``
+    (ragged left-padded prompts) replaces the built mask and declines the
+    kernel, as a tree bundle over a contiguous cache does: that kernel
+    has no mask input.
+
+    What a model's own layout needs, it passes in. ``flash_prefill``: a
+    prompt at offset 0 of a contiguous cache runs the flash-attention
+    kernel over the step's ``k``/``v`` alone (equal to the masked
+    attention over the padded cache; a paged chunk must read earlier
+    blocks through its table and never takes it). ``after_write(cache)
+    -> cache`` runs between the write and the read (a second write of
+    the same step: EVA's chunk summaries)."""
+    from .nn import functional as F
+    from .pallas_kernels.decode_attention import (
+        decode_dispatch, flash_decode_attention, paged_flash_decode_attention)
+
+    paged, quantized = kv_cache_layout(kv_cache)
+    s = q.shape[1]
+    if flash_prefill and not paged and attn_mask is None and s > 1 \
+            and isinstance(position_offset, int) and position_offset == 0:
+        _, _, new_cache, _ = update_static_kv_cache(
+            kv_cache, k, v, 0, build_mask=False, gather=False)
+        return _flash_causal_attention(q, k, v), new_cache
+    tree_mask = kv_cache.get("tree_mask")
+    kernel = decode_dispatch(
+        family, paged=paged, q_len=s, dtype=q.dtype, quantized=quantized,
+        has_mask=attn_mask is not None
+        or (tree_mask is not None and not paged))
+    kf, vf, new_cache, mask = update_static_kv_cache(
+        kv_cache, k, v, position_offset,
+        build_mask=attn_mask is None and not kernel, gather=not kernel)
+    if after_write is not None:
+        new_cache = after_write(new_cache)
+    if not kernel:
+        sdpa = F.grouped_query_sdpa if kf.shape[2] != q.shape[2] \
+            else F.scaled_dot_product_attention
+        return sdpa(q, kf, vf, attn_mask=mask if attn_mask is None
+                    else attn_mask), new_cache
+    scales = {"k_scale": new_cache.get("ks"), "v_scale": new_cache.get("vs")}
+    if paged:
+        return paged_flash_decode_attention(
+            q, new_cache["k"], new_cache["v"], new_cache["bt"],
+            position_offset, ancestor_mask=tree_mask, **scales), new_cache
+    return flash_decode_attention(q, kf, vf, position_offset,
+                                  **scales), new_cache
+
+
+def _flash_causal_attention(q, k, v):
+    """Causal flash attention of a whole prompt [b, s, h, d] against its
+    own ``k``/``v`` [b, s, kv_heads, d]: the heads expanded (the Pallas
+    prefill kernel wants them so) and the prompt padded to the kernel's
+    128 grid. Padded queries are sliced off, and causal masking means no
+    real query (row < s) ever attends a padded key (row >= s)."""
+    from .nn.functional import repeat_kv
+    from .pallas_kernels.flash_attention import flash_attention
+
+    s, rep = q.shape[1], q.shape[2] // k.shape[2]
+    if rep > 1:
+        k, v = repeat_kv(k, rep), repeat_kv(v, rep)
+    if s % 128 == 0:
+        return flash_attention(q, k, v, causal=True)
+    pad = ((0, 0), (0, 128 - s % 128), (0, 0), (0, 0))
+    q, k, v = (Tensor(jnp.pad(t._data, pad)) for t in (q, k, v))
+    return flash_attention(q, k, v, causal=True)[:, :s]
 
 
 def _mask_after_eos(gen, eos_id):

@@ -155,33 +155,28 @@ class EvaAttention(LlamaAttention):
             out = eva_attention(q, k, v, self.adaptive_phi, self.adaptive_mu_k,
                                 cfg.window_size, cfg.chunk_size)
             return self.o_proj(out.reshape([b, s, -1]))
-        if not (isinstance(kv_cache, dict) and "bt" in kv_cache) \
-                or "ks" in kv_cache:
+        from ..generation import (cached_attention, eva_summary_write,
+                                  eva_virtual_position, kv_cache_layout)
+
+        paged, quantized = kv_cache_layout(kv_cache) \
+            if isinstance(kv_cache, dict) else (False, False)
+        if not paged or quantized:
             raise TypeError(
                 "an EVA model's cache is the paged pool of the serving "
                 "engine in the model's own dtype (window and summary blocks "
                 "behind one block table); contiguous and quantized caches "
                 "hold no summaries")
-        from ..generation import (eva_summary_write, eva_virtual_position,
-                                  update_static_kv_cache)
-        from ..pallas_kernels.decode_attention import (
-            paged_decode_dispatch, paged_flash_decode_attention)
-
         pos = position_offset._data if isinstance(position_offset, Tensor) \
             else position_offset
-        vpos = eva_virtual_position(pos, cfg.window_size, cfg.chunk_size)
-        kernel = paged_decode_dispatch("evabyte", q_len=s, has_mask=False,
-                                       dtype=q.dtype)
-        kf, vf, new_cache, mask = update_static_kv_cache(
-            kv_cache, k, v, vpos, build_mask=not kernel, gather=not kernel)
-        new_cache = eva_summary_write(
-            new_cache, self.adaptive_phi, self.adaptive_mu_k, pos, s,
-            cfg.window_size, cfg.chunk_size, self.head_dim ** -0.5)
-        if kernel:
-            out = paged_flash_decode_attention(
-                q, new_cache["k"], new_cache["v"], new_cache["bt"], vpos)
-        else:
-            out = F.scaled_dot_product_attention(q, kf, vf, attn_mask=mask)
+        # the keys go to the row's virtual position and are read from
+        # there; the chunks this write completes are pooled in between
+        out, new_cache = cached_attention(
+            q, k, v, kv_cache,
+            eva_virtual_position(pos, cfg.window_size, cfg.chunk_size),
+            family="evabyte",
+            after_write=lambda cache: eva_summary_write(
+                cache, self.adaptive_phi, self.adaptive_mu_k, pos, s,
+                cfg.window_size, cfg.chunk_size, self.head_dim ** -0.5))
         return self.o_proj(out.reshape([b, s, -1])), new_cache
 
 

@@ -74,57 +74,21 @@ class GPTBlock(nn.Layer):
         q, k, v = (maybe_constrain_heads(q), maybe_constrain_heads(k),
                    maybe_constrain_heads(v))
         new_cache = None
-        use_flash_decode = False
-        paged_cache = isinstance(kv_cache, dict) and "bt" in kv_cache
         if isinstance(kv_cache, dict):
-            # pre-allocated [b, max_len, h, d] buffers updated in place
-            # (the generation.py static-cache protocol, as in llama.py;
-            # "bt"-carrying dicts are paged pools + block tables); the
-            # decode step (s small, no external mask) dispatches to the
-            # Pallas flash-decode kernel — same gate as llama
-            from ..generation import update_static_kv_cache
-            from ..pallas_kernels.decode_attention import (
-                decode_dispatch, paged_decode_dispatch)
+            # contiguous buffers or paged pools: generation.py writes
+            # the cache and picks what reads it
+            from ..generation import cached_attention
 
-            dispatch = paged_decode_dispatch if paged_cache else decode_dispatch
-            # spec-tree bundles: the PAGED kernel takes the ancestor
-            # mask natively; the contiguous kernel has no mask input so
-            # a tree bundle there declines like an external mask
-            tree_mask = kv_cache.get("tree_mask")
-            ext_mask = attn_mask is not None or (
-                tree_mask is not None and not paged_cache)
-            use_flash_decode = dispatch(
-                "gpt", q_len=s, has_mask=ext_mask,
-                dtype=q.dtype, quantized="ks" in kv_cache)
-            k, v, new_cache, mask = update_static_kv_cache(
-                kv_cache, k, v, position_offset,
-                build_mask=attn_mask is None and not use_flash_decode,
-                gather=not use_flash_decode)
-            if attn_mask is None and not use_flash_decode:
-                attn_mask = mask
+            a, new_cache = cached_attention(
+                q, k, v, kv_cache, position_offset, family="gpt",
+                attn_mask=attn_mask)
         elif kv_cache is not None:
             raise TypeError(
                 f"GPT kv_cache must be the generation.py static-cache dict, "
                 f"got {type(kv_cache).__name__}")
-        if use_flash_decode:
-            from ..pallas_kernels.decode_attention import (
-                flash_decode_attention, paged_flash_decode_attention)
-
-            if paged_cache:
-                a = paged_flash_decode_attention(
-                    q, new_cache["k"], new_cache["v"], new_cache["bt"],
-                    position_offset, k_scale=new_cache.get("ks"),
-                    v_scale=new_cache.get("vs"),
-                    ancestor_mask=new_cache.get("tree_mask"))
-            else:
-                a = flash_decode_attention(
-                    q, k, v, position_offset,
-                    k_scale=new_cache.get("ks"),
-                    v_scale=new_cache.get("vs"))
         else:
             a = F.scaled_dot_product_attention(
-                q, k, v, attn_mask=attn_mask,
-                is_causal=attn_mask is None and kv_cache is None)
+                q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None)
         x = x + self.attn.out_proj(a.reshape([b, s, nh * hd]))
         x = x + self.fc_out(F.gelu(self.fc_in(self.ln_2(x)), approximate=True))
         if kv_cache is not None:

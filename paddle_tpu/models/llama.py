@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import functional as F
+from ..nn.functional import repeat_kv
 from ..ops import dispatch as _dispatch
 from ..ops.dispatch import apply_op
 
@@ -156,16 +157,6 @@ def apply_rotary_pos_emb(q: Tensor, k: Tensor, cos_tab, sin_tab, position_offset
     return qo, ko
 
 
-def repeat_kv(x, rep: int):
-    """GQA head expansion: [b, s, kv_heads, d] -> [b, s, kv_heads*rep, d]
-    (reference PaddleNLP repeat_kv; each kv head serves ``rep`` query
-    heads)."""
-    from ..ops.dispatch import apply_op, ensure_tensor
-
-    return apply_op("repeat_kv", lambda a: jnp.repeat(a, rep, axis=2),
-                    ensure_tensor(x))
-
-
 class LlamaAttention(nn.Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -205,13 +196,10 @@ class LlamaAttention(nn.Layer):
 
         q, k, v = (maybe_constrain_heads(q), maybe_constrain_heads(k),
                    maybe_constrain_heads(v))
-        # spec-tree bundle companions riding the cache dict: the
-        # [b, s, s] ancestor mask and the [s] node-depth vector that
-        # decouples each node's rotary position from its cache slot
-        tree_mask = tree_depth = None
-        if isinstance(kv_cache, dict):
-            tree_mask = kv_cache.get("tree_mask")
-            tree_depth = kv_cache.get("tree_depth")
+        # a spec-tree bundle's [s] node-depth vector rides the cache dict
+        # and decouples each node's rotary position from its cache slot
+        tree_depth = kv_cache.get("tree_depth") \
+            if isinstance(kv_cache, dict) else None
         rope_pos = position_offset
         if tree_depth is not None:
             td = tree_depth._data if isinstance(tree_depth, Tensor) \
@@ -224,113 +212,33 @@ class LlamaAttention(nn.Layer):
             rope_pos = po[:, None] + td[None, :].astype(jnp.int32)
         q, k = apply_rotary_pos_emb(q, k, cos_tab, sin_tab, rope_pos)
 
-        static_cache = isinstance(kv_cache, dict)
-        # paged static cache: the dict carries a "bt" block table and
-        # [num_blocks, block_size, h, d] pools (the serving engine's
-        # paged KV pool) instead of contiguous [b, max_len, h, d] rows
-        paged_cache = static_cache and "bt" in kv_cache
-        # quantized cache: int8/fp8 storage + "ks"/"vs" absmax scale
-        # companions; the flash-decode kernels dequantize in their
-        # prologue, the XLA fallbacks at the gather
-        quant_cache = static_cache and "ks" in kv_cache
-        # flash prefill: at offset 0 causal attention over the prompt
-        # alone equals the masked-dense attention over the padded cache
-        # (positions >= s are masked out anyway) — keep the step k/v for
-        # the Pallas kernel and skip the [s, max_len] mask entirely.
-        # Long-prompt serving stays flash-fast; the per-token decode path
-        # (s == 1) is unchanged. Paged caches never take it: with prefix
-        # sharing the chunk MUST read earlier blocks through the table.
-        flash_prefill = (static_cache and not paged_cache
-                         and self.config.use_flash_attention
-                         and attn_mask is None
-                         and isinstance(position_offset, int)
-                         and position_offset == 0 and s > 1)
-        # flash decode: the static-cache decode step (s small) runs the
-        # Pallas flash-decode kernel over the cache, GQA-native and
-        # per-row length-masked — no repeat_kv, no [s, max_len] mask
-        use_flash_decode = False
-        if static_cache and not flash_prefill:
-            from ..pallas_kernels.decode_attention import (
-                decode_dispatch, paged_decode_dispatch)
+        if isinstance(kv_cache, dict):
+            # contiguous buffers or paged pools: generation.py writes
+            # the cache and picks what reads it
+            from ..generation import cached_attention
 
-            dispatch = paged_decode_dispatch if paged_cache else decode_dispatch
-            # the PAGED kernel scores tree bundles natively (ancestor
-            # mask input); the contiguous kernel has no mask input, so a
-            # tree bundle there counts as an external mask and declines
-            ext_mask = attn_mask is not None or (
-                tree_mask is not None and not paged_cache)
-            use_flash_decode = dispatch(
-                "llama", q_len=s, has_mask=ext_mask,
-                dtype=q.dtype, quantized=quant_cache)
-        if static_cache:
-            # pre-allocated buffers updated in place at position_offset
-            # (jit-friendly decode path; the reference's cache_kv
-            # semantics with TPU-native dynamic_update_slice — or a
-            # block-table scatter for paged pools)
-            from ..generation import update_static_kv_cache
-
-            step_k, step_v = k, v
-            k, v, new_cache, mask = update_static_kv_cache(
-                kv_cache, k, v, position_offset,
-                build_mask=(attn_mask is None and not flash_prefill
-                            and not use_flash_decode),
-                gather=not use_flash_decode)
-            if flash_prefill:
-                k, v = step_k, step_v
-            elif attn_mask is None and not use_flash_decode:
-                attn_mask = mask
-        elif kv_cache is not None:
-            pk, pv = kv_cache
-            from ..ops.manipulation import concat
-
-            k = concat([pk, k], axis=1)
-            v = concat([pv, v], axis=1)
-            new_cache = (k, v)
+            out, new_cache = cached_attention(
+                q, k, v, kv_cache, position_offset, family="llama",
+                attn_mask=attn_mask,
+                flash_prefill=self.config.use_flash_attention)
         else:
             new_cache = None
+            if kv_cache is not None:  # the legacy growing (k, v) tuple
+                from ..ops.manipulation import concat
 
-        if use_flash_decode:
-            from ..pallas_kernels.decode_attention import (
-                flash_decode_attention, paged_flash_decode_attention)
-
-            if paged_cache:
-                out = paged_flash_decode_attention(
-                    q, new_cache["k"], new_cache["v"], new_cache["bt"],
-                    position_offset, k_scale=new_cache.get("ks"),
-                    v_scale=new_cache.get("vs"),
-                    ancestor_mask=tree_mask)
-            else:
-                out = flash_decode_attention(
-                    q, k, v, position_offset,
-                    k_scale=new_cache.get("ks"),
-                    v_scale=new_cache.get("vs"))
-        else:
-            # GQA: the static-cache (decode/cached-prefill) fallback uses
-            # the grouped contraction — k/v stay [b, max_len, kv, d], no
-            # HBM expansion; the training/uncached paths keep repeat_kv
-            # (the Pallas prefill kernel wants expanded heads)
-            gqa = self.num_kv_heads != self.num_heads
-            grouped_fallback = gqa and static_cache and not flash_prefill
-            if gqa and not grouped_fallback:
+                k = concat([kv_cache[0], k], axis=1)
+                v = concat([kv_cache[1], v], axis=1)
+                new_cache = (k, v)
+            # the training/uncached paths expand the heads (the Pallas
+            # prefill kernel wants them so)
+            if self.num_kv_heads != self.num_heads:
                 rep = self.num_heads // self.num_kv_heads
                 k = repeat_kv(k, rep)
                 v = repeat_kv(v, rep)
-
-            if self.config.use_flash_attention and attn_mask is None \
-                    and (not static_cache or flash_prefill):
+            if self.config.use_flash_attention and attn_mask is None:
                 from ..pallas_kernels.flash_attention import flash_attention
 
-                if flash_prefill and s % 128:
-                    # pad the prompt to the kernel's 128 grid: padded queries
-                    # are sliced off below, and causal masking means no REAL
-                    # query (row < s) ever attends a padded key (row >= s)
-                    pad = ((0, 0), (0, 128 - s % 128), (0, 0), (0, 0))
-                    qp, kp, vp = (Tensor(jnp.pad(t._data, pad)) for t in (q, k, v))
-                    out = flash_attention(qp, kp, vp, causal=True)[:, :s]
-                else:
-                    out = flash_attention(q, k, v, causal=True)
-            elif grouped_fallback:
-                out = F.grouped_query_sdpa(q, k, v, attn_mask=attn_mask)
+                out = flash_attention(q, k, v, causal=True)
             else:
                 out = F.scaled_dot_product_attention(
                     q, k, v, attn_mask=attn_mask,

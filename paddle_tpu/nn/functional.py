@@ -740,19 +740,19 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None) -> Tensor:
 
 def _bn_train_fwd(a, w, b, axes, epsilon):
     if a.dtype in (jnp.bfloat16, jnp.float16):
-        # single-pass E[x^2]-E[x]^2 stats (reference GPU BN kernels'
-        # form): both channel reductions read ``a`` once in fp32 — on a
-        # bandwidth-bound TPU conv step this halves the stat-pass HBM
-        # traffic. Half-precision inputs can't carry means large enough
-        # for the cancellation to matter beyond their own resolution.
-        # Accepted variance tolerance vs the two-pass form is DOCUMENTED
-        # and pinned in tests/test_nn.py::TestNorms::
-        # test_batch_norm_bf16_single_pass_stats_tolerance (5e-4 at
-        # mean/std=10, 6e-2 at 100).
+        # single-pass stats in fp32: both channel reductions share one
+        # read of ``a`` (half the stat-pass HBM traffic of mean-then-var
+        # on a bandwidth-bound conv step), taken about each channel's
+        # first element so that E[d^2]-E[d]^2 cancels by the pivot's few
+        # std, not by mean/std. Pinned at mean/std 10 and 100 by tests/
+        # test_nn.py::test_batch_norm_bf16_single_pass_stats_tolerance.
         af = a.astype(jnp.float32)
-        m = jnp.mean(af, axis=axes, keepdims=True)
-        ex2 = jnp.mean(jnp.square(af), axis=axes, keepdims=True)
-        v = jnp.maximum(ex2 - jnp.square(m), 0.0)
+        pivot = jax.lax.stop_gradient(af[tuple(
+            slice(0, 1) if i in axes else slice(None) for i in range(a.ndim))])
+        d = af - pivot
+        md = jnp.mean(d, axis=axes, keepdims=True)
+        v = jnp.maximum(jnp.mean(d * d, axis=axes, keepdims=True) - md * md, 0.0)
+        m = pivot + md
     else:
         # fp32/fp64: two-pass mean/var in the input dtype — E[x^2]-E[x]^2
         # cancels catastrophically for large-mean fp32 inputs
@@ -1272,6 +1272,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     if dropout_p > 0.0 and training:
         out = dropout(out, p=dropout_p, training=training)
     return out
+
+
+def repeat_kv(x, rep: int) -> Tensor:
+    """GQA head expansion: [b, s, kv_heads, d] -> [b, s, kv_heads*rep, d]
+    (reference PaddleNLP repeat_kv; each kv head serves ``rep`` query
+    heads)."""
+    return apply_op("repeat_kv", lambda a: jnp.repeat(a, rep, axis=2),
+                    ensure_tensor(x))
 
 
 def grouped_query_sdpa(query, key, value, attn_mask=None, name=None) -> Tensor:

@@ -40,9 +40,10 @@ Layout contract matches generation.make_kv_caches: q [B, q_len, heads,
 d], caches [B, max_len, kv_heads, d], query head j reads kv head
 j // (heads // kv_heads) (the repeat_kv mapping).
 
-Dispatch: llama/gpt decode paths call ``decode_dispatch`` (env
+Dispatch: ``generation.cached_attention``, the one cached-attention
+call under the models, asks ``decode_dispatch`` (env
 ``PADDLE_TPU_FLASH_DECODE``; default on for TPU backends, opt-in on CPU
-where Pallas interprets) and fall back to XLA with reason counters —
+where Pallas interprets), which falls back to XLA with reason counters —
 ``paddle_tpu_flash_decode_{hits,fallbacks}_total`` — mirroring the
 fused-conv instrumentation pattern.
 """
@@ -66,7 +67,7 @@ from .flash_attention import (NEG_INF, VMEM_LIMIT_BYTES, _dot_prec,
 
 __all__ = ["flash_decode_attention", "flash_decode_enabled",
            "decode_dispatch", "MAX_DECODE_Q_LEN",
-           "paged_flash_decode_attention", "paged_decode_dispatch",
+           "paged_flash_decode_attention",
            "MAX_PAGED_Q_LEN", "MAX_SPEC_K", "spec_verify_eligibility",
            "spec_tree_width"]
 
@@ -123,18 +124,19 @@ def _tp_sharded() -> bool:
     return tp_active() > 1
 
 
-def decode_dispatch(model: str, *, q_len: int, has_mask: bool,
+def decode_dispatch(model: str, *, paged: bool, q_len: int, has_mask: bool,
                     dtype, quantized: bool = False) -> bool:
-    """The decode-path dispatch decision for one attention layer call:
-    True -> run ``flash_decode_attention``; False -> XLA fallback, with
-    the reason counted. Called from the static-cache branch of the
-    llama/gpt attention forwards (python-side, so under jit this costs
-    nothing after the first trace).
+    """The dispatch decision of one ``generation.cached_attention`` call
+    (python-side, so under jit it costs nothing after the first trace):
+    True -> the Pallas kernel (``paged_flash_decode_attention`` over a
+    paged pool, else ``flash_decode_attention``); False -> the XLA
+    fallback (gather, dequantize, masked SDPA), with the reason counted.
 
-    ``quantized``: the cache is an int8/fp8 store — hits count under a
-    ``<model>_quant`` label and fallbacks under ``quant_<reason>``, so a
-    config regression that silently pushes the quantized lane onto the
-    XLA dequant-gather fallback is visible in the metrics."""
+    Hits count under ``<model>[_paged][_quant]`` and fallbacks under
+    ``[paged_][quant_]<reason>``, so a config regression that silently
+    pushes a lane onto the gather fallback is visible in the metrics.
+    The layouts differ in the query window alone: a paged bundle covers
+    the prefill chunk (``MAX_PAGED_Q_LEN``)."""
     reason = None
     if not flash_decode_enabled():
         reason = "disabled"
@@ -146,7 +148,7 @@ def decode_dispatch(model: str, *, q_len: int, has_mask: bool,
         # caller brought its own attention mask (ragged left-padded
         # prompts): the kernel's masking is position-derived only
         reason = "external_mask"
-    elif q_len > MAX_DECODE_Q_LEN:
+    elif q_len > (MAX_PAGED_Q_LEN if paged else MAX_DECODE_Q_LEN):
         reason = "q_len"
     elif str(dtype) not in ("float32", "bfloat16"):
         reason = "dtype"
@@ -157,50 +159,13 @@ def decode_dispatch(model: str, *, q_len: int, has_mask: bool,
             # forward-only kernel (decode is inference); taping it would
             # fail at vjp derivation
             reason = "grad_mode"
-    if reason is None:
-        if _obs_on[0]:
-            _fd_hits.labels(model + ("_quant" if quantized else "")).inc()
-        return True
-    if _obs_on[0]:
-        _fd_fallbacks.labels(("quant_" if quantized else "") + reason).inc()
-    return False
-
-
-def paged_decode_dispatch(model: str, *, q_len: int, has_mask: bool,
-                          dtype, quantized: bool = False) -> bool:
-    """Dispatch decision for the PAGED decode/chunk-prefill path: True
-    -> ``paged_flash_decode_attention`` (the kernel walks the block
-    table itself); False -> the XLA gather fallback
-    (``gather_paged_kv`` + grouped SDPA — ``gather_paged_kv_dequant``
-    for quantized pools), with the reason counted under a ``paged_``
-    prefix (``paged_quant_`` when the pool is quantized). Same gates as
-    ``decode_dispatch`` except the query window covers the prefill
-    chunk (``MAX_PAGED_Q_LEN``)."""
-    reason = None
-    if not flash_decode_enabled():
-        reason = "disabled"
-    elif _tp_sharded():
-        reason = "tp_sharded"
-    elif has_mask:
-        reason = "external_mask"
-    elif q_len > MAX_PAGED_Q_LEN:
-        reason = "q_len"
-    elif str(dtype) not in ("float32", "bfloat16"):
-        reason = "dtype"
-    else:
-        from ..core.autograd import is_grad_enabled
-
-        if is_grad_enabled():
-            reason = "grad_mode"
-    if reason is None:
-        if _obs_on[0]:
-            _fd_hits.labels(
-                model + "_paged" + ("_quant" if quantized else "")).inc()
-        return True
-    if _obs_on[0]:
-        _fd_fallbacks.labels(
-            ("paged_quant_" if quantized else "paged_") + reason).inc()
-    return False
+    if _obs_on[0] and reason is None:
+        _fd_hits.labels(model + ("_paged" if paged else "")
+                        + ("_quant" if quantized else "")).inc()
+    elif _obs_on[0]:
+        _fd_fallbacks.labels(("paged_" if paged else "")
+                             + ("quant_" if quantized else "") + reason).inc()
+    return reason is None
 
 
 def spec_tree_width(spec_tree) -> int:
@@ -220,7 +185,7 @@ def spec_verify_eligibility(spec_k: int, dtype, spec_tree=None):
     the flattened node count for a ``spec_tree``) take the paged
     flash-decode kernel, and if not, why? Called ONCE per engine at
     construction — the per-layer dispatch still decides each trace via
-    ``paged_decode_dispatch``; this is the engine-level preflight that
+    ``decode_dispatch``; this is the engine-level preflight that
     records the expected path (and its fallback reason, under the
     ``spec_`` / ``spec_tree_`` prefix) so a config that silently pushes
     every verify onto the XLA gather fallback is visible in the metrics

@@ -15,8 +15,7 @@ static-shape decode substrate:
                   ONE jitted decode step for the whole pool (per-slot
                   positions/params/keys/block tables as traced arrays),
                   slots freed on EOS/max-tokens and refilled
-                  immediately. ``kv_mode="contiguous"`` keeps the
-                  pre-paging per-slot-buffer engine as the A/B baseline.
+                  immediately.
                   ``draft_model=`` adds SPECULATIVE DECODING: a small
                   draft proposes ``spec_k`` tokens per slot, the target
                   scores the whole bundle in one paged flash-decode

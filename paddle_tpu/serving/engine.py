@@ -1,50 +1,41 @@
-"""Continuous-batching serving engine over a paged (or contiguous) KV
-cache.
+"""Continuous-batching serving engine over a paged KV cache.
 
 The TPU-native translation of iteration-level scheduling (Orca) +
 PagedAttention-class KV management (vLLM) + RadixAttention-style prefix
-reuse, built on this repo's static-shape decode substrate:
+reuse, built on this repo's static-shape decode substrate. Device HBM
+holds ONE fixed pool of KV blocks (per layer, [num_blocks, block_size,
+kv_heads, d]); each slot's cache is an int32 block table into the pool.
+Capacity is bounded by TOKENS IN FLIGHT instead of slots * worst-case
+length — a short request strands at most ``block_size - 1`` token
+slots, not ``max_len - L``. On top of the pool:
 
-- ``kv_mode="paged"`` (default): device HBM holds ONE fixed pool of KV
-  blocks (per layer, [num_blocks, block_size, kv_heads, d]); each slot's
-  cache is an int32 block table into the pool. Capacity is bounded by
-  TOKENS IN FLIGHT instead of slots * worst-case length — a short
-  request strands at most ``block_size - 1`` token slots, not
-  ``max_len - L``. On top of the pool:
-
-  * **prefix sharing**: a prompt whose prefix was already prefilled
-    (same tokens, same positions — e.g. a shared system prompt) adopts
-    those blocks by reference from the host-side prefix cache instead of
-    recomputing them; ref-counted copy-on-write forks a shared block on
-    the first divergent write, so sharing is invisible to outputs.
-  * **chunked prefill**: prompts are admitted in fixed-size chunks
-    (ONE ``serving.prefill_chunk`` executable replaces every per-bucket
-    prefill program) interleaved with decode steps, so a long prompt
-    never head-of-line-blocks running requests for its whole length.
-    The chunks of the slots that prefill in one iteration are the rows
-    of ONE ``[P, C]`` program: every prefilling slot still advances one
-    chunk an iteration, and the weights are read once for all of them
-    (an iteration's lone chunk rides the ``[1, C]`` form of the same
-    body: two executables, ``serving.prefill_chunk`` and
-    ``serving.prefill_chunk[P]``).
-  * **preemption by recompute**: under pool pressure the latest-admitted
-    request is preempted — its blocks freed, the request requeued at the
-    queue front with its generated tokens folded into the prefill and
-    its PRNG chain replayed, so the resumed decode is bit-identical and
-    nothing is ever re-delivered.
-
-- ``kv_mode="contiguous"``: the pre-paging design — per-slot
-  [B, max_len, h, d] buffers, bucketed padded prefill + cache splice —
-  kept as the A/B baseline (``benchmarks/bench_paged_kv.py``).
-
-- ``draft_model=`` (paged only): SPECULATIVE DECODING. Decode is
-  KV-bandwidth-bound, so idle FLOPs verify ``spec_k`` draft tokens per
-  slot per round: ONE jitted draft program (k cached draft-model
-  forwards over draft KV pools that share the target's block tables),
-  then ONE jitted verify scoring the whole [B, k+1] bundle with the
-  target through ``paged_flash_decode_attention``'s q_len > 1 path.
-  Acceptance is the Leviathan/Chen rule under a common-noise coupling:
-  draft and target select with the SAME per-position PRNG subkey, so
+* **prefix sharing**: a prompt whose prefix was already prefilled (same
+  tokens, same positions — e.g. a shared system prompt) adopts those
+  blocks by reference from the host-side prefix cache instead of
+  recomputing them; ref-counted copy-on-write forks a shared block on
+  the first divergent write, so sharing is invisible to outputs.
+* **chunked prefill**: prompts are admitted in fixed-size chunks
+  interleaved with decode steps, so a long prompt never
+  head-of-line-blocks running requests for its whole length. The chunks
+  of the slots that prefill in one iteration are the rows of ONE
+  ``[P, C]`` program: every prefilling slot still advances one chunk an
+  iteration, and the weights are read once for all of them (an
+  iteration's lone chunk rides the ``[1, C]`` form of the same body: two
+  executables, ``serving.prefill_chunk`` and
+  ``serving.prefill_chunk[P]``).
+* **preemption by recompute**: under pool pressure the latest-admitted
+  request is preempted — its blocks freed, the request requeued at the
+  queue front with its generated tokens folded into the prefill and its
+  PRNG chain replayed, so the resumed decode is bit-identical and
+  nothing is ever re-delivered.
+* ``draft_model=``: SPECULATIVE DECODING. Decode is KV-bandwidth-bound,
+  so idle FLOPs verify ``spec_k`` draft tokens per slot per round: ONE
+  jitted draft program (k cached draft-model forwards over draft KV
+  pools that share the target's block tables), then ONE jitted verify
+  scoring the whole [B, k+1] bundle with the target through
+  ``paged_flash_decode_attention``'s q_len > 1 path. Acceptance is the
+  Leviathan/Chen rule under a common-noise coupling: draft and target
+  select with the SAME per-position PRNG subkey, so
   accept-with-prob-min(1, p/q) collapses to exact token match and the
   emitted sequence is BIT-IDENTICAL to non-speculative decode — greedy
   and sampled — while the chain still advances one split per emitted
@@ -56,19 +47,20 @@ reuse, built on this repo's static-shape decode substrate:
   width 1 as plain decode steps, so mixed pools share the same two
   executables — each compiles exactly once.
 
-Both modes drive ONE jitted pool-wide decode step per iteration:
-per-slot positions / sampling params / PRNG keys / active mask — and in
-paged mode the block tables — are traced arrays, so mixed
-occupancy/length/sharing patterns share a single step executable that
-compiles exactly once (recompile-monitor-asserted across request waves).
+ONE jitted pool-wide decode step runs per iteration: per-slot positions
+/ sampling params / PRNG keys / active mask and the block tables are
+traced arrays, so mixed occupancy/length/sharing patterns share a single
+step executable that compiles exactly once (recompile-monitor-asserted
+across request waves).
 
-Per-request outputs are bit-identical to ``generation.generate`` with
-the same sampling seed/params in BOTH modes: the slot key chain
-reproduces generate's ``key, sub = split(key)`` walk, ``select_tokens``
-is row-wise equal to the config-static ``_select_token``, and the paged
-read path gathers the exact same K/V values the contiguous cache holds
-(garbage beyond a row's length is an exact no-op under the additive
-causal mask, just like the contiguous cache's zeros).
+Per-request outputs are bit-identical to ``generation.generate`` (whose
+contiguous static cache is the reference lane of the parity tests) with
+the same sampling seed/params: the slot key chain reproduces generate's
+``key, sub = split(key)`` walk, ``select_tokens`` is row-wise equal to
+the config-static ``_select_token``, and the paged read path gathers the
+exact same K/V values the contiguous cache holds (garbage beyond a row's
+length is an exact no-op under the additive causal mask, just like the
+contiguous cache's zeros).
 
 Observability: every iteration that does work records ``engine.iter``
 and its phases (``engine.admit`` / ``.prefill`` / ``.reserve`` /
@@ -77,14 +69,13 @@ loop) on the engine lane of ``observability.tracing`` and, while a
 ``jax.profiler`` session is active, on its host plane; the counts the
 benchmark reads are plain integers on the engine (``counters()``, O(1)
 and lock-free; ``stats()`` adds the sections that cost). Then the
-``paddle_tpu_serving_*`` instruments plus the paged
+``paddle_tpu_serving_*`` instruments plus the
 ``paddle_tpu_kv_blocks_{total,in_use,shared}`` gauges (set once an
 iteration) and
 ``paddle_tpu_prefix_cache_{hits,misses}_total`` counters; compiles are
 attributed to ``serving.step`` / ``serving.prefill_chunk`` /
-``serving.cow`` (paged) or ``serving.prefill[bucket]`` (contiguous) —
-a ``serving.step`` retrace after warmup is a bug and the monitor flags
-it.
+``serving.cow`` — a ``serving.step`` retrace after warmup is a bug and
+the monitor flags it.
 """
 
 from __future__ import annotations
@@ -102,9 +93,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..generation import (make_cached_runner, make_kv_caches,
-                          make_paged_kv_pools, select_tokens,
-                          spec_accept_length, split_key_levels, split_keys)
+from ..generation import (make_cached_runner, make_paged_kv_pools,
+                          select_tokens, spec_accept_length,
+                          split_key_levels, split_keys)
 from ..observability import recompile as _recompile
 from ..observability import tracing as _trace
 from ..observability.recompile import entrypoint as _entrypoint
@@ -183,17 +174,6 @@ def _unpack_rows(rows, nb: int, chunk: int):
     return rows[:, :nb], rows[:, nb:nb + chunk], f
 
 
-def _default_buckets(max_len: int) -> tuple:
-    """Powers of two from 16 up to (and always including) max_len."""
-    out = []
-    b = 16
-    while b < max_len:
-        out.append(b)
-        b *= 2
-    out.append(max_len)
-    return tuple(out)
-
-
 @dataclass
 class ServingConfig:
     """Engine knobs.
@@ -201,21 +181,21 @@ class ServingConfig:
     - ``max_slots``: the decode batch B — slots in flight at once.
     - ``max_len``: per-slot KV capacity; every request needs
       prompt_len + max_new_tokens <= max_len.
-    - ``kv_mode``: ``"paged"`` (block-pool KV, prefix sharing, chunked
-      prefill — the default) or ``"contiguous"`` (per-slot buffers,
-      bucketed prefill — the A/B baseline).
-    - ``block_size``: tokens per KV block (paged). Must divide
+    - ``kv_mode``: ``"paged"``, the engine's one cache layout (block-pool
+      KV, prefix sharing, chunked prefill). A field only because
+      configuration files still name it; any other value is refused.
+    - ``block_size``: tokens per KV block. Must divide
       ``max_len`` — the per-slot block table covers max_len in whole
       blocks.
     - ``num_blocks``: pool size INCLUDING the reserved dump block.
       Default ``max_slots * (max_len / block_size) + 1`` (worst case —
       paging can never run out); size it below that to oversubscribe
       slots against a fixed HBM budget (preemption keeps it safe).
-    - ``prefill_chunk``: tokens per prefill chunk (paged): a prompt
+    - ``prefill_chunk``: tokens per prefill chunk: a prompt
       advances this many tokens an iteration, between decode steps, so
       a long one never blocks the running requests for its length. One
-      fixed ``[P, prefill_chunk]`` executable replaces every prefill
-      bucket: the next chunks of up to P prefilling slots are the rows
+      fixed ``[P, prefill_chunk]`` executable serves every prompt
+      length: the next chunks of up to P prefilling slots are the rows
       of one program, so their one pass over the weights is shared;
       an iteration's lone chunk rides the ``[1, prefill_chunk]`` form
       of the same body. P is not an option: ``prefill_batch_rows``
@@ -224,14 +204,11 @@ class ServingConfig:
     - ``prefix_caching``: reuse previously prefilled prompt prefixes
       (ref-counted, COW-protected). Disable for strictly independent
       workloads.
-    - ``prefill_buckets``: (contiguous mode) padded prompt lengths; each
-      bucket costs one prefill + one splice compile. Defaults to powers
-      of two up to max_len.
     - ``max_queue_depth``: admission backpressure bound
       (``QueueFullError`` beyond it).
     - ``pad_token_id``: right-pad filler for padded prefill — any valid
-      token id works (padded positions are causally invisible, and paged
-      mode routes their writes to the dump block).
+      token id works (padded positions are causally invisible, and
+      their writes go to the dump block).
     - ``spec_k``: draft tokens per speculative round when the engine is
       built with a ``draft_model`` (the verify bundle is ``spec_k + 1``
       query positions through the paged kernel). Requests opt out (or
@@ -249,7 +226,7 @@ class ServingConfig:
       per-request, clamping the tree DEPTH (0 = plain decode rows
       riding the bundle at width 1). Outputs stay bit-identical to
       non-speculative decode, greedy and sampled.
-    - ``kv_format``: KV block storage (paged only) — ``"bf16"`` keeps
+    - ``kv_format``: KV block storage — ``"bf16"`` keeps
       the model compute dtype (default); ``"int8"``/``"fp8"`` store the
       pool narrow with per-token-per-head absmax scale pools riding the
       same blocks: writes quantize in the scatter epilogue, the paged
@@ -264,15 +241,15 @@ class ServingConfig:
       ``distributed/partition.py`` rule tables; every executable runs
       under jit with explicit shardings over the TP mesh. Outputs are
       bit-identical to the tp=1 engine (greedy and sampled, spec and
-      preemption lanes included); requires ``kv_mode="paged"`` and a
+      preemption lanes included); requires a
       model whose heads/kv-heads/intermediate/vocab divide by tp.
     - ``kv_tier``: hierarchical KV (``serving/kv_tier.py``) — prefix-
       cache eviction victims and preempted requests' blocks DEMOTE to a
       host-RAM tier (device->host at quantized width) instead of being
       freed, and a returning prefix re-admits via one jitted host->HBM
       block splice instead of prefill chunks. Defaults from the
-      ``PADDLE_TPU_KV_TIER`` env var ("1" enables); requires paged mode
-      with prefix caching. Outputs stay bit-identical tier-on vs
+      ``PADDLE_TPU_KV_TIER`` env var ("1" enables); requires
+      prefix caching. Outputs stay bit-identical tier-on vs
       tier-off. ``kv_tier_host_blocks`` caps host residency (LRU);
       ``kv_tier_path`` (env ``PADDLE_TPU_KV_TIER_PATH``) adds the
       crash-safe disk tier below host, making cached prefixes persist
@@ -283,7 +260,6 @@ class ServingConfig:
 
     max_slots: int = 4
     max_len: int = 256
-    prefill_buckets: Sequence[int] = ()
     max_queue_depth: int = 64
     pad_token_id: int = 0
     kv_mode: str = "paged"
@@ -317,10 +293,10 @@ class ServingConfig:
     kv_tier_safety: float = 1.5
 
     def __post_init__(self):
-        if self.kv_mode not in ("paged", "contiguous"):
+        if self.kv_mode != "paged":
             raise ValueError(
-                f"kv_mode must be 'paged' or 'contiguous', got "
-                f"{self.kv_mode!r}")
+                f"kv_mode must be 'paged', the engine's one cache layout "
+                f"(the contiguous mode is gone), got {self.kv_mode!r}")
         from ..quantization.intx import KV_FORMATS, format_dtype
 
         if self.kv_format not in KV_FORMATS:
@@ -329,14 +305,6 @@ class ServingConfig:
                 f"{self.kv_format!r}")
         if self.kv_format != "bf16":
             format_dtype(self.kv_format)  # actionable fp8-missing error
-            if self.kv_mode != "paged":
-                raise ValueError(
-                    f"kv_format={self.kv_format!r} requires "
-                    f"kv_mode='paged': quantized KV lives in the block "
-                    f"pool (per-block scale companions, dequant in the "
-                    f"paged kernel prologue) — switch kv_mode to 'paged' "
-                    f"or drop kv_format (the contiguous engine is the "
-                    f"bf16 A/B baseline)")
         from ..pallas_kernels.decode_attention import (
             MAX_PAGED_Q_LEN, MAX_SPEC_K, spec_tree_width)
 
@@ -376,28 +344,21 @@ class ServingConfig:
             self.spec_tree = factors
         if int(self.tp) < 1:
             raise ValueError(f"tp ({self.tp}) must be >= 1")
-        if int(self.tp) > 1 and self.kv_mode != "paged":
+        if self.block_size < 1 or self.max_len % self.block_size:
             raise ValueError(
-                f"tp={self.tp} requires kv_mode='paged': tensor-parallel "
-                f"serving shards the block pools on the kv-heads axis — "
-                f"switch kv_mode to 'paged' (the contiguous engine is the "
-                f"single-chip A/B baseline)")
-        if self.kv_mode == "paged":
-            if self.block_size < 1 or self.max_len % self.block_size:
-                raise ValueError(
-                    f"block_size ({self.block_size}) must divide max_len "
-                    f"({self.max_len}): the per-slot block table covers "
-                    f"max_len in whole KV blocks — pick a block_size that "
-                    f"divides max_len (e.g. 16) or round max_len up to a "
-                    f"multiple of block_size")
-            if self.prefill_chunk < 1:
-                raise ValueError(
-                    f"prefill_chunk must be >= 1, got {self.prefill_chunk}")
-            if self.num_blocks is not None and self.num_blocks < 2:
-                raise ValueError(
-                    f"num_blocks ({self.num_blocks}) must be >= 2: block 0 "
-                    f"is the reserved dump block, so at least one usable "
-                    f"block is needed")
+                f"block_size ({self.block_size}) must divide max_len "
+                f"({self.max_len}): the per-slot block table covers "
+                f"max_len in whole KV blocks — pick a block_size that "
+                f"divides max_len (e.g. 16) or round max_len up to a "
+                f"multiple of block_size")
+        if self.prefill_chunk < 1:
+            raise ValueError(
+                f"prefill_chunk must be >= 1, got {self.prefill_chunk}")
+        if self.num_blocks is not None and self.num_blocks < 2:
+            raise ValueError(
+                f"num_blocks ({self.num_blocks}) must be >= 2: block 0 "
+                f"is the reserved dump block, so at least one usable "
+                f"block is needed")
         # hierarchical KV: env-resolved defaults, then validation
         if self.kv_tier is None:
             self.kv_tier = os.environ.get("PADDLE_TPU_KV_TIER", "") \
@@ -410,12 +371,6 @@ class ServingConfig:
             self.kv_tier_host_gbps = float(
                 os.environ.get("PADDLE_TPU_KV_TIER_HOST_GBPS", "12.0"))
         if self.kv_tier:
-            if self.kv_mode != "paged":
-                raise ValueError(
-                    "kv_tier=True requires kv_mode='paged': the host/disk "
-                    "tiers hold demoted POOL BLOCKS and re-admit them "
-                    "through the block tables — switch kv_mode to 'paged' "
-                    "or drop kv_tier")
             if not self.prefix_caching:
                 raise ValueError(
                     "kv_tier=True requires prefix_caching=True: tier "
@@ -436,11 +391,6 @@ class ServingConfig:
         draft models (called by the engine when ``draft_model`` is
         given; lives here so the error surface sits with the other
         config validation)."""
-        if self.kv_mode != "paged":
-            raise ValueError(
-                "speculative decoding requires kv_mode='paged': the "
-                "verify bundle and rollback-by-position ride the block "
-                "tables — drop draft_model or switch kv_mode to 'paged'")
         if self.spec_k < 1:
             raise ValueError(
                 f"spec_k ({self.spec_k}) must be >= 1 when a draft_model "
@@ -461,15 +411,6 @@ class ServingConfig:
                 f"({draft_config.max_position_embeddings}); the draft "
                 f"decodes the same positions the target does — shrink "
                 f"max_len or use a draft with a longer position table")
-
-    def buckets(self) -> tuple:
-        bs = tuple(sorted({int(b) for b in self.prefill_buckets
-                           if int(b) <= self.max_len}))
-        if not bs:
-            return _default_buckets(self.max_len)
-        if bs[-1] != self.max_len:
-            bs = bs + (self.max_len,)
-        return bs
 
     def blocks_per_slot(self) -> int:
         return self.max_len // self.block_size
@@ -521,7 +462,6 @@ class ServingEngine:
             raise ValueError(
                 f"max_len ({config.max_len}) exceeds the model's "
                 f"max_position_embeddings ({mcfg.max_position_embeddings})")
-        self.paged = config.kv_mode == "paged"
         # a model with chunked linearized attention (EVA) keeps exact
         # keys inside a window and summaries behind it: its slots give
         # blocks back in mid-sequence (WindowedLayout)
@@ -589,9 +529,9 @@ class ServingEngine:
 
         # per-slot decode state (last token, position, PRNG chain,
         # sampling params) lives on DEVICE across steps — the decode loop
-        # transfers ONE [B] token vector per iteration (plus, in paged
-        # mode, the tiny int32 block table); admission updates a slot's
-        # state rows inside the jitted chunk/splice program.
+        # transfers ONE [B] token vector per iteration (plus the tiny
+        # int32 block table); admission updates a slot's state rows
+        # inside the jitted chunk program.
         self._state = {
             "tokens": jnp.zeros(B, jnp.int32),     # last token per slot
             "pos": jnp.zeros(B, jnp.int32),        # next cache write index
@@ -687,10 +627,7 @@ class ServingEngine:
         self._run = run
 
         self._tier: Optional[KVTier] = None  # set by _init_paged(kv_tier)
-        if self.paged:
-            self._init_paged(B, run)
-        else:
-            self._init_contiguous(B, run)
+        self._init_paged(B, run)
         self._register_memory_components()
 
     @staticmethod
@@ -699,9 +636,6 @@ class ServingEngine:
         options whose bookkeeping assumes that a slot keeps the exact
         keys of every position it has passed."""
         refused = [
-            (config.kv_mode != "paged",
-             "kv_mode='contiguous': summaries live in pool blocks behind "
-             "the block table; use kv_mode='paged'"),
             (config.prefix_caching,
              "prefix_caching=True: a slot forgets its exact keys behind "
              "the window, so a later prompt cannot adopt them; pass "
@@ -752,10 +686,8 @@ class ServingEngine:
                 return None
             total = int(sum(arr.nbytes for c in pools for arr in c.values()))
             out = {"bytes": total, "kv_format": eng.config.kv_format,
-                   "bytes_per_token": eng._kv_bytes_per_token
-                   if eng.paged else None}
-            if eng.paged:
-                out["blocks"] = eng._nblocks
+                   "bytes_per_token": eng._kv_bytes_per_token,
+                   "blocks": eng._nblocks}
             if eng._tp > 1:
                 # jax .nbytes is the GLOBAL logical size; the pools
                 # shard on the kv-heads axis, so each chip holds 1/tp
@@ -788,20 +720,16 @@ class ServingEngine:
                 out["bytes_per_device"] = per_dev
             return out
 
-        if self.paged:
+        _perf.register_memory_component(
+            "serving_kv_pool", functools.partial(_pool_bytes, "_pools"))
+        if self.spec:
             _perf.register_memory_component(
-                "serving_kv_pool", functools.partial(_pool_bytes, "_pools"))
-            if self.spec:
-                _perf.register_memory_component(
-                    "serving_draft_kv_pool",
-                    functools.partial(_pool_bytes, "_dpools"))
-        else:
-            _perf.register_memory_component(
-                "serving_kv_cache", functools.partial(_pool_bytes, "_caches"))
+                "serving_draft_kv_pool",
+                functools.partial(_pool_bytes, "_dpools"))
         _perf.register_memory_component("serving_model_weights",
                                         _weight_bytes)
 
-    # -- executables: paged --------------------------------------------------
+    # -- executables --------------------------------------------------------
     def _init_paged(self, B: int, run):
         config = self.config
         mcfg = self._mcfg
@@ -939,10 +867,11 @@ class ServingEngine:
             """ONE decode iteration for the whole slot pool, reading and
             writing KV through the traced block tables ``bt`` [B, nb]
             (inactive rows are zeroed by the host -> their static-shape
-            writes land in the dump block). Everything else matches the
-            contiguous step: traced per-slot positions/params/keys,
-            ``any_sampling`` cond skipping the sampler for pure-argmax
-            pools, free rows pinned to pos 0. Compiles exactly once —
+            writes land in the dump block). Traced per-slot positions,
+            sampling params and keys drive the per-row RoPE, cache write
+            and batched sampler; the ``any_sampling`` cond skips the
+            sampler for pure-argmax pools; free rows ride along pinned
+            to pos 0. Compiles exactly once —
             occupancy, length mix, and SHARING patterns are all data."""
             caches = [dict(c, bt=bt) for c in pools]
             logits, newc = run(pb, state["tokens"][:, None], caches,
@@ -1207,7 +1136,7 @@ class ServingEngine:
             warnings.warn(f"kv_tier: drain-time flush failed "
                           f"(persistence skipped): {e!r}")
 
-    # -- executables: speculative lane (paged only) --------------------------
+    # -- executables: speculative lane ---------------------------------------
     def _init_spec(self, B: int, run, first_tokens):
         """Draft + verify executables over the shared block tables.
 
@@ -1617,103 +1546,16 @@ class ServingEngine:
                         (rep, rep, pool_sh, dpool_sh, state_sh))
         return _draft, _verify
 
-    # -- executables: contiguous (the pre-paging engine, A/B baseline) -------
-    def _init_contiguous(self, B: int, run):
-        config = self.config
-        mcfg = self._mcfg
-        self._buckets = config.buckets()
-        _recompile.reset_warmup(
-            "serving.step", *(f"serving.prefill[{b}]" for b in self._buckets))
-        self._caches = make_kv_caches(mcfg, B, config.max_len, self._dtype)
-
-        @jax.jit
-        def _prefill(pb, ids, last_idx, key, do_sample, temp, top_k, top_p):
-            """Bucketed prefill: one forward over the right-padded
-            prompt into fresh [1, Lb] caches, then the FIRST token
-            select with generate's exact key chain
-            (key, sub = split(key); select(last_logits, sub))."""
-            Lb = ids.shape[1]
-            caches = make_kv_caches(mcfg, 1, Lb, self._dtype)
-            logits, caches = run(pb, ids, caches, 0)
-            last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1, axis=1)[:, 0]
-            key, sub = jax.random.split(key)
-            token = jax.lax.cond(
-                do_sample[0],
-                lambda: select_tokens(last, sub[None], do_sample, temp,
-                                      top_k, top_p),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            return token, key, caches
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def _splice(caches, state, pcaches, slot, token, pos0, key,
-                    ds, temp, tk, tp):
-            """Admission: copy a prefilled [1, Lb, h, d] cache into slot
-            ``slot`` of the pool (rows [slot, 0:Lb]) via
-            ``dynamic_update_slice`` AND set that slot's rows of the
-            device-resident decode state — one dispatch, no recompile,
-            nothing round-trips through the host."""
-            out = []
-            for c, p in zip(caches, pcaches):
-                out.append({
-                    "k": jax.lax.dynamic_update_slice(
-                        c["k"], p["k"].astype(c["k"].dtype), (slot, 0, 0, 0)),
-                    "v": jax.lax.dynamic_update_slice(
-                        c["v"], p["v"].astype(c["v"].dtype), (slot, 0, 0, 0)),
-                })
-            state = dict(state)
-            state["tokens"] = state["tokens"].at[slot].set(token)
-            state["pos"] = state["pos"].at[slot].set(pos0)
-            state["keys"] = state["keys"].at[slot].set(key)
-            state["ds"] = state["ds"].at[slot].set(ds)
-            state["temp"] = state["temp"].at[slot].set(temp)
-            state["tk"] = state["tk"].at[slot].set(tk)
-            state["tp"] = state["tp"].at[slot].set(tp)
-            return out, state
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def _step(pb, caches, state, any_sampling, active):
-            """ONE decode iteration for the whole slot pool (contiguous
-            caches): per-slot positions drive per-row RoPE/cache-write/
-            mask; per-slot params + keys drive the batched sampler.
-            Compiles once; free slots ride along pinned to pos 0."""
-            logits, caches = run(pb, state["tokens"][:, None], caches,
-                                 state["pos"])
-            last = logits[:, 0]
-            new_keys, subs = split_keys(state["keys"])
-            nxt = jax.lax.cond(
-                any_sampling,
-                lambda: select_tokens(last, subs, state["ds"], state["temp"],
-                                      state["tk"], state["tp"]),
-                lambda: jnp.argmax(last, axis=-1).astype(jnp.int32))
-            state = dict(state)
-            state["tokens"] = nxt
-            state["pos"] = jnp.where(
-                active,
-                jnp.minimum(state["pos"] + 1, jnp.int32(config.max_len - 1)),
-                jnp.int32(0))
-            state["keys"] = new_keys
-            return nxt, caches, state
-
-        self._prefill_fn = _prefill
-        self._splice_fn = _splice
-        self._step_fn = _step
-        _recompile.register_entry_location("serving.step", _step)
-        for b in self._buckets:
-            _recompile.register_entry_location(f"serving.prefill[{b}]",
-                                               _prefill)
-
     # -- warmup: AOT-compile every executable before taking traffic ----------
     def warmup(self) -> dict:
         """Compile every executable this engine will dispatch — the
         pool-wide decode step (or the spec draft+verify pair), the
         prefill program at ``[1, C]`` and ``[P, C]``, and the COW fork
-        (contiguous mode:
-        every prefill bucket + splice + step) — by running each once
-        with inert inputs: zeroed block tables route every write to the
-        reserved dump block, ``valid``/``active`` masks are all-off, and
-        ``is_last`` is False, so no slot state a future request relies
-        on is touched (free rows' tokens/keys are scratch that admission
-        rewrites anyway).
+        — by running each once with inert inputs: zeroed block tables
+        route every write to the reserved dump block, ``valid``/``active``
+        masks are all-off, and ``is_last`` is False, so no slot state a
+        future request relies on is touched (free rows' tokens/keys are
+        scratch that admission rewrites anyway).
 
         A replica that warms up before registering with the router
         serves its FIRST request with zero compiles — the recompile
@@ -1731,10 +1573,7 @@ class ServingEngine:
                     "every executable with inert (dump-block-routed) "
                     "inputs — warm up before submitting traffic")
             with _recompile.warmup_scope():
-                if self.paged:
-                    entries = self._warmup_paged()
-                else:
-                    entries = self._warmup_contiguous()
+                entries = self._warmup_paged()
             self._warmed_up = True
         return {"entries": entries,
                 "compiles": _recompile.total_compiles() - before,
@@ -1791,34 +1630,6 @@ class ServingEngine:
             self._tier_splice(DUMP_BLOCK, self._tier_extract(DUMP_BLOCK))
         return entries
 
-    def _warmup_contiguous(self) -> list:
-        B = self.config.max_slots
-        entries = ["serving.step"]
-        for b in self._buckets:
-            entries.append(f"serving.prefill[{b}]")
-            with _entrypoint(f"serving.prefill[{b}]"):
-                token, key, pcaches = self._prefill_fn(
-                    self._pb,
-                    jnp.full((1, b), self.config.pad_token_id, jnp.int32),
-                    jnp.asarray(0, jnp.int32), jax.random.PRNGKey(0),
-                    jnp.asarray([False]), jnp.asarray([1.0], jnp.float32),
-                    jnp.asarray([0], jnp.int32),
-                    jnp.asarray([1.0], jnp.float32))
-                # pos0 = 0: the step pins free rows to position 0, so
-                # the scratch splice into (free) slot 0 is invisible
-                self._caches, self._state = self._splice_fn(
-                    self._caches, self._state, pcaches,
-                    jnp.asarray(0, jnp.int32), token[0],
-                    jnp.asarray(0, jnp.int32), key, jnp.asarray(False),
-                    jnp.asarray(1.0, jnp.float32),
-                    jnp.asarray(0, jnp.int32),
-                    jnp.asarray(1.0, jnp.float32))
-        with _entrypoint("serving.step"):
-            _, self._caches, self._state = self._step_fn(
-                self._pb, self._caches, self._state, jnp.asarray(False),
-                jnp.zeros(B, bool))
-        return entries
-
     # -- submission ----------------------------------------------------------
     def submit(self, prompt, deadline_s: Optional[float] = None,
                on_token=None, params: Optional[SamplingParams] = None,
@@ -1859,18 +1670,17 @@ class ServingEngine:
                 f"prompt ({L}) + max_new_tokens ({params.max_new_tokens}) "
                 f"exceeds the slot KV capacity max_len="
                 f"{self.config.max_len}")
-        if self.paged:
-            bs = self.config.block_size
-            worst = -(-(L + params.max_new_tokens - 1) // bs) \
-                if self._layout is None \
-                else self._layout.peak(L + params.max_new_tokens - 1)
-            if worst > self.pool.usable_blocks:
-                raise ValueError(
-                    f"prompt ({L}) + max_new_tokens "
-                    f"({params.max_new_tokens}) needs up to {worst} KV "
-                    f"blocks of {bs} tokens, but the pool only has "
-                    f"{self.pool.usable_blocks} usable blocks — raise "
-                    f"num_blocks or shrink the request")
+        bs = self.config.block_size
+        worst = -(-(L + params.max_new_tokens - 1) // bs) \
+            if self._layout is None \
+            else self._layout.peak(L + params.max_new_tokens - 1)
+        if worst > self.pool.usable_blocks:
+            raise ValueError(
+                f"prompt ({L}) + max_new_tokens "
+                f"({params.max_new_tokens}) needs up to {worst} KV "
+                f"blocks of {bs} tokens, but the pool only has "
+                f"{self.pool.usable_blocks} usable blocks — raise "
+                f"num_blocks or shrink the request")
         req = Request(prompt, params, deadline_s=deadline_s, on_token=on_token)
         self.scheduler.submit(req)  # may raise QueueFullError
         with self._wake:
@@ -1881,13 +1691,6 @@ class ServingEngine:
         return self.scheduler.cancel(req)
 
     # -- slot bookkeeping ----------------------------------------------------
-    def _bucket(self, L: int) -> int:
-        for b in self._buckets:
-            if b >= L:
-                return b
-        raise ValueError(f"prompt length {L} exceeds max bucket "
-                         f"{self._buckets[-1]}")
-
     def busy_slots(self) -> int:
         return sum(r is not None for r in self._slot_req)
 
@@ -1902,18 +1705,17 @@ class ServingEngine:
         self._slot_req[slot] = None
         self._slot_sampling[slot] = False
         self._decoding[slot] = False
-        if self.paged:
-            self._jobs[slot] = None
-            for b in self._slot_blocks[slot]:
-                self.pool.decref(b)
-            self._slot_blocks[slot] = []
-            self._bt[slot, :] = 0
-            self._slot_len[slot] = 0
-            self._slot_win[slot] = 0
+        self._jobs[slot] = None
+        for b in self._slot_blocks[slot]:
+            self.pool.decref(b)
+        self._slot_blocks[slot] = []
+        self._bt[slot, :] = 0
+        self._slot_len[slot] = 0
+        self._slot_win[slot] = 0
 
     def _note_admission(self, req: Request, now: float,
                         resumed: bool = False):
-        """Queue-wait digest + trace transitions shared by both engines:
+        """Queue-wait digest + trace transitions of an admission:
         the ``queued`` span ends, ``admitted`` (and ``resume`` for a
         preempted request) lands, and the wait feeds the p50/p95/p99
         digest."""
@@ -1974,7 +1776,7 @@ class ServingEngine:
             return True
         return False
 
-    # -- paged: pool pressure (eviction -> preemption) -----------------------
+    # -- pool pressure (eviction -> preemption) ------------------------------
     def _reclaim_alloc(self, n: int, requester: int,
                        allow_preempt: bool = True) -> List[int]:
         """Allocate ``n`` blocks, reclaiming under pressure: first evict
@@ -2025,7 +1827,7 @@ class ServingEngine:
         bookkeeping (``(None, 0)`` when nothing ran yet: a fresh
         prefill replays everything)."""
         req = self._slot_req[slot]
-        job = self._jobs[slot] if self.paged else None
+        job = self._jobs[slot]
         if job is not None:
             # mid-prefill: nothing delivered yet; restart the same job
             req._resume = (job.tokens, job.key, job.skip)
@@ -2043,7 +1845,7 @@ class ServingEngine:
             [req.prompt,
              np.asarray(req.output_tokens[:g - 1], np.int32)])
         req._resume = (tokens, key, 1)
-        return tokens, (self._slot_len[slot] if self.paged else len(tokens))
+        return tokens, self._slot_len[slot]
 
     def _preempt(self, slot: int):
         """Preemption by recompute: release the slot's blocks and push
@@ -2465,64 +2267,11 @@ class ServingEngine:
         self._finish_or_keep(slot, req, tok0, now)
         self._update_occupancy_gauges()
 
-    # -- contiguous: admission / prefill -------------------------------------
-    def _prefill_into_slot(self, req: Request, slot: int):
-        p = req.params
-        L = int(req.prompt.shape[0])
-        Lb = self._bucket(L)
-        ids = np.full((1, Lb), self.config.pad_token_id, np.int32)
-        ids[0, :L] = req.prompt
-        t0 = time.perf_counter()
-        req.slot = slot
-        self._n_prompt_tokens += L
-        self._note_admission(req, t0)
-        with _trace.trace_context(req.trace), \
-                _entrypoint(f"serving.prefill[{Lb}]"):
-            token, key, pcaches = self._prefill_fn(
-                self._pb, jnp.asarray(ids), jnp.asarray(L - 1, jnp.int32),
-                jax.random.PRNGKey(p.seed),
-                jnp.asarray([p.do_sample]),
-                jnp.asarray([p.temperature], jnp.float32),
-                jnp.asarray([p.top_k], jnp.int32),
-                jnp.asarray([p.top_p], jnp.float32))
-            # prefill outputs stay on device: the splice wires them into
-            # the pool caches + the slot's decode-state rows directly
-            self._caches, self._state = self._splice_fn(
-                self._caches, self._state, pcaches,
-                jnp.asarray(slot, jnp.int32), token[0],
-                jnp.asarray(L, jnp.int32), key,
-                jnp.asarray(p.do_sample),
-                jnp.asarray(p.temperature, jnp.float32),
-                jnp.asarray(p.top_k, jnp.int32),
-                jnp.asarray(p.top_p, jnp.float32))
-        tok0 = int(np.asarray(token)[0])
-        now = time.perf_counter()
-        _sm.prefill_seconds.observe(now - t0)
-        _sm.tokens_total.labels("prompt").inc(L)
-        _sm.tokens_generated.inc()
-
-        self._slot_req[slot] = req
-        self._slot_sampling[slot] = bool(p.do_sample)
-        self._decoding[slot] = True
-        req.slot = slot
-        req.status = RequestStatus.RUNNING
-        req.prefill_done_ts = now
-        req._tr_end("prefill", tokens=L)
-        req._tr_begin("decode")
-
-        req.push_token(tok0, now)
-        req._tr_event("first_token")
-        _sm.ttft_seconds.observe(req.ttft_s)
-        _sm.ttft_summary.observe(req.ttft_s)
-        self._finish_or_keep(slot, req, tok0, now)
-        self._update_occupancy_gauges()
-
     def _admit(self):
         """Fill every free slot FCFS from the queue; runs at the top of
         each iteration so a slot freed by EOS is refilled before the
-        next decode step. Paged admission only claims blocks and queues
-        the chunk job; contiguous admission runs the whole bucketed
-        prefill inline (the pre-paging behavior)."""
+        next decode step. Admission only claims blocks and queues the
+        chunk job; the chunks run in the iteration's prefill phase."""
         # quarantine-probe isolation: a crash SUSPECT the supervisor
         # requeued runs ALONE — admitted only into an idle pool, with
         # nothing admitted beside it. A repeat crash then implicates
@@ -2544,10 +2293,7 @@ class ServingEngine:
                     self.scheduler.requeue(req)
                     return
                 try:
-                    if self.paged:
-                        self._begin_prefill(req, slot)
-                    else:
-                        self._prefill_into_slot(req, slot)
+                    self._begin_prefill(req, slot)
                 except PoolExhaustedError:
                     # not enough free blocks even after cache eviction:
                     # FCFS holds — the request waits at the queue front
@@ -2566,7 +2312,7 @@ class ServingEngine:
     # -- the iteration -------------------------------------------------------
     def step(self) -> bool:
         """One engine iteration: admit into free slots, advance every
-        in-flight chunked prefill by one chunk (paged), then (if any
+        in-flight chunked prefill by one chunk, then (if any
         slot is decoding) run the single jitted decode step for the
         whole pool and deliver/retire per-slot tokens. Returns True when
         any work happened.
@@ -2613,30 +2359,29 @@ class ServingEngine:
                 ph.mark("engine.prefill", ph.on and {
                     "prefix_hit_tokens": self._n_prefix_hit_tokens - n_hit,
                     "prompt_tokens": self._n_prompt_tokens - n_prompt})
-                if self.paged:
-                    # every prefilling slot advances one chunk, in slot
-                    # order; the chunks ride one program, P rows each,
-                    # which goes out as soon as its rows are claimed
-                    claimed, ran = [], []
-                    for slot in range(self.config.max_slots):
-                        job = self._jobs[slot]
-                        if job is None:
-                            continue
-                        worked = True
-                        try:
-                            if self._claim_chunk(slot, job):
-                                claimed.append((slot, job))
-                        except PoolExhaustedError:
-                            self._preempt(slot)  # retried from the queue front
-                        except Exception as e:  # noqa: BLE001
-                            self._free_slot(slot, RequestStatus.FAILED,
-                                            "failed", error=repr(e))
-                        if len(claimed) == self._chunk_rows:
-                            ran.append(self._enqueue_claimed(claimed))
-                            claimed = []
-                    if claimed:
+                # every prefilling slot advances one chunk, in slot
+                # order; the chunks ride one program, P rows each,
+                # which goes out as soon as its rows are claimed
+                claimed, ran = [], []
+                for slot in range(self.config.max_slots):
+                    job = self._jobs[slot]
+                    if job is None:
+                        continue
+                    worked = True
+                    try:
+                        if self._claim_chunk(slot, job):
+                            claimed.append((slot, job))
+                    except PoolExhaustedError:
+                        self._preempt(slot)  # retried from the queue front
+                    except Exception as e:  # noqa: BLE001
+                        self._free_slot(slot, RequestStatus.FAILED,
+                                        "failed", error=repr(e))
+                    if len(claimed) == self._chunk_rows:
                         ran.append(self._enqueue_claimed(claimed))
-                    self._book_chunks([r for r in ran if r is not None])
+                        claimed = []
+                if claimed:
+                    ran.append(self._enqueue_claimed(claimed))
+                self._book_chunks([r for r in ran if r is not None])
                 # rows and programs, on the iterations that enqueued any
                 ph.mark("engine.reserve", ph.on
                         and self._n_prefill_programs > n_programs and {
@@ -2655,47 +2400,45 @@ class ServingEngine:
                 if not active:
                     return worked
 
-                dispatch_args = None
-                if self.paged:
-                    # every active row writes this step's K/V at its current
-                    # length — or, speculatively, at its whole verify-bundle
-                    # window [len, len + spec_len): cross a block boundary
-                    # -> allocate; write into a shared (prefix-cached) block
-                    # -> COW fork. Allocation pressure preempts the
-                    # latest-admitted request, which can shrink `active`.
-                    bs = self.config.block_size
-                    for i in list(active):
-                        if self._slot_req[i] is None or not self._decoding[i]:
-                            continue  # preempted by an earlier row's reclaim
-                        # _row_spec_len is a pure function of host state that
-                        # does not change between here and the dispatch, so
-                        # the bundle can never write past this coverage
-                        m = self._row_spec_len(i) if self.spec else 1
-                        try:
-                            self._reserve_write(i, self._slot_len[i],
-                                                self._slot_len[i] + m)
-                        except PoolExhaustedError:
-                            self._preempt(i)
-                    active = [i for i in active
-                              if self._slot_req[i] is not None
-                              and self._decoding[i]]
-                    if not active:
-                        worked = True
-                        return True
-                    # the pool blocks the step's attention reads: each
-                    # active row's, up to the end of what it writes
-                    if self._layout is None:
-                        dispatch_args = ph.on and {"kv_blocks": sum(
-                            -(-(self._slot_len[i] + (self._row_spec_len(i)
-                                                     if self.spec else 1))
-                              // bs) for i in active)}
-                    else:
-                        # exact keys of the window, and summaries behind it
-                        read = ph.on and [self._layout.read_blocks(
-                            self._slot_len[i] + 1) for i in active]
-                        dispatch_args = ph.on and {
-                            "kv_blocks": sum(r[0] for r in read),
-                            "summary_blocks": sum(r[1] for r in read)}
+                # every active row writes this step's K/V at its current
+                # length — or, speculatively, at its whole verify-bundle
+                # window [len, len + spec_len): cross a block boundary
+                # -> allocate; write into a shared (prefix-cached) block
+                # -> COW fork. Allocation pressure preempts the
+                # latest-admitted request, which can shrink `active`.
+                bs = self.config.block_size
+                for i in list(active):
+                    if self._slot_req[i] is None or not self._decoding[i]:
+                        continue  # preempted by an earlier row's reclaim
+                    # _row_spec_len is a pure function of host state that
+                    # does not change between here and the dispatch, so
+                    # the bundle can never write past this coverage
+                    m = self._row_spec_len(i) if self.spec else 1
+                    try:
+                        self._reserve_write(i, self._slot_len[i],
+                                            self._slot_len[i] + m)
+                    except PoolExhaustedError:
+                        self._preempt(i)
+                active = [i for i in active
+                          if self._slot_req[i] is not None
+                          and self._decoding[i]]
+                if not active:
+                    worked = True
+                    return True
+                # the pool blocks the step's attention reads: each
+                # active row's, up to the end of what it writes
+                if self._layout is None:
+                    dispatch_args = ph.on and {"kv_blocks": sum(
+                        -(-(self._slot_len[i] + (self._row_spec_len(i)
+                                                 if self.spec else 1))
+                          // bs) for i in active)}
+                else:
+                    # exact keys of the window, and summaries behind it
+                    read = ph.on and [self._layout.read_blocks(
+                        self._slot_len[i] + 1) for i in active]
+                    dispatch_args = ph.on and {
+                        "kv_blocks": sum(r[0] for r in read),
+                        "summary_blocks": sum(r[1] for r in read)}
 
                 worked = True
                 t0_ns = ph.mark("engine.dispatch") \
@@ -2708,17 +2451,11 @@ class ServingEngine:
                                     t0_ns, ph, dispatch_args)
                     return True
                 with _entrypoint("serving.step"):
-                    if self.paged:
-                        bt_step = self._bt.copy()
-                        bt_step[~active_mask] = 0  # inactive -> dump block
-                        toks, self._pools, self._state = self._step_fn(
-                            self._pb, self._pools, self._state, bt_step,
-                            np.asarray(any_sampling, bool), active_mask)
-                    else:
-                        toks, self._caches, self._state = self._step_fn(
-                            self._pb, self._caches, self._state,
-                            jnp.asarray(any_sampling),
-                            jnp.asarray(active_mask))
+                    bt_step = self._bt.copy()
+                    bt_step[~active_mask] = 0  # inactive -> dump block
+                    toks, self._pools, self._state = self._step_fn(
+                        self._pb, self._pools, self._state, bt_step,
+                        np.asarray(any_sampling, bool), active_mask)
                 ph.mark("engine.wait", dispatch_args)
                 toks_np = np.asarray(toks)  # the step's ONE device->host sync
                 now_ns = ph.mark("engine.emit") or time.perf_counter_ns()
@@ -2741,9 +2478,8 @@ class ServingEngine:
 
                 for i in active:
                     req = self._slot_req[i]
-                    if self.paged:
-                        self._slot_len[i] = min(self._slot_len[i] + 1,
-                                                self.config.max_len - 1)
+                    self._slot_len[i] = min(self._slot_len[i] + 1,
+                                            self.config.max_len - 1)
                     t = int(toks_np[i])
                     prev = req.last_token_ts
                     req.push_token(t, now)
@@ -2755,8 +2491,7 @@ class ServingEngine:
                 return True
             finally:
                 self._update_occupancy_gauges()
-                if self.paged:
-                    self.pool.set_gauges()
+                self.pool.set_gauges()
                 # an iteration that only admitted (and lost the request
                 # again) is recorded too: its engine.admit has tokens
                 ph.close(worked or self._n_prompt_tokens > n_prompt, None,
@@ -3006,8 +2741,7 @@ class ServingEngine:
             req.finish(RequestStatus.FAILED, error=error)
             _sm.requests_total.labels("failed").inc()
             self._outcomes["failed"] = self._outcomes.get("failed", 0) + 1
-        if self.paged:
-            self.pool.set_gauges()  # slots freed outside an iteration
+        self.pool.set_gauges()  # slots freed outside an iteration
 
     def _export_inflight(self) -> tuple:
         """Detach every running and queued request WITHOUT finishing
@@ -3019,10 +2753,7 @@ class ServingEngine:
         same ``_build_resume`` recipe preemption uses, so a FRESH
         engine resumes each running request bit-identically. Queued
         requests were never touched by the crashing step and carry no
-        resume state at all. On a contiguous engine (no resume support
-        in its prefill path) only fresh running requests are detached —
-        ones with delivered tokens stay and fail as before rather than
-        re-deliver duplicates."""
+        resume state at all."""
         running = []
         order = sorted(
             (slot for slot in range(self.config.max_slots)
@@ -3030,8 +2761,6 @@ class ServingEngine:
             key=lambda s: self._slot_seq[s])
         for slot in order:
             req = self._slot_req[slot]
-            if not self.paged and req.output_tokens:
-                continue  # contiguous decode cannot replay; fail it
             self._build_resume(slot)
             req.slot = None
             req._tr_end("prefill")
@@ -3040,8 +2769,7 @@ class ServingEngine:
                           generated=len(req.output_tokens))
             self._slot_req[slot] = None
             self._decoding[slot] = False
-            if self.paged:
-                self._jobs[slot] = None
+            self._jobs[slot] = None
             running.append(req)
         return running, self.scheduler.detach_all()
 
@@ -3117,7 +2845,7 @@ class ServingEngine:
                     "mid-flight — resubmit to another replica")
         elif self._crashed is None:
             self.drain(timeout_s=drain_timeout_s)
-        if self.paged and self._crashed is None:
+        if self._crashed is None:
             # persist the prefix cache across the restart (disk tier)
             # BEFORE the terminal flip: the engine is drained, so the
             # pool blocks are stable under the step lock
@@ -3201,14 +2929,12 @@ class ServingEngine:
             }
         return out
 
-    def kv_block_stats(self) -> Optional[dict]:
+    def kv_block_stats(self) -> dict:
         """Pool utilization + internal fragmentation (allocated token
-        slots the slots' sequences do not fill) — paged mode only.
-        Carries the quantization accounting: the storage format, bytes
-        per cached token (values + scales, all layers), and the
-        capacity multiplier vs a bf16 pool of the same HBM budget."""
-        if not self.paged:
-            return None
+        slots the slots' sequences do not fill), with the quantization
+        accounting: the storage format, bytes per cached token (values +
+        scales, all layers), and the capacity multiplier vs a bf16 pool
+        of the same HBM budget."""
         from ..generation import kv_cache_bytes_per_token
 
         stats = self.pool.stats()
@@ -3245,14 +2971,11 @@ class ServingEngine:
             if r is None:
                 continue
             row = r.debug_row()
-            if self.paged:
-                job = self._jobs[slot]
-                row["phase"] = "prefill" if job is not None else "decode"
-                row["tokens_in_cache"] = (job.done if job is not None
-                                          else self._slot_len[slot])
-                row["kv_blocks"] = len(self._slot_blocks[slot])
-            else:
-                row["phase"] = "decode"
+            job = self._jobs[slot]
+            row["phase"] = "prefill" if job is not None else "decode"
+            row["tokens_in_cache"] = (job.done if job is not None
+                                      else self._slot_len[slot])
+            row["kv_blocks"] = len(self._slot_blocks[slot])
             running.append(row)
         recent = [r.debug_row() for r in list(self._recent)]
         return {"ts": time.time(), "queued": queued, "running": running,
@@ -3288,12 +3011,11 @@ class ServingEngine:
             "warmed_up": self._warmed_up,
             "crashed": self._crashed,
         }
-        if self.paged:
-            kv = self.kv_block_stats()
-            payload["kv_blocks_in_use"] = kv["in_use"]
-            payload["kv_blocks_total"] = kv["usable"]
-            payload["kv_blocks_shared"] = kv["shared"]
-            payload["kv_block_utilization"] = round(kv["utilization"], 4)
+        kv = self.kv_block_stats()
+        payload["kv_blocks_in_use"] = kv["in_use"]
+        payload["kv_blocks_total"] = kv["usable"]
+        payload["kv_blocks_shared"] = kv["shared"]
+        payload["kv_block_utilization"] = round(kv["utilization"], 4)
         if self._crashed is not None:
             payload["status"] = "crashed"
             return 503, payload
@@ -3387,24 +3109,21 @@ class ServingEngine:
         out["perf"] = {"ledger": _perf.ledger(prefix="serving."),
                        "peaks": _perf.peak_specs()}
         out["spec"] = self.spec_stats()
-        if self.paged:
-            out["block_size"] = self.config.block_size
-            out["prefill_chunk"] = self.config.prefill_chunk
-            out["kv_format"] = self.config.kv_format
-            out["kv_blocks"] = self.kv_block_stats()
-            out["prefix_cache"] = (self.prefix_cache.stats()
-                                   if self.prefix_cache is not None else None)
-            out["kv_tier"] = (self._tier.stats()
-                              if self._tier is not None else None)
-            out["requests"] = [
-                {"request_id": r.id, "slot": slot,
-                 "tokens_in_cache": (self._jobs[slot].done
-                                     if self._jobs[slot] is not None
-                                     else self._slot_len[slot]),
-                 "kv_blocks": len(self._slot_blocks[slot]),
-                 "phase": ("prefill" if self._jobs[slot] is not None
-                           else "decode")}
-                for slot, r in enumerate(self._slot_req) if r is not None]
-        else:
-            out["prefill_buckets"] = list(self._buckets)
+        out["block_size"] = self.config.block_size
+        out["prefill_chunk"] = self.config.prefill_chunk
+        out["kv_format"] = self.config.kv_format
+        out["kv_blocks"] = self.kv_block_stats()
+        out["prefix_cache"] = (self.prefix_cache.stats()
+                               if self.prefix_cache is not None else None)
+        out["kv_tier"] = (self._tier.stats()
+                          if self._tier is not None else None)
+        out["requests"] = [
+            {"request_id": r.id, "slot": slot,
+             "tokens_in_cache": (self._jobs[slot].done
+                                 if self._jobs[slot] is not None
+                                 else self._slot_len[slot]),
+             "kv_blocks": len(self._slot_blocks[slot]),
+             "phase": ("prefill" if self._jobs[slot] is not None
+                       else "decode")}
+            for slot, r in enumerate(self._slot_req) if r is not None]
         return out

@@ -232,7 +232,7 @@ step_seconds = _m.histogram(
              0.5, 1.0, 2.5, 5.0))
 prefill_seconds = _m.histogram(
     "paddle_tpu_serving_prefill_seconds",
-    "wall time of one bucketed prefill (+ cache splice)",
+    "wall time of one request's prefill (admission to its last chunk)",
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
              1.0, 2.5, 5.0, 10.0, 30.0))
 ttft_seconds = _m.histogram(

@@ -177,7 +177,7 @@ class Request:
                   "do_sample": params.do_sample}, ts_ns=ts0)
         self._open_spans = {}
         self._tr_begin("queued", ts_ns=ts0)
-        # paged-engine preemption state: (tokens_to_prefill, prng_key,
+        # preemption state: (tokens_to_prefill, prng_key,
         # n_reselected) set when the request is requeued for recompute —
         # the generated tokens fold into the next prefill and the final
         # select's re-derived token is skipped, never re-delivered

@@ -5,8 +5,8 @@ Iteration-level scheduling (Orca) splits serving into two loops: the
 ADMISSION decision (this module — which request gets the next free slot)
 and the ITERATION itself (engine.py — one decode step for every running
 slot). FCFS within a priority class is deliberately the whole policy
-here: the TPU-side design makes admission cheap enough (bucketed
-prefill + cache splice, no recompile) that fancier policies are a
+here: the TPU-side design makes admission cheap enough (a claim of
+blocks and a queued chunk job, no recompile) that fancier policies are a
 drop-in swap of ``pop_ready``.
 
 Overload control (the DAGOR shape — Zhou et al., SoCC'18): when the
@@ -129,8 +129,8 @@ class Scheduler:
             _sm.queue_depth.set(len(self._q))
 
     def requeue(self, req: Request):
-        """Push a request back to the FRONT of the queue (paged-engine
-        preemption / admission backoff): it keeps its FCFS position and
+        """Push a request back to the FRONT of the queue (preemption /
+        admission backoff): it keeps its FCFS position and
         is retried before anything newer. Deliberately exempt from the
         depth bound — the request was already admitted once; bouncing it
         with a rejection now would turn pool pressure into data loss."""

@@ -459,7 +459,7 @@ class EngineSupervisor:
         return False
 
     def __getattr__(self, name):
-        # everything else (scheduler, config, paged, warmed_up,
+        # everything else (scheduler, config, warmed_up,
         # debug_requests, run_until_idle-adjacent state...) delegates
         # to the CURRENT engine, so the supervisor drops in anywhere a
         # ServingEngine goes

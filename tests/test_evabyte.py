@@ -279,7 +279,6 @@ REFUSED = {
     "prefix_caching": dict(prefix_caching=True),
     "kv_tier": dict(prefix_caching=True, kv_tier=True),
     "tp2": dict(prefix_caching=False, tp=2),
-    "contiguous": dict(prefix_caching=False, kv_mode="contiguous"),
     "int8_pool": dict(prefix_caching=False, kv_format="int8"),
     "chunk_not_of_whole_chunks": dict(prefix_caching=False, prefill_chunk=24),
     "chunk_across_windows": dict(prefix_caching=False, prefill_chunk=48),

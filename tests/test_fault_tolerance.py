@@ -512,7 +512,15 @@ class TestServingEngineCrash:
         eng = object.__new__(ServingEngine)
         eng.config = ServingConfig(max_slots=2, max_len=32)
         eng.scheduler = Scheduler(8)
-        eng.paged = False  # skeleton: no block pool to release
+        # an empty pool and tables: what freeing a slot gives back
+        from paddle_tpu.serving.block_pool import BlockPool
+
+        eng.pool = BlockPool(3, 16)
+        eng._jobs = [None, None]
+        eng._slot_blocks = [[], []]
+        eng._bt = np.zeros((2, 2), np.int32)
+        eng._slot_len = [0, 0]
+        eng._slot_win = [0, 0]
         eng._slot_req = [None, None]
         eng._slot_sampling = [False, False]
         eng._decoding = [False, False]

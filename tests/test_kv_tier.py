@@ -331,8 +331,6 @@ class TestEngineParity:
 
     def test_config_validation(self, tiny_model):
         model, _ = tiny_model
-        with pytest.raises(ValueError, match="kv_mode='paged'"):
-            serving.ServingConfig(kv_mode="contiguous", kv_tier=True)
         with pytest.raises(ValueError, match="prefix_caching"):
             serving.ServingConfig(kv_tier=True, prefix_caching=False)
         with pytest.raises(ValueError, match="kv_tier_host_blocks"):

@@ -164,9 +164,12 @@ class TestConfigValidation:
                                              "max_len"):
             serving.ServingConfig(max_len=100, block_size=16)
 
-    def test_bad_kv_mode_and_num_blocks(self):
-        with pytest.raises(ValueError, match="kv_mode"):
-            serving.ServingConfig(kv_mode="virtual")
+    @pytest.mark.parametrize("kv_mode", ["virtual", "contiguous"])
+    def test_bad_kv_mode_and_num_blocks(self, kv_mode):
+        # "paged" is the one layout; the field stays while configuration
+        # files name it, and any other value is refused with a sentence
+        with pytest.raises(ValueError, match="kv_mode must be 'paged'"):
+            serving.ServingConfig(kv_mode=kv_mode)
         with pytest.raises(ValueError, match="num_blocks"):
             serving.ServingConfig(num_blocks=1)
         with pytest.raises(ValueError, match="prefill_chunk"):
@@ -233,22 +236,6 @@ class TestPagedParity:
             got = np.asarray(req.result(timeout=1.0))
             np.testing.assert_array_equal(
                 got, _ref(model, p, max_new_tokens=5))
-
-    def test_contiguous_mode_still_serves(self, tiny_model):
-        """The A/B baseline: kv_mode='contiguous' is the pre-paging
-        engine and keeps its own parity."""
-        model, cfg = tiny_model
-        eng = serving.ServingEngine(model, max_slots=2, max_len=64,
-                                    kv_mode="contiguous")
-        rng = np.random.RandomState(SEED + 1)
-        p = _prompt(rng, cfg, 9)
-        req = eng.submit(p, max_new_tokens=6)
-        eng.run_until_idle()
-        np.testing.assert_array_equal(
-            np.asarray(req.result(timeout=1.0)),
-            _ref(model, p, max_new_tokens=6))
-        assert eng.stats()["kv_mode"] == "contiguous"
-        assert "prefill_buckets" in eng.stats()
 
 
 # ---------------------------------------------------------------------------

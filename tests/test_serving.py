@@ -490,21 +490,6 @@ class TestWarmup:
         # /healthz surfaces warmed_up
         assert eng.health()[1]["warmed_up"] is True
 
-    def test_contiguous_warmup_covers_every_bucket(self, tiny_model):
-        model, cfg = tiny_model
-        eng = serving.ServingEngine(model, max_slots=2, max_len=64,
-                                    kv_mode="contiguous")
-        info = eng.warmup()
-        assert "serving.step" in info["entries"]
-        assert any(e.startswith("serving.prefill[") for e in info["entries"])
-        before = self._serving_compiles()
-        rng = np.random.RandomState(62)
-        reqs = [eng.submit(_prompt(rng, cfg, n), max_new_tokens=3)
-                for n in (4, 20, 40)]  # one request per bucket
-        eng.run_until_idle()
-        assert all(r.status == serving.RequestStatus.COMPLETED for r in reqs)
-        assert self._serving_compiles() == before
-
     def test_warmup_requires_idle_engine(self, tiny_model):
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, max_slots=1, max_len=64)

@@ -430,12 +430,6 @@ class TestValidation:
             serving.ServingConfig(spec_k=MAX_SPEC_K + 1)
         serving.ServingConfig(spec_k=MAX_SPEC_K)  # boundary OK
 
-    def test_draft_requires_paged(self, llama_pair):
-        target, draft, _ = llama_pair
-        with pytest.raises(ValueError, match="kv_mode='paged'"):
-            serving.ServingEngine(target, draft_model=draft,
-                                  kv_mode="contiguous", max_len=128)
-
     def test_draft_with_zero_k_is_rejected(self, llama_pair):
         target, draft, _ = llama_pair
         with pytest.raises(ValueError, match="spec_k"):

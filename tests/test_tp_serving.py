@@ -168,8 +168,6 @@ class TestPartitionRules:
         model, _ = tiny_model
         with pytest.raises(ValueError, match="tp"):
             serving.ServingConfig(tp=0)
-        with pytest.raises(ValueError, match="paged"):
-            serving.ServingConfig(tp=2, kv_mode="contiguous")
         with pytest.raises(ValueError, match="tp"):
             serving.ServingEngine(model, max_slots=2, max_len=64, tp=4)
 
