@@ -18,11 +18,13 @@ slots, not ``max_len - L``. On top of the pool:
   interleaved with decode steps, so a long prompt never
   head-of-line-blocks running requests for its whole length. The chunks
   of the slots that prefill in one iteration are the rows of ONE
-  ``[P, C]`` program: every prefilling slot still advances one chunk an
-  iteration, and the weights are read once for all of them (an
-  iteration's lone chunk rides the ``[1, C]`` form of the same body: two
-  executables, ``serving.prefill_chunk`` and
-  ``serving.prefill_chunk[P]``).
+  ``[P, C]`` program: every prefilling slot advances one chunk an
+  iteration, the rows the iteration's last program has left carry the
+  earliest-admitted slot's further chunks, and the weights are read
+  once for all of them (an iteration's lone chunk rides the ``[1, C]``
+  form of the same body: two executables, ``serving.prefill_chunk`` and
+  ``serving.prefill_chunk[P]``). A prompt's first token is read from
+  the device once the iteration's decode step is dispatched behind it.
 * **preemption by recompute**: under pool pressure the latest-admitted
   request is preempted — its blocks freed, the request requeued at the
   queue front with its generated tokens folded into the prefill and its
@@ -192,12 +194,16 @@ class ServingConfig:
       paging can never run out); size it below that to oversubscribe
       slots against a fixed HBM budget (preemption keeps it safe).
     - ``prefill_chunk``: tokens per prefill chunk: a prompt
-      advances this many tokens an iteration, between decode steps, so
-      a long one never blocks the running requests for its length. One
-      fixed ``[P, prefill_chunk]`` executable serves every prompt
-      length: the next chunks of up to P prefilling slots are the rows
-      of one program, so their one pass over the weights is shared;
-      an iteration's lone chunk rides the ``[1, prefill_chunk]`` form
+      advances at least this many tokens an iteration, between decode
+      steps, and at most P times as many, so a long one never blocks
+      the running requests for its length. One fixed
+      ``[P, prefill_chunk]`` executable serves every prompt length: the
+      next chunks of up to P prefilling slots are the rows of one
+      program, so their one pass over the weights is shared, and the
+      rows that the iteration's last program has left go to further
+      chunks of the same slots, the earliest admitted first (a slot
+      under a windowed layout takes none); an iteration's lone chunk
+      rides the ``[1, prefill_chunk]`` form
       of the same body. P is not an option: ``prefill_batch_rows``
       reads it from this width, the weights' dtype and ``max_slots``
       (8 at the default 32 in bf16; 1 at 256).
@@ -434,9 +440,11 @@ class _PrefillJob:
     skip: int                    # 1 on resume: final select re-derives an
     t0: float = field(default_factory=time.perf_counter)  # already-sent token
 
-    def span(self, chunk: int) -> tuple:
-        """[start, end) of the job's next chunk of ``chunk`` tokens."""
-        return self.done, min(self.done + chunk, self.total)
+    def span(self, chunk: int, start: Optional[int] = None) -> tuple:
+        """[start, end) of the chunk of ``chunk`` tokens at ``start``,
+        the job's next one where None."""
+        start = self.done if start is None else start
+        return start, min(start + chunk, self.total)
 
 
 class ServingEngine:
@@ -587,6 +595,7 @@ class ServingEngine:
         self._n_prefix_hit_tokens = 0  # of those, adopted from the cache
         self._n_prefill_rows = 0       # chunks that rode a prefill program
         self._n_prefill_programs = 0   # prefill programs enqueued
+        self._n_prefill_fill_rows = 0  # rows beyond a slot's first
         # windowed layout (EVA) only
         self._n_window_rolls = 0       # slots that crossed into a window
         self._n_window_blocks_released = 0   # exact-key blocks given back
@@ -762,6 +771,10 @@ class ServingEngine:
         self._slot_len = [0] * B                         # host mirror of pos
         self._slot_win = [0] * B     # windowed layout: the row's window
         self._jobs: List[Optional[_PrefillJob]] = [None] * B
+        # first tokens selected by a prompt's last chunk and not yet read
+        # from the device: (a program's tokens, its last rows); empty
+        # outside an iteration
+        self._parked_tokens: List[tuple] = []
         # this engine's closures are NEW executables — their first
         # compiles are warmup, not retraces of a previous engine's
         C = int(config.prefill_chunk)
@@ -1874,31 +1887,34 @@ class ServingEngine:
         _sm.preemptions_total.inc()
         self._update_occupancy_gauges()
 
-    def _reserve_write(self, slot: int, start: int, end: int):
+    def _reserve_write(self, slot: int, start: int, end: int,
+                       allow_preempt: bool = True):
         """Make the slot's table ready for a write of positions
         ``[start, end)``: allocate what the write crosses into, COW-fork
         what it would dirty of a shared block. Under a windowed layout
         the row is rolled first when ``start`` opens a new window, and
         the summary blocks of the chunks the write completes are
         allocated with the exact keys' (nothing is ever shared there).
-        Pool pressure preempts the latest-admitted other request;
-        ``PoolExhaustedError`` when that does not help."""
+        Pool pressure preempts the latest-admitted other request, unless
+        the write is one the slot can do without (``allow_preempt``
+        False: a prefill program's spare row); ``PoolExhaustedError`` when that
+        does not help."""
         lay = self._layout
         if lay is None:
             bs = self.config.block_size
             for bi in range(start // bs, (end - 1) // bs + 1):
                 if bi >= len(self._slot_blocks[slot]):
-                    nid = self._reclaim_alloc(1, slot)[0]
+                    nid = self._reclaim_alloc(1, slot, allow_preempt)[0]
                     self._slot_blocks[slot].append(nid)
                     self._bt[slot, bi] = nid
                 else:
-                    self._ensure_writable(slot, bi)
+                    self._ensure_writable(slot, bi, allow_preempt)
             return
         if start // lay.window > self._slot_win[slot]:
             self._roll_window(slot)
         for e in lay.entries(start, end):
             if not self._bt[slot, e]:
-                nid = self._reclaim_alloc(1, slot)[0]
+                nid = self._reclaim_alloc(1, slot, allow_preempt)[0]
                 self._slot_blocks[slot].append(nid)
                 self._bt[slot, e] = nid
         self._n_summary_entries += end // lay.chunk - start // lay.chunk
@@ -1926,14 +1942,15 @@ class ServingEngine:
         self._n_window_rolls += 1
         self._n_window_blocks_released += len(released)
 
-    def _ensure_writable(self, slot: int, block_idx: int):
+    def _ensure_writable(self, slot: int, block_idx: int,
+                         allow_preempt: bool = True):
         """COW: the first write into a SHARED block forks it — allocate
         a fresh block, copy the shared content (one jitted dispatch),
         repoint the slot's table, drop the shared reference."""
         bid = self._slot_blocks[slot][block_idx]
         if self.pool.ref(bid) <= 1:
             return
-        new_id = self._reclaim_alloc(1, slot)[0]
+        new_id = self._reclaim_alloc(1, slot, allow_preempt)[0]
         with _entrypoint("serving.cow"):
             if self.spec:
                 self._pools, self._dpools = self._cow_spec_fn(
@@ -2088,15 +2105,16 @@ class ServingEngine:
                                        skip=skip)
         self._update_occupancy_gauges()
 
-    def _claim_chunk(self, slot: int, job: _PrefillJob) -> bool:
+    def _claim_chunk(self, slot: int, job: _PrefillJob) -> Optional[tuple]:
         """Make the slot's next chunk ready to ride this iteration's
-        prefill program: the cancel and deadline checks (False when one
-        of them freed the slot), then the blocks its write needs.
-        ``PoolExhaustedError`` where the pool cannot give them."""
+        prefill program: the cancel and deadline checks (None when one
+        of them freed the slot), then the blocks its write needs;
+        returns the chunk's ``(start, end)``. ``PoolExhaustedError``
+        where the pool cannot give them."""
         req = job.req
         if req.cancel_requested:
             self._free_slot(slot, RequestStatus.CANCELLED, "cancelled")
-            return False
+            return None
         if req.deadline_ts is not None \
                 and time.perf_counter() > req.deadline_ts:
             # the deadline can expire BETWEEN admission and the first
@@ -2104,9 +2122,52 @@ class ServingEngine:
             # burning chunk dispatches on a request nobody will read
             self._free_slot(slot, RequestStatus.EXPIRED, "expired",
                             error="deadline passed during prefill")
-            return False
-        self._reserve_write(slot, *job.span(self._chunk_size))
-        return True
+            return None
+        span = job.span(self._chunk_size)
+        self._reserve_write(slot, *span)
+        return span
+
+    def _claim_spare_rows(self, claimed):
+        """Give the rows that the iteration's last prefill program has
+        left (``claimed``, its rows so far, ``(slot, job, start,
+        end)``) to FURTHER chunks of the prefilling slots (each has
+        claimed its next chunk this iteration, or lost its job), the
+        earliest admitted first, each taking as many of its remaining
+        chunks as rows are left. The program costs what its width costs
+        whatever rows are live (PERF.md section 6, PR 28), so an empty
+        row is paid for and a prompt in it reaches its first token an
+        iteration sooner. Rows of one slot in one program are ordinary
+        rows: every row's K/V is scattered into the pool before any row
+        reads it (``generation.cached_attention``), which is how a
+        chunk's own positions already see each other. A spare row is
+        one the slot can do without: its blocks come without preempting
+        anybody, and ``PoolExhaustedError`` ends that slot's share.
+
+        What it adapts to is what it can see: at P = 1 no program has a
+        spare row, and a slot under a windowed layout takes none,
+        because ``_roll_window`` gives blocks back to the pool that an
+        earlier row of the same program still reads through its copy of
+        the table row."""
+        spare = self._chunk_rows - len(claimed)
+        if not spare or self._layout is not None:
+            return
+        C = self._chunk_size
+        for slot in sorted((i for i, job in enumerate(self._jobs)
+                            if job is not None),
+                           key=self._slot_seq.__getitem__):
+            job = self._jobs[slot]
+            start = job.done + C
+            while spare and start < job.total:
+                span = job.span(C, start)
+                try:
+                    self._reserve_write(slot, *span, allow_preempt=False)
+                except PoolExhaustedError:
+                    break
+                claimed.append((slot, job, *span))
+                start += C
+                spare -= 1
+            if not spare:
+                return
 
     @staticmethod
     def _chunk_entry(width: int) -> str:
@@ -2118,23 +2179,22 @@ class ServingEngine:
 
     def _chunk_args(self, rows, width: int) -> np.ndarray:
         """The host argument of one ``[width, C]`` prefill program for
-        ``rows``, at most ``width`` pairs of a slot and its job: one
-        int32 array, a row of it a program row's table row, token ids
-        and ``_ROW_COLUMNS``. A row past ``rows`` carries nothing:
-        ``valid`` 0 and a zeroed table row, so it writes the dump block
-        alone. A fresh array every call, handed over as it is: the call
-        moves it with its other arguments, where a ``jnp.asarray`` is a
-        dispatch and a transfer of its own (PERF.md, PR 27), and the
-        backend may alias a host array, so one still in flight must not
-        be written again (the table rows are copies for the same
-        reason: a windowed row is rolled while earlier chunks are in
-        flight)."""
+        ``rows``, at most ``width`` rows ``(slot, job, start, end)``, a
+        chunk ``[start, end)`` of the job's tokens: one int32 array, a
+        row of it a program row's table row, token ids and
+        ``_ROW_COLUMNS``. A row past ``rows`` carries nothing: ``valid``
+        0 and a zeroed table row, so it writes the dump block alone. A
+        fresh array every call, handed over as it is: the call moves it
+        with its other arguments, where a ``jnp.asarray`` is a dispatch
+        and a transfer of its own (PERF.md, PR 27), and the backend may
+        alias a host array, so one still in flight must not be written
+        again (the table rows are copies for the same reason: a
+        windowed row is rolled while earlier chunks are in flight)."""
         C, nb = self._chunk_size, self._bt.shape[1]
         packed = np.zeros((width, nb + C + len(_ROW_COLUMNS)), np.int32)
         packed[:, nb:nb + C] = self.config.pad_token_id
         packed[:, -2:] = _ONE_BITS          # temp, tp
-        for r, (slot, job) in enumerate(rows):
-            start, end = job.span(C)
+        for r, (slot, job, start, end) in enumerate(rows):
             p = job.req.params
             row = packed[r]
             row[:nb] = self._bt[slot]
@@ -2165,7 +2225,7 @@ class ServingEngine:
 
     def _enqueue_claimed(self, claimed):
         """One ``serving.prefill_chunk`` program for ``claimed``, at
-        most P pairs of a slot and its job; None where nothing was
+        most P rows ``(slot, job, start, end)``; None where nothing was
         enqueued. It goes out as soon as its rows are claimed, so the
         device works while the host claims the next program's (at P = 1
         every chunk is enqueued before the next slot's blocks are
@@ -2178,8 +2238,7 @@ class ServingEngine:
         from two chunks on the wide program is the cheaper."""
         # a row whose slot a later row's reservation preempted carries
         # nothing: its blocks may be that row's by now
-        rows = [(slot, job) for slot, job in claimed
-                if self._jobs[slot] is job]
+        rows = [row for row in claimed if self._jobs[row[0]] is row[1]]
         if not rows:
             return None
         tc0 = time.perf_counter_ns()
@@ -2191,34 +2250,38 @@ class ServingEngine:
                 token, entry = self._enqueue_chunks(self._chunk_args(
                     rows, self._chunk_rows if len(rows) > 1 else 1))
         except Exception as e:  # noqa: BLE001 — engine must survive
-            for slot, _ in rows:
+            for slot in {row[0] for row in rows}:
                 self._free_slot(slot, RequestStatus.FAILED, "failed",
                                 error=repr(e))
             return None
         self._n_prefill_rows += len(rows)
         self._n_prefill_programs += 1
+        # a job's ``done`` moves when its rows are booked, after every
+        # program of the iteration is out: a row that starts past it is
+        # a spare row's
+        self._n_prefill_fill_rows += sum(
+            start != job.done for _, job, start, _ in rows)
         return rows, token, entry, tc0, time.perf_counter_ns()
 
     def _book_chunks(self, ran):
         """The bookkeeping of the rows of this iteration's prefill
         programs (``_enqueue_claimed``'s records), once all of them are
-        enqueued: a chunk that ends its prompt also selects the first
-        token (generate's key chain) and flips the slot into the decode
-        batch, its prompt blocks registered with the prefix cache BEFORE
-        any decode write can dirty them (COW keeps them pristine). One
-        device-to-host read a program, and only where a row of it is
-        last."""
+        enqueued. A chunk that ends its prompt also selected the first
+        token (generate's key chain) and wrote it into the slot's state
+        row on the device, so the slot flips into the decode batch here,
+        on the host's side alone (``_finish_prefill``), and its token is
+        parked: the host's copy is read once this iteration's step is
+        dispatched (``_deliver_first_tokens``), so the device never
+        drains between a prompt's last chunk and the step behind it."""
         from ..observability import perf as _perf
-        C = self._chunk_size
         for rows, token, entry, tc0, tc1 in ran:
             _sm.prefill_chunk_seconds.observe((tc1 - tc0) / 1e9)
-            toks = None
-            for r, (slot, job) in enumerate(rows):
+            last = []
+            for r, (slot, job, start, end) in enumerate(rows):
                 if self._jobs[slot] is not job:
                     # preempted, after its program went out, by the
                     # reservation of a later program's row: recomputed
                     continue
-                start, end = job.span(C)
                 job.done = end
                 # the rows of one program share its two clock reads
                 _trace.complete(
@@ -2232,28 +2295,63 @@ class ServingEngine:
                 if end < job.total:
                     continue
                 try:
-                    if toks is None:
-                        toks = np.asarray(token)
-                    self._finish_prefill(slot, job, int(toks[r]))
+                    self._finish_prefill(slot, job)
+                    last.append((r, slot, job))
                 except Exception as e:  # noqa: BLE001 — that slot alone
                     self._free_slot(slot, RequestStatus.FAILED, "failed",
                                     error=repr(e))
+            if last:
+                self._parked_tokens.append((token, last))
 
-    def _finish_prefill(self, slot: int, job: _PrefillJob, tok0: int):
-        """The slot's last chunk ran and selected ``tok0``."""
-        req, p = job.req, job.req.params
+    def _finish_prefill(self, slot: int, job: _PrefillJob):
+        """The slot's last chunk is enqueued: the host's side of its
+        flip into the decode batch. The prompt's blocks are registered
+        with the prefix cache BEFORE any decode write can dirty them
+        (COW keeps them pristine): the step's reservation comes after
+        this."""
+        req = job.req
         if self.prefix_cache is not None:
             bs = self.config.block_size
             n_reg = min(int(req.prompt.shape[0]), job.total)
             self.prefix_cache.insert(
                 job.tokens, n_reg,
                 self._slot_blocks[slot][:-(-n_reg // bs)])
-        now = time.perf_counter()
-        _sm.prefill_seconds.observe(now - job.t0)
         self._jobs[slot] = None
         self._decoding[slot] = True
         self._slot_len[slot] = job.total
-        self._slot_sampling[slot] = bool(p.do_sample)
+        self._slot_sampling[slot] = bool(req.params.do_sample)
+
+    def _deliver_first_tokens(self):
+        """Read the parked first tokens (``_book_chunks``), one
+        device-to-host read a program, and deliver each to its request.
+        Called with the iteration's step already dispatched, and on
+        every other way out of the iteration: a prefill program is done
+        long before the step behind it, so a first token never waits
+        for a decode step it does not depend on. A slot that was
+        cancelled or preempted since its chunk was booked gets nothing
+        (a preempted request's resumed prefill selects the token
+        again); a request that ends on its first token frees its slot
+        here, and the row it has in the step is dropped when the step's
+        tokens are emitted."""
+        parked, self._parked_tokens = self._parked_tokens, []
+        for token, last in parked:
+            toks = None
+            for r, slot, job in last:
+                if self._slot_req[slot] is not job.req:
+                    continue
+                try:
+                    if toks is None:
+                        toks = np.asarray(token)
+                    self._first_token(slot, job, int(toks[r]))
+                except Exception as e:  # noqa: BLE001 — that slot alone
+                    self._free_slot(slot, RequestStatus.FAILED, "failed",
+                                    error=repr(e))
+
+    def _first_token(self, slot: int, job: _PrefillJob, tok0: int):
+        """The slot's last chunk selected ``tok0``, now on the host."""
+        req = job.req
+        now = time.perf_counter()
+        _sm.prefill_seconds.observe(now - job.t0)
         req.prefill_done_ts = now
         req._tr_end("prefill", tokens=job.total)
         req._tr_begin("decode")
@@ -2312,10 +2410,13 @@ class ServingEngine:
     # -- the iteration -------------------------------------------------------
     def step(self) -> bool:
         """One engine iteration: admit into free slots, advance every
-        in-flight chunked prefill by one chunk, then (if any
-        slot is decoding) run the single jitted decode step for the
-        whole pool and deliver/retire per-slot tokens. Returns True when
-        any work happened.
+        in-flight chunked prefill by one chunk (and, in the rows its
+        prefill program has left, the earliest-admitted ones by
+        further chunks), then (if any slot is decoding) run the single
+        jitted decode step for the whole pool and deliver/retire
+        per-slot tokens; a prompt that ended this iteration is in that
+        step, and its first token is delivered once the step is
+        dispatched. Returns True when any work happened.
 
         A ``PoolExhaustedError`` escaping the iteration (every in-loop
         exhaustion is normally absorbed by eviction/preemption — an
@@ -2350,8 +2451,9 @@ class ServingEngine:
             n_hit, n_prompt, n_pre = (self._n_prefix_hit_tokens,
                                       self._n_prompt_tokens,
                                       self._preempt_count)
-            n_rows, n_programs = (self._n_prefill_rows,
-                                  self._n_prefill_programs)
+            n_rows, n_programs, n_fill = (self._n_prefill_rows,
+                                          self._n_prefill_programs,
+                                          self._n_prefill_fill_rows)
             self._last_progress_ts = ph.open("engine.admit") / 1e9
             worked = False
             try:
@@ -2361,7 +2463,8 @@ class ServingEngine:
                     "prompt_tokens": self._n_prompt_tokens - n_prompt})
                 # every prefilling slot advances one chunk, in slot
                 # order; the chunks ride one program, P rows each,
-                # which goes out as soon as its rows are claimed
+                # which goes out as soon as its rows are claimed; the
+                # rows the last program has left carry further chunks
                 claimed, ran = [], []
                 for slot in range(self.config.max_slots):
                     job = self._jobs[slot]
@@ -2369,8 +2472,9 @@ class ServingEngine:
                         continue
                     worked = True
                     try:
-                        if self._claim_chunk(slot, job):
-                            claimed.append((slot, job))
+                        span = self._claim_chunk(slot, job)
+                        if span:
+                            claimed.append((slot, job, *span))
                     except PoolExhaustedError:
                         self._preempt(slot)  # retried from the queue front
                     except Exception as e:  # noqa: BLE001
@@ -2380,14 +2484,22 @@ class ServingEngine:
                         ran.append(self._enqueue_claimed(claimed))
                         claimed = []
                 if claimed:
+                    self._claim_spare_rows(claimed)
                     ran.append(self._enqueue_claimed(claimed))
                 self._book_chunks([r for r in ran if r is not None])
-                # rows and programs, on the iterations that enqueued any
+                if self.spec:
+                    # the speculative lane sizes its bundles from what a
+                    # request has been given: it reads before it dispatches
+                    self._deliver_first_tokens()
+                # rows, programs and spare rows filled, on the iterations
+                # that enqueued any
                 ph.mark("engine.reserve", ph.on
                         and self._n_prefill_programs > n_programs and {
                             "rows": self._n_prefill_rows - n_rows,
                             "programs": self._n_prefill_programs
-                            - n_programs} or None)
+                            - n_programs,
+                            "fill": self._n_prefill_fill_rows - n_fill}
+                        or None)
                 active = [i for i, r in enumerate(self._slot_req)
                           if r is not None and self._decoding[i]]
                 # cancellation between steps: drop flagged slots without
@@ -2457,7 +2569,13 @@ class ServingEngine:
                         self._pb, self._pools, self._state, bt_step,
                         np.asarray(any_sampling, bool), active_mask)
                 ph.mark("engine.wait", dispatch_args)
+                # the step is queued behind the prefill programs: their
+                # first tokens are read now, and wait for none of it
+                self._deliver_first_tokens()
                 toks_np = np.asarray(toks)  # the step's ONE device->host sync
+                # a request that ended on its first token has a row in
+                # the step and no use for it
+                active = [i for i in active if self._slot_req[i] is not None]
                 now_ns = ph.mark("engine.emit") or time.perf_counter_ns()
                 now = now_ns / 1e9
                 step_s = (now_ns - t0_ns) / 1e9
@@ -2490,6 +2608,8 @@ class ServingEngine:
                     self._finish_or_keep(i, req, t, now)
                 return True
             finally:
+                # whatever way out: no first token stays parked
+                self._deliver_first_tokens()
                 self._update_occupancy_gauges()
                 self.pool.set_gauges()
                 # an iteration that only admitted (and lost the request
@@ -3059,9 +3179,12 @@ class ServingEngine:
             "prompt_tokens": self._n_prompt_tokens,
             "prefix_hit_tokens": self._n_prefix_hit_tokens,
             "preemptions": self._preempt_count,
-            # chunks that rode a prefill program, and the programs
+            # chunks that rode a prefill program, the programs, and
+            # the chunks that rode as a slot's second or later row of
+            # its iteration (the last program's spare rows)
             "prefill_rows": self._n_prefill_rows,
             "prefill_programs": self._n_prefill_programs,
+            "prefill_fill_rows": self._n_prefill_fill_rows,
         }
         if self._layout is not None:
             # slots that crossed into a new window, the exact-key blocks

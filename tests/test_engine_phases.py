@@ -11,6 +11,10 @@ Oracles:
   ``counters()`` no key, that nothing reads.
 - GAUGES: the ``kv_blocks_*`` gauges are set once an iteration and equal
   ``BlockPool.stats()``; no pool operation reduces over the pool.
+- FIRST TOKEN AFTER THE DISPATCH: a prompt's last chunk leaves its first
+  token on the device; the slot joins the step at once, the token is
+  read with the step already enqueued, and no way out of the iteration
+  leaves one unread.
 - HOST ARGUMENTS: a prefill program (one row or several) and a step hand
   their host arrays to the executable as they are; nothing is made with
   ``jnp.asarray`` a chunk or a step.
@@ -120,9 +124,10 @@ class TestPhases:
     def test_a_span_carries_only_the_args_a_metric_reads(self, served):
         # iter ties the lanes together; preempted is preemptions.*;
         # the two token counts are prefix_hit_share.chat; kv_blocks is
-        # decode_live_blocks_per_step.*; rows and programs are
-        # prefill_rows_per_iter.* and prefill_programs_per_iter.*, on
-        # the iterations that enqueued a prefill program and no other
+        # decode_live_blocks_per_step.*; rows, programs and fill are
+        # prefill_rows_per_iter.*, prefill_programs_per_iter.* and
+        # prefill_fill_rows_per_iter.*, on the iterations that enqueued
+        # a prefill program and no other
         _, _, events = served
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
@@ -138,7 +143,8 @@ class TestPhases:
             if e["name"] == "engine.prefill":
                 ran = e["args"]["iter"] in chunk_iters
                 assert set(e["args"]) == (
-                    {"iter", "rows", "programs"} if ran else {"iter"}), e
+                    {"iter", "rows", "programs", "fill"} if ran
+                    else {"iter"}), e
                 continue
             assert set(e["args"]) == want.get(e["name"], {"iter"}), e
         assert chunk_iters
@@ -169,8 +175,10 @@ class TestPhases:
                                  "prompt_tokens"},
                 "engine.dispatch": {"iter", "kv_blocks", "summary_blocks"}}
         for e in lane:
-            # (engine.prefill: rows and programs, as on any paged engine)
-            assert set(e["args"]) - {"rows", "programs"} \
+            # (engine.prefill: rows, programs and fill, as on any paged
+            # engine; a windowed slot takes no spare row, so fill is 0)
+            assert e["args"].get("fill", 0) == 0
+            assert set(e["args"]) - {"rows", "programs", "fill"} \
                 == want.get(e["name"], {"iter"}), e
         # six steps, the queries at positions 37..42: two windows behind
         # (a block of four summaries each), the window's 6..11 keys
@@ -195,11 +203,12 @@ class TestPhases:
 
     def test_one_prefill_chunk_span_a_live_row_and_the_sums_are_the_counters(
             self, served):
-        """``engine.prefill`` carries ``rows`` (live rows enqueued) and
-        ``programs`` on the iterations that enqueued any; each request
-        keeps one ``prefill_chunk`` span a chunk, with the args it
-        always had, and the rows of one program share its clock
-        reads."""
+        """``engine.prefill`` carries ``rows`` (live rows enqueued),
+        ``programs`` and ``fill`` (the rows that were a slot's second
+        or later of the iteration: the last program's spare rows) on
+        the iterations that enqueued any; each request keeps one
+        ``prefill_chunk`` span a chunk, with the args it always had,
+        and the rows of one program share its clock reads."""
         eng, reqs, events = served
         prefill = {e["args"]["iter"]: e["args"] for e in events
                    if e["name"] == "engine.prefill" and "rows" in e["args"]}
@@ -215,17 +224,32 @@ class TestPhases:
         for it, args in prefill.items():
             assert args["rows"] == len(chunks[it])
             assert args["programs"] == -(-args["rows"] // P)
+            # every prefilling slot has one row, and a spare row is a
+            # further chunk of one of them, starting where its last ended
             assert len({e["args"]["slot"] for e in chunks[it]}) \
-                == args["rows"]
-        # the three requests admitted together rode two programs, and the
-        # two that shared one share its two clock reads
-        assert max(a["rows"] for a in prefill.values()) == 3
-        it = next(i for i, a in prefill.items() if a["rows"] == 3)
-        assert len({(e["ts_ns"], e["dur_ns"]) for e in chunks[it]}) == 2
+                == args["rows"] - args["fill"]
+            for slot in {e["args"]["slot"] for e in chunks[it]}:
+                mine = [e["args"] for e in chunks[it]
+                        if e["args"]["slot"] == slot]
+                assert all(a["end"] == b["start"]
+                           for a, b in zip(mine, mine[1:]))
+                assert [a["last"] for a in mine[:-1]] \
+                    == [False] * (len(mine) - 1)
+        # the first prompt, alone, rode both of its chunks in one program;
+        # the three requests admitted together rode two programs, the
+        # second one's spare row the third request's last chunk, and
+        # the rows of one program share its two clock reads
+        assert [prefill[i] for i in sorted(prefill)] == [
+            {"iter": min(prefill), "rows": 2, "programs": 1, "fill": 1},
+            {"iter": max(prefill), "rows": 4, "programs": 2, "fill": 1}]
+        assert len({(e["ts_ns"], e["dur_ns"])
+                    for e in chunks[max(prefill)]}) == 2
         c = eng.counters()
         assert c["prefill_rows"] == sum(a["rows"] for a in prefill.values())
         assert c["prefill_programs"] \
             == sum(a["programs"] for a in prefill.values())
+        assert c["prefill_fill_rows"] \
+            == sum(a["fill"] for a in prefill.values())
         # every chunk of every request is a row: 32 | 5+i, 21 -> 16s
         assert c["prefill_rows"] == 2 + 1 + 1 + 2
 
@@ -421,7 +445,192 @@ class TestCounters:
         assert set(c) == {"steps", "slots", "slot_steps", "queue_depth",
                           "prompt_tokens", "prefix_hit_tokens",
                           "preemptions", "prefill_rows",
-                          "prefill_programs"}
+                          "prefill_programs", "prefill_fill_rows"}
+
+
+class TestFirstTokenAfterDispatch:
+    def test_the_step_is_enqueued_before_the_first_token_is_read(
+            self, served):
+        """Within the iteration of a prompt's last chunk: the step's
+        enqueue has returned (``engine.dispatch`` ended) before the
+        request's ``first_token``, which falls inside ``engine.wait``
+        and so before the step's own tokens."""
+        _, reqs, events = served
+        by_name = {n: {e["args"]["iter"]: e for e in events
+                       if e["name"] == n}
+                   for n in ("engine.dispatch", "engine.wait")}
+        seen = 0
+        for req in reqs:
+            mine = [e for e in events if e["trace"] == req.trace]
+            (first,) = [e for e in mine if e["name"] == "first_token"]
+            (last,) = [e for e in mine if e["name"] == "prefill_chunk"
+                       and e["args"]["last"]]
+            it = last["args"]["iter"]
+            disp, wait = by_name["engine.dispatch"][it], \
+                by_name["engine.wait"][it]
+            assert last["ts_ns"] + last["dur_ns"] <= disp["ts_ns"]
+            assert disp["ts_ns"] + disp["dur_ns"] <= first["ts_ns"] \
+                <= wait["ts_ns"] + wait["dur_ns"]
+            seen += 1
+        assert seen == 4
+
+    def test_the_cache_holds_the_prompt_before_the_step_reserves(
+            self, tiny_model):
+        """insert, then the decode write's reservation (a COW fork of
+        the half block the cache now shares), then the step, then the
+        token: the host's side of a finished prefill is booked at once,
+        only the read waits."""
+        model, cfg = tiny_model
+        eng = _engine(model)
+        order = []
+
+        def spy(obj, name, tag, when=lambda *a, **k: True):
+            real = getattr(obj, name)
+
+            def wrapped(*a, **k):
+                if when(*a, **k):
+                    order.append(tag)
+                return real(*a, **k)
+            setattr(obj, name, wrapped)
+
+        spy(eng.prefix_cache, "insert", "insert")
+        spy(eng, "_reserve_write", "reserve",
+            lambda slot, start, end, **k: end - start == 1)
+        spy(eng, "_step_fn", "step")
+        spy(eng, "_first_token", "token")
+        forks = eng.pool.stats()["cow_forks"]
+        req = eng.submit(_prompt(np.random.RandomState(21), cfg, BLOCK + 5),
+                         max_new_tokens=3)
+        assert eng.step()
+        assert order == ["insert", "reserve", "step", "token"]
+        assert eng.pool.stats()["cow_forks"] == forks + 1
+        assert len(req.output_tokens) == 2 and eng._parked_tokens == []
+
+    @pytest.mark.parametrize("how", ["max_new_tokens", "eos"])
+    def test_a_request_that_ends_on_its_first_token_wastes_one_row(
+            self, tiny_model, how):
+        """Its slot is in the step that was dispatched before the token
+        was read: the slot is free when the step's tokens are emitted,
+        the row is dropped, and exactly one token went out."""
+        model, cfg = tiny_model
+        rng = np.random.RandomState(22)
+        long_, short = _prompt(rng, cfg, 9), _prompt(rng, cfg, 7)
+        eng = _engine(model)
+        probe = eng.submit(short, max_new_tokens=1)
+        eng.run_until_idle()
+        (tok0,) = probe.output_tokens
+        params = {"max_new_tokens": 1} if how == "max_new_tokens" \
+            else {"max_new_tokens": 8, "eos_token_id": tok0}
+        eng = _engine(model, prefix_caching=False)
+        other = eng.submit(long_, max_new_tokens=4)
+        assert eng.step()       # other: first token and one more
+        before = eng.counters()
+        req = eng.submit(short, **params)
+        assert eng.step()
+        after = eng.counters()
+        assert req.status == serving.RequestStatus.COMPLETED
+        assert list(req.output_tokens) == [tok0]
+        assert req.slot is not None and eng._slot_req[req.slot] is None
+        # the step ran with both rows; one of them is counted
+        assert after["steps"] - before["steps"] == 1
+        assert after["slot_steps"] - before["slot_steps"] == 1
+        assert len(other.output_tokens) == 3
+        eng.run_until_idle()
+        assert len(other.output_tokens) == 4
+        assert eng.pool.used_blocks == 0
+
+    def test_a_failed_dispatch_still_delivers_the_first_token(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = _engine(model)
+
+        def refuse(*a):
+            raise RuntimeError("dispatch refused")
+
+        eng._step_fn = refuse
+        req = eng.submit(_prompt(np.random.RandomState(23), cfg, 20),
+                         max_new_tokens=3)
+        with pytest.raises(RuntimeError, match="dispatch refused"):
+            eng.step()
+        assert len(req.output_tokens) == 1 and eng._parked_tokens == []
+        assert req.first_token_ts is not None
+
+    def test_a_slot_cancelled_before_the_read_gets_nothing(self, tiny_model):
+        """Cancelled between its last chunk's booking and the step: the
+        iteration leaves by ``not active``, nothing stays parked, and
+        nothing is delivered to a request that left its slot."""
+        model, cfg = tiny_model
+        eng = _engine(model)
+        real = eng._finish_prefill
+
+        def finish(slot, job):
+            real(slot, job)
+            job.req.cancel()
+
+        eng._finish_prefill = finish
+        req = eng.submit(_prompt(np.random.RandomState(24), cfg, 20),
+                         max_new_tokens=3)
+        before = eng.counters()["steps"]
+        assert eng.step()
+        assert req.status == serving.RequestStatus.CANCELLED
+        assert list(req.output_tokens) == [] and eng._parked_tokens == []
+        assert eng.counters()["steps"] == before and eng.busy_slots() == 0
+
+    def test_a_slot_preempted_before_the_read_is_served_from_the_start(
+            self, tiny_model):
+        """The decode write's reservation preempts the slot whose first
+        token is parked: the token is not delivered, the request goes
+        back to the queue's front as one that has produced nothing, and
+        its tokens are those of an undisturbed run, each once."""
+        model, cfg = tiny_model
+        prompt = _prompt(np.random.RandomState(25), cfg, 20)
+        eng = _engine(model)
+        want = eng.submit(prompt, max_new_tokens=4)
+        eng.run_until_idle()
+        eng = _engine(model)
+        real, fired = eng._reserve_write, []
+
+        def reserve(slot, start, end, **kw):
+            if end - start == 1 and not fired:
+                fired.append(slot)
+                raise serving.block_pool.PoolExhaustedError("no block")
+            return real(slot, start, end, **kw)
+
+        eng._reserve_write = reserve
+        req = eng.submit(prompt, max_new_tokens=4)
+        assert eng.step()
+        assert fired and req.preempt_count == 1 and req.slot is None
+        assert list(req.output_tokens) == [] and eng._parked_tokens == []
+        eng.run_until_idle()
+        assert list(req.output_tokens) == list(want.output_tokens)
+
+    def test_the_speculative_lane_reads_before_it_dispatches(
+            self, tiny_model):
+        """Its bundle widths come from what each request has been given
+        (``_row_spec_len``), so a speculative engine reads a first token
+        where the parent did: before the round is dispatched."""
+        from paddle_tpu import generation
+
+        model, cfg = tiny_model
+        eng = _engine(model, draft_model=generation.truncated_draft(model, 1),
+                      spec_k=2)
+        t0 = tracing.events()[-1]["ts_ns"] + 1
+        req = eng.submit(_prompt(np.random.RandomState(26), cfg, 20),
+                         max_new_tokens=5)
+        eng.run_until_idle()
+        assert len(req.output_tokens) == 5
+        evs = [e for e in tracing.events() if e["ts_ns"] >= t0]
+        (first,) = [e for e in evs if e["trace"] == req.trace
+                    and e["name"] == "first_token"]
+        disp = min((e for e in evs if e["name"] == "engine.dispatch"
+                    and e["tid"] == threading.get_ident()),
+                   key=lambda e: e["ts_ns"])
+        assert first["ts_ns"] <= disp["ts_ns"]
+        plain = _engine(model)   # the plain engine's tokens
+        ref = plain.submit(_prompt(np.random.RandomState(26), cfg, 20),
+                           max_new_tokens=5)
+        plain.run_until_idle()
+        assert list(req.output_tokens) == list(ref.output_tokens)
 
 
 class TestHostArguments:
@@ -458,8 +667,9 @@ class TestHostArguments:
             assert len(req.output_tokens) == n_new
             made.append(sorted(calls))
         assert made[0] == made[1]
-        hot = {"_claim_chunk", "_chunk_args", "_enqueue_claimed",
-               "_book_chunks", "_finish_prefill", "_step_impl"}
+        hot = {"_claim_chunk", "_claim_spare_rows", "_chunk_args",
+               "_enqueue_claimed", "_book_chunks", "_finish_prefill",
+               "_deliver_first_tokens", "_first_token", "_step_impl"}
         assert not hot & set(made[1])
         # the batched call: three prompts whose chunks share programs
         del calls[:]

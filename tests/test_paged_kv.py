@@ -758,6 +758,29 @@ def _one_row_engine(monkeypatch, model, **kw):
     return eng
 
 
+def _schedule(lengths, C, P):
+    """The prefill programs of prompts admitted together, slot order
+    the admission order, an iteration a tuple ``(rows, programs,
+    fill)``: every prefilling slot's next chunk, P rows a program; the
+    rows the last program has left go to further chunks, the earliest
+    slot first; a lone chunk is a program of one row."""
+    left = [-(-n // C) for n in lengths]
+    out = []
+    while any(left):
+        live = [i for i, k in enumerate(left) if k]
+        programs = -(-len(live) // P)
+        spare = programs * P - len(live) if P > 1 else 0
+        fill = 0
+        for i in live:
+            left[i] -= 1
+        for i in live:
+            take = min(spare - fill, left[i])
+            left[i] -= take
+            fill += take
+        out.append((len(live) + fill, programs, fill))
+    return out
+
+
 def _serve_all(eng, prompts, specs):
     reqs = [eng.submit(p, **s) for p, s in zip(prompts, specs)]
     eng.run_until_idle(max_steps=5000)
@@ -827,16 +850,23 @@ class TestBatchedPrefill:
         c = eng.counters()
         chunks = sum(-(-L // 16) for L in self.LENGTHS[:n])
         assert c["prefill_rows"] == chunks
-        # the rows of an iteration share programs of four
-        live = [sum(L > 16 * i for L in self.LENGTHS[:n]) for i in range(5)]
-        assert c["prefill_programs"] == sum(-(-k // 4) for k in live)
+        # the rows of an iteration share programs of four, and the rows
+        # the last program has left carry further chunks: (3, 1) chunks
+        # are one program, (3, 1, 1, 3) two, (3, 1, 1, 3, 5) four
+        plan = _schedule(self.LENGTHS[:n], 16, 4)
+        assert sum(r for r, _, _ in plan) == chunks
+        assert c["prefill_programs"] == sum(p for _, p, _ in plan) \
+            == {2: 1, 4: 2, 5: 4}[n]
+        assert c["prefill_fill_rows"] == sum(f for _, _, f in plan) \
+            == {2: 2, 4: 2, 5: 5}[n]
         assert eng.pool.used_blocks == len(eng.prefix_cache)
 
     def test_a_lone_chunk_rides_the_one_row_form_of_the_program(
             self, tiny_model):
-        """Two prompts of five chunks and of one: the first iteration
-        is one [4, C] program, the four after it a [1, C] program
-        each."""
+        """Two prompts of eight chunks and of one: the first iteration
+        is one [4, C] program (the long prompt's first three chunks
+        beside the short one's only chunk), the second a [4, C] program
+        of its next four, and the lone last chunk a [1, C] program."""
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, **self.KW)
         seen = []
@@ -844,13 +874,16 @@ class TestBatchedPrefill:
         eng._chunk_fn = lambda pb, pools, state, bt, *a: (
             seen.append(bt.shape[0]) or real(pb, pools, state, bt, *a))
         rng = np.random.RandomState(SEED + 29)
-        prompts = [_prompt(rng, cfg, L) for L in (70, 9)]
+        prompts = [_prompt(rng, cfg, L) for L in (120, 9)]
         got = _serve_all(eng, prompts, [dict(max_new_tokens=3)] * 2)
-        assert seen == [4, 1, 1, 1, 1]
+        assert seen == [4, 4, 1]
+        assert _schedule((120, 9), 16, 4) \
+            == [(4, 1, 2), (4, 1, 3), (1, 1, 0)]
         for g, p in zip(got, prompts):
             assert g == list(_ref(model, p, max_new_tokens=3))
         c = eng.counters()
-        assert (c["prefill_rows"], c["prefill_programs"]) == (6, 5)
+        assert (c["prefill_rows"], c["prefill_programs"],
+                c["prefill_fill_rows"]) == (9, 3, 5)
 
     def test_gpt_rows_match_generate(self):
         paddle.seed(3)
@@ -875,11 +908,11 @@ class TestBatchedPrefill:
         eng.step()
         real, fired = eng._reserve_write, []
 
-        def reserve(slot, start, end):
+        def reserve(slot, start, end, **kw):
             if slot == 1 and not fired:
                 fired.append(slot)
                 raise PoolExhaustedError("no block for this row")
-            return real(slot, start, end)
+            return real(slot, start, end, **kw)
 
         eng._reserve_write = reserve
         before = eng.counters()
@@ -887,11 +920,14 @@ class TestBatchedPrefill:
         after = eng.counters()
         assert fired and after["preemptions"] - before["preemptions"] == 1
         assert reqs[1].slot is None and reqs[1].preempt_count == 1
-        # the other two rode the iteration's one program
-        assert after["prefill_rows"] - before["prefill_rows"] == 2
+        # the other two rode the iteration's one program (the first
+        # step gave slot 0 its second chunk in a spare row, so this is
+        # its last), and a row it had left went to the later one's last
+        assert after["prefill_rows"] - before["prefill_rows"] == 3
+        assert after["prefill_fill_rows"] - before["prefill_fill_rows"] == 1
         assert after["prefill_programs"] - before["prefill_programs"] == 1
-        assert [eng._jobs[r.slot].done for r in (reqs[0], reqs[2])] \
-            == [32, 32]
+        assert [eng._jobs[r.slot] for r in (reqs[0], reqs[2])] == [None] * 2
+        assert all(eng._decoding[r.slot] for r in (reqs[0], reqs[2]))
         eng.run_until_idle()
         for r, p in zip(reqs, prompts):
             assert list(r.output_tokens) == list(
@@ -910,18 +946,21 @@ class TestBatchedPrefill:
         eng.step()
         real, fired = eng._reserve_write, []
 
-        def reserve(slot, start, end):
+        def reserve(slot, start, end, **kw):
             if slot == 1 and not fired:
                 fired.append(slot)
                 eng._preempt(0)     # what _reclaim_alloc does under pressure
-            return real(slot, start, end)
+            return real(slot, start, end, **kw)
 
         eng._reserve_write = reserve
         before = eng.counters()
         eng.step()
         after = eng.counters()
         assert fired and reqs[0].preempt_count == 1
-        assert after["prefill_rows"] - before["prefill_rows"] == 1
+        # row 1 and, in the rows left, its two further chunks; row 0,
+        # preempted, neither rides nor is given a spare row
+        assert after["prefill_rows"] - before["prefill_rows"] == 3
+        assert after["prefill_fill_rows"] - before["prefill_fill_rows"] == 2
         eng.run_until_idle()
         for r, p in zip(reqs, prompts):
             assert list(r.output_tokens) == list(
@@ -946,9 +985,9 @@ class TestBatchedPrefill:
         events = []
         reserve, enqueue = eng._reserve_write, eng._enqueue_chunks
 
-        def reserving(slot, start, end):
+        def reserving(slot, start, end, **kw):
             events.append(slot)
-            return reserve(slot, start, end)
+            return reserve(slot, start, end, **kw)
 
         def enqueuing(packed):
             valid = packed[:, -len(engine_mod._ROW_COLUMNS):][:, 1]
@@ -958,8 +997,12 @@ class TestBatchedPrefill:
         eng._reserve_write, eng._enqueue_chunks = reserving, enqueuing
         eng.step()
         one, four = ("program", 1), ("program", 4)
+        # (at P = 4 the second program's three spare rows go to slot
+        # 0's two further chunks and slot 1's next, reserved after every
+        # slot's first chunk)
         assert events == ([0, one, 1, one, 2, one, 3, one, 4, one]
-                          if one_row else [0, 1, 2, 3, four, 4, one])
+                          if one_row else [0, 1, 2, 3, four, 4, 0, 0, 1, four,
+                                           0])   # slot 0's first decode write
         eng.run_until_idle()
         for r, p in zip(reqs, prompts):
             assert list(r.output_tokens) == list(
@@ -977,18 +1020,21 @@ class TestBatchedPrefill:
         reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
         real, fired = eng._reserve_write, []
 
-        def reserve(slot, start, end):
+        def reserve(slot, start, end, **kw):
             if slot == 4 and not fired:
                 fired.append(slot)
                 eng._preempt(1)     # what _reclaim_alloc does under pressure
-            return real(slot, start, end)
+            return real(slot, start, end, **kw)
 
         eng._reserve_write = reserve
         eng.step()
         assert fired and reqs[1].preempt_count == 1 and reqs[1].slot is None
-        assert eng.counters()["prefill_rows"] == 5
-        assert [eng._jobs[r.slot].done for r in reqs if r.slot is not None] \
-            == [16] * 4
+        # five first chunks, and the second program's three spare rows:
+        # two end slot 0's prompt, one goes to slot 2 (slot 1 is gone)
+        c = eng.counters()
+        assert (c["prefill_rows"], c["prefill_fill_rows"]) == (8, 3)
+        assert eng._jobs[reqs[0].slot] is None
+        assert [eng._jobs[r.slot].done for r in reqs[2:]] == [32, 16, 16]
         eng.run_until_idle()
         for r, p in zip(reqs, prompts):
             assert list(r.output_tokens) == list(
@@ -1011,7 +1057,11 @@ class TestBatchedPrefill:
         assert reqs[0].status == serving.RequestStatus.CANCELLED
         assert reqs[2].status == serving.RequestStatus.EXPIRED
         assert "prefill" in reqs[2].error
-        assert eng.counters()["prefill_rows"] - before["prefill_rows"] == 1
+        # the one slot left takes its next chunk and, in the rows the
+        # other two no longer claim, its last two
+        after = eng.counters()
+        assert after["prefill_rows"] - before["prefill_rows"] == 3
+        assert after["prefill_fill_rows"] - before["prefill_fill_rows"] == 2
         eng.run_until_idle()
         assert list(reqs[1].output_tokens) == list(
             _ref(model, prompts[1], max_new_tokens=3))
@@ -1124,3 +1174,247 @@ class TestBatchedPrefill:
             assert stats1["compiles"] == stats0[n]["compiles"]
             assert stats1["retraces"] == stats0[n]["retraces"]
         assert recompile.total_compiles() == total0
+
+
+# ---------------------------------------------------------------------------
+# the rows a prefill program has left carry the earliest slot's next chunks
+# ---------------------------------------------------------------------------
+
+
+def _program_rows(eng, seen):
+    """Record every prefill program's live rows as ``(slot, pos0,
+    is_last)`` in ``seen``, one list a program."""
+    from paddle_tpu.serving import engine as engine_mod
+
+    enqueue = eng._enqueue_chunks
+
+    def enqueuing(packed):
+        cols = packed[:, -len(engine_mod._ROW_COLUMNS):]
+        seen.append([(int(c[2]), int(c[0]), bool(c[3]))
+                     for c in cols if c[1] > 0])
+        return enqueue(packed)
+
+    eng._enqueue_chunks = enqueuing
+
+
+def _fill_family(family):
+    paddle.seed(7)
+    if family == "gpt":
+        return GPTForCausalLM(GPTConfig.tiny(max_position_embeddings=128)), {}
+    model = LlamaForCausalLM(LlamaConfig.tiny(
+        num_key_value_heads=2, max_position_embeddings=128))
+    return model, ({"kv_format": "int8"} if family.endswith("int8") else {})
+
+
+class TestSpareRows:
+    KW = dict(max_slots=4, max_len=128, block_size=16, prefill_chunk=16)
+
+    @pytest.mark.parametrize("kernel", ["0", "1"], ids=["xla", "kernel"])
+    @pytest.mark.parametrize("family", ["gpt", "llama_gqa",
+                                        "llama_gqa_int8"])
+    def test_several_chunks_a_program_match_one_chunk_an_iteration(
+            self, monkeypatch, family, kernel):
+        """A prompt alone in the engine rides ``[4, C]`` four chunks a
+        program, each row reading what the rows before it wrote in the
+        same program; greedy and sampled it gives the tokens of the same
+        prompt prefilled one chunk an iteration, and (float pools) the
+        same keys and values in its blocks."""
+        monkeypatch.setenv("PADDLE_TPU_FLASH_DECODE", kernel)
+        model, extra = _fill_family(family)
+        kw = dict(self.KW, **extra)
+        rng = np.random.RandomState(SEED + 40)
+        vocab = model.config.vocab_size
+        prompts = [rng.randint(1, vocab, n).astype("int32")
+                   for n in (101, 77)]    # 7 chunks (one padded), 5
+        specs = [dict(max_new_tokens=5),
+                 dict(max_new_tokens=5, do_sample=True, top_k=8,
+                      temperature=0.9, seed=11)]
+        eng = serving.ServingEngine(model, **kw)
+        one = _one_row_engine(monkeypatch, model, **kw)
+        assert eng._chunk_rows == 4
+        for p, s in zip(prompts, specs):
+            reqs = [e.submit(p, **s) for e in (eng, one)]
+            before = eng.counters()
+            iters = {}
+            for e in (eng, one):        # each alone, in its slot 0
+                iters[e] = 0
+                while not e._decoding[0]:
+                    e.step()
+                    iters[e] += 1
+            after = eng.counters()
+            chunks = -(-len(p) // 16)
+            assert (iters[eng], iters[one]) == (-(-chunks // 4), chunks)
+            assert after["prefill_rows"] - before["prefill_rows"] == chunks
+            assert after["prefill_fill_rows"] - before["prefill_fill_rows"] \
+                == chunks - iters[eng]
+            if not extra:
+                blocks = eng._slot_blocks[0][:len(p) // 16]
+                assert blocks == one._slot_blocks[0][:len(p) // 16]
+                for a, b in zip(eng._pools, one._pools):
+                    for name in ("k", "v"):
+                        np.testing.assert_allclose(
+                            np.asarray(a[name])[blocks],
+                            np.asarray(b[name])[blocks], atol=2e-5)
+            eng.run_until_idle()
+            one.run_until_idle()
+            got = [list(r.output_tokens) for r in reqs]
+            assert got[0] == got[1] and len(got[0]) == 5
+            if not extra:       # (an int8 pool has no generate twin)
+                assert got[0] == list(_ref(model, p, **s))
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+    def test_spare_rows_go_to_the_earliest_admitted_slot_then_the_next(
+            self, tiny_model):
+        """Admission order, not slot order: B (slot 1) was admitted
+        before C, which took the slot a finished request left (slot 0).
+        The program's two spare rows: B's one further chunk, its last,
+        then C's next."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **dict(self.KW, prefill_chunk=8))
+        assert eng._chunk_rows == 4
+        rng = np.random.RandomState(SEED + 41)
+        x, b, c = (_prompt(rng, cfg, n) for n in (5, 40, 60))
+        rx = eng.submit(x, max_new_tokens=2)
+        rb = eng.submit(b, max_new_tokens=4)
+        seen = []
+        _program_rows(eng, seen)
+        eng.step()
+        # X's only chunk, B's first, and B's next two in the spare rows
+        assert seen == [[(0, 0, True), (1, 0, False), (1, 8, False),
+                         (1, 16, False)]]
+        assert rx.status == serving.RequestStatus.COMPLETED
+        rc = eng.submit(c, max_new_tokens=4)
+        eng.step()
+        assert (rc.slot, rb.slot) == (0, 1)
+        assert eng._slot_seq[1] < eng._slot_seq[0]
+        assert seen[1] == [(0, 0, False), (1, 24, False), (1, 32, True),
+                           (0, 8, False)]
+        c1 = eng.counters()
+        assert (c1["prefill_rows"], c1["prefill_programs"],
+                c1["prefill_fill_rows"]) == (8, 2, 4)
+        eng.run_until_idle()
+        for r, p in ((rx, x), (rb, b), (rc, c)):
+            assert list(r.output_tokens) == list(
+                _ref(model, p, max_new_tokens=len(r.output_tokens)))
+        # every chunk of C rode exactly once, in order, the final one last
+        mine = [row for rows in seen[1:] for row in rows if row[0] == 0]
+        assert [pos for _, pos, _ in mine] == list(range(0, len(c), 8))
+        assert [last for _, _, last in mine] == [False] * 7 + [True]
+
+    def test_a_fill_cut_short_by_the_pool_preempts_nobody(self, tiny_model):
+        """A spare row is one its slot can do without: where the pool
+        cannot give its blocks the slot's share of the rows ends there,
+        nobody is preempted, and the prompt goes on next iteration."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 42)
+        prompts = [_prompt(rng, cfg, n) for n in (100, 90)]
+        reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        real, asked = eng._reserve_write, []
+
+        def reserve(slot, start, end, **kw):
+            asked.append((slot, start, kw))
+            if kw.get("allow_preempt") is False and (slot, start) == (0, 32):
+                raise PoolExhaustedError("no block for a spare row")
+            return real(slot, start, end, **kw)
+
+        eng._reserve_write = reserve
+        seen = []
+        _program_rows(eng, seen)
+        eng.step()
+        # slot 0 got one spare row and was refused its second; the row
+        # went to slot 1, the next in admission order
+        assert seen == [[(0, 0, False), (1, 0, False), (0, 16, False),
+                         (1, 16, False)]]
+        assert [a for a in asked if a[2]] == [
+            (slot, start, {"allow_preempt": False})
+            for slot, start in ((0, 16), (0, 32), (1, 16))]
+        c = eng.counters()
+        assert (c["preemptions"], c["prefill_fill_rows"]) == (0, 2)
+        assert all(r.preempt_count == 0 and r.slot is not None for r in reqs)
+        assert [eng._jobs[i].done for i in (0, 1)] == [32, 32]
+        eng.run_until_idle()
+        for r, p in zip(reqs, prompts):
+            assert list(r.output_tokens) == list(
+                _ref(model, p, max_new_tokens=3))
+
+    def test_a_write_the_slot_can_do_without_never_preempts(self, tiny_model):
+        """``_reserve_write(allow_preempt=False)`` on an exhausted pool raises
+        and leaves every slot its blocks; the same write with the
+        default takes them from the latest-admitted other request."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, prefix_caching=False,
+                                    num_blocks=5, **self.KW)   # 4 usable
+        rng = np.random.RandomState(SEED + 43)
+        reqs = [eng.submit(_prompt(rng, cfg, 30), max_new_tokens=20)
+                for _ in range(2)]
+        eng.step()      # two blocks each: the pool is full
+        assert eng.pool.free_blocks == 0 and all(eng._decoding[:2])
+        with pytest.raises(PoolExhaustedError):
+            eng._reserve_write(0, 32, 33, allow_preempt=False)
+        assert eng.counters()["preemptions"] == 0
+        assert [len(b) for b in eng._slot_blocks[:2]] == [2, 2]
+        eng._reserve_write(0, 32, 33)
+        assert eng.counters()["preemptions"] == 1
+        assert reqs[1].slot is None and len(eng._slot_blocks[0]) == 3
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline"])
+    def test_cancel_and_deadline_between_iterations_stop_a_filled_prompt(
+            self, tiny_model, how):
+        import time
+
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(SEED + 44)
+        prompts = [_prompt(rng, cfg, n) for n in (120, 120)]
+        reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        eng.step()      # a first chunk each, and two spare rows to slot 0
+        assert [eng._jobs[i].done for i in (0, 1)] == [48, 16]
+        if how == "cancel":
+            eng.cancel(reqs[0])
+        else:
+            reqs[0].deadline_ts = time.perf_counter() - 1.0
+        before = eng.counters()
+        eng.step()
+        assert reqs[0].status == (serving.RequestStatus.CANCELLED
+                                  if how == "cancel"
+                                  else serving.RequestStatus.EXPIRED)
+        # the slot left alone takes the whole program
+        after = eng.counters()
+        assert after["prefill_rows"] - before["prefill_rows"] == 4
+        assert after["prefill_fill_rows"] - before["prefill_fill_rows"] == 3
+        assert eng._jobs[1].done == 80 and eng._slot_req[0] is None
+        eng.run_until_idle()
+        assert list(reqs[1].output_tokens) == list(
+            _ref(model, prompts[1], max_new_tokens=3))
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+    def test_a_windowed_slot_never_has_two_rows_in_a_program(self):
+        """An EVA engine at ``[2, 32]``: a slot alone, or beside one
+        other, advances one chunk an iteration whatever rows are left,
+        because rolling its window gives blocks back that an earlier row
+        of the same program would still read."""
+        from paddle_tpu.models import EvaByteConfig, EvaByteForCausalLM
+
+        paddle.seed(0)
+        cfg = EvaByteConfig.tiny(chunk_size=16, window_size=64)
+        eng = serving.ServingEngine(
+            EvaByteForCausalLM(cfg), max_slots=2, max_len=320, block_size=4,
+            prefill_chunk=32, prefix_caching=False)
+        assert eng._chunk_rows == 2 and eng._layout is not None
+        seen = []
+        _program_rows(eng, seen)
+        rng = np.random.RandomState(SEED + 45)
+        lone = eng.submit(_prompt(rng, cfg, 150), max_new_tokens=3)
+        eng.run_until_idle()
+        assert [len(rows) for rows in seen] == [1] * 5
+        del seen[:]
+        pair = [eng.submit(_prompt(rng, cfg, n), max_new_tokens=3)
+                for n in (150, 70)]
+        eng.run_until_idle()
+        assert all(len({slot for slot, _, _ in rows}) == len(rows)
+                   for rows in seen)
+        assert [len(rows) for rows in seen] == [2, 2, 2, 1, 1]
+        c = eng.counters()
+        assert c["prefill_fill_rows"] == 0 and c["window_rolls"] >= 4
+        assert all(len(r.output_tokens) == 3 for r in [lone, *pair])

@@ -874,3 +874,150 @@ class TestBatchedPrefillServing:
             assert list(reqs[i].output_tokens) == self._ref(
                 model, prompts[i], max_new_tokens=3)
         assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+
+class TestFilledProgramServing:
+    """The rows the prefill program has left carry a prompt's further
+    chunks, and a first token is read once the step is dispatched: both
+    seen from the serving surface, where neither may show in a stream
+    but as time."""
+
+    KW = TestBatchedPrefillServing.KW
+    _ref = TestBatchedPrefillServing._ref
+
+    @pytest.mark.parametrize("spec", [
+        dict(max_new_tokens=5),
+        dict(max_new_tokens=5, do_sample=True, top_k=6, temperature=0.8,
+             seed=3)], ids=["greedy", "sampled"])
+    def test_a_lone_prompt_takes_the_whole_program_through_the_loop(
+            self, tiny_model, spec):
+        """Six chunks alone in the engine: four rows of ``[4, C]``,
+        then two; the stream is ``generate``'s."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        assert eng._chunk_rows == 4
+        p = _prompt(np.random.RandomState(94), cfg, 45)
+        eng.start()
+        try:
+            got = eng.submit(p, **spec).result(timeout=60.0)
+        finally:
+            eng.stop()
+        assert list(got) == self._ref(model, p, **spec)
+        c = eng.counters()
+        assert (c["prefill_rows"], c["prefill_programs"],
+                c["prefill_fill_rows"]) == (6, 2, 4)
+
+    def test_a_prompt_behind_a_running_batch_needs_fewer_iterations(
+            self, tiny_model):
+        """Two requests decode; a prompt of five chunks joins them. It
+        has its first token after two iterations (four rows, then its
+        last chunk), where one chunk an iteration took five, and the
+        running requests got their token in each of them: the prompt
+        is still fed between decode steps."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(95)
+        running = [eng.submit(_prompt(rng, cfg, 6), max_new_tokens=12)
+                   for _ in range(2)]
+        eng.step()
+        assert [len(r.output_tokens) for r in running] == [2, 2]
+        p = _prompt(rng, cfg, 38)
+        late = eng.submit(p, max_new_tokens=4)
+        eng.step()
+        assert late.output_tokens == [] and eng._jobs[late.slot].done == 32
+        assert [len(r.output_tokens) for r in running] == [3, 3]
+        eng.step()
+        assert len(late.output_tokens) == 2     # its first, and the step's
+        assert [len(r.output_tokens) for r in running] == [4, 4]
+        eng.run_until_idle()
+        assert list(late.output_tokens) == self._ref(
+            model, p, max_new_tokens=4)
+        for r in running:
+            assert list(r.output_tokens) == self._ref(
+                model, r.prompt, max_new_tokens=12)
+
+    def test_one_token_requests_through_the_loop_return_one_token(
+            self, tiny_model):
+        """Requests that end on their first token, beside one that runs
+        on: each is in the step dispatched before its token was read,
+        and that step's row is dropped; one token each, ``generate``'s,
+        every slot and block given back."""
+        from paddle_tpu.serving import metrics as sm
+
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, max_queue_depth=16, **self.KW)
+        rng = np.random.RandomState(96)
+        long_p = _prompt(rng, cfg, 11)
+        shorts = [_prompt(rng, cfg, 3 + 5 * i) for i in range(5)]
+        _, _, ttft0 = sm.ttft_seconds._d().snapshot()
+        eng.start()
+        try:
+            runner = eng.submit(long_p, max_new_tokens=14)
+            reqs = [eng.submit(p, max_new_tokens=1) for p in shorts]
+            got = [r.result(timeout=60.0) for r in reqs]
+            ran = runner.result(timeout=60.0)
+        finally:
+            eng.stop()
+        for g, p, r in zip(got, shorts, reqs):
+            assert list(g) == self._ref(model, p, max_new_tokens=1)
+            assert r.status == serving.RequestStatus.COMPLETED
+            assert r.ttft_s is not None and r.tpot_s is None
+        assert list(ran) == self._ref(model, long_p, max_new_tokens=14)
+        _, _, ttft1 = sm.ttft_seconds._d().snapshot()
+        assert ttft1 - ttft0 == 6       # one observation a request
+        assert eng.busy_slots() == 0
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
+
+    def test_a_first_token_is_streamed_ahead_of_the_steps_token(
+            self, tiny_model):
+        """The callback sees a request's first token before any token
+        of the step that was dispatched ahead of the read, its own
+        second token included, and every stream in order."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(97)
+        seen = []
+        first = eng.submit(_prompt(rng, cfg, 5), max_new_tokens=6,
+                           on_token=lambda r, t: seen.append(("a", t)))
+        eng.step()
+        del seen[:]
+        second = eng.submit(_prompt(rng, cfg, 20), max_new_tokens=3,
+                            on_token=lambda r, t: seen.append(("b", t)))
+        eng.step()      # b: three rows, its last among them
+        assert [who for who, _ in seen] == ["b", "a", "b"]
+        assert second.first_token_ts <= first.last_token_ts
+        eng.run_until_idle()
+        assert [t for who, t in seen if who == "b"] == self._ref(
+            model, second.prompt, max_new_tokens=3)
+        assert list(first.output_tokens) == self._ref(
+            model, first.prompt, max_new_tokens=6)
+
+    def test_a_cache_that_refuses_a_filled_prompt_fails_that_request_alone(
+            self, tiny_model):
+        """A prompt whose last chunk rode a spare row; the prefix-cache
+        insert raises while the host books it: that request fails, the
+        one whose rows it shared a program with is served, and nothing
+        stays parked."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **self.KW)
+        rng = np.random.RandomState(98)
+        prompts = [_prompt(rng, cfg, 20), _prompt(rng, cfg, 6)]
+        real = eng.prefix_cache.insert
+
+        def insert(tokens, n, blocks):
+            if np.array_equal(tokens[:n], prompts[0]):
+                raise RuntimeError("cache refused the prompt")
+            return real(tokens, n, blocks)
+
+        eng.prefix_cache.insert = insert
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        eng.step()
+        assert eng.counters()["prefill_fill_rows"] == 2
+        assert reqs[0].status == serving.RequestStatus.FAILED
+        assert "cache refused the prompt" in reqs[0].error
+        assert eng._parked_tokens == [] and len(reqs[1].output_tokens) == 2
+        eng.run_until_idle()
+        assert list(reqs[1].output_tokens) == self._ref(
+            model, prompts[1], max_new_tokens=4)
+        assert eng.busy_slots() == 0
+        assert eng.pool.used_blocks == len(eng.prefix_cache)
