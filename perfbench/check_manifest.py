@@ -17,7 +17,10 @@ driver's two refusals state:
            mean of two sets without each set's farthest run;
   ceiling  "a bound may be at most 8 times the widest spread, or 1% if
            that is more" (PR 23): taken against the widest set over all
-           the cells that report the metric.
+           the cells that report the metric, or the widest spread on
+           file for them where that is wider (``perfbench/spreads/``:
+           the driver's machines spread wider than one builder's lease,
+           and it is the driver's reading that refuses).
 
 Under each metric one more line says where the window would lie had
 the driver drawn any two of the sets given (its own rule: tight by the
@@ -42,11 +45,13 @@ import statistics
 import sys
 
 from . import manifest as manifest_mod
+from . import spreads as spreads_mod
 from . import stats
 
-FLOOR_SHARE = 0.5
-CEILING_TIMES = 8.0
-ALWAYS_ALLOWED = 0.01
+# the driver's window, stated once (perfbench/spreads.py)
+FLOOR_SHARE = 1.0 / spreads_mod.FLOOR_TIMES
+CEILING_TIMES = spreads_mod.CEILING_TIMES
+ALWAYS_ALLOWED = spreads_mod.ALWAYS_ALLOWED
 
 
 def read_runs(run_dir):
@@ -130,6 +135,11 @@ def check(root, run_dir, out=print):
         if name == "setup_s":
             out(f"{name:14s} bound {bound}: judged on medians only")
             continue
+        filed = spreads_mod.widest(root, name, cells)
+        if filed and filed[0] > widest:
+            widest = filed[0]
+            out(f"{name:14s} wider on file: {100 * widest:.3f}% "
+                f"({filed[1]}, {filed[2]})")
         ceiling = max(ALWAYS_ALLOWED, CEILING_TIMES * widest)
         verdict = "inside the window"
         if bound > ceiling:
@@ -138,7 +148,7 @@ def check(root, run_dir, out=print):
                 f"{name}: bound {bound} is over {CEILING_TIMES:g} times the "
                 f"widest spread {widest:.4f} (ceiling {ceiling:.4f})")
         out(f"{name:14s} bound {bound}: floor {2 * widest:.4f} (twice the "
-            f"widest set) ceiling {ceiling:.4f} -> {verdict}")
+            f"widest spread) ceiling {ceiling:.4f} -> {verdict}")
         out(f"{name:14s} had the driver drawn any two of these sets: tight "
             f"under {2 * pair_hi:.4f}, loose over "
             f"{max(ALWAYS_ALLOWED, CEILING_TIMES * pair_lo):.4f}")

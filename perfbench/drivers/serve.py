@@ -13,6 +13,7 @@ import numpy as np
 from .. import traffic as traffic_mod
 
 DRAIN_LIMIT_S = 120.0
+FIRST_TOKEN_LIMIT_S = 60.0
 
 
 class Live:
@@ -116,6 +117,26 @@ def run_closed(prog, engine, spec, seed, seconds, vocab, tracer):
     print(f"[perfbench] closed loop: {len(sent)} requests sent by "
           f"{len(lists)} clients", flush=True)
     return sent, (t_zero, t_end)
+
+
+def await_first_tokens(lives):
+    """After a closed loop's window has shut, and outside it: wait for
+    the first token of every request that was still prefilling. The
+    window credits a prompt evenly over the time from its submission to
+    its first token (``stats.tokens_in_window``), so a prompt with no
+    first token yet was credited nothing for the chunks it had run
+    inside, a step of 1.6% of a window for each such request that came
+    and went with the smallest change of pace (PERF.md, PR 28). Nothing
+    is sent meanwhile; a request that fails or never answers is left as
+    it is. Returns (requests waited for, seconds waited)."""
+    t0 = time.perf_counter()
+    pending = [lv for lv in lives if not lv.times and not lv.handle.done]
+    n = len(pending)
+    while pending and time.perf_counter() - t0 < FIRST_TOKEN_LIMIT_S:
+        time.sleep(0.005)
+        pending = [lv for lv in pending
+                   if not lv.times and not lv.handle.done]
+    return n, time.perf_counter() - t0
 
 
 def records(prog, lives, kind):

@@ -5,7 +5,9 @@
 A new process: refuses to start unless jax finds a TPU with the chips
 the cell asks for, makes weights and inputs from ``--seed``, warms up,
 measures for ``--seconds``, checks what the timed path produced against
-the plain reference, and prints one JSON object as its last line. With
+the plain reference, and prints one JSON object as its last line (its
+last key, ``checks``, and standard error's last lines hold each number
+compared beside its limit). With
 ``--trace 0`` the metrics are the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read in a run that also takes a
 profiler trace of a few seconds. Everything else worth reading goes on
@@ -25,6 +27,7 @@ _T_IMPORT = time.perf_counter()
 import argparse  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -32,6 +35,7 @@ import tempfile  # noqa: E402
 import threading  # noqa: E402
 
 from . import manifest as manifest_mod  # noqa: E402
+from . import trace_reduce  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -85,7 +89,9 @@ class BackendCompiles:
 class Tracer:
     """A profiler trace of ``length_s`` seconds inside the window (a
     served cell's last seconds, a training cell's from ``after_s`` on);
-    off when ``on`` is false."""
+    off when ``on`` is false. ``t0`` and ``t1`` are read on the host's
+    clock inside two marks that land on the trace's host plane, which is
+    how ``trace_reduce`` cuts the device's events to the same stretch."""
 
     def __init__(self, on, after_s, length_s):
         self.on, self.after_s, self.length_s = on, after_s, length_s
@@ -97,12 +103,14 @@ class Tracer:
 
         self.dir = tempfile.mkdtemp(prefix="perfbench_trace_")
         jax.profiler.start_trace(self.dir)
-        self.t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_OPEN):
+            self.t0 = time.perf_counter()
 
     def _stop(self):
         import jax
 
-        self.t1 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SHUT):
+            self.t1 = time.perf_counter()
         jax.profiler.stop_trace()
 
     def window_start(self, t_end=None):
@@ -144,14 +152,18 @@ class Tracer:
             self._stop()
         if self.dir is None:
             return None
-        from . import trace_reduce
-
         try:
             red = trace_reduce.reduce_dir(self.dir)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
-        red["window_s"] = self.t1 - self.t0
+        # ``window_s`` is the trace's own; the readers that count the
+        # clients' tokens take the same stretch on the host's clock
         red["host_window"] = (self.t0, self.t1)
+        cut = ("between the marks" if red["window_marked"]
+               else "NO MARKS: the whole trace")
+        say(f"trace: busy {red['busy_s']:.4f}s of {red['window_s']:.4f}s "
+            f"({cut}; host clock {self.t1 - self.t0:.4f}s; busy over the "
+            f"whole trace {red['busy_whole_trace_s']:.4f}s)")
         return red
 
 
@@ -189,6 +201,14 @@ class Checks:
 
     def values(self):
         return {r[0]: r[1] for r in self.rows}
+
+    def beside_limits(self):
+        """{name: {"value": .., "limit": ..}} of every number compared,
+        for the result's line (a value that is no finite number, as
+        where nothing finished, by its name: JSON has none for it)."""
+        return {name: {"value": value if math.isfinite(value) else str(value),
+                       "limit": limit}
+                for name, value, limit, _ in self.rows}
 
 
 def build_served(ctx):
@@ -238,6 +258,10 @@ def serve_flow(ctx):
     lives, window = loop(prog, engine, tr, seed, ctx["seconds"],
                          config["vocab_size"], ctx["tracer"])
     engine_end = prog.counters(engine)
+    if tr["kind"] == "closed":
+        n_open, waited = serve.await_first_tokens(lives)
+        say(f"waited {waited:.2f}s past the window for the first token of "
+            f"{n_open} request(s) still prefilling")
     recs = serve.records(prog, lives, tr["kind"])
     after = observe.counters()
     facts.update(
@@ -250,6 +274,11 @@ def serve_flow(ctx):
         - facts["backend_compiles_setup"],
         memory_peak_bytes=memory_peak())
     facts["trace"] = ctx["tracer"].finish()
+    if tr["kind"] == "closed":
+        from .readers import serve_tokens_per_s
+
+        say(f"tokens/s by 3 s of the window: "
+            f"{serve_tokens_per_s.by_slice(facts)}")
     prog.free(engine)
     del engine, lives
     say(f"program freed: {bytes_in_use() / 1e9:.2f} GB still in use")
@@ -407,6 +436,7 @@ def run_cell(root, workload, seed, seconds, trace, on_chip=True):
         device["window_s"] = red["window_s"]
         result["breakdown"] = {"device_ops": red["device_ops"][:10],
                                "idle_gaps": red["idle_gaps"][:10]}
+    result["checks"] = checks.beside_limits()  # last in the line
     ctx["result"] = result
     return result, ctx
 
@@ -422,6 +452,10 @@ def main(argv=None, root=ROOT, on_chip=True):
                          args.trace, on_chip)
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
+    # each number compared beside its limit: standard error's last lines
+    for name, c in result["checks"].items():
+        print(f"{name} {c['value']!r} limit {c['limit']!r}", file=sys.stderr)
+    print(f"correct {result['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

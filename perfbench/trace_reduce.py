@@ -9,6 +9,16 @@ HLO operation and ``XLA Modules`` one per executed program
 (``jit__step(<hash>)``). The host plane ``/host:CPU`` has a line for
 each thread with the runtime's spans (and the interpreter's calls when
 the Python tracer is on, which it is not in a benchmark run).
+
+Busy time and the window it is divided by are one stretch of one clock.
+``run.py`` writes two marks onto the host plane, ``WINDOW_OPEN`` as it
+reads the host's ``t0`` and ``WINDOW_SHUT`` as it reads ``t1``; every
+device event is clipped to the stretch between them and ``window_s`` is
+its length, so a device that never idles reads ``busy_s == window_s``
+and never more (the trace itself reaches further: it holds what ran
+while ``start_trace`` and ``stop_trace`` were still at work). A trace
+without the marks is taken whole: from the first event of any plane to
+the end of the last.
 """
 
 from __future__ import annotations
@@ -22,6 +32,7 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 
 DEVICE_LINES = ("XLA Ops", "XLA Modules")
+WINDOW_OPEN, WINDOW_SHUT = "perfbench.window_open", "perfbench.window_shut"
 
 
 def load(path):
@@ -92,6 +103,35 @@ def subtract(merged, holes):
     return total
 
 
+def window(planes):
+    """(lo, hi, marked) with lo and hi in the trace's ns: from the start
+    of the ``WINDOW_OPEN`` mark to the start of the ``WINDOW_SHUT`` mark
+    on the host plane, or, without them (``marked`` false), from the
+    first event of any plane to the end of the last. None for a trace
+    with no event at all."""
+    marks = {name: start for k, v in planes.items() if k.startswith("/host:")
+             for name, start, _ in v.get("host", [])
+             if name in (WINDOW_OPEN, WINDOW_SHUT)}
+    if len(marks) == 2 and marks[WINDOW_OPEN] < marks[WINDOW_SHUT]:
+        return marks[WINDOW_OPEN], marks[WINDOW_SHUT], True
+    events = [(s, s + d) for lines in planes.values()
+              for line in lines.values() for _, s, d in line]
+    if not events:
+        return None
+    return min(s for s, _ in events), max(e for _, e in events), False
+
+
+def clip(events, lo, hi):
+    """The part of every (name, start, duration) inside [lo, hi]; an
+    event wholly outside is dropped."""
+    out = []
+    for name, s, d in events:
+        a, b = max(s, lo), min(s + d, hi)
+        if b > a:
+            out.append((name, a, b - a))
+    return out
+
+
 def _host_labels(host_events, gaps):
     """What the host was doing in the middle of each gap (lo, hi): the
     innermost span of any host thread that covers it. The runtime
@@ -123,18 +163,25 @@ def _host_labels(host_events, gaps):
 
 
 def reduce(planes):
-    """The reduction, from ``load``'s structure. Times in seconds; the
-    per-operation and per-program sums are averages over the device
-    planes, so that they add up to ``busy_s``."""
+    """The reduction, from ``load``'s structure, over the stretch that
+    ``window`` gives. Times in seconds; the per-operation and
+    per-program sums are averages over the device planes, so that they
+    add up to ``busy_s``. An operation that overhangs an end of the
+    window counts with the part inside; a program counts, in time and
+    in calls, only where it ran wholly inside, so that its mean time is
+    that of whole programs."""
     devices = {k: v for k, v in planes.items()
                if k.startswith("/device:TPU:")}
+    lo, hi, marked = window(planes) or (0.0, 0.0, False)
     host_events = [ev for k, v in planes.items() if k.startswith("/host:")
                    for ev in v.get("host", [])]
     n = max(1, len(devices))
-    busy = exposed = collective = 0.0
+    busy = busy_whole = exposed = collective = 0.0
     ops, mods, mod_counts, gaps = {}, {}, {}, []
     for lines in devices.values():
-        events = lines.get("XLA Ops", [])
+        busy_whole += length(union(
+            [(s, s + d) for _, s, d in lines.get("XLA Ops", [])]))
+        events = clip(lines.get("XLA Ops", []), lo, hi)
         spans = union([(s, s + d) for _, s, d in events])
         busy += length(spans)
         coll = union([(s, s + d) for name, s, d in events
@@ -145,7 +192,9 @@ def reduce(planes):
         exposed += subtract(coll, rest)
         for name, _, d in events:
             ops[op_key(name)] = ops.get(op_key(name), 0.0) + d
-        for name, _, d in lines.get("XLA Modules", []):
+        for name, s, d in lines.get("XLA Modules", []):
+            if s < lo or s + d > hi:
+                continue
             k = module_key(name)
             mods[k] = mods.get(k, 0.0) + d
             mod_counts[k] = mod_counts.get(k, 0) + 1
@@ -156,7 +205,12 @@ def reduce(planes):
     ns = 1e-9
     return {
         "n_devices": len(devices),
+        "window_s": (hi - lo) * ns,
+        "window_marked": marked,
         "busy_s": busy * ns / n,
+        # what the trace holds beyond the window too (PR 34 was refused
+        # on this number read against the window's length)
+        "busy_whole_trace_s": busy_whole * ns / n,
         "collective_s": collective * ns / n,
         "collective_exposed_s": exposed * ns / n,
         "op_s": {k: v * ns / n for k, v in ops.items()},

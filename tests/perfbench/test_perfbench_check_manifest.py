@@ -21,13 +21,15 @@ BENCH = manifest.load_json(os.path.join(tiny.REPO, "BENCHMARK.json"))
 
 def checkout(tmp_path, case, extra_bounds=None):
     """A checkout whose manifest gives the case's metric the case's
-    bound, and a directory of run files, one per run, for that cell."""
+    bound, and a directory of run files, one per run, for that cell.
+    The recorded sets are the only spreads it holds: the real cells'
+    files of spreads stay behind."""
     root, runs = str(tmp_path / "checkout"), str(tmp_path / "runs")
     os.makedirs(root)
     os.makedirs(runs)
     shutil.copytree(os.path.join(tiny.REPO, "perfbench"),
                     os.path.join(root, "perfbench"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
+                    ignore=shutil.ignore_patterns("__pycache__", "spreads"))
     bench = copy.deepcopy(BENCH)
     cell = case["cell"]
     bench["workloads"] = [w for w in bench["workloads"] if w["name"] == cell]
@@ -111,6 +113,38 @@ def test_pr23_would_have_passed_with_that_bound_inside_the_window(tmp_path):
     faults, lines = faults_of(tmp_path, case)
     assert faults == []
     assert any("inside the window" in ln for ln in lines)
+
+
+def test_a_wider_spread_on_file_lifts_the_ceiling_and_not_the_floor(tmp_path):
+    """PR 23's bound again, with a file that says the driver read the
+    cell three times as wide as these sets: no longer too loose. The
+    floor is still held against the sets that were run."""
+    case = DATA["pr23"]
+    root, runs = checkout(tmp_path, case)
+    lines = []
+    assert any("widest spread" in f for f in
+               check_manifest.check(root, runs, out=lines.append))
+    own = max(statistics.quantiles(v, n=4)[2] - statistics.quantiles(v, n=4)[0]
+              for v in case["sets"].values()) / statistics.median(
+                  case["sets"]["a"])
+    os.makedirs(os.path.join(root, "perfbench", "spreads"))
+    with open(os.path.join(root, "perfbench", "spreads",
+                           case["cell"] + ".json"), "w") as fh:
+        json.dump({"ledger": {case["metric"]: [
+            {"pr": 99, "spread": case["bound"] / 7.0}]}}, fh)
+    assert case["bound"] / 7.0 > own
+    lines = []
+    assert check_manifest.check(root, runs, out=lines.append) == []
+    assert any("wider on file" in ln and "ledger PR 99" in ln for ln in lines)
+    tight = dict(DATA["pr22"])
+    root2, runs2 = checkout(tmp_path / "two", tight)
+    os.makedirs(os.path.join(root2, "perfbench", "spreads"))
+    with open(os.path.join(root2, "perfbench", "spreads",
+                           tight["cell"] + ".json"), "w") as fh:
+        json.dump({"ledger": {tight["metric"]: [
+            {"pr": 99, "spread": 0.0001}]}}, fh)
+    assert any("over 50% of the bound" in f for f in
+               check_manifest.check(root2, runs2, out=lambda s: None))
 
 
 def test_one_percent_is_never_too_loose(tmp_path):

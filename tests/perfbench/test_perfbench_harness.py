@@ -14,7 +14,8 @@ from perfbench import limits as limits_tool
 from perfbench import manifest, run
 from perfbench.programs import observe
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "checks"}
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -43,8 +44,12 @@ def train_run(root):
                         on_chip=False)
 
 
-def last_line(capsys):
-    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+def last_line(capsys, err=None):
+    """The result object; standard error's lines go into ``err``."""
+    got = capsys.readouterr()
+    if err is not None:
+        err.extend(got.err.strip().splitlines())
+    return json.loads(got.out.strip().splitlines()[-1])
 
 
 def test_tiny_manifest_meets_the_static_rules(root):
@@ -64,7 +69,8 @@ def test_last_line_of_an_untraced_run(root, capsys):
     rc = run.main(["--workload", "tiny-gpt.tiny-doc", "--seed", "2147483659",
                    "--seconds", "3", "--trace", "0"], root=root,
                   on_chip=False)
-    res = last_line(capsys)
+    err = []
+    res = last_line(capsys, err)
     assert rc == 0 and set(res) == RESULT_KEYS
     assert set(res["metrics"]) == {"serve_tok_s", "setup_s"}
     assert all(set(m) == {"value", "unit"} for m in res["metrics"].values())
@@ -72,6 +78,15 @@ def test_last_line_of_an_untraced_run(root, capsys):
                                   "memory_peak_bytes"}
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] > 0 and res["metrics"]["serve_tok_s"]["value"] > 0
+    # each number compared beside its limit: the line's last key, and
+    # the last lines of standard error
+    assert list(res)[-1] == "checks"
+    assert set(res["checks"]) == {"token_count_mismatches",
+                                  "served_logit_gap_max",
+                                  "served_logit_gap_mean"}
+    assert all(0 <= c["value"] <= c["limit"] for c in res["checks"].values())
+    assert err[-1] == "correct True"
+    assert [ln.split()[0] for ln in err[-4:-1]] == list(res["checks"])
 
 
 def test_last_line_of_a_traced_run(root, capsys):

@@ -40,9 +40,11 @@ def _with_prefill(events, rows_of):
 
 
 @pytest.mark.parametrize("name", NAMES)
-def test_each_metric_is_data_beside_the_accepted_ones(name):
+def test_each_metric_is_data_in_the_order_it_was_added(name):
     """A file of arguments for the reader the benchmark has, and an
-    entry at the end of ``per_layer`` that lists its one cell."""
+    entry of ``per_layer`` that lists its one cell; the six stand
+    together in the order they were added, whatever later PRs append
+    behind them."""
     real = manifest.Manifest(tiny.REPO)
     stem, suf = name.rsplit(".", 1)
     cell, moves = SUFFIXES[suf]
@@ -54,7 +56,9 @@ def test_each_metric_is_data_beside_the_accepted_ones(name):
     assert entry["workloads"] == [cell] and entry["moves"] == moves
     assert entry["layer"] == mf["layer"] == LAYER
     assert entry["source"] == "program_counter"
-    assert entry in real.data["per_layer"][-6:]
+    names = [m["name"] for m in real.data["per_layer"]]
+    first = names.index(NAMES[0])
+    assert names[first:first + len(NAMES)] == NAMES
     assert real.cell(cell)
 
 

@@ -190,11 +190,12 @@ def test_host_share_is_the_time_the_device_has_nothing_queued(man, ring):
         == pytest.approx(100.0 * (n - 1) * (0.5 + 1.0 + 3.0) / 1e3 / READ_S)
 
 
-def test_a_first_token_is_a_sync_and_an_idle_engine_starves_nobody(
+def test_a_first_token_is_no_sync_and_an_idle_engine_starves_nobody(
         man, ring):
     events = steady(n=40)
     # iteration 40: a request's last chunk (enqueued at 402 ms), its
-    # token fetched at 410 ms, then 1 ms to the next chunk's enqueue
+    # token read at 410 ms with work still queued behind it (since PR 30
+    # the step is enqueued before the token is read), then another chunk
     events += [ev("engine.admit", 400.0, 0.0, iter=40),
                ev("prefill_chunk", 400.0, 2.0, trace=7, cat="request",
                   iter=40),
@@ -210,8 +211,8 @@ def test_a_first_token_is_a_sync_and_an_idle_engine_starves_nobody(
     events += [ev("engine.idle", 419.5 + 50.0 * k, 50.0) for k in range(20)]
     facts = ring(facts_for(events))
     # 39 gaps of 4 ms; emit of 39 + admit and the first chunk (2.5 ms);
-    # first token to the next enqueue (1 ms); the last emit (0.5 ms)
-    want = 39 * 4.0 + 2.5 + 1.0 + 0.5
+    # nothing from the first token on; the last emit (0.5 ms)
+    want = 39 * 4.0 + 2.5 + 0.5
     assert reading(man, "engine_host_share.chat", facts) \
         == pytest.approx(100.0 * want / 1e3 / READ_S)
 
@@ -450,15 +451,16 @@ def test_each_twin_of_the_new_cell_names_its_one_chip_twins_reader(man):
             == (b["reader"], b["args"], b["unit"], b["layer"], b["moves"])
 
 
-def test_nothing_the_benchmark_had_lists_a_cell_it_did_not(man):
-    """Only ``train_tok_s`` gained the new cell; one four-chip cell of
-    four is what the quota allows."""
+def test_only_the_training_metrics_list_the_four_chip_cell(man):
+    """Only ``train_tok_s`` gained the new cell; of the cells the
+    manifest holds a quarter, rounded down, may take four chips, and
+    one always may."""
     listing = [m["name"] for m in man.data["end_to_end"] + man.data["per_layer"]
                if NEW_CELL in m.get("workloads", [])
                and not m["name"].endswith(".dp2mp2")]
     assert listing == ["train_tok_s", "collective_exposed_share"]
-    assert sum(w["chips"] == 4 for w in man.data["workloads"]) == 1
-    assert len(man.data["workloads"]) == 4
+    four = sum(w["chips"] == 4 for w in man.data["workloads"])
+    assert 1 <= four <= max(1, len(man.data["workloads"]) // 4)
 
 
 # -- the cells at tiny sizes, spans read end to end --------------------------
@@ -477,10 +479,11 @@ def root(tmp_path_factory):
     return tiny.make_root(str(tmp_path_factory.mktemp("checkout")))
 
 
-def test_the_tiny_checkout_holds_the_new_cell_once(root):
+def test_the_tiny_checkout_holds_every_cell_once(root, man):
     assert manifest.problems(root) == []
     names = [w["name"] for w in manifest.Manifest(root).data["workloads"]]
-    assert names.count(tiny.TRAIN4) == 1 and len(names) == 4
+    assert names.count(tiny.TRAIN4) == 1
+    assert len(names) == len(set(names)) == len(man.data["workloads"])
 
 
 def test_the_new_cell_on_four_virtual_devices_prints_what_it_reports(

@@ -107,3 +107,101 @@ def test_a_gap_no_span_covers_is_named_by_the_call_it_led_up_to():
     red = tr.reduce({"/device:TPU:0": dev, "/host:CPU": host})
     assert red["idle_gaps"] == [["python_before_D2H_Dispatch",
                                  pytest.approx(8e-6)]]
+
+
+# -- busy time and the window it is divided by: one stretch, one clock -------
+
+
+def _marks(lo, hi):
+    return [(tr.WINDOW_OPEN, lo, 50.0), (tr.WINDOW_SHUT, hi, 50.0)]
+
+
+def test_a_device_that_never_idles_is_busy_for_the_window_and_no_more():
+    """The trace reaches further than the window on both sides (it
+    holds what ran while the profiler started and stopped); operations
+    back to back, one overhanging each end: busy time is the window's
+    length, never above, and the idle share is 0."""
+    from perfbench.readers import device_idle_share
+
+    ops = [(f"%fusion.{k} = f32[] fusion()", 1000.0 * k, 1000.0)
+           for k in range(-3, 14)]               # -3 us .. 14 us
+    ops.append(("%_step.1 = f32[] custom-call()", 9500.0, 1000.0))
+    dev = {"XLA Ops": ops,
+           "XLA Modules": [("jit__step(1)", -3000.0, 3500.0),   # overhangs
+                           ("jit__step(1)", 500.0, 4000.0),
+                           ("jit__step(1)", 4500.0, 5000.0),
+                           ("jit__step(1)", 9500.0, 4500.0)]}   # overhangs
+    host = {"host": [("loop", -5000.0, 25000.0)] + _marks(500.0, 10500.0)}
+    red = tr.reduce({"/device:TPU:0": dev, "/host:CPU": host})
+    assert red["window_marked"] is True
+    assert red["window_s"] == pytest.approx(10e-6)
+    assert red["busy_s"] == red["window_s"]
+    assert device_idle_share.read({"trace": red}) == 0.0
+    # what PR 34 was refused on: the whole trace's busy time is longer
+    assert red["busy_whole_trace_s"] == pytest.approx(17e-6)
+    # an operation that overhangs counts with its part inside, so the
+    # operations still add up to busy time (the kernel ran beside one)
+    assert sum(red["op_s"].values()) == pytest.approx(11e-6)
+    assert red["op_s"]["_step_custom-call"] == pytest.approx(1e-6)
+    # a program counts only where it ran whole: two of four, 4.5 us each
+    assert red["module_calls"] == {"jit__step": 2}
+    assert red["module_s"]["jit__step"] == pytest.approx(9e-6)
+    assert red["idle_gaps"] == []
+
+
+def test_idle_time_is_read_inside_the_marks_alone():
+    """Busy 2 of the 8 us between the marks; what ran before the window
+    opened and after it shut is in the trace and counts for nothing."""
+    dev = {"XLA Ops": [("%fusion.1 = f32[] fusion()", 0.0, 4000.0),
+                       ("%fusion.2 = f32[] fusion()", 6000.0, 1000.0),
+                       ("%fusion.3 = f32[] fusion()", 11000.0, 9000.0)],
+           "XLA Modules": []}
+    host = {"host": _marks(3000.0, 11000.0)}
+    red = tr.reduce({"/device:TPU:0": dev, "/host:CPU": host})
+    assert red["window_s"] == pytest.approx(8e-6)
+    assert red["busy_s"] == pytest.approx(2e-6)
+    # the one gap between two operations, from 4 us to 6 us
+    assert [g for _, g in red["idle_gaps"]] == [pytest.approx(2e-6)]
+
+
+def test_a_trace_without_marks_is_taken_from_first_event_to_last(planes):
+    red = tr.reduce(planes)
+    assert red["window_marked"] is False
+    lo, hi, _ = tr.window(planes)
+    assert red["window_s"] == pytest.approx((hi - lo) * 1e-9)
+    assert 0 < red["busy_s"] <= red["window_s"]
+    every = [(s, s + d) for lines in planes.values()
+             for line in lines.values() for _, s, d in line]
+    assert lo == min(s for s, _ in every) and hi == max(e for _, e in every)
+
+
+def test_marks_out_of_order_or_alone_are_no_marks():
+    dev = {"XLA Ops": [("%fusion.1 = f32[] fusion()", 0.0, 4000.0)],
+           "XLA Modules": []}
+    for host in ([(tr.WINDOW_OPEN, 1000.0, 10.0)],
+                 _marks(3000.0, 1000.0)):
+        red = tr.reduce({"/device:TPU:0": dev, "/host:CPU": {"host": host}})
+        assert red["window_marked"] is False
+        assert red["busy_s"] == pytest.approx(4e-6)
+        assert red["busy_s"] <= red["window_s"]
+
+
+def test_the_tracer_reads_its_clock_inside_the_marks(tmp_path, monkeypatch):
+    """``run.Tracer`` on the CPU: the marks are on the trace's host
+    plane, and the stretch between them is the host clock's to a tenth
+    of a millisecond."""
+    import time
+
+    from perfbench import run
+
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    monkeypatch.setattr("tempfile.tempdir", None)
+    tracer = run.Tracer(True, 0.0, 0.2)
+    tracer.between_steps(0.0)
+    time.sleep(0.25)
+    tracer.between_steps(0.25)
+    red = tracer.finish()
+    assert red["window_marked"] is True
+    t0, t1 = red["host_window"]
+    assert red["window_s"] == pytest.approx(t1 - t0, abs=1e-4)
+    assert red["busy_s"] <= red["window_s"]

@@ -43,7 +43,7 @@ def test_another_seed_another_order():
 def test_every_seed_sends_the_same_multiset_of_sizes(seed):
     ref = counted(traffic.open_schedule(CHAT, 0, 51, VOCAB))
     got = counted(traffic.open_schedule(CHAT, seed, 51, VOCAB))
-    assert len(got) == len(ref) == 112  # 2.2 req/s x 51 s in blocks of 8
+    assert len(got) == len(ref) == 688  # 13.6 req/s x 51 s in blocks of 8
     for key in (lambda p: len(p.prompt), lambda p: p.out_len):
         assert Counter(map(key, got)) == Counter(map(key, ref))
     new = lambda ps, all_: sorted(  # noqa: E731
@@ -104,7 +104,7 @@ def test_the_seed_moves_whole_blocks_and_draws_the_tokens(seed):
 
     ref, got = blocks(0), blocks(seed)
     assert sorted(got) == sorted(ref) and got != ref
-    assert len(set(ref)) == len(ref) == 14
+    assert len(set(ref)) == len(ref) == 86  # 688 requests in blocks of 8
 
 
 def test_lengths_and_capacity():
@@ -125,7 +125,9 @@ def test_quantile_lengths_are_a_fixed_ascending_multiset():
 
 def test_a_window_that_is_no_whole_number_of_blocks_rounds_down():
     plans = traffic.open_schedule(CHAT, 3, 10, VOCAB)
-    assert len(counted(plans)) == 16  # 22 slots -> two blocks of 8
+    assert len(counted(plans)) == 136  # 136 slots: 17 blocks of 8
+    plans = traffic.open_schedule(CHAT, 3, 2.5, VOCAB)
+    assert len(counted(plans)) == 32   # 34 slots -> four blocks of 8
 
 
 @pytest.mark.parametrize("seed", SEEDS)
