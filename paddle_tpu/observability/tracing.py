@@ -76,6 +76,7 @@ and the fault-tolerance SIGTERM/SIGINT handler — the post-mortem for
 
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import os
@@ -113,10 +114,13 @@ _TRACING = [os.environ.get("PADDLE_TPU_TRACING", "1") != "0"]
 _COMPACT_AT = 512
 
 # The bounded flight-recorder ring: most recent events, process-wide.
-# Sized for the serving engine's iteration phases: about nine events an
-# iteration, 400 a second on a chip, so 65,536 reach back over two
-# minutes (the benchmark reads a 51 s window after its drain).
-_RING_CAPACITY = int(os.environ.get("PADDLE_TPU_TRACE_RING", "65536"))
+# Sized for the serving engine's iteration phases: eight events an
+# iteration and ten or so a request. With the host a step ahead of the
+# tokens it reads an iteration is as short as the decode step, 5 ms on
+# a chip, so some 1,800 events a second: 262,144 reach back over two
+# minutes (the benchmark reads a 51 s window after its drain; 65,536
+# stopped short of its start). About 400 bytes an event.
+_RING_CAPACITY = int(os.environ.get("PADDLE_TPU_TRACE_RING", "262144"))
 
 _lock = threading.Lock()
 _ring: deque = deque(maxlen=_RING_CAPACITY)
@@ -461,12 +465,17 @@ def _to_dict(ev: tuple) -> dict:
     return out
 
 
-def events(trace=None, name: Optional[str] = None) -> List[dict]:
+def events(trace=None, name: Optional[str] = None,
+           last: Optional[int] = None) -> List[dict]:
     """All buffered events (ring + live thread buffers), oldest first;
-    optionally filtered to one trace id and/or one event name."""
+    optionally filtered to one trace id and/or one event name.
+    ``last``: only the newest ``last`` events by their place in the
+    ring, at a cost that does not grow with the ring (the flight
+    recorder's read, under the crashed engine's step lock)."""
     _flush_locked()
     with _lock:
-        evs = list(_ring)
+        evs = list(_ring) if last is None else \
+            list(itertools.islice(reversed(_ring), int(last)))
     if trace is not None:
         evs = [e for e in evs if e[3] == trace]
     if name is not None:
@@ -697,7 +706,7 @@ def flight_dump(reason: str, extra: Optional[dict] = None,
             "ts": time.time(),
             "pid": os.getpid(),
             "tracing": summary(),
-            "events": events()[-int(last_n):],
+            "events": events(last=last_n),
             "state": state_snapshot(),
         }
         if extra:

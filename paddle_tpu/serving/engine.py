@@ -596,6 +596,10 @@ class ServingEngine:
         self._n_prefill_rows = 0       # chunks that rode a prefill program
         self._n_prefill_programs = 0   # prefill programs enqueued
         self._n_prefill_fill_rows = 0  # rows beyond a slot's first
+        # the step in flight (``_ahead``, _step_impl)
+        self._n_steps_ahead = 0    # steps enqueued with one still unread
+        self._n_ahead_flushes = 0  # times something rare read it first
+        self._n_dead_rows = 0      # rows whose request ended under them
         # windowed layout (EVA) only
         self._n_window_rolls = 0       # slots that crossed into a window
         self._n_window_blocks_released = 0   # exact-key blocks given back
@@ -772,9 +776,19 @@ class ServingEngine:
         self._slot_win = [0] * B     # windowed layout: the row's window
         self._jobs: List[Optional[_PrefillJob]] = [None] * B
         # first tokens selected by a prompt's last chunk and not yet read
-        # from the device: (a program's tokens, its last rows); empty
-        # outside an iteration
+        # from the device: (a program's tokens, its last rows), read in
+        # the NEXT iteration's engine.wait (_deliver_first_tokens)
         self._parked_tokens: List[tuple] = []
+        # the decode step that is enqueued and not yet read, a pipeline
+        # of depth one: ``(tokens on the device, [(slot, request)], the
+        # time of its dispatch)``; and per slot the tokens selected on
+        # the device for its occupant and not yet delivered (a parked
+        # first token, a row of the step in flight), which with the
+        # delivered ones decide ``max_new_tokens``
+        self._ahead: Optional[tuple] = None
+        self._slot_due = [0] * B
+        self._sync_ns = 0     # when the last step's tokens reached the host
+        self._unseated = 0    # requests _admit has popped and not seated
         # this engine's closures are NEW executables — their first
         # compiles are warmup, not retraces of a previous engine's
         C = int(config.prefill_chunk)
@@ -1580,6 +1594,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         before = _recompile.total_compiles()
         with self._step_lock:
+            self._flush_ahead()
             if self.busy_slots() or self.scheduler.depth:
                 raise RuntimeError(
                     "warmup() requires an idle engine: it dispatches "
@@ -1724,6 +1739,7 @@ class ServingEngine:
         self._slot_blocks[slot] = []
         self._bt[slot, :] = 0
         self._slot_len[slot] = 0
+        self._slot_due[slot] = 0
         self._slot_win[slot] = 0
 
     def _note_admission(self, req: Request, now: float,
@@ -1797,7 +1813,10 @@ class ServingEngine:
         only) preempt the latest-admitted OTHER request. Admission never
         preempts — a request that cannot be admitted without violence
         waits at the queue front instead (no admission/preemption
-        thrash)."""
+        thrash). Preemption waits for what is in flight: its tokens are
+        emitted first (``_flush_ahead``), which may itself free the
+        blocks, or end the requester (the error then stands: there is
+        nobody to allocate for)."""
         while True:
             try:
                 return self.pool.alloc(n)
@@ -1806,8 +1825,15 @@ class ServingEngine:
                 if self.prefix_cache is not None \
                         and self.prefix_cache.evict(deficit) > 0:
                     continue
-                victim = self._pick_victim(exclude=requester) \
-                    if allow_preempt else None
+                if not allow_preempt:
+                    raise
+                if self.in_flight:
+                    occupant = self._slot_req[requester]
+                    self._flush_ahead()
+                    if self._slot_req[requester] is not occupant:
+                        raise
+                    continue
+                victim = self._pick_victim(exclude=requester)
                 if victim is None:
                     raise
                 self._preempt(victim)
@@ -1865,8 +1891,14 @@ class ServingEngine:
         the request back to the QUEUE FRONT with its generated tokens
         folded into the next prefill and its PRNG chain replayed — the
         resumed decode is bit-identical, and the one token the resumed
-        prefill's select re-derives is skipped, never re-delivered."""
+        prefill's select re-derives is skipped, never re-delivered.
+        What is in flight is emitted first, so the resume state is built
+        from every token the device has selected; a slot whose request
+        that ends has nothing left to preempt."""
+        self._flush_ahead()
         req = self._slot_req[slot]
+        if req is None:
+            return
         tokens, recompute_len = self._build_resume(slot)
         if tokens is not None:
             # the resume prefill recomputes exactly tokens[:recompute_
@@ -2270,8 +2302,8 @@ class ServingEngine:
         token (generate's key chain) and wrote it into the slot's state
         row on the device, so the slot flips into the decode batch here,
         on the host's side alone (``_finish_prefill``), and its token is
-        parked: the host's copy is read once this iteration's step is
-        dispatched (``_deliver_first_tokens``), so the device never
+        parked: the host's copy is read in the next iteration's
+        ``engine.wait`` (``_deliver_first_tokens``), so the device never
         drains between a prompt's last chunk and the step behind it."""
         from ..observability import perf as _perf
         for rows, token, entry, tc0, tc1 in ran:
@@ -2319,21 +2351,28 @@ class ServingEngine:
         self._jobs[slot] = None
         self._decoding[slot] = True
         self._slot_len[slot] = job.total
+        # the chunk selected the first token; a resumed prompt's is one
+        # the request already has
+        self._slot_due[slot] = 0 if job.skip else 1
         self._slot_sampling[slot] = bool(req.params.do_sample)
 
-    def _deliver_first_tokens(self):
-        """Read the parked first tokens (``_book_chunks``), one
-        device-to-host read a program, and deliver each to its request.
-        Called with the iteration's step already dispatched, and on
-        every other way out of the iteration: a prefill program is done
-        long before the step behind it, so a first token never waits
-        for a decode step it does not depend on. A slot that was
-        cancelled or preempted since its chunk was booked gets nothing
-        (a preempted request's resumed prefill selects the token
-        again); a request that ends on its first token frees its slot
-        here, and the row it has in the step is dropped when the step's
-        tokens are emitted."""
-        parked, self._parked_tokens = self._parked_tokens, []
+    def _deliver_first_tokens(self, n: Optional[int] = None):
+        """Read the first ``n`` parked first tokens (``_book_chunks``;
+        all of them by default), one device-to-host read a program, and
+        deliver each to its request. ``_step_impl`` reads those of the
+        iteration before, in ``engine.wait``: this iteration's programs
+        are enqueued by then, the prefill program that selected them
+        ran before the step in flight, and they are read before that
+        step's tokens, so a first token waits for its own program and
+        for no decode step it does not depend on, and a request's
+        tokens arrive in order. Until then the slot is decoding with no
+        output token yet (``_slot_due`` counts the one parked). A slot
+        that was cancelled since its chunk was booked gets nothing; a
+        request that ends on its first token frees its slot here, and
+        the rows it has in the steps enqueued meanwhile are dead
+        (``_emit_ahead``)."""
+        parked = self._parked_tokens[:n]
+        del self._parked_tokens[:n]
         for token, last in parked:
             toks = None
             for r, slot, job in last:
@@ -2357,6 +2396,7 @@ class ServingEngine:
         req._tr_begin("decode")
         if job.skip:
             return  # resumed: tok0 re-derives the last delivered token
+        self._slot_due[slot] -= 1
         req.push_token(tok0, now)
         req._tr_event("first_token")
         _sm.ttft_seconds.observe(req.ttft_s)
@@ -2378,45 +2418,61 @@ class ServingEngine:
         # request quarantine its whole cohort).
         if any(r is not None and r.quarantine_probe for r in self._slot_req):
             return
-        for slot in range(self.config.max_slots):
-            while self._slot_req[slot] is None:
-                req = self.scheduler.pop_ready()
-                if req is None:
-                    return
-                if req.quarantine_probe and self.busy_slots():
-                    # the probe waits at the queue front for an idle
-                    # pool (admission-backoff requeue: same wait
-                    # window), and blocks everything behind it — brief,
-                    # bounded by the in-flight requests' decode
-                    self.scheduler.requeue(req)
-                    return
-                try:
-                    self._begin_prefill(req, slot)
-                except PoolExhaustedError:
-                    # not enough free blocks even after cache eviction:
-                    # FCFS holds — the request waits at the queue front
-                    # until decode completions release blocks
-                    self.scheduler.requeue(req)
-                    return
-                except Exception as e:  # noqa: BLE001 — engine must survive
-                    self._clear_slot(slot)
-                    req.finish(RequestStatus.FAILED, error=repr(e))
-                    _sm.requests_total.labels("failed").inc()
-                    self._outcomes["failed"] = self._outcomes.get("failed", 0) + 1
-                else:
-                    if req.quarantine_probe:
-                        return  # solo: nothing is admitted beside it
+        # a request between the queue and its slot is in neither count:
+        # raised before the pop and lowered after the seating, so that
+        # drain(), which reads the queue, then this, then the slots,
+        # without the step lock, never sees it nowhere
+        self._unseated += 1
+        try:
+            for slot in range(self.config.max_slots):
+                while self._slot_req[slot] is None:
+                    req = self.scheduler.pop_ready()
+                    if req is None:
+                        return
+                    if req.quarantine_probe and self.busy_slots():
+                        # the probe waits at the queue front for an idle
+                        # pool (admission-backoff requeue: same wait
+                        # window), and blocks everything behind it — brief,
+                        # bounded by the in-flight requests' decode
+                        self.scheduler.requeue(req)
+                        return
+                    try:
+                        self._begin_prefill(req, slot)
+                    except PoolExhaustedError:
+                        # not enough free blocks even after cache eviction:
+                        # FCFS holds — the request waits at the queue front
+                        # until decode completions release blocks
+                        self.scheduler.requeue(req)
+                        return
+                    except Exception as e:  # noqa: BLE001 — engine must survive
+                        self._clear_slot(slot)
+                        req.finish(RequestStatus.FAILED, error=repr(e))
+                        _sm.requests_total.labels("failed").inc()
+                        self._outcomes["failed"] = self._outcomes.get("failed", 0) + 1
+                    else:
+                        if req.quarantine_probe:
+                            return  # solo: nothing is admitted beside it
+        finally:
+            self._unseated -= 1
 
     # -- the iteration -------------------------------------------------------
     def step(self) -> bool:
         """One engine iteration: admit into free slots, advance every
         in-flight chunked prefill by one chunk (and, in the rows its
         prefill program has left, the earliest-admitted ones by
-        further chunks), then (if any slot is decoding) run the single
-        jitted decode step for the whole pool and deliver/retire
-        per-slot tokens; a prompt that ended this iteration is in that
-        step, and its first token is delivered once the step is
-        dispatched. Returns True when any work happened.
+        further chunks), then (if any slot is decoding) enqueue the
+        single jitted decode step for the whole pool, and only then
+        read and deliver the tokens of the step the iteration BEFORE
+        enqueued: the host stays one step ahead of the tokens it reads,
+        so the device has this iteration's programs queued while the
+        host emits and builds the next. On return at most one step and
+        the first tokens of the prompts that ended in this iteration
+        are in flight; the next ``step()`` delivers both, and whatever
+        needs the tokens' values first (``_flush_ahead``) reads them
+        early. A speculative engine reads before it returns, as it
+        always did. Returns True when any work happened or anything is
+        still in flight, so ``while eng.step()`` ends with every token
+        delivered.
 
         A ``PoolExhaustedError`` escaping the iteration (every in-loop
         exhaustion is normally absorbed by eviction/preemption — an
@@ -2440,10 +2496,23 @@ class ServingEngine:
 
     def _step_impl(self) -> bool:
         """The iteration, under ``_step_lock``: admit, advance prefills,
-        reserve blocks for the decode rows, dispatch the step, wait for
-        its tokens, emit them, each a phase of ``engine.iter``. Every
-        ``ph.mark`` is the one clock read of a phase boundary; the step
-        histogram and the ``serving.step`` span reuse those readings
+        reserve blocks for the decode rows, dispatch step N+1, wait for
+        step N's tokens, emit them, each a phase of ``engine.iter``: a
+        pipeline of depth one. Nothing the host does before a dispatch
+        needs the values of the tokens in flight: the step reads its
+        inputs from ``self._state`` on the device, and the table, the
+        active mask and ``any_sampling`` follow from lengths and counts,
+        which move when a step is ENQUEUED (``_slot_len``,
+        ``_slot_due``). A row whose last token by count is in flight
+        gets no further row; one that ends on a value (end of sequence,
+        a deadline, a cancel seen at emit) already has one, which is
+        dead (``_emit_ahead``). Whatever needs the values first, or
+        hands requests over, reads what is in flight first
+        (``_flush_ahead``). The speculative lane sizes its bundles from
+        tokens on the host and stays synchronous.
+
+        Every ``ph.mark`` is the one clock read of a phase boundary; the
+        step histogram and the ``serving.step`` span reuse those readings
         (with tracing disabled a mark reads no clock, and the step's
         two edges are read here)."""
         with self._step_lock:
@@ -2456,6 +2525,7 @@ class ServingEngine:
                                           self._n_prefill_fill_rows)
             self._last_progress_ts = ph.open("engine.admit") / 1e9
             worked = False
+            dispatch_args = None   # of engine.dispatch, while it is open
             try:
                 self._admit()
                 ph.mark("engine.prefill", ph.on and {
@@ -2486,6 +2556,9 @@ class ServingEngine:
                 if claimed:
                     self._claim_spare_rows(claimed)
                     ran.append(self._enqueue_claimed(claimed))
+                # the first tokens parked before this iteration's: read
+                # in engine.wait, behind this iteration's enqueues
+                n_parked = len(self._parked_tokens)
                 self._book_chunks([r for r in ran if r is not None])
                 if self.spec:
                     # the speculative lane sizes its bundles from what a
@@ -2500,26 +2573,27 @@ class ServingEngine:
                             - n_programs,
                             "fill": self._n_prefill_fill_rows - n_fill}
                         or None)
-                active = [i for i, r in enumerate(self._slot_req)
-                          if r is not None and self._decoding[i]]
+                active = self._rows_to_step()
                 # cancellation between steps: drop flagged slots without
-                # paying another decode step for them
-                for i in list(active):
-                    if self._slot_req[i].cancel_requested:
-                        self._free_slot(i, RequestStatus.CANCELLED,
-                                        "cancelled")
-                        active.remove(i)
-                if not active:
-                    return worked
+                # paying another decode step for them, once what they
+                # have in flight is emitted
+                if any(self._slot_req[i].cancel_requested for i in active):
+                    self._flush_ahead()
+                    for i in self._rows_to_step():
+                        if self._slot_req[i].cancel_requested:
+                            self._free_slot(i, RequestStatus.CANCELLED,
+                                            "cancelled")
+                    active = self._rows_to_step()
 
                 # every active row writes this step's K/V at its current
                 # length — or, speculatively, at its whole verify-bundle
                 # window [len, len + spec_len): cross a block boundary
                 # -> allocate; write into a shared (prefix-cached) block
                 # -> COW fork. Allocation pressure preempts the
-                # latest-admitted request, which can shrink `active`.
+                # latest-admitted request, which can shrink `active`
+                # (and first emits what is in flight, which can too).
                 bs = self.config.block_size
-                for i in list(active):
+                for i in active:
                     if self._slot_req[i] is None or not self._decoding[i]:
                         continue  # preempted by an earlier row's reclaim
                     # _row_spec_len is a pure function of host state that
@@ -2531,92 +2605,174 @@ class ServingEngine:
                                             self._slot_len[i] + m)
                     except PoolExhaustedError:
                         self._preempt(i)
-                active = [i for i in active
-                          if self._slot_req[i] is not None
-                          and self._decoding[i]]
-                if not active:
+                if active:
                     worked = True
-                    return True
-                # the pool blocks the step's attention reads: each
-                # active row's, up to the end of what it writes
-                if self._layout is None:
-                    dispatch_args = ph.on and {"kv_blocks": sum(
-                        -(-(self._slot_len[i] + (self._row_spec_len(i)
-                                                 if self.spec else 1))
-                          // bs) for i in active)}
-                else:
-                    # exact keys of the window, and summaries behind it
-                    read = ph.on and [self._layout.read_blocks(
-                        self._slot_len[i] + 1) for i in active]
-                    dispatch_args = ph.on and {
-                        "kv_blocks": sum(r[0] for r in read),
-                        "summary_blocks": sum(r[1] for r in read)}
-
+                    active = [i for i in active
+                              if self._slot_req[i] is not None
+                              and self._decoding[i]]
+                # a flush on the way (a cancel, pool pressure) has read
+                # this iteration's first tokens too
+                n_parked = min(n_parked, len(self._parked_tokens))
+                enqueued = None
+                if active:
+                    ahead = self._ahead is not None
+                    # the pool blocks the step's attention reads: each
+                    # active row's, up to the end of what it writes
+                    if self._layout is None:
+                        dispatch_args = ph.on and {"kv_blocks": sum(
+                            -(-(self._slot_len[i] + (self._row_spec_len(i)
+                                                     if self.spec else 1))
+                              // bs) for i in active), "ahead": int(ahead)}
+                    else:
+                        # exact keys of the window, and summaries behind it
+                        read = ph.on and [self._layout.read_blocks(
+                            self._slot_len[i] + 1) for i in active]
+                        dispatch_args = ph.on and {
+                            "kv_blocks": sum(r[0] for r in read),
+                            "summary_blocks": sum(r[1] for r in read),
+                            "ahead": int(ahead)}
+                    t0_ns = ph.mark("engine.dispatch") \
+                        or time.perf_counter_ns()
+                    any_sampling = any(self._slot_sampling[i]
+                                       for i in active)
+                    active_mask = np.zeros(self.config.max_slots, bool)
+                    active_mask[active] = True
+                    if self.spec:
+                        self._spec_step(active, active_mask, any_sampling,
+                                        t0_ns, ph, dispatch_args)
+                        dispatch_args = None
+                        return True
+                    with _entrypoint("serving.step"):
+                        bt_step = self._bt.copy()
+                        bt_step[~active_mask] = 0  # inactive -> dump block
+                        toks, self._pools, self._state = self._step_fn(
+                            self._pb, self._pools, self._state, bt_step,
+                            np.asarray(any_sampling, bool), active_mask)
+                    # enqueued: the rows' lengths and counts move now,
+                    # the next reservation needs them
+                    rows = []
+                    for i in active:
+                        rows.append((i, self._slot_req[i]))
+                        self._slot_len[i] = min(self._slot_len[i] + 1,
+                                                self.config.max_len - 1)
+                        self._slot_due[i] += 1
+                    self._n_steps_ahead += ahead
+                    enqueued = toks, rows, t0_ns
+                prev = self._ahead
+                if prev is None and not n_parked:
+                    self._ahead = enqueued
+                    return worked   # nothing to read: the pipeline fills
                 worked = True
-                t0_ns = ph.mark("engine.dispatch") \
-                    or time.perf_counter_ns()
-                any_sampling = any(self._slot_sampling[i] for i in active)
-                active_mask = np.zeros(self.config.max_slots, bool)
-                active_mask[active] = True
-                if self.spec:
-                    self._spec_step(active, active_mask, any_sampling,
-                                    t0_ns, ph, dispatch_args)
-                    return True
-                with _entrypoint("serving.step"):
-                    bt_step = self._bt.copy()
-                    bt_step[~active_mask] = 0  # inactive -> dump block
-                    toks, self._pools, self._state = self._step_fn(
-                        self._pb, self._pools, self._state, bt_step,
-                        np.asarray(any_sampling, bool), active_mask)
                 ph.mark("engine.wait", dispatch_args)
-                # the step is queued behind the prefill programs: their
-                # first tokens are read now, and wait for none of it
-                self._deliver_first_tokens()
-                toks_np = np.asarray(toks)  # the step's ONE device->host sync
-                # a request that ended on its first token has a row in
-                # the step and no use for it
-                active = [i for i in active if self._slot_req[i] is not None]
+                dispatch_args = None
+                # this iteration's programs are queued behind the step
+                # in flight: now the host reads. First the first tokens
+                # the iteration before parked (their program ran before
+                # that step), then the step's ONE device->host sync
+                self._deliver_first_tokens(n_parked)
+                toks_np = np.asarray(prev[0]) if prev else None
+                # (had that read failed, the step behind it would have
+                # gone with it: a request's tokens arrive in order)
+                self._ahead = enqueued
                 now_ns = ph.mark("engine.emit") or time.perf_counter_ns()
-                now = now_ns / 1e9
-                step_s = (now_ns - t0_ns) / 1e9
-                _sm.steps_total.inc()
-                _sm.step_seconds.observe(step_s)
-                # the engine-lane step span (dispatch plus wait) reuses the
-                # boundaries' timestamps: no extra clock read on the hot path
-                _trace.complete("serving.step", "engine", "engine", t0_ns,
-                                now_ns - t0_ns,
-                                {"active": len(active), "step": self._steps})
-                self._steps += 1
-                self._occupancy_integral += len(active)
-                from ..observability import perf as _perf
-                _perf.note_entry_items("serving.step", len(active))
-                # dispatch to tokens on the host: the one synced interval of
-                # the step, which is what the perf ledger may divide by
-                _perf.note_entry_time("serving.step", step_s)
-
-                for i in active:
-                    req = self._slot_req[i]
-                    self._slot_len[i] = min(self._slot_len[i] + 1,
-                                            self.config.max_len - 1)
-                    t = int(toks_np[i])
-                    prev = req.last_token_ts
-                    req.push_token(t, now)
-                    _sm.tokens_generated.inc()
-                    if prev is not None:
-                        _sm.tpot_seconds.observe(now - prev)
-                        _sm.tpot_summary.observe(now - prev)
-                    self._finish_or_keep(i, req, t, now)
+                if prev:
+                    self._emit_ahead(prev, toks_np, now_ns)
                 return True
+            except BaseException:
+                # whatever ended the iteration takes nothing with it: the
+                # tokens in flight are delivered, or dropped where the
+                # device no longer gives them
+                self._flush_ahead(or_drop=True)
+                raise
             finally:
-                # whatever way out: no first token stays parked
-                self._deliver_first_tokens()
                 self._update_occupancy_gauges()
                 self.pool.set_gauges()
                 # an iteration that only admitted (and lost the request
                 # again) is recorded too: its engine.admit has tokens
-                ph.close(worked or self._n_prompt_tokens > n_prompt, None,
+                ph.close(worked or self._n_prompt_tokens > n_prompt,
+                         dispatch_args,
                          ph.on and {"preempted":
                                     self._preempt_count - n_pre})
+
+    def _rows_to_step(self) -> List[int]:
+        """The slots the next decode step carries: decoding, and with a
+        token still to come by count, those delivered and those in
+        flight taken together (a row whose last token is in flight has
+        its slot until that token is emitted, and no further row)."""
+        return [i for i, r in enumerate(self._slot_req)
+                if r is not None and self._decoding[i]
+                and len(r.output_tokens) + self._slot_due[i]
+                < r.params.max_new_tokens]
+
+    @property
+    def in_flight(self) -> bool:
+        """A step is enqueued and unread, or a first token is parked."""
+        return self._ahead is not None or bool(self._parked_tokens)
+
+    def _emit_ahead(self, step: tuple, toks_np, now_ns: int):
+        """Deliver the tokens of a step that was in flight (``step``,
+        its ``_ahead`` record; ``toks_np``, read at ``now_ns``) to the
+        requests that were given its rows at dispatch, never through
+        the slot as it stands now: a row whose request has left its slot
+        since (it ended on an earlier token's value) is dead, its token
+        is dropped and counted. The step's span and the perf ledger's
+        interval run from sync to sync (from its dispatch where the
+        device had drained), the one interval a pipelined step has."""
+        _, rows, t0_ns = step
+        now = now_ns / 1e9
+        live = [(i, req) for i, req in rows if self._slot_req[i] is req]
+        self._n_dead_rows += len(rows) - len(live)
+        t0_ns = max(t0_ns, self._sync_ns)
+        self._sync_ns = now_ns
+        step_s = (now_ns - t0_ns) / 1e9
+        _sm.steps_total.inc()
+        _sm.step_seconds.observe(step_s)
+        # the engine-lane step span reuses the boundaries' timestamps:
+        # no extra clock read on the hot path
+        _trace.complete("serving.step", "engine", "engine", t0_ns,
+                        now_ns - t0_ns,
+                        {"active": len(live), "step": self._steps})
+        self._steps += 1
+        self._occupancy_integral += len(live)
+        from ..observability import perf as _perf
+        _perf.note_entry_items("serving.step", len(live))
+        _perf.note_entry_time("serving.step", step_s)
+        for i, req in live:
+            self._slot_due[i] -= 1
+            t = int(toks_np[i])
+            prev = req.last_token_ts
+            req.push_token(t, now)
+            _sm.tokens_generated.inc()
+            if prev is not None:
+                _sm.tpot_seconds.observe(now - prev)
+                _sm.tpot_summary.observe(now - prev)
+            self._finish_or_keep(i, req, t, now)
+
+    def _flush_ahead(self, or_drop: bool = False):
+        """Read and emit what is in flight, ahead of the iteration that
+        would have: the parked first tokens, then the step. Everything
+        rare that needs the tokens' values, or hands requests over,
+        calls this first and then goes on as an engine with nothing in
+        flight does: preemption and the resume state, a cancel between
+        steps, ``stop``, ``drain``, ``run_until_idle``'s return, the
+        export and the failing of requests, a crashed iteration. Caller
+        holds the step lock. ``or_drop``: where the device no longer
+        gives the tokens (the program that made them failed), what is
+        in flight is dropped and the requests keep what they were
+        given, which is what their resume state is built from."""
+        if not self.in_flight:
+            return
+        self._n_ahead_flushes += 1
+        step, self._ahead = self._ahead, None
+        try:
+            self._deliver_first_tokens()
+            if step is not None:
+                self._emit_ahead(step, np.asarray(step[0]),
+                                 time.perf_counter_ns())
+        except Exception:  # noqa: BLE001 — or_drop: the crash path's own
+            del self._parked_tokens[:]
+            if not or_drop:
+                raise
 
     # -- the speculative iteration -------------------------------------------
     def _row_spec_len(self, slot: int) -> int:
@@ -2761,16 +2917,28 @@ class ServingEngine:
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> int:
         """Drive ``step()`` until queue and slots are empty (the
-        synchronous serving loop); returns iterations executed."""
+        synchronous serving loop); returns iterations executed.
+        Returns with nothing in flight, ``max_steps`` or not."""
         n = 0
-        while n < max_steps and (self.scheduler.depth or self.busy_slots()):
+        while n < max_steps and self.has_work():
             if not self.step():
                 break
             n += 1
+        with self._step_lock:
+            self._flush_ahead()
         # admission may have drained the queue into terminal states
         # without any decode work; one more pass clears stragglers
         self._admit()
         return n
+
+    def has_work(self) -> bool:
+        """A request is queued, between the queue and its slot, or in a
+        slot, or a step is in flight (its rows may all be dead, with no
+        slot left to show for it). Read without the step lock, in the
+        order in which a request moves, so that one on its way is
+        always seen somewhere."""
+        return bool(self.scheduler.depth or self._unseated
+                    or self.busy_slots() or self.in_flight)
 
     # -- background loop -----------------------------------------------------
     def start(self):
@@ -2799,8 +2967,7 @@ class ServingEngine:
             while self._running:
                 if not self.step():
                     with self._wake:
-                        if self._running and not self.scheduler.depth \
-                                and not self.busy_slots():
+                        if self._running and not self.has_work():
                             with _trace.profiled_span("engine.idle", "engine",
                                                       "engine"):
                                 self._wake.wait(0.05)
@@ -2849,7 +3016,10 @@ class ServingEngine:
         """Fail every running slot and queued request with ``error`` so
         their ``result()``/``stream()`` callers return instead of
         hanging (crash / abort / drain-timeout paths; caller holds the
-        step lock)."""
+        step lock). What is in flight is emitted first: no token that
+        the device selected before the hand-over is lost, and none
+        arrives after it."""
+        self._flush_ahead(or_drop=True)
         for slot in range(self.config.max_slots):
             if self._slot_req[slot] is not None:
                 self._free_slot(slot, RequestStatus.FAILED, "failed",
@@ -2873,7 +3043,11 @@ class ServingEngine:
         same ``_build_resume`` recipe preemption uses, so a FRESH
         engine resumes each running request bit-identically. Queued
         requests were never touched by the crashing step and carry no
-        resume state at all."""
+        resume state at all. What is in flight is emitted first where
+        the device still gives it, and dropped where not: the resume
+        state is built from what each request was given, and nothing
+        reaches a request after its capture."""
+        self._flush_ahead(or_drop=True)
         running = []
         order = sorted(
             (slot for slot in range(self.config.max_slots)
@@ -2928,7 +3102,7 @@ class ServingEngine:
             self._wake.notify_all()
         deadline = (time.perf_counter() + timeout_s
                     if timeout_s is not None else None)
-        while self.scheduler.depth or self.busy_slots():
+        while self.has_work():
             if self._crashed is not None:
                 return False  # crash path failed everything already
             if deadline is not None and time.perf_counter() > deadline:
@@ -3168,9 +3342,11 @@ class ServingEngine:
         bumped where its event happens; the ``engine.admit`` and
         ``engine.iter`` spans carry the token and preemption counts per
         iteration. ``slot_steps`` is the occupancy integral: decode rows
-        summed over ``steps``. Blocks, COW forks and chunks are counted
-        by the pool (``stats()["kv_blocks"]``) and the metrics
-        registry."""
+        summed over ``steps``. Both count a step when its tokens are
+        emitted, ``steps_ahead`` when it is enqueued: with a step in
+        flight the latter already holds it and the former do not yet.
+        Blocks, COW forks and chunks are counted by the pool
+        (``stats()["kv_blocks"]``) and the metrics registry."""
         out = {
             "steps": self._steps,
             "slots": self.config.max_slots,
@@ -3185,6 +3361,13 @@ class ServingEngine:
             "prefill_rows": self._n_prefill_rows,
             "prefill_programs": self._n_prefill_programs,
             "prefill_fill_rows": self._n_prefill_fill_rows,
+            # decode steps enqueued while the step before was unread
+            # (of ``steps``, once both are emitted), the times something
+            # rare read what was in flight ahead of its iteration, and
+            # the rows of a step whose request had ended by its emit
+            "steps_ahead": self._n_steps_ahead,
+            "ahead_flushes": self._n_ahead_flushes,
+            "dead_rows": self._n_dead_rows,
         }
         if self._layout is not None:
             # slots that crossed into a new window, the exact-key blocks

@@ -370,7 +370,7 @@ class EngineSupervisor:
                     break
                 time.sleep(0.002)
                 continue
-            if not (eng.scheduler.depth or eng.busy_slots()):
+            if not eng.has_work():
                 break
             deadline = time.perf_counter() + self.restart_grace_s
             try:

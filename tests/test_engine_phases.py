@@ -6,6 +6,10 @@ Oracles:
   children (admit, prefill, reserve, dispatch, wait, emit) are disjoint,
   lie inside it and cover it; each carries ``iter``; a request's
   ``prefill_chunk`` carries the ``iter`` of the iteration that ran it.
+  The host is one step ahead: ``engine.wait`` and ``engine.emit`` of an
+  iteration belong to the step the iteration before dispatched, so the
+  iteration that fills the pipeline has neither and the one that drains
+  it has no ``engine.dispatch``.
 - COUNTERS: ``counters()`` is lock-free and agrees with ``stats()`` and
   with the sums of the span args; a span carries no arg, and
   ``counters()`` no key, that nothing reads.
@@ -13,8 +17,9 @@ Oracles:
   ``BlockPool.stats()``; no pool operation reduces over the pool.
 - FIRST TOKEN AFTER THE DISPATCH: a prompt's last chunk leaves its first
   token on the device; the slot joins the step at once, the token is
-  read with the step already enqueued, and no way out of the iteration
-  leaves one unread.
+  read in the next iteration's ``engine.wait``, behind that iteration's
+  enqueues and before the step's own tokens, and a failed iteration
+  leaves none unread.
 - HOST ARGUMENTS: a prefill program (one row or several) and a step hand
   their host arrays to the executable as they are; nothing is made with
   ``jnp.asarray`` a chunk or a step.
@@ -107,7 +112,9 @@ class TestPhases:
             kids = sorted((e for e in evs if e["name"] in CHILDREN),
                           key=lambda e: e["ts_ns"])
             names = [k["name"] for k in kids]
-            assert names == CHILDREN[:len(names)] and len(names) >= 3, it
+            # nothing decoding; the pipeline fills; steady; it drains
+            assert names in (CHILDREN[:3], CHILDREN[:4], CHILDREN,
+                             CHILDREN[:3] + CHILDREN[4:]), it
             lo, hi = parent["ts_ns"], parent["ts_ns"] + parent["dur_ns"]
             for a, b in zip(kids, kids[1:]):
                 assert a["ts_ns"] + a["dur_ns"] <= b["ts_ns"]   # disjoint
@@ -124,7 +131,8 @@ class TestPhases:
     def test_a_span_carries_only_the_args_a_metric_reads(self, served):
         # iter ties the lanes together; preempted is preemptions.*;
         # the two token counts are prefix_hit_share.chat; kv_blocks is
-        # decode_live_blocks_per_step.*; rows, programs and fill are
+        # decode_live_blocks_per_step.*; ahead is
+        # steps_ahead_per_step.*; rows, programs and fill are
         # prefill_rows_per_iter.*, prefill_programs_per_iter.* and
         # prefill_fill_rows_per_iter.*, on the iterations that enqueued
         # a prefill program and no other
@@ -132,7 +140,7 @@ class TestPhases:
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
                                  "prompt_tokens"},
-                "engine.dispatch": {"iter", "kv_blocks"}}
+                "engine.dispatch": {"iter", "kv_blocks", "ahead"}}
         # (another test's engine may idle on a thread of its own meanwhile)
         mine = [e for e in events if e["name"].startswith("engine.")
                 and e["name"] != "engine.idle"]
@@ -173,7 +181,8 @@ class TestPhases:
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
                                  "prompt_tokens"},
-                "engine.dispatch": {"iter", "kv_blocks", "summary_blocks"}}
+                "engine.dispatch": {"iter", "kv_blocks", "summary_blocks",
+                                    "ahead"}}
         for e in lane:
             # (engine.prefill: rows, programs and fill, as on any paged
             # engine; a windowed slot takes no spare row, so fill is 0)
@@ -265,27 +274,45 @@ class TestPhases:
             == c["prompt_tokens"] == 32 + 37 + 38 + 21
 
     def test_every_decode_step_has_one_emit_and_one_wait(self, served):
+        """... in the iteration after its dispatch; a wait with no step
+        behind it read a parked first token alone."""
         eng, reqs, events = served
-        emits = [e for e in events if e["name"] == "engine.emit"]
-        waits = [e for e in events if e["name"] == "engine.wait"]
-        assert len(emits) == len(waits) == eng.counters()["steps"] > 0
+        emits = [e["args"]["iter"] for e in events
+                 if e["name"] == "engine.emit"]
+        waits = [e["args"]["iter"] for e in events
+                 if e["name"] == "engine.wait"]
+        disp = [e["args"]["iter"] for e in events
+                if e["name"] == "engine.dispatch"]
+        assert emits == waits and len(disp) == eng.counters()["steps"] > 0
+        assert {it + 1 for it in disp} <= set(waits)
+        assert eng.counters()["ahead_flushes"] == 0
         # every request's first token comes out of its last prefill
         # chunk; the rest one a row a step, which slot_steps integrates
         assert eng.counters()["slot_steps"] \
             == sum(len(r.output_tokens) - 1 for r in reqs)
 
-    def test_serving_step_keeps_its_extent_dispatch_plus_wait(self, served):
+    def test_serving_step_runs_from_sync_to_sync(self, served):
+        """The one interval a pipelined step has: it ends where the
+        next iteration's ``engine.wait`` does (its tokens on the host),
+        and starts where the step before it ended, or at its own
+        dispatch where the device had drained."""
         _, _, events = served
-        steps = [e for e in events if e["name"] == "serving.step"]
+        steps = sorted((e for e in events if e["name"] == "serving.step"),
+                       key=lambda e: e["args"]["step"])
         disp = {e["args"]["iter"]: e for e in events
                 if e["name"] == "engine.dispatch"}
         wait = {e["args"]["iter"]: e for e in events
                 if e["name"] == "engine.wait"}
-        assert len(steps) == len(disp) == len(wait) > 0
-        by_start = {e["ts_ns"]: e for e in steps}
-        for it, d in disp.items():
-            st = by_start[d["ts_ns"]]
-            assert st["dur_ns"] == d["dur_ns"] + wait[it]["dur_ns"]
+        assert len(steps) == len(disp) > 0
+        ahead, synced = 0, 0
+        for st, it in zip(steps, sorted(disp)):
+            d, w = disp[it], wait[it + 1]
+            assert st["ts_ns"] + st["dur_ns"] == w["ts_ns"] + w["dur_ns"]
+            assert st["ts_ns"] == max(d["ts_ns"], synced)
+            assert d["args"]["ahead"] == (d["ts_ns"] < synced)
+            ahead += d["args"]["ahead"]
+            synced = st["ts_ns"] + st["dur_ns"]
+        assert 0 < ahead < len(steps)
 
     def test_an_iteration_that_did_nothing_records_nothing(self, tiny_model):
         model, _ = tiny_model
@@ -445,20 +472,22 @@ class TestCounters:
         assert set(c) == {"steps", "slots", "slot_steps", "queue_depth",
                           "prompt_tokens", "prefix_hit_tokens",
                           "preemptions", "prefill_rows",
-                          "prefill_programs", "prefill_fill_rows"}
+                          "prefill_programs", "prefill_fill_rows",
+                          "steps_ahead", "ahead_flushes", "dead_rows"}
 
 
 class TestFirstTokenAfterDispatch:
     def test_the_step_is_enqueued_before_the_first_token_is_read(
             self, served):
-        """Within the iteration of a prompt's last chunk: the step's
-        enqueue has returned (``engine.dispatch`` ended) before the
-        request's ``first_token``, which falls inside ``engine.wait``
-        and so before the step's own tokens."""
+        """A prompt's last chunk parks its first token; the step of that
+        iteration is enqueued behind it, and the token is read in the
+        NEXT iteration's ``engine.wait``: after that iteration's own
+        enqueues, and before the tokens of the step it rode in."""
         _, reqs, events = served
         by_name = {n: {e["args"]["iter"]: e for e in events
                        if e["name"] == n}
-                   for n in ("engine.dispatch", "engine.wait")}
+                   for n in ("engine.dispatch", "engine.wait",
+                             "engine.emit")}
         seen = 0
         for req in reqs:
             mine = [e for e in events if e["trace"] == req.trace]
@@ -467,19 +496,22 @@ class TestFirstTokenAfterDispatch:
                        and e["args"]["last"]]
             it = last["args"]["iter"]
             disp, wait = by_name["engine.dispatch"][it], \
-                by_name["engine.wait"][it]
+                by_name["engine.wait"][it + 1]
             assert last["ts_ns"] + last["dur_ns"] <= disp["ts_ns"]
-            assert disp["ts_ns"] + disp["dur_ns"] <= first["ts_ns"] \
-                <= wait["ts_ns"] + wait["dur_ns"]
+            assert disp["ts_ns"] + disp["dur_ns"] <= wait["ts_ns"] \
+                <= first["ts_ns"] <= wait["ts_ns"] + wait["dur_ns"]
+            ahead = by_name["engine.dispatch"].get(it + 1)
+            assert ahead is None or ahead["args"]["ahead"] == 1
             seen += 1
         assert seen == 4
 
     def test_the_cache_holds_the_prompt_before_the_step_reserves(
             self, tiny_model):
         """insert, then the decode write's reservation (a COW fork of
-        the half block the cache now shares), then the step, then the
-        token: the host's side of a finished prefill is booked at once,
-        only the read waits."""
+        the half block the cache now shares), then the step; the next
+        iteration reserves and enqueues its own step, and only then is
+        the token read: the host's side of a finished prefill is booked
+        at once, only the read waits."""
         model, cfg = tiny_model
         eng = _engine(model)
         order = []
@@ -502,42 +534,46 @@ class TestFirstTokenAfterDispatch:
         req = eng.submit(_prompt(np.random.RandomState(21), cfg, BLOCK + 5),
                          max_new_tokens=3)
         assert eng.step()
-        assert order == ["insert", "reserve", "step", "token"]
+        assert order == ["insert", "reserve", "step"]
         assert eng.pool.stats()["cow_forks"] == forks + 1
+        assert list(req.output_tokens) == [] and eng.in_flight
+        assert eng.step()
+        assert order[3:] == ["reserve", "step", "token"]
         assert len(req.output_tokens) == 2 and eng._parked_tokens == []
+        assert eng.step() and not eng.in_flight
+        assert len(req.output_tokens) == 3 and eng.step() is False
 
     @pytest.mark.parametrize("how", ["max_new_tokens", "eos"])
-    def test_a_request_that_ends_on_its_first_token_wastes_one_row(
-            self, tiny_model, how):
-        """Its slot is in the step that was dispatched before the token
-        was read: the slot is free when the step's tokens are emitted,
-        the row is dropped, and exactly one token went out."""
+    def test_a_request_that_ends_on_its_first_token(self, tiny_model, how):
+        """By count it is given no row at all: the one token it may have
+        is the one parked. By value (end of sequence) the host cannot
+        know before it reads: the rows it was given meanwhile are dead,
+        counted, and deliver nothing. Either way exactly one token goes
+        out and the neighbour's tokens are undisturbed."""
         model, cfg = tiny_model
         rng = np.random.RandomState(22)
         long_, short = _prompt(rng, cfg, 9), _prompt(rng, cfg, 7)
         eng = _engine(model)
         probe = eng.submit(short, max_new_tokens=1)
+        alone = eng.submit(long_, max_new_tokens=6)
         eng.run_until_idle()
         (tok0,) = probe.output_tokens
         params = {"max_new_tokens": 1} if how == "max_new_tokens" \
             else {"max_new_tokens": 8, "eos_token_id": tok0}
         eng = _engine(model, prefix_caching=False)
-        other = eng.submit(long_, max_new_tokens=4)
-        assert eng.step()       # other: first token and one more
-        before = eng.counters()
+        other = eng.submit(long_, max_new_tokens=6)
+        assert eng.step()       # other: first token parked, a step ahead
         req = eng.submit(short, **params)
-        assert eng.step()
-        after = eng.counters()
+        eng.run_until_idle()
+        c = eng.counters()
         assert req.status == serving.RequestStatus.COMPLETED
         assert list(req.output_tokens) == [tok0]
         assert req.slot is not None and eng._slot_req[req.slot] is None
-        # the step ran with both rows; one of them is counted
-        assert after["steps"] - before["steps"] == 1
-        assert after["slot_steps"] - before["slot_steps"] == 1
-        assert len(other.output_tokens) == 3
-        eng.run_until_idle()
-        assert len(other.output_tokens) == 4
-        assert eng.pool.used_blocks == 0
+        assert list(other.output_tokens) == list(alone.output_tokens)
+        # the steps carried other's five rows; req's are not counted
+        assert c["slot_steps"] == 5
+        assert c["dead_rows"] == (0 if how == "max_new_tokens" else 2)
+        assert eng.pool.used_blocks == 0 and not eng.in_flight
 
     def test_a_failed_dispatch_still_delivers_the_first_token(
             self, tiny_model):
@@ -555,10 +591,12 @@ class TestFirstTokenAfterDispatch:
         assert len(req.output_tokens) == 1 and eng._parked_tokens == []
         assert req.first_token_ts is not None
 
-    def test_a_slot_cancelled_before_the_read_gets_nothing(self, tiny_model):
+    def test_a_slot_cancelled_before_the_read_gets_what_was_selected(
+            self, tiny_model):
         """Cancelled between its last chunk's booking and the step: the
-        iteration leaves by ``not active``, nothing stays parked, and
-        nothing is delivered to a request that left its slot."""
+        cancel is seen with a first token in flight, which is read
+        first (none is lost before a hand-over); the request then ends
+        as cancelled, no step is paid for it and nothing stays parked."""
         model, cfg = tiny_model
         eng = _engine(model)
         real = eng._finish_prefill
@@ -573,15 +611,16 @@ class TestFirstTokenAfterDispatch:
         before = eng.counters()["steps"]
         assert eng.step()
         assert req.status == serving.RequestStatus.CANCELLED
-        assert list(req.output_tokens) == [] and eng._parked_tokens == []
+        assert len(req.output_tokens) == 1 and not eng.in_flight
         assert eng.counters()["steps"] == before and eng.busy_slots() == 0
+        assert eng.counters()["ahead_flushes"] == 1
 
-    def test_a_slot_preempted_before_the_read_is_served_from_the_start(
+    def test_a_slot_preempted_before_the_read_keeps_its_first_token(
             self, tiny_model):
         """The decode write's reservation preempts the slot whose first
-        token is parked: the token is not delivered, the request goes
-        back to the queue's front as one that has produced nothing, and
-        its tokens are those of an undisturbed run, each once."""
+        token is parked: the token is read first, the request goes back
+        to the queue's front as one that has produced it, and its tokens
+        are those of an undisturbed run, each once."""
         model, cfg = tiny_model
         prompt = _prompt(np.random.RandomState(25), cfg, 20)
         eng = _engine(model)
@@ -600,7 +639,8 @@ class TestFirstTokenAfterDispatch:
         req = eng.submit(prompt, max_new_tokens=4)
         assert eng.step()
         assert fired and req.preempt_count == 1 and req.slot is None
-        assert list(req.output_tokens) == [] and eng._parked_tokens == []
+        assert list(req.output_tokens) == list(want.output_tokens)[:1]
+        assert not eng.in_flight
         eng.run_until_idle()
         assert list(req.output_tokens) == list(want.output_tokens)
 
@@ -791,3 +831,415 @@ class TestOneClock:
         assert [e["name"] for e in evs] == ["train.dispatch"] * 3
         assert [e["args"]["step"] for e in evs] == [0, 1, 2]
         assert all(e["cat"] == "train" and e["dur_ns"] > 0 for e in evs)
+
+
+# ---------------------------------------------------------------------------
+# the host one step ahead of the tokens it reads
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def models():
+    from paddle_tpu.models import (EvaByteConfig, EvaByteForCausalLM,
+                                   GPTConfig, GPTForCausalLM)
+
+    paddle.seed(0)
+    return {
+        "gpt": (GPTForCausalLM(GPTConfig.tiny()), dict(
+            max_slots=3, max_len=64, block_size=8, prefill_chunk=16)),
+        "llama_gqa": (LlamaForCausalLM(LlamaConfig.tiny(
+            num_key_value_heads=2, max_position_embeddings=256)), dict(
+            max_slots=3, max_len=128, block_size=BLOCK, prefill_chunk=16)),
+        # window 16 in chunks of 4: a prompt of 37 and 30 more roll thrice
+        "evabyte": (EvaByteForCausalLM(EvaByteConfig.tiny()), dict(
+            max_slots=2, max_len=128, block_size=4, prefill_chunk=8,
+            prefix_caching=False)),
+    }
+
+
+def _traffic(eng, vocab, sampled, n=5, seed=31, longest=40, most=30):
+    rng = np.random.RandomState(seed)
+    reqs = []
+    for k in range(n):
+        kw = dict(do_sample=True, temperature=0.8, top_k=20, top_p=0.9,
+                  seed=100 + k) if sampled else {}
+        reqs.append(eng.submit(
+            rng.randint(1, vocab, rng.randint(3, longest)).astype("int32"),
+            max_new_tokens=int(rng.randint(1, most)), **kw))
+    return reqs
+
+
+def _leaked(eng):
+    """Blocks still held once the prefix cache has let go of its own."""
+    if eng.prefix_cache is not None:
+        eng.prefix_cache.evict(eng.pool.num_blocks)
+    return eng.pool.used_blocks
+
+
+def _drive(eng, flush):
+    """``step()`` by hand to the end; ``flush``: read what is in flight
+    after every iteration, which is the engine with no step ahead."""
+    n = 0
+    while eng.has_work():
+        assert eng.step()
+        if flush:
+            with eng._step_lock:
+                eng._flush_ahead()
+        n += 1
+        assert n < 5000
+    assert eng.step() is False and not eng.in_flight
+    return n
+
+
+class TestStepAhead:
+    @pytest.mark.parametrize("kind,fmt,sampled", [
+        ("gpt", "bf16", False), ("gpt", "bf16", True),
+        ("gpt", "int8", True), ("llama_gqa", "bf16", True),
+        ("llama_gqa", "int8", False), ("llama_gqa", "int8", True),
+        ("evabyte", "bf16", False), ("evabyte", "bf16", True)])
+    def test_tokens_are_those_of_the_engine_that_flushes_every_iteration(
+            self, models, kind, fmt, sampled):
+        model, kw = models[kind]
+        vocab = model.config.vocab_size
+        got = {}
+        for flush in (True, False):
+            eng = serving.ServingEngine(model, kv_format=fmt, **kw)
+            reqs = _traffic(eng, vocab, sampled)
+            _drive(eng, flush)
+            assert all(r.status == serving.RequestStatus.COMPLETED
+                       and len(r.output_tokens) == r.params.max_new_tokens
+                       for r in reqs)
+            got[flush] = [list(r.output_tokens) for r in reqs]
+            c = eng.counters()
+            assert c["dead_rows"] == 0 and _leaked(eng) == 0
+            if flush:
+                assert c["steps_ahead"] == 0 and c["ahead_flushes"] > 0
+            else:
+                # only the step that fills the pipeline finds it empty
+                assert c["ahead_flushes"] == 0
+                assert c["steps"] - 3 <= c["steps_ahead"] < c["steps"]
+        assert got[True] == got[False]
+
+    def test_a_window_rolls_on_the_step_in_flight(self, models):
+        """The roll depends on the length alone, which moved when the
+        step was enqueued: the blocks the window gives back are free
+        for the next program while the step that read them is unread."""
+        model, kw = models["evabyte"]
+        prompt = _prompt(np.random.RandomState(41), model.config, 13)
+        outs = []
+        for flush in (True, False):
+            eng = serving.ServingEngine(model, **kw)
+            req = eng.submit(prompt, max_new_tokens=24)
+            rolled_ahead = 0
+            while eng.has_work():
+                rolls = eng.counters()["window_rolls"]
+                ahead = eng._ahead is not None
+                assert eng.step()
+                rolled_ahead += ahead and \
+                    eng.counters()["window_rolls"] > rolls
+                if flush:
+                    eng._flush_ahead()
+            outs.append(list(req.output_tokens))
+            # positions 16 and 32 are crossed by decode steps
+            assert eng.counters()["window_rolls"] == 2
+            assert rolled_ahead == (0 if flush else 2)
+        assert outs[0] == outs[1] and len(outs[0]) == 24
+
+    def test_end_of_sequence_mid_stream_leaves_one_dead_row(self, models):
+        """The host learns of it a step late: the row it gave the
+        request meanwhile is dead, its token never delivered, and the
+        prompt's blocks stay as the prefix cache holds them."""
+        model, kw = models["llama_gqa"]
+        prompt = _prompt(np.random.RandomState(42), model.config,
+                         2 * BLOCK + 3)
+        eng = serving.ServingEngine(model, **kw)
+        probe = eng.submit(prompt, max_new_tokens=12)
+        eng.run_until_idle()
+        want = list(probe.output_tokens)
+        cut = next(j for j in range(3, 12) if want[j] not in want[:j])
+        seen = []
+        eng = serving.ServingEngine(model, **kw)
+        req = eng.submit(prompt, max_new_tokens=12, eos_token_id=want[cut],
+                         on_token=lambda r, t: seen.append(t))
+        _drive(eng, flush=False)
+        assert req.status == serving.RequestStatus.COMPLETED
+        assert list(req.output_tokens) == seen == want[:cut + 1]
+        c = eng.counters()
+        assert (c["dead_rows"], c["ahead_flushes"]) == (1, 0)
+        assert c["slot_steps"] == cut and c["steps"] == cut + 1
+        # the same prompt again adopts its whole blocks and says the same
+        again = eng.submit(prompt, max_new_tokens=12)
+        eng.run_until_idle()
+        assert list(again.output_tokens) == want
+        assert eng.counters()["prefix_hit_tokens"] == 2 * BLOCK
+        assert _leaked(eng) == 0
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline"])
+    def test_a_request_ended_from_outside_with_a_step_in_flight(
+            self, models, how):
+        """A cancel is seen between steps and flushes; a deadline is
+        seen at emit and leaves a dead row. Either way the tokens
+        delivered are a prefix of the undisturbed run's, none arrives
+        after the request has ended, and the neighbour is untouched."""
+        model, kw = models["gpt"]
+        rng = np.random.RandomState(43)
+        p0, p1 = (_prompt(rng, model.config, n) for n in (9, 12))
+        eng = serving.ServingEngine(model, **kw)
+        alone = [eng.submit(p, max_new_tokens=20) for p in (p0, p1)]
+        eng.run_until_idle()
+        eng = serving.ServingEngine(model, **kw)
+        seen = []
+        req = eng.submit(p0, max_new_tokens=20,
+                         on_token=lambda r, t: seen.append(t))
+        other = eng.submit(p1, max_new_tokens=20)
+        for _ in range(6):
+            assert eng.step()
+        assert eng._ahead is not None and 0 < len(seen) < 20
+        if how == "cancel":
+            req.cancel()
+        else:
+            req.deadline_ts = 0.0
+        assert eng.step()
+        status = serving.RequestStatus.CANCELLED if how == "cancel" \
+            else serving.RequestStatus.EXPIRED
+        assert req.status == status
+        n = len(seen)
+        assert seen == list(alone[0].output_tokens)[:n] \
+            == list(req.output_tokens)
+        eng.run_until_idle()
+        assert len(seen) == n
+        assert list(other.output_tokens) == list(alone[1].output_tokens)
+        c = eng.counters()
+        assert (c["ahead_flushes"], c["dead_rows"]) \
+            == ((1, 0) if how == "cancel" else (0, 1))
+
+    def test_pool_pressure_preempts_only_after_the_step_in_flight_is_read(
+            self, models):
+        model, kw = models["llama_gqa"]
+        vocab = model.config.vocab_size
+        outs = []
+        for blocks in (None, 13):   # roomy; 12 usable blocks for 3 slots
+            eng = serving.ServingEngine(model, num_blocks=blocks, **kw)
+            rng = np.random.RandomState(4242)
+            reqs = [eng.submit(_prompt(rng, model.config, n),
+                               max_new_tokens=30, do_sample=True,
+                               temperature=0.9, seed=n)
+                    for n in (40, 55, 33)]
+            eng.run_until_idle(max_steps=5000)
+            outs.append([list(r.output_tokens) for r in reqs])
+            c = eng.counters()
+            if blocks:
+                assert c["preemptions"] >= 1
+                assert c["ahead_flushes"] >= c["preemptions"] > 0
+                assert sum(r.preempt_count for r in reqs) \
+                    == c["preemptions"]
+            assert not eng.in_flight and _leaked(eng) == 0
+        assert outs[0] == outs[1]
+        assert vocab and all(len(o) == 30 for o in outs[0])
+
+    @pytest.mark.parametrize("how", ["abort", "drain", "export", "crash"])
+    def test_a_hand_over_takes_what_is_in_flight_first(self, models, how):
+        """None lost before it, none after it: what the device had
+        selected when the engine was stopped, drained, exported or
+        crashed reaches its request first, and nothing reaches a
+        request afterwards. An exported request resumes on a fresh
+        engine to the undisturbed run's tokens."""
+        model, kw = models["gpt"]
+        rng = np.random.RandomState(44)
+        prompts = [_prompt(rng, model.config, n) for n in (7, 18, 11)]
+        eng = serving.ServingEngine(model, **kw)
+        alone = [eng.submit(p, max_new_tokens=16, do_sample=True, seed=k)
+                 for k, p in enumerate(prompts)]
+        eng.run_until_idle()
+        want = [list(r.output_tokens) for r in alone]
+        eng = serving.ServingEngine(model, **kw)
+        seen = [[] for _ in prompts]
+        reqs = [eng.submit(p, max_new_tokens=16, do_sample=True, seed=k,
+                           on_token=lambda r, t, k=k: seen[k].append(t))
+                for k, p in enumerate(prompts)]
+        for _ in range(7):
+            assert eng.step()
+        assert eng._ahead is not None
+        given = [len(s) for s in seen]
+        due = [eng._slot_due[r.slot] for r in reqs]
+        assert sum(due) == 3
+        if how == "abort":
+            eng.stop(abort=True)
+        elif how == "drain":
+            assert eng.drain() is True
+        elif how == "export":
+            with eng._step_lock:
+                running, queued = eng._export_inflight()
+            assert running == reqs and queued == []
+        else:
+            def refuse(*a):
+                raise RuntimeError("device lost")
+
+            eng._step_fn = refuse
+            with pytest.raises(RuntimeError, match="device lost"):
+                eng.step()
+            assert not eng.in_flight
+            eng._on_loop_crash(RuntimeError("device lost"))
+        assert not eng.in_flight and eng.counters()["ahead_flushes"] \
+            == (0 if how == "drain" else 1)
+        after = [len(s) for s in seen]
+        if how == "drain":
+            assert [list(r.output_tokens) for r in reqs] == want == seen
+            return
+        assert after == [g + d for g, d in zip(given, due)]
+        assert all(s == w[:len(s)] == list(r.output_tokens)
+                   for s, w, r in zip(seen, want, reqs))
+        if how != "export":
+            assert all(r.status == serving.RequestStatus.FAILED
+                       for r in reqs)
+            assert eng.step() is False
+            assert [len(s) for s in seen] == after
+            return
+        fresh = serving.ServingEngine(model, **kw)
+        for r in reversed(running):
+            fresh.scheduler.requeue(r)
+        fresh.run_until_idle()
+        assert eng.step() is False       # the old engine holds nothing
+        assert [list(r.output_tokens) for r in reqs] == want == seen
+
+    def test_a_device_that_gives_no_tokens_back_drops_what_was_in_flight(
+            self, models):
+        """The crash path's flush where the read itself fails: nothing
+        is delivered, nothing stays in flight, the requests are failed
+        with what they had."""
+        model, kw = models["gpt"]
+        eng = serving.ServingEngine(model, **kw)
+        req = eng.submit(_prompt(np.random.RandomState(45), model.config,
+                                 9), max_new_tokens=10)
+        for _ in range(4):
+            assert eng.step()
+        had = len(req.output_tokens)
+
+        class Lost:
+            def __array__(self, *a, **k):
+                raise RuntimeError("buffer lost")
+
+        eng._ahead = (Lost(),) + eng._ahead[1:]
+        with pytest.raises(RuntimeError, match="buffer lost"):
+            eng.step()
+        assert not eng.in_flight and len(req.output_tokens) == had
+        eng._on_loop_crash(RuntimeError("buffer lost"))
+        assert req.status == serving.RequestStatus.FAILED
+        assert len(req.output_tokens) == had and eng.busy_slots() == 0
+
+    def test_by_hand_and_run_until_idle_leave_nothing_in_flight(
+            self, models):
+        model, kw = models["llama_gqa"]
+        eng = serving.ServingEngine(model, **kw)
+        reqs = _traffic(eng, model.config.vocab_size, False, n=4)
+        # a token of a step is seen no later than the next step() returns
+        total = 0
+        while eng.step():
+            now = sum(len(r.output_tokens) for r in reqs)
+            due = sum(eng._slot_due)
+            assert due <= 2 * eng.config.max_slots
+            assert now >= total
+            total = now + 0
+        assert not eng.in_flight and not eng.has_work()
+        assert all(len(r.output_tokens) == r.params.max_new_tokens
+                   for r in reqs)
+        more = _traffic(eng, model.config.vocab_size, False, n=4, seed=32)
+        assert eng.run_until_idle(max_steps=4) == 4
+        assert not eng.in_flight and eng.has_work()   # max_steps: flushed
+        assert sum(eng._slot_due) == 0
+        eng.run_until_idle()
+        assert all(r.status == serving.RequestStatus.COMPLETED
+                   for r in more) and not eng.has_work()
+
+    def test_a_request_between_the_queue_and_its_slot_counts_as_work(
+            self, models):
+        """``drain()`` asks ``has_work()`` without the step lock: a
+        request ``_admit`` has popped and not yet seated is in neither
+        the queue nor a slot, and must not read as an idle engine."""
+        model, kw = models["gpt"]
+        eng = serving.ServingEngine(model, **kw)
+        real, seen = eng._begin_prefill, []
+
+        def seat(req, slot):
+            seen.append((eng.scheduler.depth, eng.busy_slots(),
+                         eng.has_work()))
+            return real(req, slot)
+
+        eng._begin_prefill = seat
+        req = eng.submit(_prompt(np.random.RandomState(47), model.config,
+                                 9), max_new_tokens=2)
+        eng.run_until_idle()
+        assert seen == [(0, 0, True)] and len(req.output_tokens) == 2
+        assert eng._unseated == 0 and not eng.has_work()
+
+    def test_a_speculative_engine_is_never_ahead(self, tiny_model):
+        from paddle_tpu import generation
+
+        model, cfg = tiny_model
+        eng = _engine(model, draft_model=generation.truncated_draft(model, 1),
+                      spec_k=2)
+        t0 = tracing.events()[-1]["ts_ns"] + 1
+        reqs = _traffic(eng, cfg.vocab_size, False, n=3)
+        while eng.step():
+            assert not eng.in_flight
+        c = eng.counters()
+        assert all(r.status == serving.RequestStatus.COMPLETED for r in reqs)
+        assert (c["steps_ahead"], c["ahead_flushes"], c["dead_rows"]) \
+            == (0, 0, 0) and c["steps"] > 0
+        disp = [e for e in _engine_lane(t0) if e["name"] == "engine.dispatch"]
+        assert disp and all(e["args"]["ahead"] == 0 for e in disp)
+
+    def test_the_dispatch_arg_sums_to_the_counter(self, models):
+        model, kw = models["gpt"]
+        eng = serving.ServingEngine(model, **kw)
+        t0 = tracing.events()[-1]["ts_ns"] + 1
+        _traffic(eng, model.config.vocab_size, True)
+        eng.run_until_idle()
+        first = eng.counters()
+        _traffic(eng, model.config.vocab_size, True, seed=33)
+        eng.run_until_idle()
+        c = eng.counters()
+        disp = [e["args"]["ahead"] for e in _engine_lane(t0)
+                if e["name"] == "engine.dispatch"]
+        assert len(disp) == c["steps"] and sum(disp) == c["steps_ahead"]
+        # the engine had idled between the two batches: one more step
+        # found the device drained
+        assert c["steps"] - c["steps_ahead"] \
+            == 2 * (first["steps"] - first["steps_ahead"])
+
+    def test_disabled_tracing_pays_one_flag_check_and_the_steps_two_edges(
+            self, models, monkeypatch):
+        """With ``PADDLE_TPU_TRACING=0`` a steady iteration's phases
+        read the tracing flag once (``Phases.open``; ``serving.step``'s
+        own span reads it too, as it always did) and the clock three
+        times: open, the step's dispatch, the sync."""
+        import paddle_tpu.serving.engine as engine_mod
+
+        model, kw = models["gpt"]
+        eng = serving.ServingEngine(model, **kw)
+        req = eng.submit(_prompt(np.random.RandomState(46), model.config, 9),
+                         max_new_tokens=12)
+        for _ in range(4):
+            assert eng.step()
+        reads = []
+        real = engine_mod.time.perf_counter_ns
+
+        class Flag(list):
+            def __getitem__(self, i):
+                reads.append("flag")
+                return False
+
+        tracing.disable_tracing()
+        try:
+            monkeypatch.setattr(tracing, "_TRACING", Flag([False]))
+            monkeypatch.setattr(
+                engine_mod.time, "perf_counter_ns",
+                lambda: reads.append("clock") or real())
+            assert eng.step()
+        finally:
+            monkeypatch.undo()
+            tracing.enable_tracing()
+        assert reads == ["flag", "clock", "clock", "clock", "flag"]
+        assert eng._ahead is not None and not eng._phases.on
+        eng.run_until_idle()
+        assert len(req.output_tokens) == 12

@@ -521,6 +521,10 @@ class TestServingEngineCrash:
         eng._bt = np.zeros((2, 2), np.int32)
         eng._slot_len = [0, 0]
         eng._slot_win = [0, 0]
+        # nothing in flight: no step ahead, no first token parked
+        eng._slot_due = [0, 0]
+        eng._ahead = None
+        eng._parked_tokens = []
         eng._slot_req = [None, None]
         eng._slot_sampling = [False, False]
         eng._decoding = [False, False]

@@ -1266,14 +1266,15 @@ class TestSpareRows:
     def test_spare_rows_go_to_the_earliest_admitted_slot_then_the_next(
             self, tiny_model):
         """Admission order, not slot order: B (slot 1) was admitted
-        before C, which took the slot a finished request left (slot 0).
-        The program's two spare rows: B's one further chunk, its last,
-        then C's next."""
+        before C, which took the slot a finished request left (slot 0:
+        X's second token was in flight through the second iteration, and
+        its slot was free from the third). The program's two spare rows:
+        B's one further chunk, its last, then C's next."""
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, **dict(self.KW, prefill_chunk=8))
         assert eng._chunk_rows == 4
         rng = np.random.RandomState(SEED + 41)
-        x, b, c = (_prompt(rng, cfg, n) for n in (5, 40, 60))
+        x, b, c = (_prompt(rng, cfg, n) for n in (5, 72, 60))
         rx = eng.submit(x, max_new_tokens=2)
         rb = eng.submit(b, max_new_tokens=4)
         seen = []
@@ -1282,16 +1283,19 @@ class TestSpareRows:
         # X's only chunk, B's first, and B's next two in the spare rows
         assert seen == [[(0, 0, True), (1, 0, False), (1, 8, False),
                          (1, 16, False)]]
+        eng.step()
+        assert seen[1] == [(1, 24, False), (1, 32, False), (1, 40, False),
+                           (1, 48, False)]
         assert rx.status == serving.RequestStatus.COMPLETED
         rc = eng.submit(c, max_new_tokens=4)
         eng.step()
         assert (rc.slot, rb.slot) == (0, 1)
         assert eng._slot_seq[1] < eng._slot_seq[0]
-        assert seen[1] == [(0, 0, False), (1, 24, False), (1, 32, True),
+        assert seen[2] == [(0, 0, False), (1, 56, False), (1, 64, True),
                            (0, 8, False)]
         c1 = eng.counters()
         assert (c1["prefill_rows"], c1["prefill_programs"],
-                c1["prefill_fill_rows"]) == (8, 2, 4)
+                c1["prefill_fill_rows"]) == (12, 3, 7)
         eng.run_until_idle()
         for r, p in ((rx, x), (rb, b), (rc, c)):
             assert list(r.output_tokens) == list(

@@ -913,18 +913,23 @@ class TestFilledProgramServing:
         has its first token after two iterations (four rows, then its
         last chunk), where one chunk an iteration took five, and the
         running requests got their token in each of them: the prompt
-        is still fed between decode steps."""
+        is still fed between decode steps. The host is a step ahead of
+        the tokens it reads, so each is seen one ``step()`` later."""
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, **self.KW)
         rng = np.random.RandomState(95)
         running = [eng.submit(_prompt(rng, cfg, 6), max_new_tokens=12)
                    for _ in range(2)]
         eng.step()
-        assert [len(r.output_tokens) for r in running] == [2, 2]
+        assert [len(r.output_tokens) for r in running] == [0, 0]
+        assert eng.in_flight    # their first tokens parked, a step ahead
         p = _prompt(rng, cfg, 38)
         late = eng.submit(p, max_new_tokens=4)
         eng.step()
         assert late.output_tokens == [] and eng._jobs[late.slot].done == 32
+        assert [len(r.output_tokens) for r in running] == [2, 2]
+        eng.step()
+        assert late.output_tokens == [] and eng._jobs[late.slot] is None
         assert [len(r.output_tokens) for r in running] == [3, 3]
         eng.step()
         assert len(late.output_tokens) == 2     # its first, and the step's
@@ -980,11 +985,14 @@ class TestFilledProgramServing:
         first = eng.submit(_prompt(rng, cfg, 5), max_new_tokens=6,
                            on_token=lambda r, t: seen.append(("a", t)))
         eng.step()
+        eng.step()
+        assert len(seen) == 2
         del seen[:]
         second = eng.submit(_prompt(rng, cfg, 20), max_new_tokens=3,
                             on_token=lambda r, t: seen.append(("b", t)))
-        eng.step()      # b: three rows, its last among them
-        assert [who for who, _ in seen] == ["b", "a", "b"]
+        eng.step()      # b: three rows, its last among them; a's third
+        eng.step()      # b's first, read before the step's a and b
+        assert [who for who, _ in seen] == ["a", "b", "a", "b"]
         assert second.first_token_ts <= first.last_token_ts
         eng.run_until_idle()
         assert [t for who, t in seen if who == "b"] == self._ref(
@@ -1015,6 +1023,7 @@ class TestFilledProgramServing:
         assert eng.counters()["prefill_fill_rows"] == 2
         assert reqs[0].status == serving.RequestStatus.FAILED
         assert "cache refused the prompt" in reqs[0].error
+        eng.step()
         assert eng._parked_tokens == [] and len(reqs[1].output_tokens) == 2
         eng.run_until_idle()
         assert list(reqs[1].output_tokens) == self._ref(

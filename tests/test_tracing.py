@@ -383,6 +383,26 @@ class TestFlightRecorder:
         assert "error" in dump["state"]["t_fr_broken"]
         assert tracing.last_flight_dump() == path
 
+    def test_the_dump_reads_the_rings_tail_not_the_whole_ring(
+            self, tmp_path, monkeypatch):
+        """``last_n`` newest events, oldest first, without a pass over
+        a ring that may hold a quarter of a million."""
+        monkeypatch.setenv("PADDLE_TPU_SINK_DIR", str(tmp_path))
+        for k in range(40):
+            tracing.instant("fr_tail", trace="t_fr_tail", args={"k": k})
+        # (another test's engine may idle on a thread of its own
+        # meanwhile: its events may sit among the newest)
+        got = [e["args"]["k"] for e in tracing.events(
+            trace="t_fr_tail", last=5)]
+        assert 3 <= len(got) <= 5 and got == list(range(40 - len(got), 40))
+        assert len(tracing.events(trace="t_fr_tail")) == 40
+        dump = json.loads(open(tracing.flight_dump(
+            "unit_test_tail", last_n=8)).read())
+        assert len(dump["events"]) == 8
+        got = [e["args"]["k"] for e in dump["events"]
+               if e["name"] == "fr_tail"]
+        assert len(got) >= 6 and got == list(range(40 - len(got), 40))
+
     def test_dump_on_injected_decode_loop_crash(self, tiny_model, tmp_path,
                                                 monkeypatch):
         """Acceptance: an injected engine crash writes a flight dump
