@@ -149,9 +149,19 @@ def kv_format_of(arr) -> str:
     return "bf16"
 
 
+def kv_cache_planes(config) -> int:
+    """K/V planes one cached position holds: one for every decoder
+    layer, and in a looped stack (``total_ut_steps`` passes over one
+    set of layers) one for every pass of every layer, since pass ``t``
+    of a layer writes the keys of another hidden state than pass ``t'``
+    does. Plane ``t * num_hidden_layers + i`` is pass ``t`` of layer
+    ``i``. Everything that sizes a cache asks here."""
+    return config.num_hidden_layers * getattr(config, "total_ut_steps", 1)
+
+
 def kv_cache_bytes_per_token(config, kv_format: str = "bf16",
                              dtype=jnp.float32) -> int:
-    """HBM bytes one cached token costs across all layers (K + V values
+    """HBM bytes one cached token costs across all planes (K + V values
     plus, for quantized formats, the per-token-per-head f32 absmax
     scales) — the host-side accounting the capacity benches and the
     ``paddle_tpu_kv_bytes_per_token`` gauge report."""
@@ -163,7 +173,7 @@ def kv_cache_bytes_per_token(config, kv_format: str = "bf16",
         per = n_kv * head_dim * jnp.dtype(dtype).itemsize
     else:
         per = n_kv * (head_dim * _intx.format_itemsize(kv_format) + 4)
-    return 2 * per * config.num_hidden_layers
+    return 2 * per * kv_cache_planes(config)
 
 
 def make_paged_kv_pools(config, num_blocks: int, block_size: int, dtype,
@@ -173,6 +183,12 @@ def make_paged_kv_pools(config, num_blocks: int, block_size: int, dtype,
     num_key_value_heads, head_dim]. Slots address the pool through
     per-slot int32 block tables instead of owning contiguous rows, so
     HBM is bounded by TOKENS IN FLIGHT, not slots * worst-case length.
+
+    A looped stack (``kv_cache_planes`` over ``num_hidden_layers``
+    passes) keeps a layer's planes in ONE array of ``passes *
+    num_blocks`` blocks: pass ``t`` addresses block ``b`` as ``t *
+    num_blocks + b``, so a block id still covers every plane and the
+    write and the kernel are handed ``block_table + t * num_blocks``.
 
     ``kv_format="int8"``/``"fp8"`` stores the values in the narrow dtype
     and adds per-token-per-head absmax scale pools ``ks``/``vs``
@@ -185,20 +201,18 @@ def make_paged_kv_pools(config, num_blocks: int, block_size: int, dtype,
 
     n_kv = config.num_key_value_heads
     head_dim = config.hidden_size // config.num_attention_heads
+    layers = config.num_hidden_layers
+    rows = num_blocks * (kv_cache_planes(config) // layers)
     if kv_format != "bf16":
         sdt = _intx.format_dtype(kv_format)  # raises actionably for fp8
-        return [{"k": jnp.zeros((num_blocks, block_size, n_kv, head_dim),
-                                sdt),
-                 "v": jnp.zeros((num_blocks, block_size, n_kv, head_dim),
-                                sdt),
-                 "ks": jnp.zeros((num_blocks, block_size, n_kv),
-                                 jnp.float32),
-                 "vs": jnp.zeros((num_blocks, block_size, n_kv),
-                                 jnp.float32)}
-                for _ in range(config.num_hidden_layers)]
-    return [{"k": jnp.zeros((num_blocks, block_size, n_kv, head_dim), dtype),
-             "v": jnp.zeros((num_blocks, block_size, n_kv, head_dim), dtype)}
-            for _ in range(config.num_hidden_layers)]
+        return [{"k": jnp.zeros((rows, block_size, n_kv, head_dim), sdt),
+                 "v": jnp.zeros((rows, block_size, n_kv, head_dim), sdt),
+                 "ks": jnp.zeros((rows, block_size, n_kv), jnp.float32),
+                 "vs": jnp.zeros((rows, block_size, n_kv), jnp.float32)}
+                for _ in range(layers)]
+    return [{"k": jnp.zeros((rows, block_size, n_kv, head_dim), dtype),
+             "v": jnp.zeros((rows, block_size, n_kv, head_dim), dtype)}
+            for _ in range(layers)]
 
 
 def paged_kv_cache_write(pool, new, block_table, position_offset,
@@ -535,6 +549,67 @@ def head_rows(h, kv_caches):
     idx = idx._data if isinstance(idx, Tensor) else jnp.asarray(idx)
     return Tensor(jnp.take_along_axis(
         h._data, idx.astype(jnp.int32)[:, None, None], axis=1))
+
+
+def looped_cache_passes(one_pass, h, kv_caches, passes: int, *, fold: bool,
+                        scope: str):
+    """Run ``one_pass(h, caches) -> (h, new_caches)`` ``passes`` times
+    over the cache of a looped stack (``kv_cache_planes``), each pass
+    starting from the state the one before left and reading and writing
+    its own planes. Returns ``(h, caches)`` with the caches as they came.
+
+    PAGED (``make_paged_kv_pools``: one dict a layer, the layer's passes
+    side by side in one array of ``passes * num_blocks`` blocks): pass
+    ``t`` is handed the dicts with ``block_table + t * num_blocks``, so
+    the write and the kernel need nothing else, and with ``fold`` the
+    passes are one ``lax.fori_loop`` that carries the state and the
+    pools: the program holds one pass's layer bodies, not ``passes``
+    times as many (unfolded: the same arithmetic in the same order).
+    CONTIGUOUS (``make_kv_caches``: a buffer a plane): pass ``t`` is
+    handed buffers ``t * L .. (t + 1) * L - 1``, walked unrolled.
+    ``scope`` names each pass in the device trace."""
+    paged, quantized = kv_cache_layout(kv_caches[0])
+    if not paged:
+        if len(kv_caches) % passes:
+            raise ValueError(
+                f"a looped stack of {passes} passes keeps a contiguous "
+                f"cache buffer for every pass and layer (make_kv_caches), "
+                f"got {len(kv_caches)}")
+        n, new_caches = len(kv_caches) // passes, []
+        for t in range(passes):
+            h, nc = one_pass(h, kv_caches[t * n:(t + 1) * n])
+            new_caches += nc
+        return h, new_caches
+    if quantized:
+        raise ValueError(
+            "a looped stack's paged cache is an unquantized pool: the "
+            "scale pools are not carried through the passes")
+    raw = lambda x: x._data if isinstance(x, Tensor) else x  # noqa: E731
+    bt, rows = raw(kv_caches[0]["bt"]), kv_caches[0]["k"].shape[0]
+    if rows % passes:
+        raise ValueError(
+            f"a pool of {rows} blocks does not hold {passes} passes side "
+            f"by side (make_paged_kv_pools)")
+
+    def one(t, hd, kv):
+        """Pass ``t`` over the pools ``kv`` ([(k, v)] a layer)."""
+        with jax.named_scope(scope):
+            table = Tensor(bt + t * (rows // passes))
+            caches = [dict(c, k=Tensor(k), v=Tensor(v), bt=table)
+                      for c, (k, v) in zip(kv_caches, kv)]
+            out, nc = one_pass(Tensor(hd), caches)
+        return out._data, [(c["k"]._data, c["v"]._data) for c in nc]
+
+    kv = [(raw(c["k"]), raw(c["v"])) for c in kv_caches]
+    if fold:
+        hd, kv = jax.lax.fori_loop(
+            0, passes, lambda t, carry: one(t, *carry), (h._data, kv))
+    else:
+        hd = h._data
+        for t in range(passes):
+            hd, kv = one(t, hd, kv)
+    return Tensor(hd), [dict(c, k=Tensor(k), v=Tensor(v))
+                        for c, (k, v) in zip(kv_caches, kv)]
 
 
 def update_static_kv_cache(kv_cache: dict, k, v, position_offset,
@@ -933,8 +1008,9 @@ def truncated_draft(model, num_layers: int):
 
 def make_kv_caches(config, batch_size: int, max_len: int, dtype,
                    kv_format: str = "bf16"):
-    """Pre-allocated per-layer static KV buffers: a list (one per
-    decoder layer) of {"k", "v"} jnp arrays shaped
+    """Pre-allocated static KV buffers: a list (one per plane,
+    ``kv_cache_planes``: a decoder layer, or a pass of one in a looped
+    stack) of {"k", "v"} jnp arrays shaped
     [batch_size, max_len, num_key_value_heads, head_dim].
     ``kv_format="int8"``/``"fp8"`` stores narrow values plus
     per-token-per-head absmax scales ``ks``/``vs`` ([b, max_len, n_kv]
@@ -949,10 +1025,10 @@ def make_kv_caches(config, batch_size: int, max_len: int, dtype,
                  "v": jnp.zeros((batch_size, max_len, n_kv, head_dim), sdt),
                  "ks": jnp.zeros((batch_size, max_len, n_kv), jnp.float32),
                  "vs": jnp.zeros((batch_size, max_len, n_kv), jnp.float32)}
-                for _ in range(config.num_hidden_layers)]
+                for _ in range(kv_cache_planes(config))]
     return [{"k": jnp.zeros((batch_size, max_len, n_kv, head_dim), dtype),
              "v": jnp.zeros((batch_size, max_len, n_kv, head_dim), dtype)}
-            for _ in range(config.num_hidden_layers)]
+            for _ in range(kv_cache_planes(config))]
 
 
 def make_cached_runner(model):
@@ -1672,7 +1748,7 @@ def generate(model, input_ids, max_new_tokens: int = 32, do_sample: bool = False
         _cache_sh = [
             {kk: _NS(tp_mesh_obj, _partition.kv_cache_spec(nd))
              for kk, nd in _ckeys.items()}
-            for _ in range(config.num_hidden_layers)]
+            for _ in range(kv_cache_planes(config))]
 
     cache_store = model.__dict__.setdefault("_generate_jit_cache", {})
     if gen_key not in cache_store:

@@ -13,3 +13,4 @@ from .llama import (
 from .gpt import GPTConfig, GPTForCausalLM
 from .bert import BertConfig, BertForPretraining, BertModel
 from .evabyte import EvaByteConfig, EvaByteForCausalLM
+from .ouro import OuroConfig, OuroForCausalLM

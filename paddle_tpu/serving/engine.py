@@ -95,9 +95,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..generation import (make_cached_runner, make_paged_kv_pools,
-                          select_tokens, spec_accept_length,
-                          split_key_levels, split_keys)
+from ..generation import (kv_cache_planes, make_cached_runner,
+                          make_paged_kv_pools, select_tokens,
+                          spec_accept_length, split_key_levels, split_keys)
 from ..observability import recompile as _recompile
 from ..observability import tracing as _trace
 from ..observability.recompile import entrypoint as _entrypoint
@@ -476,6 +476,13 @@ class ServingEngine:
         self._layout: Optional[WindowedLayout] = None
         if getattr(mcfg, "attention_class", None) == "eva":
             self._layout = self._eva_layout(mcfg, config, draft_model)
+        # a looped stack (its layers run ``total_ut_steps`` times a
+        # token) keeps a K/V plane for every pass of every layer: a pool
+        # block id covers them all, a layer's passes side by side in one
+        # array (generation.make_paged_kv_pools)
+        self._ut_steps = kv_cache_planes(mcfg) // int(mcfg.num_hidden_layers)
+        if self._ut_steps > 1:
+            self._refuse_for_loop(mcfg, config, draft_model)
         self.draft_model = draft_model
         self.spec = draft_model is not None
         if self.spec:
@@ -600,6 +607,7 @@ class ServingEngine:
         self._n_steps_ahead = 0    # steps enqueued with one still unread
         self._n_ahead_flushes = 0  # times something rare read it first
         self._n_dead_rows = 0      # rows whose request ended under them
+        self._n_loop_passes = 0    # passes of the steps enqueued
         # windowed layout (EVA) only
         self._n_window_rolls = 0       # slots that crossed into a window
         self._n_window_blocks_released = 0   # exact-key blocks given back
@@ -681,6 +689,37 @@ class ServingEngine:
                 f"that a prefill chunk pools whole chunks and never "
                 f"straddles a window")
         return WindowedLayout(config.block_size, W, c, config.max_len)
+
+    @staticmethod
+    def _refuse_for_loop(mcfg, config: ServingConfig, draft_model):
+        """The options nobody has made work, and tested, with a looped
+        stack: each is refused with its reason."""
+        refused = [
+            (float(getattr(mcfg, "early_exit_threshold", 1.0)) < 1.0,
+             f"early_exit_threshold={mcfg.early_exit_threshold}: rows of "
+             f"one batched step would leave the stack at different passes "
+             f"while later tokens still need the planes of the passes they "
+             f"skipped; serve it at the published threshold of 1"),
+            (draft_model is not None,
+             "a draft_model: the draft's pools share the target's block "
+             "tables, and a verify bundle through the passes' planes is "
+             "not built; drop the draft model"),
+            (config.kv_tier,
+             "kv_tier=True: a tier payload holds one plane a layer, not "
+             "one a pass and layer; drop kv_tier"),
+            (int(config.tp) > 1,
+             f"tp={config.tp}: the pools' block axis carries the passes "
+             f"and no sharded run of the loop has been tested; serve it "
+             f"with tp=1"),
+            (config.kv_format != "bf16",
+             f"kv_format={config.kv_format!r}: the scale pools are not "
+             f"carried through the loop; use kv_format='bf16'"),
+        ]
+        for bad, why in refused:
+            if bad:
+                raise ValueError(
+                    "a looped stack (total_ut_steps "
+                    f"{mcfg.total_ut_steps}) cannot be served with " + why)
 
     def _register_memory_components(self):
         """HBM-ledger attribution (``observability.perf.hbm_ledger``):
@@ -924,10 +963,19 @@ class ServingEngine:
                       (pb_sh, pool_sh, state_sh, rep, rep, rep),
                       (rep, pool_sh, state_sh))
 
+        # (locals, not ``self``: an executable that held the engine
+        # would keep engine, model and weights alive in a cycle that
+        # only the collector breaks)
+        passes, nblocks = self._ut_steps, self._nblocks
+
         def _cow(pools, src, dst):
             """Copy-on-write fork: duplicate physical block ``src`` into
             ``dst`` across every layer's K and V pool (one dispatch;
             src/dst are traced so every fork shares the executable)."""
+            if passes > 1:
+                # a looped stack: the block of every pass's plane
+                at = jnp.arange(passes, dtype=jnp.int32) * nblocks
+                src, dst = src + at, dst + at
             out = []
             for c in pools:
                 out.append({kk: c[kk].at[dst].set(c[kk][src])
@@ -1050,7 +1098,7 @@ class ServingEngine:
                 "bytes_per_token": self._kv_bytes_per_token,
                 "dtype": str(np.dtype(self._dtype)),
                 "spec": spec,
-                "layers": int(self._mcfg.num_hidden_layers),
+                "layers": kv_cache_planes(self._mcfg),
             })
         self._tier = KVTier(host_blocks=config.kv_tier_host_blocks,
                             block_size=config.block_size, cost=cost,
@@ -2631,6 +2679,9 @@ class ServingEngine:
                             "kv_blocks": sum(r[0] for r in read),
                             "summary_blocks": sum(r[1] for r in read),
                             "ahead": int(ahead)}
+                    if dispatch_args and self._ut_steps > 1:
+                        # the passes the enqueued step runs
+                        dispatch_args["ut_steps"] = self._ut_steps
                     t0_ns = ph.mark("engine.dispatch") \
                         or time.perf_counter_ns()
                     any_sampling = any(self._slot_sampling[i]
@@ -2657,6 +2708,7 @@ class ServingEngine:
                                                 self.config.max_len - 1)
                         self._slot_due[i] += 1
                     self._n_steps_ahead += ahead
+                    self._n_loop_passes += self._ut_steps
                     enqueued = toks, rows, t0_ns
                 prev = self._ahead
                 if prev is None and not n_parked:
@@ -3369,6 +3421,10 @@ class ServingEngine:
             "ahead_flushes": self._n_ahead_flushes,
             "dead_rows": self._n_dead_rows,
         }
+        if self._ut_steps > 1:
+            # a looped stack: the passes of every decode step enqueued
+            # (the sum of the dispatch spans' ``ut_steps``)
+            out["loop_passes"] = self._n_loop_passes
         if self._layout is not None:
             # slots that crossed into a new window, the exact-key blocks
             # those rolls gave back, chunks pooled into a summary

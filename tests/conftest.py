@@ -19,6 +19,7 @@ import jax
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "perfbench"))
 import perfbench_tiny_evabyte  # noqa: E402,F401
+import perfbench_tiny_ouro  # noqa: E402,F401
 
 # Unit tests run on the virtual CPU mesh whatever the machine holds.
 # PADDLE_TPU_TEST_PLATFORM=tpu switches to the on-chip lane
@@ -69,10 +70,26 @@ _PINNED_AT_LAST_SIX = {
     f"[prefill_rows_per_iter.{cell}]" for cell in ("chat", "doc")}
 
 
+# And two tests of tests/perfbench/test_perfbench_evabyte.py (PR 27) pin
+# the benchmark at five cells whose last is EvaByte's, and the tiny
+# checkout at three configurations; the sixth cell (PR 37) marks them
+# here, and tests/perfbench/test_perfbench_ouro.py holds the same facts
+# at the count the manifest has.
+_PINNED_AT_FIVE_CELLS = {
+    "test_the_tiny_checkout_holds_the_fifth_cell",
+    "test_the_four_chip_cell_is_still_listed_where_pr_25_put_it"}
+
+
 def pytest_collection_modifyitems(items):
     import pytest
 
     for item in items:
+        if item.name in _PINNED_AT_FIVE_CELLS \
+                and item.path.name == "test_perfbench_evabyte.py":
+            item.add_marker(pytest.mark.xfail(
+                strict=True, reason="asserts five cells and three tiny "
+                "configurations; BENCHMARK.json has six and four since "
+                "PR 37"))
         if item.name in _PINNED_AT_FOUR_CELLS \
                 and item.path.name == "test_perfbench_spans.py":
             item.add_marker(pytest.mark.xfail(

@@ -47,6 +47,11 @@ BUNDLES = {"decode": (SLOTS, 1, False), "chunk64": (1, 64, False),
 # step and the 256-token prefill chunk
 EVA_BUNDLES = {"eva-step": (20, 1), "eva-chunk256": (1, 256)}
 EVA_NB, EVA_POOL = 208, 2816
+# the looped cell (ouro-2p6b): 8 rows of up to 672 positions (42 table
+# entries), a layer's four passes side by side in one pool array of
+# 4 x 336 blocks, the step and the [8, 32] prefill program
+LOOP_BUNDLES = {"loop-step": (8, 1), "loop-chunk8x32": (8, 32)}
+LOOP_NB, LOOP_POOL = 42, 4 * 336
 RESNET50 = [(56, 64), (28, 128), (14, 256), (7, 512)]  # (H=W, channels)
 
 
@@ -58,6 +63,9 @@ def _paged(heads, fmt, bundle):
     h, kv = HEADS[heads]
     if bundle in EVA_BUNDLES:
         (b, q_len), tree, n, nb = EVA_BUNDLES[bundle], False, EVA_POOL, EVA_NB
+    elif bundle in LOOP_BUNDLES:
+        (b, q_len), tree, n, nb = (LOOP_BUNDLES[bundle], False, LOOP_POOL,
+                                   LOOP_NB)
     else:
         (b, q_len, tree), n, nb = BUNDLES[bundle], SLOTS * NB + 1, NB
     pool = _s((n, BS, kv, D), POOL_DTYPES[fmt])
@@ -136,6 +144,8 @@ def _conv(hw, c, train):
 
 CASES = {f"paged-mha32-bf16-{_b}": (_paged, ("mha32", "bf16", _b))
          for _b in EVA_BUNDLES}
+for _b in LOOP_BUNDLES:
+    CASES[f"paged-mha16-bf16-{_b}"] = (_paged, ("mha16", "bf16", _b))
 for _h in ("mha16", "gqa32_8"):
     for _b in BUNDLES:
         CASES[f"paged-{_h}-bf16-{_b}"] = (_paged, (_h, "bf16", _b))
