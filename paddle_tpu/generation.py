@@ -541,14 +541,21 @@ def head_rows(h, kv_caches):
     ``h[b, head_idx[b]]`` as [b, 1, hidden], and the [b, s, vocab]
     product is never made. Without the key, all of ``h``. Cache
     plumbing under no_grad, like the scatters above: no op of its own
-    on the dispatch surface."""
-    idx = kv_caches[0].get("head_idx") \
-        if isinstance(kv_caches[0], dict) else None
+    on the dispatch surface.
+
+    A batch of single-token rows of which only some are read (the
+    engine's fused step: the chunks' tokens a row each, then the decode
+    rows) puts ``head_pick`` [n] int32 there instead: the head sees
+    rows ``h[head_pick]`` as [n, 1, hidden]."""
+    first = kv_caches[0] if isinstance(kv_caches[0], dict) else {}
+    raw = lambda x: x._data if isinstance(x, Tensor) else jnp.asarray(x)  # noqa: E731
+    if first.get("head_pick") is not None:
+        return Tensor(h._data[raw(first["head_pick"]).astype(jnp.int32)])
+    idx = first.get("head_idx")
     if idx is None:
         return h
-    idx = idx._data if isinstance(idx, Tensor) else jnp.asarray(idx)
     return Tensor(jnp.take_along_axis(
-        h._data, idx.astype(jnp.int32)[:, None, None], axis=1))
+        h._data, raw(idx).astype(jnp.int32)[:, None, None], axis=1))
 
 
 def looped_cache_passes(one_pass, h, kv_caches, passes: int, *, fold: bool,
@@ -692,21 +699,42 @@ def cached_attention(q, k, v, kv_cache: dict, position_offset, *, family: str,
     attention over the padded cache; a paged chunk must read earlier
     blocks through its table and never takes it). ``after_write(cache)
     -> cache`` runs between the write and the read (a second write of
-    the same step: EVA's chunk summaries)."""
-    from .nn import functional as F
-    from .pallas_kernels.decode_attention import (
-        decode_dispatch, flash_decode_attention, paged_flash_decode_attention)
+    the same step: EVA's chunk summaries).
 
-    paged, quantized = kv_cache_layout(kv_cache)
+    A paged cache that also carries ``"chunk_bt"`` [P, nb] (and
+    ``"chunk_valid"`` [P]) holds the engine's fused step: a batch of
+    single-token rows, the first ``P * C`` of them P prefill chunks of C
+    tokens through ``chunk_bt``, the rest the decode rows through
+    ``"bt"`` (``_chunk_and_step_rows_attention``)."""
+    if "chunk_bt" in kv_cache:
+        return _chunk_and_step_rows_attention(
+            q, k, v, kv_cache, position_offset, family=family,
+            attn_mask=attn_mask, after_write=after_write)
+    paged, _ = kv_cache_layout(kv_cache)
     s = q.shape[1]
     if flash_prefill and not paged and attn_mask is None and s > 1 \
             and isinstance(position_offset, int) and position_offset == 0:
         _, _, new_cache, _ = update_static_kv_cache(
             kv_cache, k, v, 0, build_mask=False, gather=False)
         return _flash_causal_attention(q, k, v), new_cache
+    written = _write_for_attention(q, k, v, kv_cache, position_offset,
+                                   family, attn_mask, after_write)
+    return _attend_written(q, written, position_offset, attn_mask), written[3]
+
+
+def _write_for_attention(q, k, v, kv_cache: dict, position_offset, family,
+                         attn_mask, after_write):
+    """The first half of ``cached_attention``: ask ``decode_dispatch``
+    who reads, write ``k``/``v`` into the cache for that reader, run
+    ``after_write``. Returns ``(kernel, k_view, v_view, new_cache,
+    mask)``, what ``_attend_written`` takes."""
+    from .pallas_kernels.decode_attention import decode_dispatch
+
+    paged, quantized = kv_cache_layout(kv_cache)
     tree_mask = kv_cache.get("tree_mask")
     kernel = decode_dispatch(
-        family, paged=paged, q_len=s, dtype=q.dtype, quantized=quantized,
+        family, paged=paged, q_len=q.shape[1], dtype=q.dtype,
+        quantized=quantized,
         has_mask=attn_mask is not None
         or (tree_mask is not None and not paged))
     kf, vf, new_cache, mask = update_static_kv_cache(
@@ -714,18 +742,87 @@ def cached_attention(q, k, v, kv_cache: dict, position_offset, *, family: str,
         build_mask=attn_mask is None and not kernel, gather=not kernel)
     if after_write is not None:
         new_cache = after_write(new_cache)
+    return kernel, kf, vf, new_cache, mask
+
+
+def _attend_written(q, written, position_offset, attn_mask):
+    """The second half: ``q`` over what ``_write_for_attention`` left,
+    by the Pallas kernel over the raw buffers or pools, or the XLA
+    fallback over the dense view under the mask."""
+    from .nn import functional as F
+    from .pallas_kernels.decode_attention import (
+        flash_decode_attention, paged_flash_decode_attention)
+
+    kernel, kf, vf, new_cache, mask = written
     if not kernel:
         sdpa = F.grouped_query_sdpa if kf.shape[2] != q.shape[2] \
             else F.scaled_dot_product_attention
         return sdpa(q, kf, vf, attn_mask=mask if attn_mask is None
-                    else attn_mask), new_cache
+                    else attn_mask)
     scales = {"k_scale": new_cache.get("ks"), "v_scale": new_cache.get("vs")}
-    if paged:
+    if kv_cache_layout(new_cache)[0]:
         return paged_flash_decode_attention(
             q, new_cache["k"], new_cache["v"], new_cache["bt"],
-            position_offset, ancestor_mask=tree_mask, **scales), new_cache
-    return flash_decode_attention(q, kf, vf, position_offset,
-                                  **scales), new_cache
+            position_offset, ancestor_mask=new_cache.get("tree_mask"),
+            **scales)
+    return flash_decode_attention(q, kf, vf, position_offset, **scales)
+
+
+def _chunk_and_step_rows_attention(q, k, v, kv_cache: dict, position_offset,
+                                   **how):
+    """``cached_attention`` of the engine's fused step. ``q``/``k``/``v``
+    [P * C + B, 1, heads, d] are one batch of single-token rows, each at
+    its own ``position_offset`` [P * C + B], so that the linear layers
+    around this call see all of an iteration's rows in one pass over
+    the weights; here they part again. Rows ``[:P * C]`` are P prefill
+    chunks of C tokens, written through ``kv_cache["chunk_bt"]`` [P, nb]
+    up to ``kv_cache["chunk_valid"]`` [P] and attended as [P, C] (a
+    chunk's start is its first row's position); rows ``[P * C:]`` are
+    the B decode rows through ``kv_cache["bt"]`` [B, nb], attended as
+    [B, 1]: the two calls that the prefill program and the decode step
+    make on their own, with the same arithmetic a row. A slot is in one
+    part or in neither, so neither part reads a block the other writes
+    (both write the dump block, which nobody reads), and BOTH parts are
+    written before either is read: a kernel that still read the pools
+    while the second write updated them in place would have XLA copy
+    every pool, 6.4 GB a program at the served sizes (PERF.md section 6,
+    PR 38). P, C and B follow from the shapes.
+
+    The device trace tells the two kernel calls apart by the scope
+    they are in (XLA names a Pallas call by its innermost scope):
+    the decode rows' under ``_step_rows`` and the prefill rows' under
+    ``_chunk_rows``, so that what reads the decode kernel's time of a
+    program called ``_step`` by ``^_step.*custom-call$`` finds the
+    decode rows' call and no other."""
+    raw = lambda x: x._data if isinstance(x, Tensor) else x  # noqa: E731
+    cbt, valid, bt = (kv_cache[key]
+                      for key in ("chunk_bt", "chunk_valid", "bt"))
+    P, B = raw(cbt).shape[0], raw(bt).shape[0]
+    n = q.shape[0] - B
+    pos = raw(position_offset)
+    pos_c, pos_s = pos[:n:n // P], pos[n:]
+
+    def parts(t):
+        t = raw(t)
+        return (Tensor(t[:n].reshape((P, n // P) + t.shape[2:])),
+                Tensor(t[n:]))
+
+    (qc, qs), (kc, ks), (vc, vs) = parts(q), parts(k), parts(v)
+    cache = {key: val for key, val in kv_cache.items()
+             if key not in ("chunk_bt", "chunk_valid")}
+    chunk = _write_for_attention(
+        qc, kc, vc, dict(cache, bt=cbt, valid=valid), pos_c, **how)
+    pools = {key: val for key, val in chunk[3].items() if key != "valid"}
+    step = _write_for_attention(qs, ks, vs, dict(pools, bt=bt), pos_s, **how)
+    # the chunks' kernel reads the pools as the decode rows' write left
+    # them (its XLA fallback: the dense view it gathered after its own)
+    chunk = chunk[:3] + (dict(step[3], bt=cbt),) + chunk[4:]
+    with jax.named_scope("_chunk_rows"):
+        out_c = raw(_attend_written(qc, chunk, pos_c, how["attn_mask"]))
+    with jax.named_scope("_step_rows"):
+        out_s = raw(_attend_written(qs, step, pos_s, how["attn_mask"]))
+    out = jnp.concatenate([out_c.reshape((n, 1) + out_c.shape[2:]), out_s])
+    return Tensor(out), dict(step[3], chunk_bt=cbt, chunk_valid=valid)
 
 
 def _flash_causal_attention(q, k, v):

@@ -23,8 +23,15 @@ slots, not ``max_len - L``. On top of the pool:
   earliest-admitted slot's further chunks, and the weights are read
   once for all of them (an iteration's lone chunk rides the ``[1, C]``
   form of the same body: two executables, ``serving.prefill_chunk`` and
-  ``serving.prefill_chunk[P]``). A prompt's first token is read from
-  the device once the iteration's decode step is dispatched behind it.
+  ``serving.prefill_chunk[P]``). Where slots are decoding, the
+  iteration's last set of rows rides the decode step's own program
+  (``serving.step+chunk[P]``: one pass over the weights for the step
+  and the rows), on every engine whose step is one pass through one
+  table layout with its tokens on the device: no windowed layout, no
+  looped stack, no draft model, ``tp`` 1; such an engine keeps the
+  ``[P, C]`` width alone (a lone chunk rides it too). A prompt's
+  first token is read from the device once the iteration's decode step
+  is dispatched.
 * **preemption by recompute**: under pool pressure the latest-admitted
   request is preempted — its blocks freed, the request requeued at the
   queue front with its generated tokens folded into the prefill and its
@@ -53,7 +60,8 @@ ONE jitted pool-wide decode step runs per iteration: per-slot positions
 / sampling params / PRNG keys / active mask and the block tables are
 traced arrays, so mixed occupancy/length/sharing patterns share a single
 step executable that compiles exactly once (recompile-monitor-asserted
-across request waves).
+across request waves); with prefill rows in its program it is the same
+function at one more width, compiled once in ``warmup()``.
 
 Per-request outputs are bit-identical to ``generation.generate`` (whose
 contiguous static cache is the reference lane of the parity tests) with
@@ -607,6 +615,7 @@ class ServingEngine:
         self._n_steps_ahead = 0    # steps enqueued with one still unread
         self._n_ahead_flushes = 0  # times something rare read it first
         self._n_dead_rows = 0      # rows whose request ended under them
+        self._n_steps_fused = 0    # steps that carried prefill rows too
         self._n_loop_passes = 0    # passes of the steps enqueued
         # windowed layout (EVA) only
         self._n_window_rolls = 0       # slots that crossed into a window
@@ -832,9 +841,28 @@ class ServingEngine:
         # compiles are warmup, not retraces of a previous engine's
         C = int(config.prefill_chunk)
         P = prefill_batch_rows(C, self._dtype, B)
-        # the one body's two widths: [1, C] for a lone chunk, and [P, C]
-        self._chunk_entries = [self._chunk_entry(w) for w in sorted({1, P})]
-        warm = ["serving.step", "serving.cow", *self._chunk_entries]
+        # one program for the decode step and the iteration's last
+        # prefill rows, so that an iteration reads the weights once:
+        # where the step is one pass through one table layout with its
+        # tokens on the device (a windowed row rolls between the two
+        # halves, a looped stack would part inside its loop, the
+        # speculative lane sizes its bundles from tokens on the host,
+        # and a sharded step's in_shardings are one fixed tuple)
+        self._fuses = (self._layout is None and self._ut_steps == 1
+                       and not self.spec and tpm is None)
+        # the widths prefill rows ride at: [P, C], and [1, C] for a lone
+        # chunk. An engine that fuses keeps [P, C] alone, for the
+        # prefill program and for the step that carries rows: every
+        # executable costs warmup() its size, warm cache or not (on the
+        # chip 1.9 s a plain program and 3.5 s a fused one at GPT-3
+        # 1.3B's depth, PERF.md section 6, PR 38), and the fused [P, C]
+        # program is worth more than the two [1, C] forms it displaces
+        self._row_widths = [P] if self._fuses else sorted({1, P})
+        self._chunk_entries = [self._chunk_entry(w)
+                               for w in self._row_widths]
+        self._fused_entries = [self._step_entry(P)] if self._fuses else []
+        warm = ["serving.step", "serving.cow", *self._chunk_entries,
+                *self._fused_entries]
         if self.spec:
             warm += ["serving.spec_draft", "serving.spec_verify"]
         if config.kv_tier:
@@ -929,7 +957,7 @@ class ServingEngine:
         _chunk = _wrap(_chunk, (1, 2), (pb_sh, pool_sh, state_sh, rep),
                        (rep, pool_sh, state_sh))
 
-        def _step(pb, pools, state, bt, any_sampling, active):
+        def _step(pb, pools, state, bt, any_sampling, active, rows=None):
             """ONE decode iteration for the whole slot pool, reading and
             writing KV through the traced block tables ``bt`` [B, nb]
             (inactive rows are zeroed by the host -> their static-shape
@@ -938,11 +966,42 @@ class ServingEngine:
             and batched sampler; the ``any_sampling`` cond skips the
             sampler for pure-argmax pools; free rows ride along pinned
             to pos 0. Compiles exactly once —
-            occupancy, length mix, and SHARING patterns are all data."""
-            caches = [dict(c, bt=bt) for c in pools]
-            logits, newc = run(pb, state["tokens"][:, None], caches,
-                               state["pos"])
-            last = logits[:, 0]
+            occupancy, length mix, and SHARING patterns are all data.
+
+            With ``rows`` (an engine that ``_fuses``: the iteration's
+            last prefill rows, packed as ``_chunk`` takes them) the
+            same program carries those chunks too: their
+            tokens and the decode rows' are ONE batch of ``P * C + B``
+            single-token rows, each at its own position, so the linear
+            layers, the norms and the head read the weights once for
+            all of them; ``generation.cached_attention`` parts them
+            again at the seam (``chunk_bt``), and the head sees the P
+            ``last_idx`` rows and the B decode rows. Each half then
+            selects as it does alone, and the program also returns the
+            chunks' first tokens. A slot is in one half or in neither:
+            one whose last chunk rides here joins the NEXT step, which
+            reads the state row written here. Two traces of one
+            function (``rows`` absent, or [P, ..])."""
+            if rows is None:
+                caches = [dict(c, bt=bt) for c in pools]
+                logits, newc = run(pb, state["tokens"][:, None], caches,
+                                   state["pos"])
+                last = logits[:, 0]
+            else:
+                cbt, ids, f = _unpack_rows(rows, nb, C)
+                n = rows.shape[0] * C
+                caches = [dict(c, bt=bt, chunk_bt=cbt,
+                               chunk_valid=f["valid"]) for c in pools]
+                caches[0]["head_pick"] = jnp.concatenate(
+                    [jnp.arange(0, n, C, dtype=jnp.int32) + f["last_idx"],
+                     n + jnp.arange(B, dtype=jnp.int32)])
+                at = f["pos0"][:, None] + jnp.arange(C, dtype=jnp.int32)
+                logits, newc = run(
+                    pb, jnp.concatenate([ids.reshape(n),
+                                         state["tokens"]])[:, None],
+                    caches, jnp.concatenate([at.reshape(n), state["pos"]]))
+                first, last = logits[:rows.shape[0], 0], \
+                    logits[rows.shape[0]:, 0]
             new_keys, subs = split_keys(state["keys"])
             nxt = jax.lax.cond(
                 any_sampling,
@@ -957,7 +1016,13 @@ class ServingEngine:
                 jnp.int32(0))
             state["keys"] = new_keys
             pools_out = [{kk: c[kk] for kk in pool_keys} for c in newc]
-            return nxt, pools_out, state
+            if rows is None:
+                return nxt, pools_out, state
+            # (after the step's own: a slot whose last chunk rides here
+            # is no decode row of this step, and what the step leaves in
+            # an inactive row is scratch)
+            token, state = _first_tokens(state, first, f)
+            return nxt, token, pools_out, state
 
         _step = _wrap(_step, (1, 2),
                       (pb_sh, pool_sh, state_sh, rep, rep, rep),
@@ -990,7 +1055,8 @@ class ServingEngine:
         self._chunk_size = C
         self._chunk_rows = P
         # retrace warnings for the engine entries cite these defs
-        _recompile.register_entry_location("serving.step", _step)
+        for entry in ("serving.step", *self._fused_entries):
+            _recompile.register_entry_location(entry, _step)
         for entry in self._chunk_entries:
             _recompile.register_entry_location(entry, _chunk)
         _recompile.register_entry_location("serving.cow", _cow)
@@ -1624,8 +1690,10 @@ class ServingEngine:
     # -- warmup: AOT-compile every executable before taking traffic ----------
     def warmup(self) -> dict:
         """Compile every executable this engine will dispatch — the
-        pool-wide decode step (or the spec draft+verify pair), the
-        prefill program at ``[1, C]`` and ``[P, C]``, and the COW fork
+        pool-wide decode step alone and, on an engine that fuses, with
+        ``[P, C]`` prefill rows in its program (or the spec draft+verify
+        pair), the prefill program at its widths (``_row_widths``), and
+        the COW fork
         — by running each once with inert inputs: zeroed block tables
         route every write to the reserved dump block, ``valid``/``active``
         masks are all-off, and ``is_last`` is False, so no slot state a
@@ -1662,7 +1730,7 @@ class ServingEngine:
         off = jnp.zeros(B, bool)
         zero_i = jnp.asarray(0, jnp.int32)
         entries = ["serving.cow"]
-        for width in sorted({1, self._chunk_rows}):
+        for width in self._row_widths:
             # no row carries a chunk
             entries.append(self._enqueue_chunks(
                 self._chunk_args((), width))[1])
@@ -1687,11 +1755,14 @@ class ServingEngine:
                         self._pb, self._pools, self._state, btB,
                         self._zero_drafts, sv0, jnp.asarray(False), off)
         else:
-            entries.append("serving.step")
-            with _entrypoint("serving.step"):
-                _, self._pools, self._state = self._step_fn(
-                    self._pb, self._pools, self._state, btB,
-                    jnp.asarray(False), off)
+            # the step alone and, on an engine that fuses, with prefill
+            # rows in its program, no row carrying a chunk
+            entries.append(self._enqueue_step(
+                btB, jnp.asarray(False), off)[2])
+            if self._fuses:
+                entries.append(self._enqueue_step(
+                    btB, jnp.asarray(False), off,
+                    self._chunk_args((), self._chunk_rows))[2])
         with _entrypoint("serving.cow"):
             if self.spec:
                 self._pools, self._dpools = self._cow_spec_fn(
@@ -2303,22 +2374,26 @@ class ServingEngine:
                     self._pb, self._pools, self._state, packed)
         return token, entry
 
-    def _enqueue_claimed(self, claimed):
+    def _enqueue_claimed(self, claimed, first):
         """One ``serving.prefill_chunk`` program for ``claimed``, at
         most P rows ``(slot, job, start, end)``; None where nothing was
-        enqueued. It goes out as soon as its rows are claimed, so the
-        device works while the host claims the next program's (at P = 1
-        every chunk is enqueued before the next slot's blocks are
-        reserved); the rows' bookkeeping waits for ``_book_chunks``.
+        enqueued. A set that is not the iteration's last goes out as
+        soon as its rows are claimed, so the device works while the
+        host claims the next program's (at P = 1 every chunk is
+        enqueued before the next slot's blocks are reserved); the last
+        set too, on an engine whose step cannot carry it or in an
+        iteration with no decode row, and otherwise it is held and
+        rides the step (``_step_impl``, ``_enqueue_step``). The rows'
+        bookkeeping waits for ``_book_chunks``. ``first``:
+        ``_note_prefill_program``'s.
 
-        A lone chunk rides the ``[1, C]`` form of the same body: on the
-        chip ``[8, 32]`` takes 2.1 ms longer than ``[1, 32]`` whatever
-        rows are live (its 256 rows are computed, PERF.md section 6, PR
-        28), which every iteration with one prefilling slot would pay;
-        from two chunks on the wide program is the cheaper."""
-        # a row whose slot a later row's reservation preempted carries
-        # nothing: its blocks may be that row's by now
-        rows = [row for row in claimed if self._jobs[row[0]] is row[1]]
+        A lone chunk rides the ``[1, C]`` form of the same body where
+        the engine keeps one (``_row_widths``): on the chip ``[8, 32]``
+        takes 2.1 ms longer than ``[1, 32]`` whatever rows are live (its
+        256 rows are computed, PERF.md section 6, PR 28), which every
+        iteration with one prefilling slot would pay; from two chunks
+        on the wide program is the cheaper."""
+        rows = self._live_rows(claimed)
         if not rows:
             return None
         tc0 = time.perf_counter_ns()
@@ -2327,34 +2402,87 @@ class ServingEngine:
             # compile fired here (the one serving.prefill_chunk warmup,
             # or a would-be-retrace bug) lands in a timeline
             with _trace.trace_context(rows[0][1].req.trace):
-                token, entry = self._enqueue_chunks(self._chunk_args(
-                    rows, self._chunk_rows if len(rows) > 1 else 1))
+                token, entry = self._enqueue_chunks(self._pack(rows))
         except Exception as e:  # noqa: BLE001 — engine must survive
             for slot in {row[0] for row in rows}:
                 self._free_slot(slot, RequestStatus.FAILED, "failed",
                                 error=repr(e))
             return None
-        self._n_prefill_rows += len(rows)
-        self._n_prefill_programs += 1
-        # a job's ``done`` moves when its rows are booked, after every
-        # program of the iteration is out: a row that starts past it is
-        # a spare row's
-        self._n_prefill_fill_rows += sum(
-            start != job.done for _, job, start, _ in rows)
+        self._note_prefill_program(rows, first)
         return rows, token, entry, tc0, time.perf_counter_ns()
 
+    def _pack(self, rows) -> np.ndarray:
+        """``_chunk_args`` of the live ``rows`` at the width they ride:
+        ``[P, C]``, and where the engine keeps it (``_row_widths``) the
+        ``[1, C]`` form for a lone row."""
+        return self._chunk_args(
+            rows, self._row_widths[0 if len(rows) == 1 else -1])
+
+    def _live_rows(self, claimed) -> list:
+        """The rows of ``claimed`` that still carry their chunk: a row
+        whose slot a later reservation preempted (a later row's, or a
+        decode row's while the set was held for the step) carries
+        nothing, its blocks may be that row's by now."""
+        return [row for row in claimed if self._jobs[row[0]] is row[1]]
+
+    def _note_prefill_program(self, rows, first: set):
+        """Count one program that carries the live prefill ``rows``: a
+        prefill program, or the step they ride. ``first``: the slots
+        that have had a row in this iteration (a further row of one is
+        a spare row's), these rows' now among them."""
+        self._n_prefill_rows += len(rows)
+        self._n_prefill_programs += 1
+        for slot, *_ in rows:
+            self._n_prefill_fill_rows += slot in first
+            first.add(slot)
+
+    @staticmethod
+    def _step_entry(width: int) -> str:
+        """The recompile monitor's name for the step that carries
+        ``width`` prefill rows, one name an executable."""
+        return f"serving.step+chunk[{width}]"
+
+    def _enqueue_step(self, bt_step, any_sampling, active_mask,
+                      packed=None) -> tuple:
+        """The decode step, its arguments host arrays handed over as
+        they are; with ``packed`` (``_chunk_args``: the prefill rows
+        that ride it, an engine that ``_fuses``) the program that
+        carries those rows too. Returns the step's tokens, the rows'
+        first tokens (None without rows), both still on the device, and
+        the entry it ran under."""
+        entry = "serving.step" if packed is None \
+            else self._step_entry(packed.shape[0])
+        first = None
+        with _entrypoint(entry):
+            if packed is None:
+                toks, self._pools, self._state = self._step_fn(
+                    self._pb, self._pools, self._state, bt_step,
+                    any_sampling, active_mask)
+            else:
+                toks, first, self._pools, self._state = self._step_fn(
+                    self._pb, self._pools, self._state, bt_step,
+                    any_sampling, active_mask, packed)
+        return toks, first, entry
+
     def _book_chunks(self, ran):
-        """The bookkeeping of the rows of this iteration's prefill
-        programs (``_enqueue_claimed``'s records), once all of them are
-        enqueued. A chunk that ends its prompt also selected the first
-        token (generate's key chain) and wrote it into the slot's state
-        row on the device, so the slot flips into the decode batch here,
-        on the host's side alone (``_finish_prefill``), and its token is
-        parked: the host's copy is read in the next iteration's
-        ``engine.wait`` (``_deliver_first_tokens``), so the device never
-        drains between a prompt's last chunk and the step behind it."""
+        """The bookkeeping of the rows of programs that are enqueued
+        (``_enqueue_claimed``'s records, and the step's where it
+        carried rows; None for a program that failed). A chunk that
+        ends its prompt also selected the first token (generate's key
+        chain) and wrote it into the slot's state row on the device, so
+        the slot flips into the decode batch here, on the host's side
+        alone (``_finish_prefill``), and its token is parked: the
+        host's copy is read in the next iteration's ``engine.wait``
+        (``_deliver_first_tokens``), so the device never drains between
+        a prompt's last chunk and the step behind it. WHEN it is called
+        decides which step the slot joins: the prefill programs are
+        booked before the iteration's decode rows are chosen, so their
+        slots join this iteration's step; rows that rode the step are
+        booked behind it, and their slots join the next (inside one
+        pass over the layers a decode row cannot read the token that
+        the same program selects)."""
         from ..observability import perf as _perf
-        for rows, token, entry, tc0, tc1 in ran:
+        for rows, token, entry, tc0, tc1 in filter(None, ran):
             _sm.prefill_chunk_seconds.observe((tc1 - tc0) / 1e9)
             last = []
             for r, (slot, job, start, end) in enumerate(rows):
@@ -2509,7 +2637,9 @@ class ServingEngine:
         in-flight chunked prefill by one chunk (and, in the rows its
         prefill program has left, the earliest-admitted ones by
         further chunks), then (if any slot is decoding) enqueue the
-        single jitted decode step for the whole pool, and only then
+        single jitted decode step for the whole pool (the iteration's
+        last prefill rows in its program, where the engine fuses), and
+        only then
         read and deliver the tokens of the step the iteration BEFORE
         enqueued: the host stays one step ahead of the tokens it reads,
         so the device has this iteration's programs queued while the
@@ -2546,7 +2676,14 @@ class ServingEngine:
         """The iteration, under ``_step_lock``: admit, advance prefills,
         reserve blocks for the decode rows, dispatch step N+1, wait for
         step N's tokens, emit them, each a phase of ``engine.iter``: a
-        pipeline of depth one. Nothing the host does before a dispatch
+        pipeline of depth one. What goes out when: every set of P
+        claimed prefill rows that is not the iteration's last, as a
+        prefill program, at once; the last set with the step, in ONE
+        program, where the engine ``_fuses`` and a slot is decoding
+        (else as the prefill program it is, before the decode rows are
+        chosen); the step, with or without rows, once its rows' blocks
+        are reserved. A slot whose last chunk rode the step joins the
+        next step. Nothing the host does before a dispatch
         needs the values of the tokens in flight: the step reads its
         inputs from ``self._state`` on the device, and the table, the
         active mask and ``any_sampling`` follow from lengths and counts,
@@ -2582,8 +2719,14 @@ class ServingEngine:
                 # every prefilling slot advances one chunk, in slot
                 # order; the chunks ride one program, P rows each,
                 # which goes out as soon as its rows are claimed; the
-                # rows the last program has left carry further chunks
-                claimed, ran = [], []
+                # rows the last program has left carry further chunks.
+                # Where the step can carry prefill rows (``_fuses``) the
+                # iteration's LAST set is ``held`` for it until the
+                # decode rows are reserved: a full set goes out at once
+                # only where a later slot still has a chunk to claim
+                fuses, P = self._fuses, self._chunk_rows
+                held, ran = [], []
+                first = set()   # the slots that have a row out already
                 for slot in range(self.config.max_slots):
                     job = self._jobs[slot]
                     if job is None:
@@ -2592,35 +2735,38 @@ class ServingEngine:
                     try:
                         span = self._claim_chunk(slot, job)
                         if span:
-                            claimed.append((slot, job, *span))
+                            held.append((slot, job, *span))
                     except PoolExhaustedError:
                         self._preempt(slot)  # retried from the queue front
                     except Exception as e:  # noqa: BLE001
                         self._free_slot(slot, RequestStatus.FAILED,
                                         "failed", error=repr(e))
-                    if len(claimed) == self._chunk_rows:
-                        ran.append(self._enqueue_claimed(claimed))
-                        claimed = []
-                if claimed:
-                    self._claim_spare_rows(claimed)
-                    ran.append(self._enqueue_claimed(claimed))
+                    if len(held) == P and (
+                            not fuses or any(self._jobs[slot + 1:])):
+                        ran.append(self._enqueue_claimed(held, first))
+                        held = []
+                if held:
+                    self._claim_spare_rows(held)
                 # the first tokens parked before this iteration's: read
                 # in engine.wait, behind this iteration's enqueues
                 n_parked = len(self._parked_tokens)
-                self._book_chunks([r for r in ran if r is not None])
+                # the slots whose prompt ended in a program that is out
+                # join this iteration's step
+                self._book_chunks(ran)
+                if held and not (fuses and self._rows_to_step()):
+                    # no step that could carry them, or no decode row to
+                    # ride with: the program they are, and its slots
+                    # join the step as well
+                    self._book_chunks([self._enqueue_claimed(held, first)])
+                    held = []
                 if self.spec:
                     # the speculative lane sizes its bundles from what a
                     # request has been given: it reads before it dispatches
                     self._deliver_first_tokens()
-                # rows, programs and spare rows filled, on the iterations
-                # that enqueued any
-                ph.mark("engine.reserve", ph.on
-                        and self._n_prefill_programs > n_programs and {
-                            "rows": self._n_prefill_rows - n_rows,
-                            "programs": self._n_prefill_programs
-                            - n_programs,
-                            "fill": self._n_prefill_fill_rows - n_fill}
-                        or None)
+                # engine.prefill's args, filled in once the iteration's
+                # last prefill rows are out (they may ride the step)
+                prefill_args = {} if ph.on else None
+                ph.mark("engine.reserve", prefill_args)
                 active = self._rows_to_step()
                 # cancellation between steps: drop flagged slots without
                 # paying another decode step for them, once what they
@@ -2661,6 +2807,24 @@ class ServingEngine:
                 # a flush on the way (a cancel, pool pressure) has read
                 # this iteration's first tokens too
                 n_parked = min(n_parked, len(self._parked_tokens))
+                # the rows the step carries: those still live (a decode
+                # row's reservation may have preempted a slot that holds
+                # one), where there is a step; else they go out as the
+                # prefill program they are
+                riding = self._live_rows(held) if active else []
+                booked = None
+                if riding:
+                    self._note_prefill_program(riding, first)
+                elif held:
+                    booked = self._enqueue_claimed(held, first)
+                if prefill_args is not None \
+                        and self._n_prefill_programs > n_programs:
+                    # rows, programs and spare rows filled, on the
+                    # iterations that enqueued any
+                    prefill_args.update(
+                        rows=self._n_prefill_rows - n_rows,
+                        programs=self._n_prefill_programs - n_programs,
+                        fill=self._n_prefill_fill_rows - n_fill)
                 enqueued = None
                 if active:
                     ahead = self._ahead is not None
@@ -2670,15 +2834,20 @@ class ServingEngine:
                         dispatch_args = ph.on and {"kv_blocks": sum(
                             -(-(self._slot_len[i] + (self._row_spec_len(i)
                                                      if self.spec else 1))
-                              // bs) for i in active), "ahead": int(ahead)}
+                              // bs) for i in active)}
                     else:
                         # exact keys of the window, and summaries behind it
                         read = ph.on and [self._layout.read_blocks(
                             self._slot_len[i] + 1) for i in active]
                         dispatch_args = ph.on and {
                             "kv_blocks": sum(r[0] for r in read),
-                            "summary_blocks": sum(r[1] for r in read),
-                            "ahead": int(ahead)}
+                            "summary_blocks": sum(r[1] for r in read)}
+                    if dispatch_args:
+                        # whether the step before was still unread, and
+                        # the prefill rows this step's program carries
+                        dispatch_args.update(
+                            ahead=int(ahead), fused=int(bool(riding)),
+                            prefill_rows=len(riding))
                     if dispatch_args and self._ut_steps > 1:
                         # the passes the enqueued step runs
                         dispatch_args["ut_steps"] = self._ut_steps
@@ -2693,12 +2862,15 @@ class ServingEngine:
                                         t0_ns, ph, dispatch_args)
                         dispatch_args = None
                         return True
-                    with _entrypoint("serving.step"):
-                        bt_step = self._bt.copy()
-                        bt_step[~active_mask] = 0  # inactive -> dump block
-                        toks, self._pools, self._state = self._step_fn(
-                            self._pb, self._pools, self._state, bt_step,
-                            np.asarray(any_sampling, bool), active_mask)
+                    bt_step = self._bt.copy()
+                    bt_step[~active_mask] = 0  # inactive -> dump block
+                    toks, tok0, entry = self._enqueue_step(
+                        bt_step, np.asarray(any_sampling, bool),
+                        active_mask, self._pack(riding) if riding else None)
+                    if riding:
+                        booked = (riding, tok0, entry, t0_ns,
+                                  time.perf_counter_ns())
+                        self._n_steps_fused += 1
                     # enqueued: the rows' lengths and counts move now,
                     # the next reservation needs them
                     rows = []
@@ -2709,7 +2881,10 @@ class ServingEngine:
                         self._slot_due[i] += 1
                     self._n_steps_ahead += ahead
                     self._n_loop_passes += self._ut_steps
-                    enqueued = toks, rows, t0_ns
+                    enqueued = toks, rows, t0_ns, entry
+                # a slot whose prompt ended in the held rows joins the
+                # NEXT step
+                self._book_chunks([booked])
                 prev = self._ahead
                 if prev is None and not n_parked:
                     self._ahead = enqueued
@@ -2770,7 +2945,7 @@ class ServingEngine:
         is dropped and counted. The step's span and the perf ledger's
         interval run from sync to sync (from its dispatch where the
         device had drained), the one interval a pipelined step has."""
-        _, rows, t0_ns = step
+        _, rows, t0_ns, entry = step
         now = now_ns / 1e9
         live = [(i, req) for i, req in rows if self._slot_req[i] is req]
         self._n_dead_rows += len(rows) - len(live)
@@ -2787,8 +2962,8 @@ class ServingEngine:
         self._steps += 1
         self._occupancy_integral += len(live)
         from ..observability import perf as _perf
-        _perf.note_entry_items("serving.step", len(live))
-        _perf.note_entry_time("serving.step", step_s)
+        _perf.note_entry_items(entry, len(live))
+        _perf.note_entry_time(entry, step_s)
         for i, req in live:
             self._slot_due[i] -= 1
             t = int(toks_np[i])
@@ -3420,6 +3595,10 @@ class ServingEngine:
             "steps_ahead": self._n_steps_ahead,
             "ahead_flushes": self._n_ahead_flushes,
             "dead_rows": self._n_dead_rows,
+            # decode steps whose program carried prefill rows too (the
+            # sum of the dispatch spans' ``fused``; counted, as
+            # ``steps_ahead`` is, when the step is enqueued)
+            "steps_fused": self._n_steps_fused,
         }
         if self._ut_steps > 1:
             # a looped stack: the passes of every decode step enqueued

@@ -132,7 +132,9 @@ class TestPhases:
         # iter ties the lanes together; preempted is preemptions.*;
         # the two token counts are prefix_hit_share.chat; kv_blocks is
         # decode_live_blocks_per_step.*; ahead is
-        # steps_ahead_per_step.*; rows, programs and fill are
+        # steps_ahead_per_step.*; fused is steps_fused_per_step.* and
+        # prefill_rows the rows such a step carried; rows, programs and
+        # fill are
         # prefill_rows_per_iter.*, prefill_programs_per_iter.* and
         # prefill_fill_rows_per_iter.*, on the iterations that enqueued
         # a prefill program and no other
@@ -140,7 +142,8 @@ class TestPhases:
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
                                  "prompt_tokens"},
-                "engine.dispatch": {"iter", "kv_blocks", "ahead"}}
+                "engine.dispatch": {"iter", "kv_blocks", "ahead", "fused",
+                                    "prefill_rows"}}
         # (another test's engine may idle on a thread of its own meanwhile)
         mine = [e for e in events if e["name"].startswith("engine.")
                 and e["name"] != "engine.idle"]
@@ -182,7 +185,7 @@ class TestPhases:
                 "engine.admit": {"iter", "prefix_hit_tokens",
                                  "prompt_tokens"},
                 "engine.dispatch": {"iter", "kv_blocks", "summary_blocks",
-                                    "ahead"}}
+                                    "ahead", "fused", "prefill_rows"}}
         for e in lane:
             # (engine.prefill: rows, programs and fill, as on any paged
             # engine; a windowed slot takes no spare row, so fill is 0)
@@ -204,11 +207,25 @@ class TestPhases:
                    if e["name"] == "engine.prefill"}
         chunks = [e for e in events if e["name"] == "prefill_chunk"]
         assert len(chunks) >= len(reqs)
+        dispatch = {e["args"]["iter"]: e for e in events
+                    if e["name"] == "engine.dispatch"}
+        rode = 0
         for ch in chunks:
-            ph = prefill[ch["args"]["iter"]]   # its iteration recorded one
+            it = ch["args"]["iter"]
+            # inside its iteration's engine.prefill, or, where its rows
+            # rode the step's program, inside that engine.dispatch
+            ph = prefill[it]                   # its iteration recorded one
+            if ch["ts_ns"] >= ph["ts_ns"] + ph["dur_ns"]:
+                ph = dispatch[it]
+                assert ph["args"]["fused"] == 1
+                rode += 1
             assert ph["ts_ns"] <= ch["ts_ns"]
             assert ch["ts_ns"] + ch["dur_ns"] <= ph["ts_ns"] + ph["dur_ns"]
             assert ch["trace"] != "engine"     # stays on the request's lane
+        # the three requests admitted together: the second program's two
+        # rows rode the step that the first program's two slots joined
+        assert rode == 2 == sum(e["args"]["prefill_rows"]
+                                for e in dispatch.values())
 
     def test_one_prefill_chunk_span_a_live_row_and_the_sums_are_the_counters(
             self, served):
@@ -473,16 +490,18 @@ class TestCounters:
                           "prompt_tokens", "prefix_hit_tokens",
                           "preemptions", "prefill_rows",
                           "prefill_programs", "prefill_fill_rows",
-                          "steps_ahead", "ahead_flushes", "dead_rows"}
+                          "steps_ahead", "ahead_flushes", "dead_rows",
+                          "steps_fused"}
 
 
 class TestFirstTokenAfterDispatch:
     def test_the_step_is_enqueued_before_the_first_token_is_read(
             self, served):
         """A prompt's last chunk parks its first token; the step of that
-        iteration is enqueued behind it, and the token is read in the
-        NEXT iteration's ``engine.wait``: after that iteration's own
-        enqueues, and before the tokens of the step it rode in."""
+        iteration is enqueued behind it (or is the program the chunk
+        rode), and the token is read in the NEXT iteration's
+        ``engine.wait``: after that iteration's own enqueues, and
+        before the tokens of that step."""
         _, reqs, events = served
         by_name = {n: {e["args"]["iter"]: e for e in events
                        if e["name"] == n}
@@ -497,7 +516,11 @@ class TestFirstTokenAfterDispatch:
             it = last["args"]["iter"]
             disp, wait = by_name["engine.dispatch"][it], \
                 by_name["engine.wait"][it + 1]
-            assert last["ts_ns"] + last["dur_ns"] <= disp["ts_ns"]
+            # (the lone request's rows rode the step's own program)
+            rode = last["ts_ns"] >= disp["ts_ns"]
+            assert rode == (req is reqs[3]) <= disp["args"]["fused"]
+            assert last["ts_ns"] + last["dur_ns"] \
+                <= disp["ts_ns"] + (disp["dur_ns"] if rode else 0)
             assert disp["ts_ns"] + disp["dur_ns"] <= wait["ts_ns"] \
                 <= first["ts_ns"] <= wait["ts_ns"] + wait["dur_ns"]
             ahead = by_name["engine.dispatch"].get(it + 1)
@@ -570,9 +593,11 @@ class TestFirstTokenAfterDispatch:
         assert list(req.output_tokens) == [tok0]
         assert req.slot is not None and eng._slot_req[req.slot] is None
         assert list(other.output_tokens) == list(alone.output_tokens)
-        # the steps carried other's five rows; req's are not counted
-        assert c["slot_steps"] == 5
-        assert c["dead_rows"] == (0 if how == "max_new_tokens" else 2)
+        # the steps carried other's five rows; req's are not counted.
+        # Its chunk rode a step, so it joined the one after, and had one
+        # row out when its first token was read
+        assert c["slot_steps"] == 5 and c["steps_fused"] == 1
+        assert c["dead_rows"] == (0 if how == "max_new_tokens" else 1)
         assert eng.pool.used_blocks == 0 and not eng.in_flight
 
     def test_a_failed_dispatch_still_delivers_the_first_token(
@@ -1243,3 +1268,115 @@ class TestStepAhead:
         assert eng._ahead is not None and not eng._phases.on
         eng.run_until_idle()
         assert len(req.output_tokens) == 12
+
+
+# ---------------------------------------------------------------------------
+# who fuses: decided by what the engine can see
+# ---------------------------------------------------------------------------
+
+# sha256 (first 16 hex digits) of each program's lowered text, locations
+# stripped, as the tree BEFORE the step learned to carry prefill rows
+# lowers it (PR 37's, commit b879033): an engine that does not fuse
+# keeps its pair of programs to the byte. After a change that is MEANT
+# to alter one of these programs, read the new values off the assertion.
+PARENT_PROGRAMS = {
+    "windowed": {"step": "32d37b4bd1bca4b7", "chunk1": "1a1f5869a1a51027",
+                 "chunk2": "1cf6f1fe09e6aecf"},
+    "looped": {"step": "59b11bd31512f08c", "chunk1": "fa00005191cd7c80",
+               "chunk2": "8de0a59a918496e3"},
+    "speculative": {"draft": "a617c0279bb0f853", "verify": "e07f95900d4241c4",
+                    "chunk1": "3c2146d857f51616",
+                    "chunk4": "3a5c737a77241795"},
+}
+
+
+def _unfused_engine(kind):
+    if kind == "windowed":
+        import test_evabyte as te
+        return te.engine_for(te.build()[0], slots=2), te.SIZES["vocab_size"]
+    if kind == "looped":
+        import test_ouro as to
+        return (to.engine_for(to.build()[0], slots=3, max_len=96,
+                              num_blocks=30), to.SIZES["vocab_size"])
+    from paddle_tpu import generation
+
+    paddle.seed(0)
+    llama = LlamaForCausalLM(LlamaConfig.tiny(num_key_value_heads=2))
+    return serving.ServingEngine(
+        llama, draft_model=generation.truncated_draft(llama, 1), spec_k=3,
+        max_slots=4, max_len=64, block_size=8), llama.config.vocab_size
+
+
+def _lowered_programs(eng):
+    """{name: digest} of the engine's decode and prefill programs."""
+    import hashlib
+    import re
+
+    import jax.numpy as jnp
+
+    loc = re.compile(r"\s*loc\([^\n]*\)|^#loc[^\n]*\n", re.M)
+    B, nb = eng.config.max_slots, eng._bt.shape[1]
+    bt, off = np.zeros((B, nb), np.int32), np.zeros(B, bool)
+    no = np.asarray(False, bool)
+    low = {}
+    if eng.spec:
+        sv0 = jnp.zeros(B, jnp.int32)
+        low["draft"] = eng._draft_fn.lower(
+            eng._dpb, eng._dpools, eng._state, bt, sv0, jnp.asarray(False))
+        low["verify"] = eng._verify_fn.lower(
+            eng._pb, eng._pools, eng._state, bt, eng._zero_drafts, sv0,
+            jnp.asarray(False), off)
+    else:
+        low["step"] = eng._step_fn.lower(eng._pb, eng._pools, eng._state,
+                                         bt, no, off)
+    for w in sorted({1, eng._chunk_rows}):
+        rows = eng._chunk_args((), w)
+        low[f"chunk{w}"] = eng._chunk_spec_fn.lower(
+            eng._pb, eng._dpb, eng._pools, eng._dpools, eng._state, rows) \
+            if eng.spec else eng._chunk_fn.lower(eng._pb, eng._pools,
+                                                 eng._state, rows)
+    return {k: hashlib.sha256(loc.sub("", v.as_text()).encode())
+            .hexdigest()[:16] for k, v in low.items()}
+
+
+class TestWhoFuses:
+    @pytest.mark.parametrize("kind", list(PARENT_PROGRAMS))
+    def test_an_engine_that_does_not_fuse_lowers_the_parents_programs(
+            self, kind):
+        """A windowed layout (a row rolls between the two halves), a
+        looped stack (the passes' loop) and the speculative lane
+        (synchronous) keep today's pair of programs, byte for byte, go
+        on in the parent's order (nothing is ever held for the step)
+        and say ``fused`` 0 of every step."""
+        eng, vocab = _unfused_engine(kind)
+        assert not eng._fuses and eng._fused_entries == []
+        assert _lowered_programs(eng) == PARENT_PROGRAMS[kind]
+        t0 = tracing.events()[-1]["ts_ns"] + 1 if tracing.events() else 0
+        rng = np.random.RandomState(61)
+        first = eng.submit(rng.randint(1, vocab, 9).astype("int32"),
+                           max_new_tokens=8)
+        eng.step()
+        eng.step()
+        # prefill rows while a slot decodes: what a fusing engine carries
+        # in its step
+        late = eng.submit(rng.randint(1, vocab, 21).astype("int32"),
+                          max_new_tokens=3)
+        eng.run_until_idle()
+        assert (len(first.output_tokens), len(late.output_tokens)) == (8, 3)
+        disp = [e["args"] for e in _engine_lane(t0)
+                if e["name"] == "engine.dispatch"]
+        assert disp and all((a["fused"], a["prefill_rows"]) == (0, 0)
+                            for a in disp)
+        assert eng.counters()["steps_fused"] == 0
+        assert not [e for e in eng.warmup()["entries"]
+                    if "+" in e]
+
+    def test_a_plain_paged_engine_fuses_and_a_sharded_one_keeps_the_pair(
+            self, tiny_model):
+        """Every plain GPT or Llama engine carries its prefill rows in
+        the step; under ``tp > 1`` the executables' shardings are one
+        fixed tuple and the pair stays."""
+        model, cfg = tiny_model
+        assert _engine(model)._fuses
+        assert _engine(model, kv_format="int8")._fuses
+        assert not _engine(model, tp=2)._fuses

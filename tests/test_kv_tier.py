@@ -321,7 +321,8 @@ class TestEngineParity:
                        for r in reqs)
             eng.prefix_cache.evict(100)          # demote + readmit churn
         stats = recompile.entry_stats()
-        for entry in ("serving.step", "serving.prefill_chunk",
+        for entry in ("serving.step", *eng._chunk_entries,
+                      *eng._fused_entries,
                       "serving.kv_demote", "serving.kv_splice"):
             assert stats[entry]["retraces"] == 0, entry
         assert stats["serving.kv_demote"]["compiles"] >= 1

@@ -99,8 +99,12 @@ def test_dispatch_spans_carry_the_passes_and_the_counter_adds_up(served):
     events = served["dispatches"]
     assert events and all(e["args"]["ut_steps"] == T for e in events)
     assert set(events[0]["args"]) == {"iter", "kv_blocks", "ahead",
-                                      "ut_steps"}
+                                      "fused", "prefill_rows", "ut_steps"}
+    # a looped stack keeps its pair of programs an iteration
+    assert not any(e["args"]["fused"] or e["args"]["prefill_rows"]
+                   for e in events)
     c = served["eng"].counters()
+    assert c["steps_fused"] == 0
     assert c["loop_passes"] == T * len(events)
     assert c["loop_passes"] >= T * c["steps"]
 

@@ -411,8 +411,8 @@ class TestOneCompile:
         after = recompile.entry_stats()["serving.step"]
         assert after["compiles"] - before["compiles"] == 1
         assert after["retraces"] - before["retraces"] == 0
-        chunk = recompile.entry_stats()["serving.prefill_chunk"]
-        assert chunk["retraces"] == 0
+        for entry in eng._chunk_entries + eng._fused_entries:
+            assert recompile.entry_stats()[entry]["retraces"] == 0, entry
         cow = recompile.entry_stats().get("serving.cow")
         if cow is not None:
             assert cow["retraces"] == 0
@@ -861,22 +861,33 @@ class TestBatchedPrefill:
             == {2: 2, 4: 2, 5: 5}[n]
         assert eng.pool.used_blocks == len(eng.prefix_cache)
 
+    @pytest.mark.parametrize("fuses", [True, False], ids=["fuses", "twin"])
     def test_a_lone_chunk_rides_the_one_row_form_of_the_program(
-            self, tiny_model):
+            self, tiny_model, fuses):
         """Two prompts of eight chunks and of one: the first iteration
         is one [4, C] program (the long prompt's first three chunks
-        beside the short one's only chunk), the second a [4, C] program
-        of its next four, and the lone last chunk a [1, C] program."""
+        beside the short one's only chunk), the second carries its next
+        four as [4, C] rows of the short one's decode step, and the
+        lone last chunk, the short one done by then, a [1, C] program
+        on an engine that keeps that form (one that does not fuse: the
+        twin), the [4, C] program on one that fuses."""
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, **self.KW)
+        assert eng._row_widths == [4]
+        eng._fuses, eng._row_widths = fuses, [4] if fuses else [1, 4]
         seen = []
-        real = eng._chunk_fn
-        eng._chunk_fn = lambda pb, pools, state, bt, *a: (
-            seen.append(bt.shape[0]) or real(pb, pools, state, bt, *a))
+        real, step = eng._chunk_fn, eng._step_fn
+        eng._chunk_fn = lambda pb, pools, state, rows: (
+            seen.append(("chunk", rows.shape[0]))
+            or real(pb, pools, state, rows))
+        eng._step_fn = lambda *a: (
+            len(a) == 7 and seen.append(("step", a[6].shape[0]))
+            or step(*a))
         rng = np.random.RandomState(SEED + 29)
         prompts = [_prompt(rng, cfg, L) for L in (120, 9)]
         got = _serve_all(eng, prompts, [dict(max_new_tokens=3)] * 2)
-        assert seen == [4, 4, 1]
+        assert seen == ([("chunk", 4), ("step", 4), ("chunk", 4)] if fuses
+                        else [("chunk", 4), ("chunk", 4), ("chunk", 1)])
         assert _schedule((120, 9), 16, 4) \
             == [(4, 1, 2), (4, 1, 3), (1, 1, 0)]
         for g, p in zip(got, prompts):
@@ -1155,7 +1166,8 @@ class TestBatchedPrefill:
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, max_queue_depth=32, **self.KW)
         eng.warmup()
-        names = ("serving.prefill_chunk", "serving.prefill_chunk[4]")
+        names = ("serving.prefill_chunk[4]", "serving.step+chunk[4]")
+        assert list(names) == eng._chunk_entries + eng._fused_entries
         stats0 = {n: dict(recompile.entry_stats()[n]) for n in names}
         total0 = recompile.total_compiles()
         rng = np.random.RandomState(SEED + 28)
@@ -1166,8 +1178,9 @@ class TestBatchedPrefill:
             eng.run_until_idle()
             assert all(r.status == serving.RequestStatus.COMPLETED
                        for r in reqs)
-        # the one body at its two widths, [1, C] for a lone chunk: both
-        # compiled by warmup(), neither again
+        # the [4, C] rows as a prefill program and in the step's (an
+        # engine that fuses keeps no [1, C] form): both compiled by
+        # warmup(), neither again
         for n in names:
             stats1 = recompile.entry_stats()[n]
             assert stats1["calls"] > stats0[n]["calls"]
@@ -1181,20 +1194,32 @@ class TestBatchedPrefill:
 # ---------------------------------------------------------------------------
 
 
-def _program_rows(eng, seen):
+def _program_rows(eng, seen, rode=None):
     """Record every prefill program's live rows as ``(slot, pos0,
-    is_last)`` in ``seen``, one list a program."""
+    is_last)`` in ``seen``, one list a program, be it a prefill program
+    or the decode step that carried the rows (``rode``, where given:
+    True for each of those, False for the others)."""
     from paddle_tpu.serving import engine as engine_mod
 
-    enqueue = eng._enqueue_chunks
+    enqueue, step = eng._enqueue_chunks, eng._enqueue_step
 
-    def enqueuing(packed):
+    def note(packed, in_step):
         cols = packed[:, -len(engine_mod._ROW_COLUMNS):]
         seen.append([(int(c[2]), int(c[0]), bool(c[3]))
                      for c in cols if c[1] > 0])
+        if rode is not None:
+            rode.append(in_step)
+
+    def enqueuing(packed):
+        note(packed, False)
         return enqueue(packed)
 
-    eng._enqueue_chunks = enqueuing
+    def stepping(bt, any_sampling, active, packed=None):
+        if packed is not None:
+            note(packed, True)
+        return step(bt, any_sampling, active, packed)
+
+    eng._enqueue_chunks, eng._enqueue_step = enqueuing, stepping
 
 
 def _fill_family(family):
@@ -1422,3 +1447,262 @@ class TestSpareRows:
         c = eng.counters()
         assert c["prefill_fill_rows"] == 0 and c["window_rolls"] >= 4
         assert all(len(r.output_tokens) == 3 for r in [lone, *pair])
+
+
+# ---------------------------------------------------------------------------
+# the decode step and the iteration's last prefill rows in ONE program
+# ---------------------------------------------------------------------------
+
+
+FUSED_KW = dict(max_slots=6, max_len=128, block_size=16, prefill_chunk=8)
+FAMILIES = {"llama": {}, "llama_gqa_int8": {"kv_format": "int8"}, "gpt": {}}
+
+
+def _first_eos(ref, k):
+    """What a request gives whose end-of-sequence token is ``ref[k]``."""
+    ref = list(ref)
+    return ref[:ref.index(ref[k]) + 1]
+
+
+def _mixed_traffic(eng, model, cfg, refs):
+    """One run through everything a step that carries prefill rows
+    meets, the same requests whatever the engine's order of programs: a
+    greedy runner; five prompts admitted behind it at once (sampled and
+    greedy, one that ends on a token's value, one that adopts the
+    runner's blocks and forks the half block it shares); a late prompt
+    whose slot the reservation of a decode row preempts while its rows
+    are claimed; one cancelled with a step in flight. Returns ``{name:
+    (request, the tokens it should have)}``, the programs' rows, which
+    of them rode a step, and the first program after the late prompt.
+    ``refs`` keeps ``generate``'s tokens by name from run to run."""
+    rng = np.random.RandomState(SEED + 50)
+    seen, rode = [], []
+    _program_rows(eng, seen, rode)
+
+    def sampled(i):
+        return dict(do_sample=True, top_k=8, temperature=0.8, seed=60 + i)
+
+    out = {}
+
+    def submit(name, prompt, cut=None, **spec):
+        if name not in refs:
+            refs[name] = list(_ref(model, prompt, **spec))
+        ref = refs[name]
+        if cut is not None:
+            spec["eos_token_id"] = ref[cut]
+            ref = _first_eos(ref, cut)
+        out[name] = (eng.submit(prompt, **spec), ref)
+
+    a = _prompt(rng, cfg, 20)
+    submit("a", a, max_new_tokens=70)
+    eng.step()
+    eng.step()
+    assert eng._decoding[0]
+    submit("b", _prompt(rng, cfg, 11), max_new_tokens=9, **sampled(1))
+    submit("c", _prompt(rng, cfg, 45), max_new_tokens=7, **sampled(2))
+    submit("d", _prompt(rng, cfg, 9), cut=3, max_new_tokens=12)
+    submit("e", _prompt(rng, cfg, 30), max_new_tokens=6, **sampled(3))
+    submit("f", np.concatenate([a, _prompt(rng, cfg, 7)]), max_new_tokens=5)
+    n = 0
+    while not all(out[k][0].done for k in "bd"):
+        assert eng.step()
+        n += 1
+        assert n < 200
+    # a late prompt of six chunks; as the next decode row is reserved,
+    # with rows of its claimed (or just out, in the twin), its slot is
+    # preempted
+    g_from = len(seen)
+    submit("g", _prompt(rng, cfg, 45), max_new_tokens=4, **sampled(4))
+    real, fired = eng._reserve_write, []
+
+    def reserve(slot, start, end, **kw):
+        victim = out["g"][0].slot
+        if end - start == 1 and not fired and victim is not None \
+                and eng._jobs[victim] is not None:
+            fired.append(victim)
+            eng._preempt(victim)    # what _reclaim_alloc does under pressure
+        return real(slot, start, end, **kw)
+
+    eng._reserve_write = reserve
+    while not fired:
+        assert eng.step()
+    eng._reserve_write = real
+    # one more, cancelled with a step in flight and its prompt under way
+    submit("h", _prompt(rng, cfg, 50), max_new_tokens=8)
+    eng.step()
+    eng.step()
+    assert eng._ahead is not None
+    eng.cancel(out["h"][0])
+    eng.run_until_idle(max_steps=5000)
+    return out, seen, rode, g_from
+
+
+@pytest.fixture(scope="module", params=FAMILIES, ids=list(FAMILIES))
+def mixed(request):
+    """The mixed traffic once through an engine that fuses and once
+    through its twin that keeps the parent's pair of programs an
+    iteration, in the parent's order (nothing is held for the step)."""
+    model, more = _fill_family(request.param)
+    cfg = model.config
+    runs, refs = {}, {}
+    for fuses in (True, False):
+        eng = serving.ServingEngine(model, **FUSED_KW, **more)
+        assert eng._fuses and eng._chunk_rows == 4
+        eng._fuses = fuses
+        runs[fuses] = (eng, *_mixed_traffic(eng, model, cfg, refs))
+    return runs, request.param
+
+
+class TestFusedStep:
+    def test_every_request_has_generates_tokens_in_either_order(self, mixed):
+        """Greedy and sampled, through prefix hit, fork, preemption,
+        end of sequence and cancel: the tokens are ``generate``'s
+        (int8 pools: the twin's), whichever program carried the rows."""
+        runs, family = mixed
+        for fuses, (eng, out, *_) in runs.items():
+            for name, (req, ref) in out.items():
+                if name == "h":
+                    assert req.status == serving.RequestStatus.CANCELLED
+                    continue
+                assert req.status == serving.RequestStatus.COMPLETED, name
+                if "int8" not in family:
+                    assert list(req.output_tokens) == ref, (fuses, name)
+            assert eng.pool.used_blocks == len(eng.prefix_cache)
+            assert not eng.in_flight and eng.busy_slots() == 0
+        fused, pair = runs[True][1], runs[False][1]
+        for name in fused:
+            got, want = (list(o[name][0].output_tokens)
+                         for o in (fused, pair))
+            if name == "h":     # cancelled at another token of its stream
+                n = min(len(got), len(want))
+                got, want = got[:n], want[:n]
+            assert got == want, name
+
+    def test_the_traffic_met_what_it_was_built_to_meet(self, mixed):
+        runs, _ = mixed
+        eng, out, seen, rode, _ = runs[True]
+        c = eng.counters()
+        assert c["steps_fused"] == sum(rode) >= 4
+        assert c["prefix_hit_tokens"] >= 20
+        assert eng.pool.stats()["cow_forks"] >= 1
+        assert c["preemptions"] == 1 == out["g"][0].preempt_count
+        assert c["dead_rows"] >= 1          # d ended on a token's value
+        # two chunks of one slot in one program that rode a step
+        assert any(r and len({slot for slot, _, _ in rows}) < len(rows)
+                   for rows, r in zip(seen, rode))
+        # an iteration of two programs: four rows out at once, the
+        # fifth slot's and the spare rows with the step
+        k = rode.index(True)
+        assert not rode[k - 1] and len(seen[k - 1]) == 4
+        assert {slot for slot, _, _ in seen[k - 1]} == {1, 2, 3, 4}
+        # (f, in slot 5, starts behind the twenty tokens it adopted)
+        assert seen[k][0] == (5, 20, True) and len(seen[k]) == 4
+        # the twin never carried a row in its step
+        eng, _, _, rode2, _ = runs[False]
+        assert eng.counters()["steps_fused"] == 0 == sum(rode2)
+
+    def test_a_prompt_that_ends_in_the_steps_program_decodes_from_the_next(
+            self, tiny_model):
+        """The slot is no decode row of the step its last chunk rides:
+        the program writes its state row, the step after reads it."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **FUSED_KW)
+        rng = np.random.RandomState(SEED + 51)
+        runner = eng.submit(_prompt(rng, cfg, 6), max_new_tokens=12)
+        eng.step()
+        late = eng.submit(_prompt(rng, cfg, 13), max_new_tokens=4)
+        masks, real = [], eng._enqueue_step
+
+        def stepping(bt, any_sampling, active, packed=None):
+            masks.append((active.copy(), packed is not None))
+            return real(bt, any_sampling, active, packed)
+
+        eng._enqueue_step = stepping
+        eng.step()
+        # its two chunks rode the runner's step, and it was no row of it
+        assert masks == [(masks[0][0], True)]
+        assert masks[0][0].tolist() == [True] + [False] * 5
+        assert eng._decoding[late.slot] and eng._slot_due[late.slot] == 1
+        assert len(eng._parked_tokens) == 1
+        eng.step()
+        assert masks[1][0].tolist() == [True, True] + [False] * 4
+        assert not masks[1][1] and len(late.output_tokens) == 1
+        eng.run_until_idle()
+        for r in (runner, late):
+            assert list(r.output_tokens) == list(_ref(
+                model, r.prompt, max_new_tokens=r.params.max_new_tokens))
+
+    def test_a_preempted_slots_claimed_row_carries_nothing(self, mixed):
+        """The decode row's reservation preempted the slot whose rows
+        were held for the step: the program that went out next has no
+        row of that slot, and the request ran again from the queue."""
+        runs, _ = mixed
+
+        def first_chunks(fuses):
+            _, out, seen, _, g_from = runs[fuses]
+            g = out["g"][0]
+            assert g.preempt_count == 1
+            return sum((slot, pos) == (g.slot, 0)
+                       for rows in seen[g_from:] for slot, pos, _ in rows)
+
+        # its first chunk rode once: the rows held when it was preempted
+        # never went out (the twin, whose program was out, ran it twice)
+        assert (first_chunks(True), first_chunks(False)) == (1, 2)
+
+    def test_empty_rows_and_inactive_rows_write_the_dump_block_alone(
+            self, tiny_model, width=4):
+        """The step's program with no row carrying a chunk (``valid``
+        0, a zeroed table row) and every decode row inactive (a zeroed
+        table): every block but the dump block is as it was."""
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **FUSED_KW)
+        req = eng.submit(_prompt(np.random.RandomState(SEED + 52), cfg, 21),
+                         max_new_tokens=3)
+        eng.run_until_idle()
+        assert req.status == serving.RequestStatus.COMPLETED
+        before = [{k: np.asarray(v) for k, v in c.items()}
+                  for c in eng._pools]
+        assert any(np.abs(c["k"][1:]).sum() > 0 for c in before)
+        B, nb = eng.config.max_slots, eng._bt.shape[1]
+        eng._enqueue_step(np.zeros((B, nb), np.int32), np.asarray(False),
+                          np.zeros(B, bool), eng._chunk_args((), width))
+        for was, now in zip(before, eng._pools):
+            for k in was:
+                np.testing.assert_array_equal(was[k][1:],
+                                              np.asarray(now[k])[1:])
+
+    def test_warmup_leaves_no_compile_for_a_run_through_every_trace(
+            self, tiny_model):
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, **FUSED_KW)
+        info = eng.warmup()
+        assert {"serving.step", "serving.step+chunk[4]",
+                "serving.prefill_chunk[4]"} <= set(info["entries"])
+        before = recompile.total_compiles()
+        stats0 = {k: dict(v) for k, v in recompile.entry_stats().items()}
+        entries = []
+        real = eng._enqueue_step
+
+        def stepping(*a):
+            out = real(*a)
+            entries.append(out[2])
+            return out
+
+        eng._enqueue_step = stepping
+        rng = np.random.RandomState(SEED + 53)
+        reqs = [eng.submit(_prompt(rng, cfg, 5), max_new_tokens=14)]
+        eng.step()
+        reqs += [eng.submit(_prompt(rng, cfg, n), max_new_tokens=3)
+                 for n in (30, 12)]
+        for _ in range(4):
+            eng.step()
+        reqs.append(eng.submit(_prompt(rng, cfg, 7), max_new_tokens=3))
+        eng.run_until_idle()
+        # (the lone chunk of the last prompt rode the [4, C] rows too)
+        assert set(entries) == {"serving.step", "serving.step+chunk[4]"}
+        assert recompile.total_compiles() == before
+        for name, st in recompile.entry_stats().items():
+            assert st["retraces"] == stats0.get(name, st)["retraces"], name
+        for r in reqs:
+            assert list(r.output_tokens) == list(_ref(
+                model, r.prompt, max_new_tokens=r.params.max_new_tokens))

@@ -381,8 +381,8 @@ class TestQuantizedEngine:
         after = recompile.entry_stats()["serving.step"]
         assert after["compiles"] - before["compiles"] == 1
         assert after["retraces"] - before["retraces"] == 0
-        assert recompile.entry_stats()["serving.prefill_chunk"][
-            "retraces"] == 0
+        for entry in (*eng._chunk_entries, *eng._fused_entries):
+            assert recompile.entry_stats()[entry]["retraces"] == 0, entry
 
     def test_preemption_on_quantized_blocks_keeps_parity(self, tiny_model):
         """Oversubscribed int8 pool: preemption-by-recompute releases

@@ -470,10 +470,11 @@ class TestWarmup:
         assert not eng.warmed_up
         info = eng.warmup()
         assert eng.warmed_up
-        # the prefill program at its two widths: [1, C] for an
-        # iteration's lone chunk, and [2, C]
+        # an engine that fuses keeps one width of prefill rows, [2, C]:
+        # the prefill program, and the step with the rows in its
+        # program; the [1, C] forms made room for it
         assert set(info["entries"]) == {"serving.step",
-                                        "serving.prefill_chunk",
+                                        "serving.step+chunk[2]",
                                         "serving.prefill_chunk[2]",
                                         "serving.cow"}
         assert info["compiles"] >= 4
@@ -834,8 +835,12 @@ class TestBatchedPrefillServing:
             self, tiny_model):
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, **self.KW)
-        names = ("serving.prefill_chunk", "serving.prefill_chunk[4]",
+        # (an engine that fuses: the [4, C] rows alone, as a prefill
+        # program and in the step's)
+        names = ("serving.prefill_chunk[4]", "serving.step+chunk[4]",
                  "serving.step")
+        assert set(names) == {"serving.step", *eng._chunk_entries,
+                              *eng._fused_entries}
         stats0 = {n: dict(recompile.entry_stats().get(
             n, {"compiles": 0, "retraces": 0})) for n in names}
         eng.warmup()
@@ -912,9 +917,11 @@ class TestFilledProgramServing:
         """Two requests decode; a prompt of five chunks joins them. It
         has its first token after two iterations (four rows, then its
         last chunk), where one chunk an iteration took five, and the
-        running requests got their token in each of them: the prompt
-        is still fed between decode steps. The host is a step ahead of
-        the tokens it reads, so each is seen one ``step()`` later."""
+        running requests got their token in each of them: the prompt's
+        rows ride the decode steps' own programs. The host is a step
+        ahead of the tokens it reads, so each is seen one ``step()``
+        later; the prompt's slot joins the step after the one its last
+        chunk rode."""
         model, cfg = tiny_model
         eng = serving.ServingEngine(model, **self.KW)
         rng = np.random.RandomState(95)
@@ -932,8 +939,10 @@ class TestFilledProgramServing:
         assert late.output_tokens == [] and eng._jobs[late.slot] is None
         assert [len(r.output_tokens) for r in running] == [3, 3]
         eng.step()
-        assert len(late.output_tokens) == 2     # its first, and the step's
+        assert len(late.output_tokens) == 1     # its first; its slot has
+        assert eng._slot_due[late.slot] == 1    # joined the step in flight
         assert [len(r.output_tokens) for r in running] == [4, 4]
+        assert eng.counters()["steps_fused"] == 2
         eng.run_until_idle()
         assert list(late.output_tokens) == self._ref(
             model, p, max_new_tokens=4)
@@ -990,10 +999,12 @@ class TestFilledProgramServing:
         del seen[:]
         second = eng.submit(_prompt(rng, cfg, 20), max_new_tokens=3,
                             on_token=lambda r, t: seen.append(("b", t)))
-        eng.step()      # b: three rows, its last among them; a's third
-        eng.step()      # b's first, read before the step's a and b
-        assert [who for who, _ in seen] == ["a", "b", "a", "b"]
+        eng.step()      # b: two rows in the step of a's fourth; a's third
+        eng.step()      # b's first, read before that step's a; b joins
+        assert [who for who, _ in seen] == ["a", "b", "a"]
         assert second.first_token_ts <= first.last_token_ts
+        eng.step()      # the step b joined: a's fifth, b's second
+        assert [who for who, _ in seen[3:]] == ["a", "b"]
         eng.run_until_idle()
         assert [t for who, t in seen if who == "b"] == self._ref(
             model, second.prompt, max_new_tokens=3)

@@ -265,7 +265,8 @@ class TestEngineLifecycleTrace:
         assert req.status == serving.RequestStatus.COMPLETED
         compiles = [e for e in tracing.events(trace=req.id)
                     if e["cat"] == "compile"]
-        assert any(e["name"] == "xla_compile:serving.prefill_chunk"
+        # (the lone chunk rides the engine's one width of rows, [2, C])
+        assert any(e["name"] == "xla_compile:serving.prefill_chunk[2]"
                    and e["dur_ns"] > 0 for e in compiles)
 
     def test_zero_retraces_with_tracing_on_3_waves(self, tiny_model):
