@@ -260,6 +260,7 @@ class ShardedTrainStep:
         staging the batch and the enqueue) is the trainer's one host
         phase inside the program; the step itself runs on the device
         after this returns."""
+        _trace.watch_process()   # the lane ``proc``; idempotent
         with _trace.profiled_span("train.dispatch", "train", "train",
                                   {"step": self._eager_opt._step_count}):
             in_datas, lab_datas = self._stage_batch(inputs, labels)

@@ -48,11 +48,20 @@ Trace event schema (``tracing.events()`` rows / trace JSONL lines)::
              resume | first_token | prefix_cache_hit |
              prefix_cache_miss | cow_fork | preempted | requeued |
              completed | cancelled | expired | failed | rejected;
-             engine: serving.step; generation: generation.prefill |
-             generation.decode | generation.generate; compiles:
-             xla_compile:<entry>,
-     "cat":  request | engine | generation | compile | profiler,
-     "trace": serving request id | "engine" | null,
+             engine: serving.step | engine.iter and its phases |
+             engine.idle | engine.stall (instant: an iteration, or
+             the gap between two, longer than tracing.STALL_NS, with
+             its longest phase and the thread's CPU time); process
+             (lane "proc", on once a loop is started): proc.watch
+             (instant: the lane's first event) | proc.pause (the beat
+             thread woke late and the collector's passes do not
+             explain it: the whole process stood still; args cpu_ms,
+             majflt) | proc.gc (a long or full pass of the collector;
+             args gen, collected);
+             generation: generation.prefill | generation.decode |
+             generation.generate; compiles: xla_compile:<entry>,
+     "cat":  request | engine | proc | generation | compile | profiler,
+     "trace": serving request id | "engine" | "proc" | null,
      "tid":  recording OS thread ident,
      "ts_ns": monotonic perf_counter_ns start,
      "dur_ns": span duration (0 for instants),

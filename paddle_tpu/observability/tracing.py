@@ -44,16 +44,43 @@ name. A tool that needs both clocks in one file reads the annotation
 host clock adds it to the trace's times. With no session active an
 annotation is a flag check in the runtime (0.3 us each, measured here).
 
+Lost time. A second that a loop loses names its cause on the same
+ring. ``Phases`` records the instant ``<lane>.stall`` where an iteration
+that worked, or the gap between two that the loop did not idle in,
+lasts longer than ``STALL_NS``, with its longest phase and the thread's
+own CPU time (one ``thread_time_ns`` read an iteration). The lane
+``proc`` (``watch_process()``, started by whoever starts a loop, never
+at import) says what the whole process did meanwhile: a ``gc.callbacks``
+entry records the collector's long or full passes (``proc.gc``), and a
+daemon thread sleeps 25 ms at a time and records ONLY late wakes that
+those passes do not explain (``proc.pause``: the process stood still,
+with the CPU it used and its major page faults over that stretch, two
+calls a beat and no file). The callback runs wherever the collector
+trips, under the ring's lock as likely as anywhere, and takes no lock.
+Each kind is also one ``logger.warning`` line, the first eight times a
+process. What it costs while on was measured on the chip (PERF.md
+section 6, PR 40; three untraced pairs a cell on one lease, parent /
+change on the same seeds, with a beat that still opened three files of
+``/proc`` four times a second where this one makes its two calls):
+nothing that an end-to-end metric resolves. Chat-open's ``itl_p99_ms``,
+the metric a thread that asks for the interpreter's lock forty times a
+second could move, read 9.52 / 9.55, 9.64 / 9.50 and 9.59 / 9.51 ms
+(9.66 with tracing off), doc-closed 19,134 / 19,240, 19,161 / 19,272
+and 19,092 / 19,239 tokens/s (19,332 off), ``setup_s`` the same on both
+sides. With tracing disabled: no thread, no ``gc.callbacks`` entry, no
+thread clock, no stall.
+
 Event schema (what ``events()`` returns and the JSONL export writes,
 one JSON object per line):
 
 - ``ph``:     ``"X"`` (complete span) or ``"i"`` (instant event)
 - ``name``:   span/event name (``queued``, ``prefill_chunk``, ...)
-- ``cat``:    category (``request``, ``engine``, ``generation``,
-              ``compile``, ``profiler``)
+- ``cat``:    category (``request``, ``engine``, ``proc``,
+              ``generation``, ``compile``, ``profiler``)
 - ``trace``:  trace id — the serving request id for request-lifecycle
-              events, ``"engine"`` for pool-wide engine events, or
-              null for unattributed events
+              events, ``"engine"`` for pool-wide engine events,
+              ``"proc"`` for what the whole process did, or null for
+              unattributed events
 - ``tid``:    OS thread ident of the recording thread
 - ``ts_ns``:  monotonic start time (``time.perf_counter_ns`` — the
               same clock the Request timestamps use)
@@ -76,10 +103,13 @@ and the fault-tolerance SIGTERM/SIGINT handler — the post-mortem for
 
 from __future__ import annotations
 
+import atexit
+import gc
 import itertools
 import json
 import logging
 import os
+import resource
 import threading
 import time
 import weakref
@@ -94,7 +124,7 @@ from . import metrics as _m
 __all__ = [
     "tracing_enabled", "enable_tracing", "disable_tracing",
     "span", "begin_span", "end_span", "instant", "complete",
-    "profiled_span", "Phases",
+    "profiled_span", "Phases", "watch_process",
     "trace_context", "current_trace",
     "events", "clear", "chrome_trace", "export_chrome_trace",
     "export_jsonl", "span_counts", "summary",
@@ -367,6 +397,36 @@ class profiled_span:
         return False
 
 
+# An iteration that worked, or the gap between two that the loop did not
+# idle in, is a stall once it lasts longer than this. The longest
+# ordinary iteration of the benchmark's cells is 86 ms (Ouro's step
+# behind a prefill program, 38.5 + 47.8 ms on a v5e, PERF.md section 6,
+# PR 37): three times that, and a quarter of the shortest loss anyone
+# asked about (1 s). ``health()``'s ``stalled`` is the same clock held
+# against ``stall_timeout_s``, forty times this.
+STALL_NS = 250_000_000
+# the lines a process logs of each kind of lost time (a stall, a pause
+# or a pass of the collector longer than ``STALL_NS``); the ring holds
+# every one of them regardless, and the shorter pauses and passes
+_WARNINGS = 8
+_warned: Dict[str, int] = {}
+# the process's one watch (``watch_process``): (stop event, thread, when
+# it began), or None
+_watch: list = [None]
+
+
+def _warn(kind: str, at_ns: int, fmt: str, *args):
+    """One line an operator can grep, the first ``_WARNINGS`` times,
+    with when the lost stretch began, in seconds since the process
+    began to watch itself (a bare log has no clock of its own)."""
+    n = _warned.get(kind, 0)
+    if n < _WARNINGS:
+        _warned[kind] = n + 1
+        watch = _watch[0]
+        at = f" (at +{(at_ns - watch[2]) / 1e9:.1f} s)" if watch else ""
+        logger.warning(fmt + "%s", *args, at)
+
+
 class Phases:
     """The consecutive phases of one loop iteration on one thread, under
     a parent span: ``open`` starts the parent and its first phase,
@@ -379,14 +439,29 @@ class Phases:
     carries ``iter``, the number of the iteration, which ties a span on
     another lane to the iteration that ran it.
 
+    A stall. Where an iteration that worked lasted longer than
+    ``STALL_NS``, ``close`` records one instant ``<lane>.stall`` at the
+    iteration's start: ``ms`` (its length), ``phase`` and ``phase_ms``
+    (its longest child) and ``cpu_ms``, the thread's own CPU time since
+    the ``close`` before (ONE ``thread_time_ns`` read an iteration,
+    taken beside ``close``'s clock; left out where another thread closed
+    the iteration before). ``cpu_ms`` near ``ms``: the thread was busy;
+    near 0: it was blocked or not scheduled. A loop that goes straight
+    from one iteration into the next says so (``follows``); where the
+    next ``open`` then comes later than the ``close`` by more than
+    ``STALL_NS`` the thread lost that time between the two, which is a
+    stall with ``phase`` ``"between"``. ``stalls`` and ``stall_ns``
+    count both kinds, and the first few are logged.
+
     ``open`` reads the tracing flag once for the iteration (``on``).
     With tracing disabled ``open`` is its one clock read (its caller
     uses the time), ``mark`` returns 0 and ``close`` only counts: no
-    annotation, no list append, no further clock read. A caller builds
-    a phase's args only where ``on`` is true."""
+    annotation, no list append, no further clock read, no stall. A
+    caller builds a phase's args only where ``on`` is true."""
 
     __slots__ = ("parent", "cat", "trace", "seq", "on", "t_open", "t_mark",
-                 "_name", "_done", "_ann", "_ann_parent")
+                 "_name", "_done", "_ann", "_ann_parent",
+                 "follows", "stalls", "stall_ns", "_t_close", "_cpu", "_tid")
 
     def __init__(self, parent: str, cat: str, trace):
         self.parent, self.cat, self.trace = parent, cat, trace
@@ -396,6 +471,11 @@ class Phases:
         self._name = None
         self._done: list = []
         self._ann = self._ann_parent = None
+        self.follows = False  # the loop came here straight from close()
+        self.stalls = self.stall_ns = 0
+        # the last close(): its clock (0: tracing was off), the thread's
+        # CPU clock there, and the thread
+        self._t_close = self._cpu = self._tid = 0
 
     def open(self, first: str) -> int:
         """Start an iteration in phase ``first``; returns the time."""
@@ -410,6 +490,16 @@ class Phases:
             self._ann.__enter__()
             self._name = first
         self.t_open = self.t_mark = time.perf_counter_ns()
+        if self.follows:
+            self.follows = False
+            if self.on and self._t_close \
+                    and self.t_open - self._t_close > STALL_NS:
+                # rare: the one place a second CPU clock is read
+                cpu = time.thread_time_ns()
+                self._stall(self._t_close, self.t_open, "between",
+                            self.t_open - self._t_close, cpu,
+                            threading.get_ident())
+                self._cpu = cpu
         return self.t_open
 
     def mark(self, name: str, args: Optional[dict] = None) -> int:
@@ -433,12 +523,13 @@ class Phases:
         now = 0
         if self.on:
             now = time.perf_counter_ns()
+            cpu = time.thread_time_ns()
+            tid = threading.get_ident()
             self._ann.__exit__(None, None, None)
             self._ann_parent.__exit__(None, None, None)
             self._done.append((self._name, self.t_mark, now, args))
             self._name = None
             if worked:
-                tid = threading.get_ident()
                 seq = {"iter": self.seq}
                 for name, t0, t1, a in self._done:
                     _record("X", name, self.cat, self.trace, tid, t0,
@@ -446,9 +537,194 @@ class Phases:
                 _record("X", self.parent, self.cat, self.trace, tid,
                         self.t_open, now - self.t_open,
                         {**parent_args, **seq} if parent_args else seq)
+                if now - self.t_open > STALL_NS:
+                    name, t0, t1, _ = max(self._done,
+                                          key=lambda d: d[2] - d[1])
+                    self._stall(self.t_open, now, name, t1 - t0, cpu, tid)
+            self._cpu, self._tid = cpu, tid
+        self._t_close = now
         if worked:
             self.seq += 1
         return now
+
+    def _stall(self, t0: int, t1: int, phase: str, phase_ns: int,
+               cpu: int, tid: int):
+        """Record, count and (the first few times) log the stall
+        ``[t0, t1)`` whose longest part was ``phase``."""
+        args = {"iter": self.seq, "ms": (t1 - t0) / 1e6, "phase": phase,
+                "phase_ms": phase_ns / 1e6}
+        said = "not read"
+        if tid == self._tid:
+            args["cpu_ms"] = (cpu - self._cpu) / 1e6
+            said = f"{args['cpu_ms'] / 1e3:.2f} s"
+        self.stalls += 1
+        self.stall_ns += t1 - t0
+        _record("i", f"{self.trace}.stall", self.cat, self.trace, tid, t0, 0,
+                args)
+        _warn("stall", t0,
+              "%s stalled %.2f s in %s (iter %d): thread cpu %s",
+              self.trace, (t1 - t0) / 1e9, phase, self.seq, said)
+
+
+# ---------------------------------------------------------------------------
+# the lane ``proc``: what the whole process did meanwhile
+# ---------------------------------------------------------------------------
+
+# The beat thread sleeps this long at a time and records NOTHING while
+# it wakes on time; a wake later than ``_LATE_NS`` is a ``proc.pause``
+# from the time it was due to the time it came, unless the collector's
+# passes (``proc.gc``, which hold the interpreter) cover all but
+# ``_LATE_NS`` of it: the same seconds are not told twice.
+_BEAT_NS = 25_000_000
+_LATE_NS = 100_000_000
+# a pass of the collector is recorded where it took longer than this, or
+# was of the oldest generation
+_GC_NS = 10_000_000
+_WATCH_THREAD = "paddle-tpu-proc-watch"
+
+# bound here, once: a test that patches ``time.perf_counter_ns`` to count
+# a loop's clock reads must not count the beat's or the collector's
+_now = time.perf_counter_ns
+
+# What the collector's callback leaves behind. It runs on whichever
+# thread trips the collector, at any bytecode: inside ``with _lock:``
+# as likely as anywhere (``_flush_locked`` allocates under it). So it
+# takes NO lock, not the ring's (``_record`` does, on a thread's first
+# event and at every compaction) and not the logger's: a bare append to
+# each of two deques that others drain. ``_gc_buf`` holds the finished
+# ring events and stands among the threads' buffers for good, so every
+# flush carries them over; ``_gc_passes`` holds (start, end, generation,
+# collected) of the same passes for the beat, which logs the long ones
+# and takes them off a late wake.
+_gc_buf: deque = deque()
+_buffers.append((lambda: _gc_buf, _gc_buf))
+_gc_passes: deque = deque(maxlen=256)
+# the pass that is open: its start (0: none is), its annotation
+_gc_open: list = [0, None]
+
+
+def _kernel_reading() -> tuple:
+    """Running totals of what the kernel says of the process: (ns of CPU
+    on all its threads, major page faults). Two calls and no file, 0.8 us
+    here: cheap enough to be taken at every beat, so a pause's args are
+    differences over the pause and the beat before it, no more."""
+    return (time.process_time_ns(),
+            resource.getrusage(resource.RUSAGE_SELF).ru_majflt)
+
+
+class _Beat:
+    """The beat's rule, apart from its thread (a test drives ``woke``
+    with a clock and a reading of its own)."""
+
+    __slots__ = ("_clock", "_reading", "due", "_kept")
+
+    def __init__(self, clock=_now, reading=_kernel_reading):
+        self._clock, self._reading = clock, reading
+        self._kept = reading()
+        self.due = clock() + _BEAT_NS
+
+    def woke(self):
+        """Called as the thread comes back from each sleep."""
+        now, new = self._clock(), self._reading()
+        # the passes that ended since the last wake: the only ones that
+        # can lie in [due, now) (this thread alone pops)
+        in_gc = 0
+        while _gc_passes:
+            t0, t1, gen, collected = _gc_passes.popleft()
+            in_gc += max(0, min(t1, now) - max(t0, self.due))
+            if t1 - t0 > STALL_NS:
+                _warn("gc", t0,
+                      "collector ran %.2f s (generation %d, %d collected)",
+                      (t1 - t0) / 1e9, gen, collected)
+        # and the pass that is still open: a callback that stands before
+        # ours (jax's frees device buffers at every "stop", and lets go
+        # of the interpreter for it) lets this thread in before ours has
+        # closed the pass
+        if _gc_open[0]:
+            in_gc += max(0, now - max(_gc_open[0], self.due))
+        unexplained = now - self.due - in_gc
+        if unexplained > _LATE_NS and tracing_enabled():
+            args = {"cpu_ms": (new[0] - self._kept[0]) / 1e6,
+                    "majflt": new[1] - self._kept[1]}
+            _record("X", "proc.pause", "proc", "proc", threading.get_ident(),
+                    self.due, now - self.due, args)
+            if unexplained > STALL_NS:
+                _warn("pause", self.due,
+                      "process paused %.2f s: cpu %.2f s, %d major faults",
+                      (now - self.due) / 1e9, args["cpu_ms"] / 1e3,
+                      args["majflt"])
+        self._kept = new
+        self.due = now + _BEAT_NS
+
+
+def _on_gc(phase: str, info: dict):
+    """``gc.callbacks`` entry: times every pass (two clock reads), and
+    puts those of an older generation onto the host plane of an active
+    profiler session under the name the ring gives them, so that a gap
+    of the device can be named ``proc.gc`` as it is named ``engine.*``.
+    The collector never runs inside itself: one slot holds the open
+    pass. Takes no lock (``_gc_buf``, above)."""
+    if phase == "start":
+        if info["generation"]:
+            _gc_open[1] = _Annotation("proc.gc")
+            _gc_open[1].__enter__()
+        _gc_open[0] = _now()
+        return
+    t1 = _now()
+    ann, _gc_open[1] = _gc_open[1], None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    t0, gen, _gc_open[0] = _gc_open[0], info["generation"], 0
+    if (t1 - t0 > _GC_NS or gen == 2) and t0 and tracing_enabled():
+        _gc_buf.append(("X", "proc.gc", "proc", "proc",
+                        threading.get_ident(), t0, t1 - t0,
+                        {"gen": gen, "collected": info["collected"]}))
+        _gc_passes.append((t0, t1, gen, info["collected"]))
+
+
+def watch_process():
+    """Start watching the process, once: a daemon thread that records a
+    ``proc.pause`` whenever it wakes late (``_Beat``) and a
+    ``gc.callbacks`` entry that records ``proc.gc`` (``_on_gc``), both
+    on the lane ``proc``, whose first event is the instant
+    ``proc.watch``: a reader tells by it an empty lane (nothing was
+    lost) from a missing one. Idempotent. Called by whoever starts a
+    loop worth watching (``ServingEngine.start``, the first
+    ``ShardedTrainStep.step``), never at import: a process pays for the
+    thread only once it serves or trains. With tracing disabled nothing
+    is started and nothing installed."""
+    if _watch[0] is not None or not _TRACING[0]:
+        return
+    with _lock:
+        if _watch[0] is not None:
+            return
+        stop = threading.Event()
+
+        def run():
+            beat = _Beat()
+            while not stop.wait(_BEAT_NS / 1e9):
+                beat.woke()
+
+        thread = threading.Thread(target=run, name=_WATCH_THREAD, daemon=True)
+        _watch[0] = (stop, thread, _now())
+    gc.callbacks.append(_on_gc)
+    # the interpreter's last collections are nobody's lost time
+    atexit.register(_unwatch_process)
+    instant("proc.watch", "proc", "proc")
+    thread.start()
+
+
+def _unwatch_process():
+    """Stop the watch and take the collector's entry out (tests)."""
+    with _lock:
+        watch, _watch[0] = _watch[0], None
+    if watch is None:
+        return
+    watch[0].set()
+    watch[1].join()
+    gc.callbacks.remove(_on_gc)
+    _gc_open[:] = [0, None]
+    _gc_passes.clear()
 
 
 # ---------------------------------------------------------------------------

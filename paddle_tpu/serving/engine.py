@@ -76,7 +76,11 @@ Observability: every iteration that does work records ``engine.iter``
 and its phases (``engine.admit`` / ``.prefill`` / ``.reserve`` /
 ``.dispatch`` / ``.wait`` / ``.emit``; ``engine.idle`` in the serving
 loop) on the engine lane of ``observability.tracing`` and, while a
-``jax.profiler`` session is active, on its host plane; the counts the
+``jax.profiler`` session is active, on its host plane; an iteration (or
+the gap between two) longer than ``tracing.STALL_NS`` also records the
+instant ``engine.stall``, with its longest phase and the thread's CPU
+time, and ``start()`` turns on the lane ``proc`` (the process's pauses
+and the collector's passes); the counts the
 benchmark reads are plain integers on the engine (``counters()``, O(1)
 and lock-free; ``stats()`` adds the sections that cost). Then the
 ``paddle_tpu_serving_*`` instruments plus the
@@ -3183,6 +3187,9 @@ class ServingEngine:
             self._thread = threading.Thread(
                 target=self._serve_loop, name="paddle-tpu-serving", daemon=True)
             self._thread.start()
+        # what the whole process does while the loop runs (the lane
+        # ``proc``): started here, after warm-up, never at import
+        _trace.watch_process()
         return self
 
     def _serve_loop(self):
@@ -3192,7 +3199,11 @@ class ServingEngine:
         # silently and every result() caller hung forever
         try:
             while self._running:
-                if not self.step():
+                if self.step():
+                    # straight into the next iteration: a long gap from
+                    # here to its open() is time the thread lost
+                    self._phases.follows = True
+                else:
                     with self._wake:
                         if self._running and not self.has_work():
                             with _trace.profiled_span("engine.idle", "engine",
@@ -3379,6 +3390,12 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=5)
             self._thread = None
+        ph = self._phases
+        if ph.stalls:
+            # each was logged as it came (the first few); the totals
+            _trace.logger.warning(
+                "engine stalled %d time(s), %.2f s in all", ph.stalls,
+                ph.stall_ns / 1e9)
 
     def __enter__(self):
         return self
@@ -3522,6 +3539,12 @@ class ServingEngine:
         - ``stalled`` (503): the background loop has work pending but
           hasn't reached a step boundary for ``stall_timeout_s`` — a
           hung device dispatch; probes should treat it like a crash.
+          One clock, two thresholds: ``_last_progress_ts`` is the
+          iteration's ``Phases.open``, the clock against which an
+          iteration longer than ``tracing.STALL_NS`` (250 ms) is
+          recorded as ``engine.stall`` and counted in ``counters()``
+          once it ENDS; this state is for the one that has not ended
+          after ``stall_timeout_s`` (10 s), and keeps no record.
         """
         payload = {
             "ts": time.time(),
@@ -3599,6 +3622,12 @@ class ServingEngine:
             # sum of the dispatch spans' ``fused``; counted, as
             # ``steps_ahead`` is, when the step is enqueued)
             "steps_fused": self._n_steps_fused,
+            # iterations that worked, and gaps between two with no idle
+            # wait, longer than ``tracing.STALL_NS``, and their lengths
+            # summed (the ``engine.stall`` instants and their ``ms``;
+            # counted only while tracing is on)
+            "stalls": self._phases.stalls,
+            "stall_ns": self._phases.stall_ns,
         }
         if self._ut_steps > 1:
             # a looped stack: the passes of every decode step enqueued

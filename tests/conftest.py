@@ -49,28 +49,14 @@ else:
 # ---------------------------------------------------------------------------
 _RECORDED_NAMES = set()
 
-# Two tests of tests/perfbench/test_perfbench_spans.py (PR 25) pin the
-# number of cells at four beside what they are about (which metrics
-# list the four-chip cell, and that the tiny checkout holds it once).
-# The file is part of the accepted benchmark, which only a benchmark PR
-# may edit, so the fifth cell (PR 27) marks them here;
-# tests/perfbench/test_perfbench_evabyte.py holds the same facts at the
-# count the manifest has. Strict: once the file counts what the manifest
-# holds, these markers fail and go.
-_PINNED_AT_FOUR_CELLS = {
-    "test_nothing_the_benchmark_had_lists_a_cell_it_did_not",
-    "test_the_tiny_checkout_holds_the_new_cell_once"}
-# Likewise tests/perfbench/test_perfbench_prefill_rows.py (PR 28) looks
-# for its six entries among the LAST six of ``per_layer``; PR 30 appended
-# two behind them, which pushes the first two out.
-# tests/perfbench/test_perfbench_fill_rows.py holds the same facts of
-# all six by the entries' order.
-_PINNED_AT_LAST_SIX = {
-    "test_each_metric_is_data_beside_the_accepted_ones"
-    f"[prefill_rows_per_iter.{cell}]" for cell in ("chat", "doc")}
-
-
-# And two tests of tests/perfbench/test_perfbench_evabyte.py (PR 27) pin
+# Tests of the accepted benchmark (tests/perfbench/, which only a
+# benchmark PR may edit) that pin a count or a place beside what they
+# are about, and that a later PR's additions alone fail: marked here,
+# strict, with a new file of that PR holding the same facts at the count
+# the manifest has. Once the accepted file counts what the manifest
+# holds, the marker fails and goes.
+#
+# Two tests of tests/perfbench/test_perfbench_evabyte.py (PR 27) pin
 # the benchmark at five cells whose last is EvaByte's, and the tiny
 # checkout at three configurations; the sixth cell (PR 37) marks them
 # here, and tests/perfbench/test_perfbench_ouro.py holds the same facts
@@ -87,32 +73,42 @@ _PINNED_AT_FIVE_CELLS = {
 _PINNED_AT_LAST_SIXTEEN = {
     "test_every_ouro_metric_is_data_beside_the_accepted_ones"}
 
+# And tests/perfbench/test_perfbench_steps_fused.py (PR 38) asserts that
+# exactly sixteen entries list the Ouro cell alone and that only its own
+# two names follow them, and tests/perfbench/test_perfbench_spans.py
+# (PR 25) that exactly eight metrics list the four-chip cell; PR 40
+# appended twenty metrics, four of them the Ouro cell's and two the
+# four-chip cell's. tests/perfbench/test_perfbench_stalls.py holds the
+# same facts by the entries' order.
+_PINNED_AT_EIGHTEEN_FROM_OURO = {
+    "test_the_ouro_entries_stay_together_where_pr_37_put_them"}
+_PINNED_AT_EIGHT_ON_FOUR_CHIPS = {
+    "test_the_four_chip_cell_is_the_traffic_file_that_was_there"}
+
 
 def pytest_collection_modifyitems(items):
     import pytest
 
+    pinned = [
+        (_PINNED_AT_LAST_SIXTEEN, "test_perfbench_ouro.py",
+         "asserts its entries are per_layer's last sixteen; PR 38 "
+         "appended two metrics behind them"),
+        (_PINNED_AT_FIVE_CELLS, "test_perfbench_evabyte.py",
+         "asserts five cells and three tiny configurations; "
+         "BENCHMARK.json has six and four since PR 37"),
+        (_PINNED_AT_EIGHTEEN_FROM_OURO, "test_perfbench_steps_fused.py",
+         "asserts sixteen entries for the Ouro cell alone and only PR "
+         "38's two behind them; PR 40 appended twenty, four of them "
+         "that cell's"),
+        (_PINNED_AT_EIGHT_ON_FOUR_CHIPS, "test_perfbench_spans.py",
+         "asserts eight per-layer metrics on the four-chip cell; PR 40 "
+         "appended proc_pause_s.dp2mp2 and proc_gc_s.dp2mp2"),
+    ]
     for item in items:
-        if item.name in _PINNED_AT_LAST_SIXTEEN \
-                and item.path.name == "test_perfbench_ouro.py":
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="asserts its entries are per_layer's "
-                "last sixteen; PR 38 appended two metrics behind them"))
-        if item.name in _PINNED_AT_FIVE_CELLS \
-                and item.path.name == "test_perfbench_evabyte.py":
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="asserts five cells and three tiny "
-                "configurations; BENCHMARK.json has six and four since "
-                "PR 37"))
-        if item.name in _PINNED_AT_FOUR_CELLS \
-                and item.path.name == "test_perfbench_spans.py":
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="asserts len(workloads) == 4; "
-                "BENCHMARK.json has five cells since PR 27"))
-        if item.name in _PINNED_AT_LAST_SIX \
-                and item.path.name == "test_perfbench_prefill_rows.py":
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="asserts its entries are per_layer's "
-                "last six; PR 30 appended two metrics behind them"))
+        for names, file_name, reason in pinned:
+            if item.name in names and item.path.name == file_name:
+                item.add_marker(pytest.mark.xfail(strict=True,
+                                                  reason=reason))
 
 
 def pytest_configure(config):

@@ -24,6 +24,13 @@ Oracles:
   their host arrays to the executable as they are; nothing is made with
   ``jnp.asarray`` a chunk or a step.
 - ONE CLOCK: the phases also reach an active profiler session.
+- A STALL NAMES ITS CAUSE: an iteration that worked and lasted longer
+  than ``tracing.STALL_NS`` leaves ONE ``engine.stall`` with its longest
+  phase and the thread's CPU time over it (near 0 where the thread was
+  blocked, near the length where it was busy), a gap between two
+  iterations that the loop did not idle in leaves one with ``phase``
+  ``"between"``, both count in ``counters()``, and no ordinary iteration
+  of a warm tiny engine is one.
 - OFF MEANS OFF: with tracing disabled nothing of this is recorded, no
   annotation is made, no clock is read beyond the step's own, and the
   tokens are the same.
@@ -31,6 +38,7 @@ Oracles:
 
 import glob
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -125,8 +133,10 @@ class TestPhases:
     def test_every_engine_span_is_on_the_engine_lane(self, served):
         _, _, events = served
         mine = [e for e in events if e["name"].startswith("engine.")]
-        assert mine and all(e["cat"] == "engine" and e["trace"] == "engine"
-                            and e["ph"] == "X" for e in mine)
+        assert mine and all(
+            e["cat"] == "engine" and e["trace"] == "engine"
+            and e["ph"] == ("i" if e["name"] == "engine.stall" else "X")
+            for e in mine)
 
     def test_a_span_carries_only_the_args_a_metric_reads(self, served):
         # iter ties the lanes together; preempted is preemptions.*;
@@ -138,16 +148,25 @@ class TestPhases:
         # prefill_rows_per_iter.*, prefill_programs_per_iter.* and
         # prefill_fill_rows_per_iter.*, on the iterations that enqueued
         # a prefill program and no other
+        # ms, phase, phase_ms and cpu_ms of the instant engine.stall
+        # (the fixture's engine is not warmed up: its first iterations
+        # compile, and stall) are stall_s.* and stall_blocked_s.* and
+        # the line the engine logs
         _, _, events = served
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
                                  "prompt_tokens"},
                 "engine.dispatch": {"iter", "kv_blocks", "ahead", "fused",
-                                    "prefill_rows"}}
+                                    "prefill_rows"},
+                "engine.stall": {"iter", "ms", "phase", "phase_ms",
+                                 "cpu_ms"}}
         # (another test's engine may idle on a thread of its own meanwhile)
         mine = [e for e in events if e["name"].startswith("engine.")
                 and e["name"] != "engine.idle"]
-        assert {e["name"] for e in mine} == {"engine.iter", *CHILDREN}
+        assert {e["name"] for e in mine} - {"engine.stall"} \
+            == {"engine.iter", *CHILDREN}
+        assert all(e["ph"] == "i" for e in mine
+                   if e["name"] == "engine.stall")
         chunk_iters = {e["args"]["iter"] for e in events
                        if e["name"] == "prefill_chunk"}
         for e in mine:
@@ -156,6 +175,11 @@ class TestPhases:
                 assert set(e["args"]) == (
                     {"iter", "rows", "programs", "fill"} if ran
                     else {"iter"}), e
+                continue
+            if e["name"] == "engine.stall":
+                # (the engine's very first iteration has no close before
+                # it to hold the thread's clock against)
+                assert set(e["args"]) | {"cpu_ms"} == want[e["name"]], e
                 continue
             assert set(e["args"]) == want.get(e["name"], {"iter"}), e
         assert chunk_iters
@@ -178,9 +202,10 @@ class TestPhases:
         eng.submit(_prompt(np.random.RandomState(3), cfg, 37),
                    max_new_tokens=7)
         eng.run_until_idle()
+        # (engine.stall: the iterations that compile, on this cold engine)
         lane = [e for e in _engine_lane(t0)
                 if e["name"].startswith("engine.")
-                and e["name"] != "engine.idle"]
+                and e["name"] not in ("engine.idle", "engine.stall")]
         want = {"engine.iter": {"iter", "preempted"},
                 "engine.admit": {"iter", "prefix_hit_tokens",
                                  "prompt_tokens"},
@@ -408,6 +433,173 @@ class TestPhases:
         assert not eng._phases.on and eng._phases._done == []
 
 
+class TestStalls:
+    def _warm(self, tiny_model, n_new=24):
+        model, cfg = tiny_model
+        eng = _engine(model)
+        eng.warmup()
+        req = eng.submit(_prompt(np.random.RandomState(71), cfg, 20),
+                         max_new_tokens=n_new)
+        for _ in range(4):
+            assert eng.step()
+        return eng, req
+
+    def _slowed(self, eng, how):
+        """One dispatch that first spends 0.3 s in ``how``; returns the
+        engine lane's stalls since."""
+        real, t0 = eng._enqueue_step, tracing._now()
+
+        def slow(*a, **k):
+            how()
+            return real(*a, **k)
+
+        eng._enqueue_step = slow
+        assert eng.step()
+        eng._enqueue_step = real
+        eng.run_until_idle()
+        return [e for e in _engine_lane(t0) if e["name"] == "engine.stall"]
+
+    def test_a_sleeping_dispatch_is_one_stall_that_used_no_cpu(
+            self, tiny_model):
+        eng, req = self._warm(tiny_model)
+        assert eng.counters()["stalls"] == 0
+        it, since = eng._phases.seq, tracing._now()
+        (e,) = self._slowed(eng, lambda: threading.Event().wait(0.3))
+        a = e["args"]
+        assert (e["ph"], e["cat"], e["trace"]) == ("i", "engine", "engine")
+        assert a["phase"] == "engine.dispatch" and a["iter"] == it
+        assert 300 <= a["phase_ms"] <= a["ms"] < 600
+        assert 0 <= a["cpu_ms"] < 50
+        # the instant stands at the start of the iteration it names
+        (parent,) = [p for p in _engine_lane(since)
+                     if p["name"] == "engine.iter" and p["args"]["iter"] == it]
+        assert e["ts_ns"] == parent["ts_ns"]
+        assert a["ms"] == pytest.approx(parent["dur_ns"] / 1e6)
+        c = eng.counters()
+        assert c["stalls"] == 1
+        assert c["stall_ns"] == pytest.approx(a["ms"] * 1e6)
+        assert eng.stats()["counters"]["stalls"] == 1
+        assert len(req.output_tokens) == 24
+
+    def test_a_busy_dispatch_is_a_stall_that_used_its_time(self, tiny_model):
+        eng, _ = self._warm(tiny_model)
+
+        def spin():
+            # 0.3 s of the thread's OWN clock: on a machine that shares
+            # its cores the wall clock runs ahead of it
+            end = time.thread_time_ns() + 300_000_000
+            while time.thread_time_ns() < end:
+                pass
+
+        (e,) = self._slowed(eng, spin)
+        a = e["args"]
+        assert a["phase"] == "engine.dispatch"
+        assert 300 <= a["cpu_ms"] <= a["ms"] + 1
+
+    def test_no_ordinary_iteration_of_a_warm_tiny_engine_stalls(
+            self, tiny_model, models):
+        model, cfg = tiny_model
+        engines = [_engine(model)] + [
+            serving.ServingEngine(m, **kw) for m, kw in models.values()]
+        for eng in engines:
+            eng.warmup()
+            vocab = eng.model.config.vocab_size
+            # (a loaded machine may hold any one wave up for a quarter
+            # of a second: what the lane is for. Not three in a row.)
+            for _ in range(3):
+                t0, before = tracing._now(), eng.counters()
+                for n in (9, 40):
+                    eng.submit(np.random.RandomState(n).randint(
+                        1, vocab, n).astype("int32"), max_new_tokens=6)
+                eng.run_until_idle()
+                c = eng.counters()
+                new = [e for e in _engine_lane(t0)
+                       if e["name"] == "engine.stall"]
+                assert c["steps"] > before["steps"]
+                assert c["stalls"] - before["stalls"] == len(new)
+                if not new:
+                    break
+            assert not new, new
+
+    def test_a_gap_the_loop_did_not_idle_in_is_a_stall_between(self):
+        ph = tracing.Phases("t_st.iter", "test", "t_st")
+        ph.open("t_st.a")
+        t_close = ph.close(True)
+        # the caller idled, or drives by hand: a late open is no stall
+        threading.Event().wait(0.3)
+        ph.open("t_st.a")
+        t_close = ph.close(True)
+        assert ph.stalls == 0
+        # the loop says it comes straight from close(): the gap is one
+        ph.follows = True
+        threading.Event().wait(0.3)
+        t_open = ph.open("t_st.a")
+        assert not ph.follows
+        ph.close(True)
+        (e,) = tracing.events(trace="t_st", name="t_st.stall")
+        assert e["ts_ns"] == t_close and e["ph"] == "i"
+        a = e["args"]
+        assert (a["phase"], a["iter"]) == ("between", 2)
+        assert a["ms"] == a["phase_ms"] == pytest.approx(
+            (t_open - t_close) / 1e6)
+        assert 0 <= a["cpu_ms"] < 50
+        assert (ph.stalls, ph.stall_ns) == (1, t_open - t_close)
+        # and the iteration behind the gap is not charged the gap's CPU
+        ph.follows = True
+        ph.open("t_st.a")
+        ph.close(True)
+        assert ph.stalls == 1
+
+    def test_an_iteration_another_thread_closed_before_has_no_cpu_reading(
+            self):
+        """The thread's CPU clock is held against the reading of the
+        ``close`` before: where another thread took that, the two are
+        different clocks and the arg is left out."""
+        ph = tracing.Phases("t_st2.iter", "test", "t_st2")
+        t = threading.Thread(target=lambda: (ph.open("t_st2.a"),
+                                             ph.close(True)))
+        t.start()
+        t.join()
+        ph.open("t_st2.a")
+        threading.Event().wait(0.3)
+        ph.close(True)
+        (e,) = tracing.events(trace="t_st2", name="t_st2.stall")
+        assert set(e["args"]) == {"iter", "ms", "phase", "phase_ms"}
+        assert e["args"]["phase"] == "t_st2.a"
+
+    def test_the_serving_loop_says_when_it_goes_straight_on(self, tiny_model):
+        """``_serve_loop`` sets ``follows`` after an iteration that
+        worked and not after its idle wait; a request served from start
+        to end by a warm engine leaves no stall, and ``start()`` has
+        turned the lane ``proc`` on."""
+        eng, _ = self._warm(tiny_model, n_new=6)
+        eng.run_until_idle()
+        seen, real = [], eng.step
+
+        def step():
+            follows = eng._phases.follows
+            seen.append((follows, real()))
+            return seen[-1][1]
+
+        eng.step = step
+        eng.start()
+        try:
+            assert tracing._WATCH_THREAD in [
+                t.name for t in threading.enumerate()]
+            req = eng.submit(_prompt(np.random.RandomState(72),
+                                     tiny_model[1], 12), max_new_tokens=8)
+            req.result(timeout=120)
+            threading.Event().wait(0.12)     # two idle waits
+        finally:
+            eng.stop()
+        assert (True, True) in seen and (False, False) in seen
+        for (_, worked), (follows, _) in zip(seen, seen[1:]):
+            assert follows == worked
+        assert eng.counters()["stalls"] == 0
+        assert any(e["name"] == "proc.watch"
+                   for e in tracing.events(trace="proc"))
+
+
 class TestPhasesObject:
     def test_marks_are_shared_edges_and_close_records_in_order(self):
         ph = tracing.Phases("t_ph.iter", "test", "t_ph")
@@ -491,7 +683,7 @@ class TestCounters:
                           "preemptions", "prefill_rows",
                           "prefill_programs", "prefill_fill_rows",
                           "steps_ahead", "ahead_flushes", "dead_rows",
-                          "steps_fused"}
+                          "steps_fused", "stalls", "stall_ns"}
 
 
 class TestFirstTokenAfterDispatch:

@@ -15,10 +15,22 @@ Oracles:
   exactly within the window.
 - ZERO RETRACES: the one-step-compile invariant holds over 3 request
   waves WITH tracing enabled (host-side instrumentation only).
+- THE LANE ``proc``: the beat records nothing while it wakes on time and
+  one ``proc.pause`` for a late wake, with the kernel's counters as
+  differences of two reads; a process stopped and continued comes back
+  with one pause of that length; a pass of the collector is a
+  ``proc.gc`` where it was long or of the oldest generation; a flight
+  dump taken after a stall holds the stall and what overlapped it; with
+  tracing disabled no thread, no ``gc.callbacks`` entry and no thread
+  clock.
 """
 
+import gc
 import json
 import os
+import signal
+import subprocess
+import sys
 import threading
 import time
 import urllib.request
@@ -441,6 +453,44 @@ class TestFlightRecorder:
         assert state["kv_blocks"]["in_use"] >= 1
         assert state["slots_busy"] >= 1
 
+    def test_a_dump_after_a_stall_holds_it_and_what_overlapped_it(
+            self, tiny_model, tmp_path, monkeypatch, proc_watch):
+        """What an operator asks a crash dump first: did the loop stand
+        still, in which phase, and what did the process do meanwhile.
+        A dispatch that sleeps 0.3 s behind a full collection leaves one
+        ``engine.stall`` and the ``proc.gc`` inside it, both among the
+        dump's events."""
+        monkeypatch.setenv("PADDLE_TPU_SINK_DIR", str(tmp_path))
+        model, cfg = tiny_model
+        eng = serving.ServingEngine(model, max_slots=2, max_len=64)
+        eng.warmup()
+        tracing.watch_process()
+        eng.submit(_prompt(np.random.RandomState(SEED + 9), cfg, 6),
+                   max_new_tokens=8)
+        for _ in range(3):
+            assert eng.step()
+        real, t0 = eng._enqueue_step, tracing._now()
+
+        def slow(*a, **k):
+            gc.collect()
+            time.sleep(0.3)
+            return real(*a, **k)
+
+        eng._enqueue_step = slow
+        assert eng.step()
+        eng._enqueue_step = real
+        eng.run_until_idle()
+        dump = json.loads(open(tracing.flight_dump("unit_test_stall")).read())
+        (stall,) = [e for e in dump["events"]
+                    if e["name"] == "engine.stall" and e["ts_ns"] >= t0]
+        assert stall["args"]["phase"] == "engine.dispatch"
+        lo = stall["ts_ns"]
+        hi = lo + stall["args"]["ms"] * 1e6
+        inside = [e for e in dump["events"] if e["name"] == "proc.gc"
+                  and lo <= e["ts_ns"] and e["ts_ns"] + e["dur_ns"] <= hi]
+        assert inside and inside[0]["args"]["gen"] == 2
+        assert dump["state"]["serving_engine"]["counters"]["stalls"] == 1
+
     def test_pool_exhausted_escape_dumps(self, tiny_model, tmp_path,
                                          monkeypatch):
         """Every in-engine PoolExhaustedError is absorbed by
@@ -465,6 +515,274 @@ class TestFlightRecorder:
         assert "injected reclaim wedge" in dump["extra"]["error"]
         # the state provider captured this engine's pool accounting
         assert dump["state"]["serving_engine"]["kv_blocks"]["usable"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the lane ``proc``
+# ---------------------------------------------------------------------------
+
+MS = 1_000_000
+
+
+@pytest.fixture()
+def proc_watch():
+    """No watch running before the test (an engine that an earlier test
+    started has turned one on, and its lines may have used up the
+    process's eight of a kind) and none after it."""
+    tracing._unwatch_process()
+    tracing._warned.clear()
+    yield
+    tracing._unwatch_process()
+
+
+def _proc_lane(since=0):
+    return [e for e in tracing.events(trace="proc") if e["ts_ns"] >= since]
+
+
+class _Script:
+    """A clock and a kernel that a test moves by hand."""
+
+    def __init__(self):
+        self.now, self.reads = 0, 0
+        self.cpu_ms, self.majflt = 10.0, 3
+
+    def clock(self):
+        return self.now
+
+    def reading(self):
+        self.reads += 1
+        return int(self.cpu_ms * MS), self.majflt
+
+
+class _GuardedLock:
+    """Stands in for the ring's lock: a second ``with`` on it fails the
+    test instead of hanging the suite."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+
+    def __enter__(self):
+        assert self._lock.acquire(timeout=2), "would wait for ever"
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
+class TestProcLane:
+    def test_a_wake_on_time_records_nothing_and_a_late_one_is_a_pause(self):
+        sc = _Script()
+        sc.now = 5_000 * MS
+        t0 = tracing._now()
+        beat = tracing._Beat(sc.clock, sc.reading)
+        assert beat.due == sc.now + tracing._BEAT_NS
+        # on time, and late by less than the rule's 100 ms
+        for late in (0, 1 * MS, 100 * MS):
+            sc.now = beat.due + late
+            sc.cpu_ms += 1.0
+            beat.woke()
+            assert beat.due == sc.now + tracing._BEAT_NS
+        assert _proc_lane(t0) == []
+        # 500 ms late: a span from the time it was due to the time it
+        # came; the kernel's totals less what they read a beat before
+        due = beat.due
+        sc.now = due + 500 * MS
+        sc.cpu_ms, sc.majflt = sc.cpu_ms + 2.5, sc.majflt + 4
+        beat.woke()
+        (e,) = [e for e in tracing.events(trace="proc")
+                if e["ts_ns"] == due]
+        assert (e["name"], e["ph"], e["cat"], e["dur_ns"]) \
+            == ("proc.pause", "X", "proc", 500 * MS)
+        assert e["args"] == {"cpu_ms": 2.5, "majflt": 4}
+        assert e["tid"] == threading.get_ident()
+        # one reading a beat, and one when the beat began
+        assert sc.reads == 1 + 4
+        cpu_ns, majflt = tracing._kernel_reading()
+        assert cpu_ns > 0 and majflt >= 0
+
+    def test_a_late_wake_that_the_collector_explains_is_no_pause(
+            self, proc_watch, caplog):
+        """The collector holds the interpreter, so the beat wakes late
+        by every long pass: the pass is told once, as ``proc.gc``. A
+        pause is what the passes leave of the lateness, where that is
+        over the rule's 100 ms; it is recorded whole (the reader takes
+        the overlap off). The long passes are logged here, by the beat's
+        thread, not by the collector's callback."""
+        sc = _Script()
+        sc.now = 9_000 * MS
+        beat = tracing._Beat(sc.clock, sc.reading)
+        caplog.set_level("WARNING", logger="paddle_tpu.observability")
+
+        def late(by_ms, *passes):
+            due = beat.due
+            for start_ms, dur_ms in passes:
+                tracing._gc_passes.append(
+                    (due + start_ms * MS, due + (start_ms + dur_ms) * MS,
+                     2, 0))
+            sc.now = due + by_ms * MS
+            beat.woke()
+            assert not tracing._gc_passes
+            return [e for e in tracing.events(trace="proc")
+                    if e["name"] == "proc.pause" and e["ts_ns"] == due]
+
+        # 130 ms late behind a pass of 125 ms (what a quiet run of the
+        # benchmark's served cells does two or three times): nothing
+        assert late(130, (2, 125)) == []
+        # the pass began before the wake was due: only its part inside
+        # counts, and 150 - 40 is still a pause
+        (e,) = late(150, (-300, 340))
+        assert e["dur_ns"] == 150 * MS
+        # two passes and a stretch nobody explains
+        assert late(400, (0, 150), (160, 150)) == []
+        (e,) = late(700, (0, 150), (160, 150))
+        assert e["dur_ns"] == 700 * MS
+        # a pass that is still open as the beat wakes (a callback of the
+        # collector's that stands before ours let go of the interpreter)
+        tracing._gc_open[0] = beat.due - 10 * MS
+        assert late(200) == []
+        tracing._gc_open[0] = 0
+        (e,) = late(200)
+        said = [r.getMessage() for r in caplog.records]
+        # a line for the one pass over the stall threshold, and for the
+        # one pause of which more than that was nobody's
+        assert [m for m in said if m.startswith("collector ran")] \
+            == ["collector ran 0.34 s (generation 2, 0 collected)"]
+        assert [m for m in said if m.startswith("process paused")] \
+            == ["process paused 0.70 s: cpu 0.00 s, 0 major faults"]
+
+    def test_a_pass_that_fires_while_the_rings_lock_is_held_takes_no_lock(
+            self, proc_watch, monkeypatch):
+        """The collector runs on whichever thread trips it, at any
+        bytecode, ``_flush_locked``'s first lines among them: with the
+        caller's buffer one short of compaction and the ring's lock
+        held, a full pass must leave its event without asking for the
+        lock (it once recorded through ``_record``, which flushes at
+        ``_COMPACT_AT`` and would have waited for its own thread for
+        ever)."""
+        monkeypatch.setattr(tracing, "_lock", _GuardedLock())
+        tracing.watch_process()
+        done = []
+
+        def holder():
+            t0 = tracing._now()
+            for i in range(tracing._COMPACT_AT - 1):
+                tracing.instant("t_lock.fill", "test", "t_lock")
+            buf = tracing._buf()
+            assert len(buf) == tracing._COMPACT_AT - 1
+            with tracing._lock:
+                tracing._on_gc("start", {"generation": 2})
+                tracing._on_gc("stop", {"generation": 2, "collected": 5})
+                gc.collect()         # and a real one, callbacks and all
+                assert len(buf) == tracing._COMPACT_AT - 1
+            mine = [e for e in _proc_lane(t0) if e["name"] == "proc.gc"
+                    and e["tid"] == threading.get_ident()]
+            assert len(mine) == 2 and mine[0]["args"] \
+                == {"gen": 2, "collected": 5}
+            done.append(True)
+
+        t = threading.Thread(target=holder, daemon=True)
+        t.start()
+        t.join(30)
+        assert done == [True]
+
+    def test_a_process_stopped_and_continued_comes_back_with_one_pause(
+            self, tmp_path):
+        """A real ``SIGSTOP`` / ``SIGCONT`` of a child that watches
+        itself: one ``proc.pause`` of the time it stood still, and the
+        kernel's counters say nobody computed and nobody waited for a
+        CPU meanwhile."""
+        code = (
+            "import json, sys\n"
+            "from paddle_tpu.observability import tracing\n"
+            "tracing.watch_process()\n"
+            "print('ready', flush=True)\n"
+            "sys.stdin.readline()\n"
+            "print(json.dumps(tracing.events(trace='proc')), flush=True)\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PADDLE_TPU_TRACING="1")
+        child = subprocess.Popen(
+            [sys.executable, "-c", code], stdin=subprocess.PIPE,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            env=env, cwd=os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))))
+        try:
+            assert child.stdout.readline().strip() == "ready"
+            time.sleep(0.3)          # some beats on time first
+            os.kill(child.pid, signal.SIGSTOP)
+            time.sleep(0.5)
+            os.kill(child.pid, signal.SIGCONT)
+            time.sleep(0.2)
+            out, err = child.communicate("go\n", timeout=60)
+        finally:
+            child.kill()
+        events = json.loads(out.strip().splitlines()[-1])
+        assert events[0]["name"] == "proc.watch"
+        pauses = [e for e in events if e["name"] == "proc.pause"
+                  and e["dur_ns"] > 300 * MS]
+        assert len(pauses) == 1, events
+        assert 400 * MS <= pauses[0]["dur_ns"] <= 700 * MS
+        # the operator's line, on standard error, with when it began
+        # (some 0.3 s into the watch)
+        (line,) = [ln for ln in err.splitlines()
+                   if ln.startswith("process paused ")
+                   and float(ln.split()[2]) >= 0.4]
+        # nobody computed meanwhile: the process was stopped whole
+        assert ": cpu 0.0" in line and line.endswith(" s)")
+        assert pauses[0]["args"]["cpu_ms"] < 100
+        assert 0.2 <= float(line.rsplit("(at +", 1)[1][:-3]) <= 1.5
+
+    def test_a_full_collection_is_recorded_and_a_short_young_one_is_not(
+            self, proc_watch):
+        tracing.watch_process()
+        t0 = tracing._now()
+        gc.collect(0)
+        gc.collect(1)
+        young = [e for e in _proc_lane(t0) if e["name"] == "proc.gc"]
+        assert all(e["dur_ns"] > tracing._GC_NS for e in young)
+        t1 = tracing._now()
+        gc.collect()
+        full = [e for e in _proc_lane(t1) if e["name"] == "proc.gc"
+                and e["tid"] == threading.get_ident()]
+        assert full and full[-1]["args"]["gen"] == 2
+        assert set(full[-1]["args"]) == {"gen", "collected"}
+        assert (full[-1]["cat"], full[-1]["trace"]) == ("proc", "proc")
+
+    def test_the_watch_is_one_thread_one_entry_and_one_mark(self, proc_watch):
+        before = list(gc.callbacks)
+        t0 = tracing._now()
+        tracing.watch_process()
+        tracing.watch_process()
+        names = [t.name for t in threading.enumerate()]
+        assert names.count(tracing._WATCH_THREAD) == 1
+        assert gc.callbacks == before + [tracing._on_gc]
+        assert [e["name"] for e in _proc_lane(t0)].count("proc.watch") == 1
+        tracing._unwatch_process()
+        assert gc.callbacks == before
+        assert tracing._WATCH_THREAD not in [
+            t.name for t in threading.enumerate()]
+
+    def test_with_tracing_off_nothing_is_started_or_installed(
+            self, proc_watch, monkeypatch):
+        before = list(gc.callbacks)
+        reads = []
+        tracing.disable_tracing()
+        try:
+            monkeypatch.setattr(tracing.time, "thread_time_ns",
+                                lambda: reads.append(1) or 0)
+            tracing.watch_process()
+            assert gc.callbacks == before
+            assert tracing._WATCH_THREAD not in [
+                t.name for t in threading.enumerate()]
+            # and the loop's phases read no thread clock, stall or not
+            ph = tracing.Phases("t_off.iter", "test", "t_off")
+            ph.open("t_off.a")
+            ph.close(True)
+            ph.follows = True
+            ph.open("t_off.a")
+            ph.close(True)
+        finally:
+            monkeypatch.undo()
+            tracing.enable_tracing()
+        assert reads == [] and ph.stalls == 0
+        assert tracing.events(trace="t_off") == []
 
 
 # ---------------------------------------------------------------------------
