@@ -79,11 +79,6 @@ class ShardedTrainStep:
         # optimizer=None: forward/backward machinery only — the caller owns
         # the update (HostOffloadTrainStep keeps state in pinned host
         # memory; eagerly allocating device m/v here would defeat it).
-        # Per-leaf AdamW is the measured default: the stacked adamw_flat
-        # variant was A/B'd on-chip (interleaved, 2x20 steps) at ~2%
-        # SLOWER — XLA lowers the per-step stack/unstack to a
-        # dynamic-update-slice chain that costs more than the ~111 small
-        # per-leaf update launches it replaces.
         self._fopt = (fopt.from_eager(optimizer)
                       if optimizer is not None else None)
         self.grad_clip_norm = grad_clip_norm
@@ -117,6 +112,10 @@ class ShardedTrainStep:
         self.opt_state = (self._shard_opt_state(self._fopt.init(self.params))
                           if self._fopt is not None else None)
         self._step_fn = None
+        # the linear weights whose gradient the traced step makes from
+        # factors written once (``_build``), and their share of the
+        # parameters
+        self._fused = {"fused_leaves": 0, "fused_param_share": 0.0}
         self._batch_spec = batch_spec
         self._label_spec = label_spec
         # HBM-ledger attribution: the engine owns the two big persistent
@@ -204,13 +203,42 @@ class ShardedTrainStep:
 
         return forward_loss
 
+    def _one_program_one_device(self) -> bool:
+        """What the step that was measured looks like (PERF.md section 6,
+        PR 41): AdamW, the mesh shards neither batch nor parameters, no
+        gradient is seen whole before its update (no clip by the global
+        norm) and nothing is rematerialised. Any other step is traced as
+        it always was."""
+        from ..optimizer.optimizer import AdamW
+
+        return (isinstance(self._eager_opt, AdamW)
+                and self.grad_clip_norm is None and not self._remat
+                and self.dp_axis is None and self._batch_spec is None
+                and self._label_spec is None
+                and not any(getattr(p, "placements", None)
+                            for p in self._param_objs.values()))
+
     def _build(self):
+        from ..nn import functional as F
+
         f = self._fopt
         clip_norm = self.grad_clip_norm
         forward_loss = self._make_forward_loss()
+        factors_once = self._one_program_one_device()
 
         def step(params, opt_state, lr, inputs, labels):
-            loss, grads = jax.value_and_grad(forward_loss)(params, self.buffers, inputs, labels)
+            # ``linear`` reads the slot while it is traced, forward and
+            # backward both inside this call
+            routed = F._factors_once.routed = [] if factors_once else None
+            try:
+                loss, grads = jax.value_and_grad(forward_loss)(params, self.buffers, inputs, labels)
+            finally:
+                F._factors_once.routed = None
+            weights = {id(w): w.size for w in routed or ()}
+            self._fused = {
+                "fused_leaves": len(weights),
+                "fused_param_share": sum(weights.values()) / max(1, sum(
+                    p.size for p in params.values()))}
             if clip_norm is not None:
                 grads, _ = fopt.clip_by_global_norm(grads, clip_norm)
             new_params, new_state = f.update(grads, opt_state, params, lr)
@@ -261,12 +289,13 @@ class ShardedTrainStep:
         phase inside the program; the step itself runs on the device
         after this returns."""
         _trace.watch_process()   # the lane ``proc``; idempotent
-        with _trace.profiled_span("train.dispatch", "train", "train",
-                                  {"step": self._eager_opt._step_count}):
+        args = {"step": self._eager_opt._step_count}
+        with _trace.profiled_span("train.dispatch", "train", "train", args):
             in_datas, lab_datas = self._stage_batch(inputs, labels)
             lr = jnp.asarray(self._eager_opt.get_lr(), jnp.float32)
             loss, self.params, self.opt_state = self._step_fn(
                 self.params, self.opt_state, lr, in_datas, lab_datas)
+            args.update(self._fused)    # known once the step has traced
         self._eager_opt._step_count += 1
         if isinstance(self._eager_opt._learning_rate, LRScheduler):
             pass  # user drives scheduler.step() as in eager flow

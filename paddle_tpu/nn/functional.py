@@ -11,6 +11,7 @@ from __future__ import annotations
 import builtins
 import functools
 import math as pymath
+import threading
 from typing import Optional, Sequence
 
 import jax
@@ -203,14 +204,65 @@ def maxout(x, groups, axis=1, name=None) -> Tensor:
 # ---------------------------------------------------------------------------
 
 
+# While a train step that wants every linear's weight gradient made from
+# factors written once traces its forward and backward
+# (distributed/engine.py ``ShardedTrainStep``), ``routed`` is the list of
+# the weights routed so far, on the tracing thread alone; None at every
+# other time, and ``linear`` then builds the program it always built: the
+# check is at trace time only.
+_factors_once = threading.local()
+_factors_once.routed = None
+
+
+@jax.custom_vjp
+def _matmul_factors_once(a, w):
+    """``a @ w`` for a weight ``w [in, out]``, whose gradient ``a^T dy``
+    reads both its factors from HBM, each written once.
+
+    XLA runs a weight-gradient matmul with the parameter's whole
+    optimizer update as its epilogue, so the gradient never reaches
+    HBM; but left to itself it also folds what MADE the factors into
+    the matmul's operands (d logits of the fused cross entropy,
+    ``exponential`` and all; ``silu(gate) * up`` in front of
+    ``down_proj``) and computes it again for every window it reads:
+    16.9 ms for ``lm_head`` at Mistral-7B widths on a v5e where the same
+    fusion on materialised factors takes 6.8 (PERF.md section 6, PR 41).
+    The barrier is on the weight gradient's operands alone; the forward
+    and the input's gradient are what autodiff makes of ``matmul``."""
+    return jnp.matmul(a, w)
+
+
+def _factors_once_fwd(a, w):
+    return jnp.matmul(a, w), (a, w)
+
+
+def _factors_once_bwd(res, dy):
+    a, w = res
+    da = jax.lax.dot_general(dy, w, (((dy.ndim - 1,), (1,)), ((), ())))
+    rows, cots = jax.lax.optimization_barrier(
+        (a.reshape(-1, w.shape[0]), dy.reshape(-1, w.shape[1])))
+    return da, jax.lax.dot_general(rows, cots, (((0,), (0,)), ((), ())))
+
+
+_matmul_factors_once.defvjp(_factors_once_fwd, _factors_once_bwd)
+
+
+def _linear_matmul(a, w):
+    routed = getattr(_factors_once, "routed", None)
+    if routed is None or w.ndim != 2 or a.dtype != w.dtype:
+        return jnp.matmul(a, w)
+    routed.append(w)
+    return _matmul_factors_once(a, w)
+
+
 def linear(x, weight, bias=None, name=None) -> Tensor:
     """y = x @ W + b. Weight layout [in, out] (reference:
     python/paddle/nn/functional/common.py linear; phi matmul kernel)."""
     x, weight = ensure_tensor(x), ensure_tensor(weight)
     if bias is None:
-        return apply_op("linear", lambda a, w: jnp.matmul(a, w), x, weight)
+        return apply_op("linear", lambda a, w: _linear_matmul(a, w), x, weight)
     bias = ensure_tensor(bias)
-    return apply_op("linear", lambda a, w, b: jnp.matmul(a, w) + b, x, weight, bias)
+    return apply_op("linear", lambda a, w, b: _linear_matmul(a, w) + b, x, weight, bias)
 
 
 def embedding(x, weight, padding_idx=None, sparse=False, name=None) -> Tensor:

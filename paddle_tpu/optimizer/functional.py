@@ -147,95 +147,13 @@ def clip_by_global_norm(grads, clip_norm: float):
     return jax.tree.map(lambda g: None if g is None else (g.astype(jnp.float32) * scale).astype(g.dtype), grads), gnorm
 
 
-def adamw_flat(beta1: float = 0.9, beta2: float = 0.999, epsilon: float = 1e-8,
-               weight_decay: float = 0.01,
-               decay_mask_fn: Optional[Callable] = None) -> FunctionalOptimizer:
-    """AdamW with the update FUSED across parameters: leaves sharing a
-    (shape, dtype, wd) signature update as ONE stacked launch, and their
-    m/v state lives stacked — a transformer's 12x-repeated layer weights
-    collapse from ~111 tiny launch chains into ~10 vectorized ones.
-
-    Rationale (v5e profile of the 134M bench step): the per-leaf update
-    ran as ~111 sequential `subtract_convert_fusion` launches costing
-    22.9 ms of a 128 ms step (18%) — launch latency, not math (HBM-bound
-    floor is ~3.5 ms). Same-shape jnp.stack lowers to a single concat
-    kernel (a mixed-size flat concat degenerates into a
-    dynamic-update-slice chain — measured slower than the baseline).
-
-    Numerics are identical to `adamw` (same math, same per-leaf wd mask;
-    elementwise ops don't care about packing). The reference analogue is
-    the fused multi_tensor adam path (distributed_fused_lamb /
-    multi_tensor_apply)."""
-
-    def _groups(params):
-        """Leaf indices grouped by (shape, dtype, wd)."""
-        p_flat_path, treedef = jax.tree_util.tree_flatten_with_path(params)
-        groups = {}
-        for i, (path, p) in enumerate(p_flat_path):
-            wd = weight_decay if (decay_mask_fn is None
-                                  or decay_mask_fn(_path_name(path))) else 0.0
-            groups.setdefault((tuple(p.shape), str(p.dtype), wd),
-                              []).append(i)
-        return treedef, list(groups.items())
-
-    def update(grads, state, params, lr):
-        treedef, groups = _groups(params)
-        p_leaves = treedef.flatten_up_to(params)
-        g_leaves = treedef.flatten_up_to(grads)
-        if any(g is None for g in g_leaves):
-            raise ValueError("adamw_flat requires a gradient for every "
-                             "parameter; use adamw for partial updates")
-        t = state["t"] + 1.0
-        new_p = [None] * len(p_leaves)
-        new_m, new_v = dict(state["m"]), dict(state["v"])
-        for gi, ((shape, dt, wd), idxs) in enumerate(groups):
-            pg = jnp.stack([p_leaves[i] for i in idxs]).astype(jnp.float32)
-            gg = jnp.stack([g_leaves[i] for i in idxs]).astype(jnp.float32)
-            m = beta1 * state["m"][gi] + (1 - beta1) * gg
-            v = beta2 * state["v"][gi] + (1 - beta2) * jnp.square(gg)
-            mhat = m / (1 - beta1 ** t)
-            vhat = v / (1 - beta2 ** t)
-            out = (pg * (1.0 - lr * wd)
-                   - lr * mhat / (jnp.sqrt(vhat) + epsilon)).astype(dt)
-            for k, i in enumerate(idxs):
-                new_p[i] = out[k]
-            new_m[gi], new_v[gi] = m, v
-        return treedef.unflatten(new_p), {"m": new_m, "v": new_v, "t": t}
-
-    def init(params):
-        _, groups = _groups(params)
-        return {
-            "m": {gi: jnp.zeros((len(idxs),) + tuple(shape), jnp.float32)
-                  for gi, ((shape, _dt, _wd), idxs) in enumerate(groups)},
-            "v": {gi: jnp.zeros((len(idxs),) + tuple(shape), jnp.float32)
-                  for gi, ((shape, _dt, _wd), idxs) in enumerate(groups)},
-            "t": jnp.zeros((), jnp.float32),
-        }
-
-    return FunctionalOptimizer(init, update)
-
-
-def from_eager(opt, fused: bool = False) -> FunctionalOptimizer:
-    """Map an eager Optimizer instance to its functional twin.
-
-    fused=True picks the flat cross-parameter AdamW (single launch chain;
-    ~18% of the 134M bench step was per-leaf update launches). Only valid
-    when the optimizer state does NOT need per-parameter placement (ZeRO
-    state sharding keys placements by parameter)."""
+def from_eager(opt) -> FunctionalOptimizer:
+    """Map an eager Optimizer instance to its functional twin."""
     from . import optimizer as eager
 
     if isinstance(opt, eager.AdamW):
-        fn = opt._apply_decay_param_fun
-        if fused:
-            return adamw_flat(opt._beta1, opt._beta2, opt._epsilon, opt._wd,
-                              decay_mask_fn=fn)
         return adamw(opt._beta1, opt._beta2, opt._epsilon, opt._wd,
-                     decay_mask_fn=fn)
-    if fused:
-        raise NotImplementedError(
-            f"fused=True is implemented for AdamW only (got "
-            f"{type(opt).__name__}) — silently falling back would "
-            "misreport any A/B the caller runs")
+                     decay_mask_fn=opt._apply_decay_param_fun)
     if isinstance(opt, eager.Adam):
         return adam(opt._beta1, opt._beta2, opt._epsilon, opt._weight_decay)
     if isinstance(opt, eager.Momentum):

@@ -244,35 +244,3 @@ class TestNewOptimizers:
                                               line_search_fn="strong_wolfe", parameters=ps),
             steps=15, closure_based=True)
         assert losses[-1] < losses[0] * 0.05  # quadratic: LBFGS should crush it
-
-
-def test_adamw_flat_matches_per_leaf():
-    """adamw_flat (stacked multi-tensor update) must be numerically
-    identical to the per-leaf adamw — the fused path is opt-in
-    (from_eager(opt, fused=True)); this pins its parity."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.optimizer import functional as fopt
-
-    paddle.seed(0)
-    model = LlamaForCausalLM(LlamaConfig.tiny())
-    params = {k: v._data for k, v in model.named_parameters_dict().items()}
-    rng = np.random.RandomState(0)
-    grads = {k: jnp.asarray(rng.randn(*p.shape).astype(np.float32) * 0.01)
-             for k, p in params.items()}
-    mask = lambda n: "bias" not in n and "norm" not in n
-
-    eager = paddle.optimizer.AdamW(learning_rate=1e-2, weight_decay=0.01,
-                                   parameters=model.parameters(),
-                                   apply_decay_param_fun=mask)
-    a = fopt.from_eager(eager)
-    b = fopt.from_eager(eager, fused=True)
-    sa, sb = a.init(params), b.init(params)
-    pa, pb = dict(params), dict(params)
-    for _ in range(3):
-        pa, sa = a.update(grads, sa, pa, 1e-2)
-        pb, sb = b.update(grads, sb, pb, 1e-2)
-    worst = max(float(jnp.abs(pa[k] - pb[k]).max()) for k in pa)
-    assert worst < 1e-6, worst
