@@ -36,6 +36,7 @@ __all__ = ["GenerationConfig", "generate", "generate_uncached",
            "kv_cache_write_quant", "paged_kv_cache_write_quant",
            "gather_paged_kv_dequant", "dequantize_kv_buffer",
            "kv_format_of", "kv_cache_bytes_per_token",
+           "latent_cache_width", "latent_cached_attention",
            "eva_virtual_position", "eva_pool_chunks", "eva_summary_write"]
 
 
@@ -159,14 +160,49 @@ def kv_cache_planes(config) -> int:
     return config.num_hidden_layers * getattr(config, "total_ut_steps", 1)
 
 
+def latent_cache_width(config):
+    """Values one cached position holds in each layer of a LATENT cache
+    (multi-head latent attention: the normalised compressed key/value of
+    ``kv_lora_rank`` and the ``qk_rope_head_dim`` rotated key dimensions
+    that every head shares; nothing per head), or None for a model that
+    caches per-head K and V. Everything that sizes or shapes a cache
+    asks here."""
+    rank = getattr(config, "kv_lora_rank", None)
+    return None if rank is None else rank + config.qk_rope_head_dim
+
+
+# a latent pool's last axis is padded to whole lane tiles: the paged
+# kernel contracts over it and slices the value off it on tile bounds.
+# The padding is in the pool's bytes and in no count of what a position
+# costs to read (``kv_cache_bytes_per_token``, the benchmark's rooflines)
+_LATENT_LANES = 128
+
+
+def _latent_pool_width(config) -> int:
+    return -(-latent_cache_width(config) // _LATENT_LANES) * _LATENT_LANES
+
+
+def _refuse_latent_format(kv_format: str):
+    if kv_format != "bf16":
+        raise ValueError(
+            f"kv_format={kv_format!r}: a latent (MLA) cache is stored "
+            f"unquantized; its rotated key dimensions and its latent "
+            f"share one vector and no scale pool is built for it")
+
+
 def kv_cache_bytes_per_token(config, kv_format: str = "bf16",
                              dtype=jnp.float32) -> int:
     """HBM bytes one cached token costs across all planes (K + V values
     plus, for quantized formats, the per-token-per-head f32 absmax
-    scales) — the host-side accounting the capacity benches and the
+    scales; for a latent cache the one vector a layer) — the host-side
+    accounting the capacity benches and the
     ``paddle_tpu_kv_bytes_per_token`` gauge report."""
     from .quantization import intx as _intx
 
+    width = latent_cache_width(config)
+    if width is not None:
+        _refuse_latent_format(kv_format)
+        return width * jnp.dtype(dtype).itemsize * kv_cache_planes(config)
     n_kv = config.num_key_value_heads
     head_dim = config.hidden_size // config.num_attention_heads
     if kv_format == "bf16":
@@ -196,12 +232,22 @@ def make_paged_kv_pools(config, num_blocks: int, block_size: int, dtype,
     — writes quantize in the scatter epilogue, reads dequantize in the
     paged flash-decode prologue (or the XLA gather fallback), so KV HBM
     traffic drops ~2x and everything else (block tables, COW, prefix
-    sharing, preemption) is unchanged."""
+    sharing, preemption) is unchanged.
+
+    A LATENT cache (``latent_cache_width``) is one array ``"c"`` a
+    layer, [num_blocks, block_size, width padded to whole lane tiles]:
+    no kv-heads axis, written and read by
+    ``latent_cached_attention``."""
     from .quantization import intx as _intx
 
+    layers = config.num_hidden_layers
+    if latent_cache_width(config) is not None:
+        _refuse_latent_format(kv_format)
+        return [{"c": jnp.zeros((num_blocks, block_size,
+                                 _latent_pool_width(config)), dtype)}
+                for _ in range(layers)]
     n_kv = config.num_key_value_heads
     head_dim = config.hidden_size // config.num_attention_heads
-    layers = config.num_hidden_layers
     rows = num_blocks * (kv_cache_planes(config) // layers)
     if kv_format != "bf16":
         sdt = _intx.format_dtype(kv_format)  # raises actionably for fp8
@@ -825,6 +871,207 @@ def _chunk_and_step_rows_attention(q, k, v, kv_cache: dict, position_offset,
     return Tensor(out), dict(step[3], chunk_bt=cbt, chunk_valid=valid)
 
 
+# -- the latent (MLA) cache ---------------------------------------------------
+
+def latent_absorb_below(rank: int, d_nope: int, d_v: int) -> int:
+    """Query rows that must share the positions they attend before
+    DECOMPRESSING those positions costs less than attending them in the
+    latent's space. Per query row, position and head the absorbed form
+    multiplies ``2 * rank`` values and the decompressed form ``d_nope +
+    d_v`` (and both the rotated key dimensions); decompressing a position costs ``rank *
+    (d_nope + d_v)`` a head, once for all the rows that share it. Below
+    the ratio (171 at DeepSeek-V2's widths) a call attends absorbed. A
+    pure function of the shapes: nothing to configure."""
+    return -(-rank * (d_nope + d_v) // max(1, 2 * rank - d_nope - d_v))
+
+
+def _latent_write(pool, latent, bt, pos, valid):
+    """Scatter ``latent`` [b, s, width] (zero-padded to the pool's) into
+    ``pool`` [N, bs, padded width] through the block tables; rows past
+    ``valid`` go to the dump block, as ``paged_kv_cache_write``'s do."""
+    n_blocks, bs, pw = pool.shape
+    b, s, w = latent.shape
+    idx = _paged_flat_indices(bt, pos, valid, n_blocks, bs, b, s)
+    new = jnp.pad(latent.astype(pool.dtype), ((0, 0), (0, 0), (0, pw - w)))
+    flat = pool.reshape(n_blocks * bs, pw).at[idx.reshape(-1)].set(
+        new.reshape(b * s, pw))
+    return flat.reshape(pool.shape)
+
+
+def _latent_absorbed(q_nope, q_pe, pool, bt, pos, w_kvb, sm_scale, kernel):
+    """Attention in the latent's space: ``q_abs_h = q_nope_h W_uk_h^T``
+    beside ``q_pe_h`` against the cached vector, the weighted sum of
+    latents through ``W_uv_h``. By the paged kernel over the pool, or in
+    XLA over the dense view under the causal mask."""
+    from .pallas_kernels.decode_attention import \
+        latent_paged_flash_decode_attention
+
+    b, s, heads, dn = q_nope.shape
+    rank, pw = w_kvb.shape[0], pool.shape[-1]
+    q_abs = jnp.einsum("bshd,rhd->bshr", q_nope,
+                       w_kvb[..., :dn].astype(q_nope.dtype))
+    q = jnp.concatenate(
+        [q_abs, q_pe, jnp.zeros((b, s, heads, pw - rank - q_pe.shape[-1]),
+                                q_abs.dtype)], -1)
+    with jax.named_scope(f"mla_absorbed_q{s}"):
+        if kernel:
+            o_lat = latent_paged_flash_decode_attention(
+                q, pool, bt, pos, sm_scale=sm_scale, v_width=rank)
+        else:
+            lat = pool[bt].reshape(b, -1, pw)       # [b, L, pw]
+            sc = jnp.einsum("bshw,bkw->bhsk", q, lat.astype(q.dtype),
+                            preferred_element_type=jnp.float32) * sm_scale
+            mask = _causal_cache_mask(pos, s, lat.shape[1])._data
+            p = jax.nn.softmax(sc + mask, axis=-1)
+            o_lat = jnp.einsum("bhsk,bkr->bshr", p.astype(q.dtype),
+                               lat[..., :rank].astype(q.dtype))
+    return jnp.einsum("bshr,rhd->bshd", o_lat,
+                      w_kvb[..., dn:].astype(o_lat.dtype))
+
+
+# positions a step of the decompressed form decompresses at once
+_DECOMPRESS_POSITIONS = 512
+
+
+def _latent_decompressed(q_nope, q_pe, pool, bt, pos, w_kvb, sm_scale):
+    """Attention on decompressed keys and values: the cached positions
+    come through the table a stretch at a time, ``[k_nope | v] = c_kv
+    W_kvb`` for every head, and an online softmax (float32 statistics)
+    carries the rows over the stretches, as far as the longest row
+    reaches. Position 0 is in the first stretch and every query sees
+    it, so the running maximum is a real score from there on."""
+    b, s, heads, dn = q_nope.shape
+    rank, dr = w_kvb.shape[0], q_pe.shape[-1]
+    dv = w_kvb.shape[-1] - dn
+    bs, nb = pool.shape[1], bt.shape[1]
+    kb = max(1, min(_DECOMPRESS_POSITIONS // bs, nb))
+    span = kb * bs
+    bt = jnp.pad(bt, ((0, 0), (0, -nb % kb)))
+    qpos = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    w = w_kvb.astype(q_nope.dtype)
+
+    def stretch(j, carry):
+        m, l, acc = carry
+        lat = pool[jax.lax.dynamic_slice_in_dim(bt, j * kb, kb, 1)]
+        lat = lat.reshape(b, span, -1).astype(q_nope.dtype)
+        kv = jnp.einsum("bkr,rhd->bkhd", lat[..., :rank], w)
+        sc = (jnp.einsum("bshd,bkhd->bhsk", q_nope, kv[..., :dn],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshd,bkd->bhsk", q_pe, lat[..., rank:rank + dr],
+                           preferred_element_type=jnp.float32)) * sm_scale
+        kpos = j * span + jnp.arange(span, dtype=jnp.int32)
+        seen = kpos[None, None, :] <= qpos[:, :, None]          # [b, s, k]
+        sc = jnp.where(seen[:, None], sc, -1e30)
+        m_new = jnp.maximum(m, sc.max(-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m - m_new)
+        acc = alpha * acc + jnp.einsum(
+            "bhsk,bkhd->bhsd", p.astype(kv.dtype), kv[..., dn:],
+            preferred_element_type=jnp.float32)
+        return m_new, alpha * l + p.sum(-1, keepdims=True), acc
+
+    with jax.named_scope(f"mla_decompressed_q{s}"):
+        reach = jnp.max(pos) + s
+        m, l, acc = jax.lax.fori_loop(
+            0, (reach + span - 1) // span, stretch,
+            (jnp.full((b, heads, s, 1), -1e30, jnp.float32),
+             jnp.zeros((b, heads, s, 1), jnp.float32),
+             jnp.zeros((b, heads, s, dv), jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-30)
+    return jnp.swapaxes(out, 1, 2).astype(q_nope.dtype)
+
+
+def _latent_attend(q_nope, q_pe, pool, bt, pos, w_kvb, sm_scale, family):
+    """``q`` over what the pool holds. Where the paged kernel takes the
+    call (a decode step or a chunk inside its query window, on the
+    chip) it attends absorbed whatever the rows: in head groups the
+    kernel runs at 73% of the MXU's peak, 2.06 ns a query row and
+    position, where the decompressed form's fewer operations are bound
+    by the softmax's own vector work at 2.0-2.7 ns (PERF.md section 6,
+    PR 43). In XLA the shapes decide (``latent_absorb_below``: the
+    absorbed form keeps a whole row of scores, the decompressed one a
+    stretch of positions)."""
+    from .pallas_kernels.decode_attention import decode_dispatch
+
+    s, dn = q_nope.shape[1], q_nope.shape[-1]
+    rank, dv = w_kvb.shape[0], w_kvb.shape[-1] - dn
+    kernel = decode_dispatch(family, paged=True, q_len=s, has_mask=False,
+                             dtype=q_nope.dtype)
+    if kernel or s < latent_absorb_below(rank, dn, dv):
+        return _latent_absorbed(q_nope, q_pe, pool, bt, pos, w_kvb, sm_scale,
+                                kernel)
+    return _latent_decompressed(q_nope, q_pe, pool, bt, pos, w_kvb, sm_scale)
+
+
+def latent_cached_attention(q_nope, q_pe, latent, kv_cache: dict,
+                            position_offset, *, w_kvb, sm_scale: float,
+                            family: str):
+    """Attention of one cached forward over a LATENT cache (multi-head
+    latent attention), ``cached_attention``'s twin: write this call's
+    ``latent`` [b, s, rank + d_rope] (the normalised compressed
+    key/value and the rotated key dimensions all heads share) into
+    ``kv_cache["c"]`` at ``position_offset``, then attend ``q_nope``
+    [b, s, heads, d_nope] and the rotated ``q_pe`` [b, s, heads, d_rope]
+    over what the cache holds up to each query's own position. ``w_kvb``
+    [rank, heads, d_nope + d_v] is the layer's decompression, used on
+    the keys and values (decompressed form) or folded into the query and
+    the output (absorbed form): the same mathematics, and
+    ``_latent_attend`` takes the one that is cheaper for this call. Raw arrays in, ``(out [b, s, heads, d_v],
+    new_cache)`` out; cache plumbing under no_grad, no op of its own on
+    the dispatch surface.
+
+    A paged cache carries ``"bt"`` (and ``"valid"``); a contiguous one
+    ([b, max_len, width]) is a pool whose table is the identity. With
+    ``"chunk_bt"`` the call is the engine's fused step (a batch of
+    single-token rows, the first ``P * C`` of them P prefill chunks; see
+    ``_chunk_and_step_rows_attention``): both parts are written, then
+    each is attended as the prefill program and the decode step attend
+    alone."""
+    raw = lambda x: x._data if isinstance(x, Tensor) else x  # noqa: E731
+    how = dict(w_kvb=w_kvb, sm_scale=sm_scale, family=family)
+    pool = raw(kv_cache["c"])
+    pos = jnp.asarray(raw(position_offset), jnp.int32)
+    if "bt" not in kv_cache:
+        b, max_len, pw = pool.shape
+        bs = next(x for x in (16, 8, 4, 2, 1) if max_len % x == 0)
+        bt = jnp.arange(b * (max_len // bs), dtype=jnp.int32).reshape(b, -1)
+        out, new = latent_cached_attention(
+            q_nope, q_pe, latent,
+            dict(kv_cache, c=pool.reshape(-1, bs, pw), bt=bt),
+            jnp.broadcast_to(pos, (b,)), **how)
+        new = {k: v for k, v in new.items() if k != "bt"}
+        return out, dict(new, c=Tensor(raw(new["c"]).reshape(pool.shape)))
+    bt = jnp.asarray(raw(kv_cache["bt"]), jnp.int32)
+    if "chunk_bt" in kv_cache:
+        cbt = jnp.asarray(raw(kv_cache["chunk_bt"]), jnp.int32)
+        valid = raw(kv_cache["chunk_valid"])
+        P, B = cbt.shape[0], bt.shape[0]
+        n = q_nope.shape[0] - B
+        pos_c, pos_s = pos[:n:n // P], pos[n:]
+
+        def parts(t):
+            return t[:n].reshape((P, n // P) + t.shape[2:]), t[n:]
+
+        (qn_c, qn_s), (qp_c, qp_s), (lat_c, lat_s) = (
+            parts(q_nope), parts(q_pe), parts(latent))
+        # both written before either is read (PERF.md section 6, PR 38)
+        pool = _latent_write(pool, lat_c, cbt, pos_c, valid)
+        pool = _latent_write(pool, lat_s, bt, pos_s, None)
+        with jax.named_scope("_chunk_rows"):
+            out_c = _latent_attend(qn_c, qp_c, pool, cbt, pos_c, **how)
+        with jax.named_scope("_step_rows"):
+            out_s = _latent_attend(qn_s, qp_s, pool, bt, pos_s, **how)
+        out = jnp.concatenate(
+            [out_c.reshape((n, 1) + out_c.shape[2:]), out_s])
+        return out, dict(kv_cache, c=Tensor(pool))
+    valid = kv_cache.get("valid")
+    pos = jnp.broadcast_to(pos, (bt.shape[0],))
+    pool = _latent_write(pool, latent, bt, pos,
+                         None if valid is None else raw(valid))
+    out = _latent_attend(q_nope, q_pe, pool, bt, pos, **how)
+    return out, dict(kv_cache, c=Tensor(pool))
+
+
 def _flash_causal_attention(q, k, v):
     """Causal flash attention of a whole prompt [b, s, h, d] against its
     own ``k``/``v`` [b, s, kv_heads, d]: the heads expanded (the Pallas
@@ -1111,9 +1358,15 @@ def make_kv_caches(config, batch_size: int, max_len: int, dtype,
     [batch_size, max_len, num_key_value_heads, head_dim].
     ``kv_format="int8"``/``"fp8"`` stores narrow values plus
     per-token-per-head absmax scales ``ks``/``vs`` ([b, max_len, n_kv]
-    f32) — the contiguous twin of the quantized paged pools."""
+    f32) — the contiguous twin of the quantized paged pools. A latent
+    cache is ``"c"`` [batch_size, max_len, padded width] a layer."""
     from .quantization import intx as _intx
 
+    if latent_cache_width(config) is not None:
+        _refuse_latent_format(kv_format)
+        return [{"c": jnp.zeros((batch_size, max_len,
+                                 _latent_pool_width(config)), dtype)}
+                for _ in range(config.num_hidden_layers)]
     n_kv = config.num_key_value_heads
     head_dim = config.hidden_size // config.num_attention_heads
     if kv_format != "bf16":

@@ -68,6 +68,7 @@ from .flash_attention import (NEG_INF, VMEM_LIMIT_BYTES, _dot_prec,
 __all__ = ["flash_decode_attention", "flash_decode_enabled",
            "decode_dispatch", "MAX_DECODE_Q_LEN",
            "paged_flash_decode_attention",
+           "latent_paged_flash_decode_attention",
            "MAX_PAGED_Q_LEN", "MAX_SPEC_K", "spec_verify_eligibility",
            "spec_tree_width"]
 
@@ -263,6 +264,13 @@ def _blocks_per_cell(block_size: int, nb: int, kv_heads: int, d: int,
     width), as many as ``_CELL_VMEM_BYTES`` holds. A pure function of
     the shapes the call can see — nothing to configure."""
     item = jnp.dtype(kv_dtype).itemsize
+    if not kv_heads:
+        # a latent pool [N, bs, d]: one buffer of two slots, positions
+        # on the sublanes, and one head's scores
+        per_pos = 2 * d * item + 3 * _round_up(gq, 8) * 4
+        positions = min(max(_CELL_VMEM_BYTES // per_pos // 128 * 128, 128),
+                        512)
+        return max(1, min(positions // block_size, nb))
     # VMEM tiles are 8 x 32-bit sublanes by 128 lanes: a narrow dtype
     # packs more rows a tile, and the kv-heads axis pads up to it
     per_pos = 2 * 2 * _round_up(kv_heads, 32 // item) * d * item
@@ -273,7 +281,8 @@ def _blocks_per_cell(block_size: int, nb: int, kv_heads: int, d: int,
 
 
 def _decode_kernel(lens_ref, bt_ref, q_ref, *refs, bpc: int, sm_scale: float,
-                   q_len: int, group: int, bound, tree: bool):
+                   q_len: int, group: int, bound, tree: bool,
+                   latent: int = 0):
     """One batch row: the row's whole attention in one pass.
 
     The row's cache is walked in cells of ``bpc`` pool blocks, and only
@@ -304,8 +313,15 @@ def _decode_kernel(lens_ref, bt_ref, q_ref, *refs, bpc: int, sm_scale: float,
     MXU matmuls, so the HBM stream is the narrow one. ``q * s / bound``
     in that exact order matches ``quantization.intx.unpack_absmax``
     bitwise, keeping the kernel and the XLA gather fallback
-    interchangeable."""
-    pools, refs = refs[:2], refs[2:]
+    interchangeable.
+
+    ``latent`` (a width; 0: none): ONE pool [N, bs, d] with no kv-heads
+    axis, a latent (MLA) cache that every query head reads whole
+    (``KV`` 1). A position's vector is its key and its first ``latent``
+    columns are its value, so one buffer serves both and ``o``, ``acc``
+    are ``latent`` wide."""
+    n_pools = 1 if latent else 2
+    pools, refs = refs[:n_pools], refs[n_pools:]
     scales = (None, None)
     if bound is not None:
         scales, refs = refs[:2], refs[2:]
@@ -376,7 +392,12 @@ def _decode_kernel(lens_ref, bt_ref, q_ref, *refs, bpc: int, sm_scale: float,
         vis = _visible(length, j * cell, gq=gq, block_k=cell, q_len=q_len,
                        group=group, mask=mask_ref[0] if tree else None)
         q = q_ref[0]                              # [KV, gq, d]
-        k, v = (heads_first(buf, sc, slot, j) for buf, sc in zip(bufs, scales))
+        if latent:
+            k = bufs[0][slot][None]               # [1, cell, d]
+            v = k[..., :latent]
+        else:
+            k, v = (heads_first(buf, sc, slot, j)
+                    for buf, sc in zip(bufs, scales))
         sc = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),   # [KV, gq, cell]
             preferred_element_type=jnp.float32,
@@ -405,7 +426,8 @@ def _decode_kernel(lens_ref, bt_ref, q_ref, *refs, bpc: int, sm_scale: float,
 
 
 def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
-                        k_scale=None, v_scale=None, ancestor_mask=None):
+                        k_scale=None, v_scale=None, ancestor_mask=None,
+                        latent: int = 0):
     """The one attention call behind both cache layouts.
 
     q5 [B, q_len, KV, group, d]; pools [N, bs, KV, d] (scales
@@ -417,6 +439,10 @@ def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
     in-bundle visibility for tree-speculative verify. None compiles the
     causal bundle.
 
+    ``latent`` (0: none): ``kp`` is a latent pool [N, bs, d] with no
+    kv-heads axis and ``vp`` is None; a position's value is the first
+    ``latent`` columns of its key, and the result is ``latent`` wide.
+
     Grid (B,): a row is one grid step, and the rows run in order (the
     fetch of a row's first cell is started by the row before it)."""
     B, q_len, KV, group, d = q5.shape
@@ -426,12 +452,14 @@ def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
     fmt = None if k_scale is None else \
         "int8" if kp.dtype == jnp.int8 else "fp8"
     tree = ancestor_mask is not None
-    bpc = _blocks_per_cell(bs, nb, KV, d, kp.dtype, gq)
+    bpc = _blocks_per_cell(bs, nb, 0 if latent else KV, d, kp.dtype, gq)
     # per-kv-head query bundles as whole [gq, d] tiles: merging q_len
     # into the group axis inside the cell is a sublane relayout Mosaic
     # only takes for group % 8 == 0, so it happens here in XLA (tiny)
     qk = jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(B, KV, gq, d)
-    operands = [lens.astype(jnp.int32), bt.astype(jnp.int32), qk, kp, vp]
+    operands = [lens.astype(jnp.int32), bt.astype(jnp.int32), qk, kp]
+    if not latent:
+        operands.append(vp)
     cells = -(-nb // bpc)
     if fmt is not None:
         # Mosaic cannot slice an HBM ref whose minor dim is under a lane
@@ -451,12 +479,13 @@ def _paged_flash_decode(q5, kp, vp, bt, lens, *, sm_scale: float,
         operands.append(jnp.pad(am, ((0, 0), (0, 0), (0, qp - q_len))))
     return _decode_call(B, q_len, KV, group, d, bs, cells, bpc,
                         jnp.dtype(kp.dtype), jnp.dtype(q5.dtype),
-                        float(sm_scale), fmt, tree, _interpret())(*operands)
+                        float(sm_scale), fmt, tree, _interpret(),
+                        latent)(*operands)
 
 
 @functools.lru_cache(maxsize=64)
 def _decode_call(B, q_len, KV, group, d, bs, cells, bpc, kv_dtype, q_dtype,
-                 sm_scale, fmt, tree, interpret):
+                 sm_scale, fmt, tree, interpret, latent=0):
     """The ``pallas_call`` behind ``_paged_flash_decode``, one object
     for each set of shapes. The object is a jitted (inlined) callable
     that traces ``_decode_kernel`` when it first meets its operands'
@@ -465,38 +494,44 @@ def _decode_call(B, q_len, KV, group, d, bs, cells, bpc, kv_dtype, q_dtype,
     traced it 24 times a program (0.17 s each on the chip's host, a
     third of what ``warmup()`` costs an executable out of the compile
     cache: PERF.md section 6, PR 28). ``fmt`` is the quantized pools'
-    format, or None."""
+    format, or None; ``latent`` the value width of a latent pool
+    [N, bs, d], or 0."""
     from ..quantization.intx import format_bound
 
     gq = q_len * group
+    dv = latent or d
 
     def row(b, *_):
         return (b, 0, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, KV, gq, d), row), hbm, hbm]
+    in_specs = [pl.BlockSpec((1, KV, gq, d), row), hbm] \
+        + ([] if latent else [hbm])
     if fmt is not None:
         in_specs += [pl.BlockSpec((1, cells * bpc * bs, KV),
                                   lambda b, *_: (b, 0, 0))] * 2
     if tree:
         in_specs.append(pl.BlockSpec((1, gq, _round_up(q_len, 128)),
                                      lambda b, *_: (b, 0, 0)))
-    scratch = [pltpu.VMEM((2, bpc * bs, KV, d), kv_dtype)] * 2 + [
+    bufs = [pltpu.VMEM((2, bpc * bs, d), kv_dtype)] if latent \
+        else [pltpu.VMEM((2, bpc * bs, KV, d), kv_dtype)] * 2
+    scratch = bufs + [
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.VMEM((KV, gq, 1), jnp.float32),
         pltpu.VMEM((KV, gq, 1), jnp.float32),
-        pltpu.VMEM((KV, gq, d), jnp.float32),
+        pltpu.VMEM((KV, gq, dv), jnp.float32),
         pltpu.SMEM((1,), jnp.int32)]
     kern = functools.partial(
         _decode_kernel, bpc=bpc, sm_scale=sm_scale, q_len=q_len, group=group,
-        tree=tree, bound=None if fmt is None else format_bound(fmt))
+        tree=tree, bound=None if fmt is None else format_bound(fmt),
+        latent=latent)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B,), in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, KV, gq, d), row),
+            out_specs=pl.BlockSpec((1, KV, gq, dv), row),
             scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((B, KV, gq, d), q_dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, gq, dv), q_dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -658,3 +693,57 @@ def paged_flash_decode_attention(q, k_pool, v_pool, block_table, positions,
     if is_tensor:
         return apply_op("paged_flash_decode_attention", _f, q, k_pool, v_pool)
     return _f(jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool))
+
+
+def latent_paged_flash_decode_attention(q, pool, block_table, positions, *,
+                                        sm_scale: float, v_width: int,
+                                        max_rows=None):
+    """Multi-query attention over a paged LATENT pool (MLA, absorbed
+    form): every query head reads the one vector a position holds.
+
+    q: [B, q_len, heads, d] raw array (q_len <= MAX_PAGED_Q_LEN: a decode
+    step or a prefill chunk), already in the latent's space; pool:
+    [num_blocks, block_size, d], this step's vectors ALREADY scattered;
+    ``block_table`` [B, nb] and ``positions`` [B] as
+    ``paged_flash_decode_attention`` takes them. A position's value is
+    the first ``v_width`` columns of its key, so the pool is streamed
+    once. Returns [B, q_len, heads, v_width] in q's dtype: softmax(q . k
+    * sm_scale) v under the causal rule of the positions. The same
+    kernel as the per-head pools', with one buffer (``latent``)."""
+    B, q_len, H, d = q.shape
+    bt = jnp.asarray(block_table, jnp.int32)
+    pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B,))
+    lens = jnp.minimum(pos + q_len, bt.shape[1] * pool.shape[1])
+    # a chunk's q_len * heads query rows are more than a cell of the
+    # cache is worth streaming for at once (their float32 accumulator
+    # alone is rescaled every cell): the heads go in groups, each a row
+    # of the grid that walks the same cache row
+    groups = _latent_head_groups(q_len, H, max_rows)
+    if groups > 1:
+        hg = H // groups
+        q = jnp.moveaxis(q.reshape(B, q_len, groups, hg, d), 2, 1)
+        q = q.reshape(B * groups, q_len, hg, d)
+        bt, lens = jnp.repeat(bt, groups, 0), jnp.repeat(lens, groups, 0)
+    o = _paged_flash_decode(q.reshape(q.shape[0], q_len, 1, q.shape[2], d),
+                            pool, None, bt, lens, sm_scale=sm_scale,
+                            latent=int(v_width))
+    if groups > 1:
+        o = jnp.moveaxis(o.reshape(B, groups, q_len, H // groups, v_width),
+                         1, 2)
+    return o.reshape(B, q_len, H, v_width)
+
+
+# query rows (q_len * heads of a group) one grid row of the latent kernel
+# attends at once
+_LATENT_ROWS = 2048
+
+
+def _latent_head_groups(q_len: int, heads: int, max_rows=None) -> int:
+    """Groups the heads of a latent bundle part into: the fewest (a
+    power of two dividing ``heads``) that keep ``q_len * heads /
+    groups`` at or under ``max_rows``."""
+    groups = 1
+    while q_len * heads // groups > (max_rows or _LATENT_ROWS) \
+            and heads % (2 * groups) == 0:
+        groups *= 2
+    return groups

@@ -107,7 +107,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..generation import (kv_cache_planes, make_cached_runner,
+from ..generation import (kv_cache_planes, latent_cache_width,
+                          make_cached_runner,
                           make_paged_kv_pools, select_tokens,
                           spec_accept_length, split_key_levels, split_keys)
 from ..observability import recompile as _recompile
@@ -495,6 +496,20 @@ class ServingEngine:
         self._ut_steps = kv_cache_planes(mcfg) // int(mcfg.num_hidden_layers)
         if self._ut_steps > 1:
             self._refuse_for_loop(mcfg, config, draft_model)
+        # a latent cache (MLA: one vector a position and layer, no
+        # kv-heads axis) is the cache protocol's to shape, write and
+        # read; the block pool and the tables see blocks as before
+        if latent_cache_width(mcfg) is not None:
+            self._refuse_for_latent(config, draft_model)
+        # a model with routed experts hands back, with each program's
+        # tokens, what it counted of its routing (``route_stats``); the
+        # host reads the counts an iteration later, behind the tokens
+        # of the step they rode with (``_read_routing``)
+        self._routed = bool(getattr(mcfg, "n_routed_experts", 0))
+        self._route_unread: List[tuple] = []
+        self._route_totals = dict.fromkeys(
+            ("programs", "pairs_here", "pairs", "experts_touched",
+             "load_max"), 0)
         self.draft_model = draft_model
         self.spec = draft_model is not None
         if self.spec:
@@ -734,6 +749,29 @@ class ServingEngine:
                     "a looped stack (total_ut_steps "
                     f"{mcfg.total_ut_steps}) cannot be served with " + why)
 
+    @staticmethod
+    def _refuse_for_latent(config: ServingConfig, draft_model):
+        """The options nobody has made work, and tested, over a latent
+        cache: each is refused with its reason."""
+        refused = [
+            (draft_model is not None,
+             "a draft_model: a verify bundle over the latent pool is not "
+             "built; drop the draft model"),
+            (config.kv_tier,
+             "kv_tier=True: no tier payload of a latent block has been "
+             "tested; drop kv_tier"),
+            (int(config.tp) > 1,
+             f"tp={config.tp}: the latent has no heads axis to shard and "
+             f"the paged kernel cannot be partitioned; serve it with tp=1"),
+            (config.kv_format != "bf16",
+             f"kv_format={config.kv_format!r}: a latent cache is stored "
+             f"unquantized; use kv_format='bf16'"),
+        ]
+        for bad, why in refused:
+            if bad:
+                raise ValueError(
+                    "a latent (MLA) cache cannot be served with " + why)
+
     def _register_memory_components(self):
         """HBM-ledger attribution (``observability.perf.hbm_ledger``):
         the engine owns the KV pools and holds the model weights — the
@@ -936,6 +974,14 @@ class ServingEngine:
                     new.astype(state[name].dtype), mode="drop")
             return token, state
 
+        routed = self._routed
+
+        def _chunk_live(valid):
+            """[P, C] bool: the tokens of a batch of chunks that are
+            real (a routed model leaves the rest out of its experts'
+            work and of its counts)."""
+            return jnp.arange(C, dtype=jnp.int32)[None, :] < valid[:, None]
+
         def _chunk(pb, pools, state, rows):
             """The fixed-shape prefill program: the next chunk of up to
             P prefilling slots as the rows of ``rows`` (packed:
@@ -953,9 +999,13 @@ class ServingEngine:
             bt, ids, f = _unpack_rows(rows, nb, C)
             caches = [dict(c, bt=bt, valid=f["valid"]) for c in pools]
             caches[0]["head_idx"] = f["last_idx"]
+            if routed:
+                caches[0]["live"] = _chunk_live(f["valid"])
             logits, newc = run(pb, ids, caches, f["pos0"])
             token, state = _first_tokens(state, logits[:, 0], f)
             pools_out = [{kk: c[kk] for kk in pool_keys} for c in newc]
+            if routed:
+                token = token, newc[0]["route_stats"]
             return token, pools_out, state
 
         _chunk = _wrap(_chunk, (1, 2), (pb_sh, pool_sh, state_sh, rep),
@@ -988,6 +1038,8 @@ class ServingEngine:
             function (``rows`` absent, or [P, ..])."""
             if rows is None:
                 caches = [dict(c, bt=bt) for c in pools]
+                if routed:
+                    caches[0]["live"] = active[:, None]
                 logits, newc = run(pb, state["tokens"][:, None], caches,
                                    state["pos"])
                 last = logits[:, 0]
@@ -996,6 +1048,9 @@ class ServingEngine:
                 n = rows.shape[0] * C
                 caches = [dict(c, bt=bt, chunk_bt=cbt,
                                chunk_valid=f["valid"]) for c in pools]
+                if routed:
+                    caches[0]["live"] = jnp.concatenate(
+                        [_chunk_live(f["valid"]).reshape(n), active])[:, None]
                 caches[0]["head_pick"] = jnp.concatenate(
                     [jnp.arange(0, n, C, dtype=jnp.int32) + f["last_idx"],
                      n + jnp.arange(B, dtype=jnp.int32)])
@@ -1020,6 +1075,8 @@ class ServingEngine:
                 jnp.int32(0))
             state["keys"] = new_keys
             pools_out = [{kk: c[kk] for kk in pool_keys} for c in newc]
+            if routed:
+                nxt = nxt, newc[0]["route_stats"]
             if rows is None:
                 return nxt, pools_out, state
             # (after the step's own: a slot whose last chunk rides here
@@ -2376,7 +2433,7 @@ class ServingEngine:
             else:
                 token, self._pools, self._state = self._chunk_fn(
                     self._pb, self._pools, self._state, packed)
-        return token, entry
+        return self._note_routing(token, False), entry
 
     def _enqueue_claimed(self, claimed, first):
         """One ``serving.prefill_chunk`` program for ``claimed``, at
@@ -2466,7 +2523,42 @@ class ServingEngine:
                 toks, first, self._pools, self._state = self._step_fn(
                     self._pb, self._pools, self._state, bt_step,
                     any_sampling, active_mask, packed)
-        return toks, first, entry
+        return self._note_routing(toks, True), first, entry
+
+    def _note_routing(self, out, step: bool):
+        """A program's tokens. A model with routed experts returns them
+        with its routing counts (``distributed/moe_serving.ROUTE_STATS``,
+        on the device); those wait in ``_route_unread`` until the tokens
+        of a step enqueued behind them are read."""
+        if not self._routed:
+            return out
+        toks, stats = out
+        self._route_unread.append((step, stats))
+        return toks
+
+    def _read_routing(self, n: int, dispatch_args, prefill_args):
+        """Read the counts of the oldest ``n`` programs (they have run:
+        the step whose tokens were just read was enqueued behind them)
+        into the engine's counters, and into the args of this
+        iteration's ``engine.dispatch`` (the steps among them) and
+        ``engine.prefill`` (the prefill programs), where it has them:
+        a span tells the routing of the programs READ in its iteration,
+        the ones the iteration before enqueued."""
+        from ..distributed.moe_serving import ROUTE_STATS
+
+        read, self._route_unread = (self._route_unread[:n],
+                                    self._route_unread[n:])
+        for args, step in ((dispatch_args, True), (prefill_args, False)):
+            mine = [np.asarray(stats) for was, stats in read if was == step]
+            if not mine:
+                continue
+            got = dict(zip(ROUTE_STATS, np.sum(mine, axis=0).tolist()))
+            self._route_totals["programs"] += len(mine)
+            for key, val in got.items():
+                self._route_totals[key] += val
+            if args is not None:
+                args.update(expert_pairs=got["pairs_here"],
+                            experts_touched=got["experts_touched"])
 
     def _book_chunks(self, ran):
         """The bookkeeping of the rows of programs that are enqueued
@@ -2715,6 +2807,7 @@ class ServingEngine:
             self._last_progress_ts = ph.open("engine.admit") / 1e9
             worked = False
             dispatch_args = None   # of engine.dispatch, while it is open
+            n_route = len(self._route_unread)   # programs run by the wait
             try:
                 self._admit()
                 ph.mark("engine.prefill", ph.on and {
@@ -2895,7 +2988,7 @@ class ServingEngine:
                     return worked   # nothing to read: the pipeline fills
                 worked = True
                 ph.mark("engine.wait", dispatch_args)
-                dispatch_args = None
+                wait_args, dispatch_args = dispatch_args or None, None
                 # this iteration's programs are queued behind the step
                 # in flight: now the host reads. First the first tokens
                 # the iteration before parked (their program ran before
@@ -2905,6 +2998,8 @@ class ServingEngine:
                 # (had that read failed, the step behind it would have
                 # gone with it: a request's tokens arrive in order)
                 self._ahead = enqueued
+                if prev and n_route:
+                    self._read_routing(n_route, wait_args, prefill_args)
                 now_ns = ph.mark("engine.emit") or time.perf_counter_ns()
                 if prev:
                     self._emit_ahead(prev, toks_np, now_ns)
@@ -3000,8 +3095,11 @@ class ServingEngine:
             if step is not None:
                 self._emit_ahead(step, np.asarray(step[0]),
                                  time.perf_counter_ns())
+            if self._route_unread:
+                self._read_routing(len(self._route_unread), None, None)
         except Exception:  # noqa: BLE001 — or_drop: the crash path's own
             del self._parked_tokens[:]
+            del self._route_unread[:]
             if not or_drop:
                 raise
 
@@ -3633,6 +3731,20 @@ class ServingEngine:
             # a looped stack: the passes of every decode step enqueued
             # (the sum of the dispatch spans' ``ut_steps``)
             out["loop_passes"] = self._n_loop_passes
+        if self._routed:
+            # routed experts, over the programs whose counts were read
+            # (``_read_routing``): the chosen (token, expert) pairs of
+            # live rows, summed over the expert layers; those whose
+            # expert is held and was computed here; those left out
+            # because their expert is on another share; held experts
+            # with a pair; and the busiest one's pairs, a layer
+            t = self._route_totals
+            out.update(
+                route_programs=t["programs"], expert_pairs=t["pairs"],
+                expert_pairs_here=t["pairs_here"],
+                expert_pairs_absent=t["pairs"] - t["pairs_here"],
+                experts_touched=t["experts_touched"],
+                expert_load_max=t["load_max"])
         if self._layout is not None:
             # slots that crossed into a new window, the exact-key blocks
             # those rolls gave back, chunks pooled into a summary

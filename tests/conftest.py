@@ -20,6 +20,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "perfbench"))
 import perfbench_tiny_evabyte  # noqa: E402,F401
 import perfbench_tiny_ouro  # noqa: E402,F401
+import perfbench_tiny_deepseek_v2  # noqa: E402,F401
 
 # Unit tests run on the virtual CPU mesh whatever the machine holds.
 # PADDLE_TPU_TEST_PLATFORM=tpu switches to the on-chip lane
@@ -94,6 +95,24 @@ _PINNED_AT_LAST_TWENTY = {
     "test_each_metric_is_data_beside_the_accepted_ones",
     "test_the_ouro_entries_stay_together_where_pr_37_put_them"}
 
+# And the seventh cell (PR 43: a configuration, a tiny configuration and
+# ten per-layer metrics behind PR 41's three):
+# tests/perfbench/test_perfbench_ouro.py (PR 37) pins six cells and four
+# tiny configurations; tests/perfbench/test_perfbench_grad_update.py
+# (PR 41) pins 118 per-layer metrics, its three entries as the last
+# three and PR 40's twenty as the twenty before them (once for each of
+# them, and once more for the Ouro cell's).
+# tests/perfbench/test_perfbench_deepseek_v2.py holds the same facts at
+# the count the manifest has.
+_PINNED_AT_SIX_CELLS = {
+    "test_the_tiny_checkout_holds_the_sixth_cell",
+    "test_the_benchmark_gained_one_configuration_and_one_cell_on_one_chip"}
+_PINNED_AT_LAST_THREE = {
+    "test_each_metric_is_an_entry_behind_the_accepted_ones",
+    "test_the_manifest_with_the_three_entries_meets_the_static_rules",
+    "test_pr_40s_twenty_stand_where_they_stood",
+    "test_the_ouro_entries_stay_together_where_pr_37_put_them"}
+
 
 def pytest_collection_modifyitems(items):
     import pytest
@@ -115,6 +134,12 @@ def pytest_collection_modifyitems(items):
         (_PINNED_AT_LAST_TWENTY, "test_perfbench_stalls.py",
          "asserts its twenty entries are per_layer's last; PR 41 "
          "appended three metrics behind them"),
+        (_PINNED_AT_SIX_CELLS, "test_perfbench_ouro.py",
+         "asserts six cells and four tiny configurations; BENCHMARK.json "
+         "has seven and five since PR 43"),
+        (_PINNED_AT_LAST_THREE, "test_perfbench_grad_update.py",
+         "asserts 118 per-layer metrics whose last three are its own; PR "
+         "43 appended ten behind them"),
     ]
     for item in items:
         for names, file_name, reason in pinned:

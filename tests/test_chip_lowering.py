@@ -52,6 +52,15 @@ EVA_NB, EVA_POOL = 208, 2816
 # 4 x 336 blocks, the step and the [8, 32] prefill program
 LOOP_BUNDLES = {"loop-step": (8, 1), "loop-chunk8x32": (8, 32)}
 LOOP_NB, LOOP_POOL = 42, 4 * 336
+# the latent cell (deepseek-v2-cut): 64 rows of up to 17,408 positions
+# (1,088 table entries) over ONE pool array of 69,632 blocks with no
+# kv-heads axis, 640 wide (the 512 + 64 values of a position padded to
+# lane tiles), 128 query heads on the one vector, the value its first
+# 512 columns; the step and the [8, 32] and [4, 64] prefill programs
+LATENT_BUNDLES = {"latent-step": (64, 1), "latent-chunk8x32": (8, 32),
+                  "latent-chunk4x64": (4, 64)}
+LATENT_NB, LATENT_POOL, LATENT_W, LATENT_V, LATENT_HEADS = (
+    1088, 69632, 640, 512, 128)
 RESNET50 = [(56, 64), (28, 128), (14, 256), (7, 512)]  # (H=W, channels)
 
 
@@ -82,6 +91,19 @@ def _paged(heads, fmt, bundle):
         return decode_attention.paged_flash_decode_attention(
             q, kp, vp, bt, pos, k_scale=ks, v_scale=vs,
             ancestor_mask=rest[-1] if tree else None)
+
+    return fn, args
+
+
+def _latent(bundle):
+    b, q_len = LATENT_BUNDLES[bundle]
+    args = [_s((b, q_len, LATENT_HEADS, LATENT_W), BF16),
+            _s((LATENT_POOL, BS, LATENT_W), BF16),
+            _s((b, LATENT_NB), jnp.int32), _s((b,), jnp.int32)]
+
+    def fn(q, pool, bt, pos):
+        return decode_attention.latent_paged_flash_decode_attention(
+            q, pool, bt, pos, sm_scale=0.1147, v_width=LATENT_V)
 
     return fn, args
 
@@ -146,6 +168,8 @@ CASES = {f"paged-mha32-bf16-{_b}": (_paged, ("mha32", "bf16", _b))
          for _b in EVA_BUNDLES}
 for _b in LOOP_BUNDLES:
     CASES[f"paged-mha16-bf16-{_b}"] = (_paged, ("mha16", "bf16", _b))
+for _b in LATENT_BUNDLES:
+    CASES[f"paged-{_b}"] = (_latent, (_b,))
 for _h in ("mha16", "gqa32_8"):
     for _b in BUNDLES:
         CASES[f"paged-{_h}-bf16-{_b}"] = (_paged, (_h, "bf16", _b))
